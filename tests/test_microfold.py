@@ -380,3 +380,39 @@ def test_classify_warmup_all_warmup_judges_nothing():
     assert out["warmup_intervals"] == 1
     assert out["cadence_frac_steady"] == 1.0
     assert out["tick_block_ms_steady"] == 0.0
+
+
+def test_dispatched_chunk_buffers_are_not_refilled():
+    """An upload returns before the device has the bytes (XLA:CPU aliases
+    an aligned host buffer outright), so the host arrays of a dispatched
+    chunk must never be written again. Reusing one carry buffer put the
+    next chunk's samples under the previous chunk's rows — found by
+    chip_smoke.py as hot-series values under cold series' names."""
+    import jax.numpy as jnp
+
+    from veneur_tpu.ops import microfold as mf
+
+    uploaded = []
+
+    class Ledger:
+        def epoch_h2d(self, host_arr, kind, replicas=1, put=None):
+            uploaded.append(host_arr)
+            return jnp.asarray(host_arr)
+
+        h2d = epoch_h2d
+
+    chunk = 8
+    m = mf.MicroFoldMirror(depth=4, ledger=Ledger(), initial_rows=16,
+                           chunk=chunk)
+    rows = np.arange(chunk, dtype=np.int32)
+    slots = np.zeros(chunk, np.int32)
+    first = np.full(chunk, 1.0, np.float32)
+    m.feed(rows, slots, first, first)        # a full chunk: dispatched
+    sent = [a.copy() for a in uploaded]
+    second = np.full(chunk - 1, 2.0, np.float32)
+    m.feed(rows[:-1], slots[:-1] + 1, second, second)   # refills the carry
+    for before, now in zip(sent, uploaded):
+        np.testing.assert_array_equal(before, now)
+    st = m.finish()
+    np.testing.assert_array_equal(np.asarray(st.vals)[:chunk, 0], first)
+    np.testing.assert_array_equal(np.asarray(st.vals)[:chunk - 1, 1], second)

@@ -85,7 +85,7 @@ def run_phase(w, lock, batch_arrays, qs, seconds: float, overlapped: bool,
                 # the swap resets the pool; real ingest recreates it on
                 # first use (_upsert_histo -> _ensure_histo)
                 w._ensure_histo(series)
-                # jitter values so the relay/runtime can't dedupe work
+                # jitter values so no two dispatches are the same work
                 w._device_histo_step(rows, vals + np.float32(i * 1e-6), wts)
             spans.append((t0, t_acq, time.perf_counter()))
             i += 1
@@ -158,9 +158,6 @@ def main() -> None:
     import jax
 
     backend = jax.default_backend()
-    from veneur_tpu.utils.backend import normalize_backend
-
-    backend = normalize_backend(backend)
     on_cpu = backend == "cpu"
     series = int(os.environ.get(
         "VENEUR_OVERLAP_SERIES", 1 << 16 if on_cpu else 1 << 20))

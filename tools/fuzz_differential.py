@@ -858,9 +858,9 @@ def _git_rev() -> str:
 def _update_tally(path: str, seed: int, per_target: dict[str, int],
                   divergences: list[str]) -> None:
     """Accumulate a round's results into the standing tally artifact
-    (VERDICT r4 item 6: the long-run campaign is a standing gate, its
-    tally committed like BENCH_CACHE so codec parity keeps being hunted
-    after every codec change, not just pinned at a fixed seed)."""
+    (the long-run campaign is a standing gate, its tally committed so
+    codec parity keeps being hunted after every codec change, not just
+    pinned at a fixed seed)."""
     import json
 
     tally = {"total_cases": 0, "runs": 0, "seeds": [], "per_target": {},

@@ -2,7 +2,7 @@
 
 Run on hardware: python tools/profile_ingest.py
 Each stage is jitted separately with a scalar force-read so the timing
-reflects real execution, not dispatch (see bench.py `force` note).
+reflects real execution, not dispatch.
 """
 
 import os

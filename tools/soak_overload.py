@@ -93,7 +93,9 @@ def main() -> None:
                  aggregates=["min", "max", "count"],
                  statsd_listen_addresses=["udp://127.0.0.1:19125"],
                  tpu_native_ingest=True, tpu_native_readers=True,
-                 tpu_compilation_cache_dir="/tmp/veneur_soak_xla_cache",
+                 # a fixed path inside the checkout (the path is part of
+                 # the cache key); JAX_COMPILATION_CACHE_DIR wins over it
+                 tpu_compilation_cache_dir=os.path.join(REPO, ".jax_cache"),
                  num_workers=2, num_readers=2)
     srv = Server(cfg, metric_sinks=[BlackholeMetricSink()])
     # per-flush wall times (the cadence evidence)

@@ -391,10 +391,11 @@ class Config:
     tpu_set_store: str = "staged"
     tpu_initial_histo_rows: int = 4096
     tpu_initial_set_rows: int = 512
-    # persistent XLA compilation cache: first compile of each flush/fold
-    # program shape costs ~20-40s on TPU; with a cache dir set, restarts
-    # (watchdog, fd-handoff upgrades) reuse compiled programs instead of
-    # re-paying it. Empty = disabled.
+    # persistent XLA compilation cache: restarts (watchdog, fd-handoff
+    # upgrades) reuse compiled flush/fold programs instead of re-paying
+    # the first compile per shape. JAX_COMPILATION_CACHE_DIR, where set,
+    # wins over this key; empty = <checkout>/.jax_cache
+    # (utils/backend.place_compilation_cache).
     tpu_compilation_cache_dir: str = ""
     # precompile the flush programs at startup (background thread, first
     # row bucket) so the first real flush doesn't pay the per-shape XLA

@@ -105,14 +105,13 @@ class Server:
                  ) -> None:
         self.config = cfg
         self.interval = cfg.interval_seconds()
-        if cfg.tpu_compilation_cache_dir:
-            # restarts (watchdog, fd-handoff upgrade) reuse compiled
-            # flush/fold programs instead of re-paying the 20-40s
-            # first-compile per shape on TPU
-            import jax as _jax
+        # restarts (watchdog, fd-handoff upgrade) reuse compiled
+        # flush/fold programs instead of re-paying the first compile per
+        # shape; the environment places the cache before the config does
+        from veneur_tpu.utils.backend import place_compilation_cache
 
-            _jax.config.update("jax_compilation_cache_dir",
-                               cfg.tpu_compilation_cache_dir)
+        self.compilation_cache_dir = place_compilation_cache(
+            cfg.tpu_compilation_cache_dir)
         self.hostname = cfg.hostname or (
             "" if cfg.omit_empty_hostname else socket.gethostname())
         self.tags = list(cfg.tags)
@@ -1496,6 +1495,11 @@ class Server:
     def start(self) -> dict[str, int]:
         """Start listeners, sinks and the flush ticker
         (reference Server.Start, server.go:826)."""
+        import jax
+
+        devices = jax.devices()
+        log.info("device: platform=%s kind=%s count=%d",
+                 devices[0].platform, devices[0].device_kind, len(devices))
         if self.config.enable_profiling:
             # XLA-native analog of the reference's profile.Start()
             # (server.go:1392-1399): a JAX profiler trace capturing both
@@ -1721,9 +1725,10 @@ class Server:
             w.flush(qs, interval_s=self.interval)
             log.debug("flush programs warm (first row bucket)")
         except Exception:
-            # warmup is best-effort: a failure only restores the lazy
-            # first-flush compile
-            log.debug("flush warmup failed", exc_info=True)
+            # warmup is best-effort, but not silent: the first real
+            # flush compiles the same programs and meets the same error
+            # where the device guard counts it
+            log.warning("flush warmup failed", exc_info=True)
 
     def sync_native_series_once(self) -> None:
         """One locked new-series adoption sweep across all workers.
@@ -2292,14 +2297,6 @@ class Server:
             self.stats.time_in_nanoseconds(
                 "flush.phase_duration_ns", secs * 1e9,
                 tags=[f"phase:{phase_name.removesuffix('_s')}"])
-        from veneur_tpu.core.worker import DeviceWorker as _DW
-
-        if _DW.pallas_fallbacks:
-            # nonzero means the fused TPU kernel raised and extraction
-            # was demoted to the XLA path for the process lifetime
-            self.stats.count("flush.pallas_fallback_total",
-                             _DW.pallas_fallbacks)
-            _DW.pallas_fallbacks = 0
         # device fault domain telemetry (ops/device_guard.py): the guard
         # counters are lifetime totals — emit deltas, same discipline as
         # the reader/tenant counters above. host_fallbacks counts flushes

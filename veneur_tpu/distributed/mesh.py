@@ -32,17 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # top-level export landed after 0.4.37; same callable either way
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(*args, **kwargs):
-        # the experimental spelling of check_vma (skip the replication-
-        # invariance check) is check_rep
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_old(*args, **kwargs)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veneur_tpu.ops import tdigest as td

@@ -4,8 +4,8 @@ The reference makes the flush deadline existential: a flush that
 outlives `flush_watchdog_missed_flushes` intervals kills the process
 (server.go:948-990). That contract is only honest on hardware that can
 extract the whole pool inside the interval — a CPU-only deployment at
-1M series measures 320s of extraction against a 10s budget
-(E2E_FLUSH_1M_CPU.json). This package replaces hope with governance:
+1M series measured 320s of extraction against a 10s budget. This
+package replaces hope with governance:
 
 - governor.FlushDeadlineGovernor — slices the flush extraction into
   bounded sub-interval chunks (config `flush_chunk_target_ms`) and

@@ -34,8 +34,14 @@ def _build() -> bool:
         subprocess.run(["make", "-C", _NATIVE_DIR],
                        capture_output=True, check=True, timeout=120)
         return True
-    except Exception as e:
-        log.info("native build unavailable: %s", e)
+    except subprocess.CalledProcessError as e:
+        # the libraries are not committed (-march=native): a failed build
+        # on a fresh checkout means the Python parser, and must be seen
+        log.warning("native build failed (%s): %s", e,
+                    e.stderr.decode("utf-8", "replace")[-2000:])
+        return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        log.warning("native build unavailable: %s", e)
         return False
 
 

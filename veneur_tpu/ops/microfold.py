@@ -150,6 +150,14 @@ class MicroFoldMirror:
         self._unsynced = 0
         # carry buffer: the partial-chunk remainder persists across
         # drains so upload totals are partition-invariant
+        self._new_carry()
+
+    def _new_carry(self) -> None:
+        """Fresh host buffers for the next chunk. An upload returns
+        before the device has the bytes (and the CPU backend aliases an
+        aligned host buffer outright), so a dispatched chunk's buffers
+        belong to its scatter: refilling them in place put the next
+        chunk's samples under the previous chunk's rows."""
         self._c_rows = np.empty(self.chunk, np.int32)
         self._c_slots = np.empty(self.chunk, np.int32)
         self._c_vals = np.empty(self.chunk, np.float32)
@@ -177,7 +185,7 @@ class MicroFoldMirror:
             i += take
             if self._c_n == self.chunk:
                 self._dispatch()
-                self._c_n = 0
+                self._new_carry()
 
     def finish(self) -> Optional[MirrorState]:
         """Flush the carry (padded to a full chunk with drop-sentinel
@@ -192,7 +200,7 @@ class MicroFoldMirror:
             self._c_vals[self._c_n:] = 0.0
             self._c_wts[self._c_n:] = 0.0
             self._dispatch()
-            self._c_n = 0
+            self._new_carry()
         state = MirrorState(self._dvals, self._dwts, self.rows_hi,
                             self.samples, self.chunks)
         self._dvals = None

@@ -14,9 +14,9 @@ VMEM pass per row block:
 * all P quantiles and the sum/count aggregates come out of the single load
   of means/weights.
 
-Falls back to the XLA implementation (ops/tdigest.quantile et al.) on
-platforms without Pallas TPU support; tests run the kernel in interpret
-mode.
+Off by default (see supported()); the XLA implementation
+(ops/tdigest.quantile et al.) is the served path. Tests run the kernel
+in interpret mode.
 """
 
 from __future__ import annotations
@@ -35,8 +35,8 @@ DEFAULT_BLOCK_ROWS = 256
 
 def _extract_kernel(means_ref, weights_ref, dmin_ref, dmax_ref, qs_ref,
                     tril_ref, quant_ref, dsum_ref, dcount_ref):
-    # Mosaic lowering constraints, all verified on the real chip by
-    # tools/probe_pallas_minimal.py (interpret mode can't see them):
+    # Mosaic lowering constraints (interpret mode can't see them;
+    # tests/test_tpu_compile.py compiles the kernel for a v5e):
     #   * every ref is rank-2 — rank-1 memrefs don't tile onto the
     #     (sublane, lane) register layout
     #   * no negative static indices (x[:, -1] lowers to dynamic_slice,
@@ -137,41 +137,18 @@ def flush_extract(means, weights, dmin, dmax, qs,
 
 
 def flush_extract_reference(means, weights, dmin, dmax, qs):
-    """The XLA path producing identical outputs (fallback + test oracle)."""
+    """The XLA path producing identical outputs (test oracle)."""
     quant = td.quantile(means, weights, dmin, dmax, qs)
     return quant, td.row_sum(means, weights), td.row_count(weights)
 
 
-def ab_verdict_ok() -> bool:
-    """The A/B gate (TPU_BACKEND.md): the Pallas extract path is only
-    the production default once PALLAS_AB.json proves it on the real
-    target — platform "tpu" AND >=1.0x over XLA. The committed artifact
-    is CPU interpret-mode (0.13x, latency not meaningful), so until an
-    on-chip capture lands, XLA extraction is the default on every
-    backend. VENEUR_PALLAS=1 overrides for benchmarking/bringup;
-    VENEUR_PALLAS=0 force-disables regardless of the artifact."""
-    import json
+def supported() -> bool:
+    """The Pallas extract runs only where it is asked for: on a TPU with
+    VENEUR_PALLAS=1. It is off by default because it has not been timed
+    against the XLA program on a chip (ROADMAP D5 decides whether it
+    stays). Where it is on and fails, DeviceWorker._extract raises."""
     import os
 
-    force = os.environ.get("VENEUR_PALLAS")
-    if force is not None:
-        return force == "1"
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))), "PALLAS_AB.json")
-    try:
-        with open(path) as f:
-            ab = json.load(f)
-    except (OSError, ValueError):
-        return False
-    return (ab.get("platform") == "tpu"
-            and float(ab.get("speedup_pallas_vs_xla", 0.0)) >= 1.0)
-
-
-def supported() -> bool:
-    # if Pallas lowering fails on a real TPU, DeviceWorker._extract
-    # demotes to the XLA path and counts it in
-    # veneur.flush.pallas_fallback_total
     from veneur_tpu.utils.backend import is_tpu_backend
 
-    return is_tpu_backend() and ab_verdict_ok()
+    return os.environ.get("VENEUR_PALLAS") == "1" and is_tpu_backend()
