@@ -313,33 +313,31 @@ class CompileClock(logging.Handler):
                 "programs": progs}
 
 
-def await_ticker(server, tick1: float, k: int,
-                 prev_cold: bool) -> tuple[float, int]:
-    """Wait until the flush ticker is on schedule again; returns the time
-    of the next tick and how many quiet ticks went by. A first flush
-    that compiles can run past its interval; the ticker then fires the
-    ticks it missed back to back, and a window that started among them
-    would be cut at once. Only a cold flush, one in which JAX compiled,
-    may do this: a warm flush that outlasts its interval is a missed
-    window."""
+def await_ticker(server, last: dict, first_tick: float) -> tuple[float, int]:
+    """The time of the next window's tick, and how many quiet ticks went
+    by first. After a warm flush that is one interval after its own
+    tick. A flush that compiled may have run past its interval (a warm
+    one that does has already failed the run); the ticker then fires the
+    ticks it missed back to back, some of them slowly, and a window that
+    started among them would be cut at once. So after a cold flush, wait
+    for a quiet flush that began on the ticker's schedule (the first
+    tick plus whole intervals) and has ended, and start behind it."""
+    if not last["cold"]:
+        return last["tick"] + INTERVAL_S, 0
     fc0 = server.flush_count
+    t_in = time.time()
     while True:
-        fc = server.flush_count
-        nxt = tick1 + fc * INTERVAL_S
-        if time.time() < nxt:
-            if fc == fc0:
-                return nxt, 0
+        began = server.last_flush_unix
+        off = (began - first_tick) % INTERVAL_S
+        if (began > t_in and min(off, INTERVAL_S - off) < 0.05
+                and server.last_emit_unix >= began):
             break
+        if time.time() > t_in + 30 * INTERVAL_S:
+            raise SmokeFailure("the ticker did not resume its schedule")
         time.sleep(0.01)
-    if not prev_cold:
-        raise SmokeFailure(
-            f"window {k - 1}: its flush compiled nothing, outlasted the "
-            f"interval, and {fc - fc0} tick(s) fired late")
-    # start the window on a whole interval: let one more tick pass
-    while server.flush_count == fc:
-        time.sleep(0.01)
-    fc = server.flush_count
-    return tick1 + fc * INTERVAL_S, fc - fc0
+    emit("ticker_resumed", after_flush_s=last["flush_tick_to_sink_s"],
+         waited_s=time.time() - t_in, quiet_flush_s=time.time() - began)
+    return began + INTERVAL_S, server.flush_count - fc0
 
 
 def accepted(server) -> int:
@@ -359,13 +357,12 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
     windows = []
     accepted_before = accepted(server)
     next_tick = t_start + INTERVAL_S  # the ticker starts inside start()
-    tick1 = None
     try:
         for k in range(1, WINDOWS + 1):
             quiet = 0
             if k > 1:
-                next_tick, quiet = await_ticker(
-                    server, tick1, k, windows[-1]["cold"])
+                next_tick, quiet = await_ticker(server, windows[-1],
+                                                windows[0]["tick"])
             fc0 = server.flush_count
             comp0 = clock.read()
             t0 = time.time()
@@ -409,8 +406,6 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
             # the flush of this window: tick -> sink, on the host's clock
             seen = collector.wait_for(k, t_acc, fc0 + 1, server)
             tick = seen["tick"]
-            if k == 1:
-                tick1 = tick
             # _flush_emit rebinds last_flush_phases after the sinks return
             t_wait = time.time() + 5.0
             while server.last_emit_unix < seen["t_seen"]:
@@ -420,6 +415,7 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
             comp = clock.delta(comp0, clock.read())
             win = {
                 "window": k,
+                "tick": tick,
                 # cold: JAX compiled during this window or its flush
                 "cold": comp["n"] > 0,
                 "quiet_ticks_before": quiet,
