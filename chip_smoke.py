@@ -406,7 +406,7 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
             # the flush of this window: tick -> sink, on the host's clock
             seen = collector.wait_for(k, t_acc, fc0 + 1, server)
             tick = seen["tick"]
-            # _flush_emit rebinds last_flush_phases after the sinks return
+            # the flush rebinds last_flush_phases after the sinks return
             t_wait = time.time() + 5.0
             while server.last_emit_unix < seen["t_seen"]:
                 if time.time() > t_wait:
@@ -425,7 +425,11 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
                 "lines_per_s_accepted": traffic.n_lines / (t_acc - t0),
                 "send_slack_s": deadline - t_acc,
                 "flush_tick_to_sink_s": seen["t_seen"] - tick,
-                "flush_phases": dict(server.last_flush_phases),
+                # the phase seconds; the spans behind them are the
+                # benchmark's to read (bench/TRACING.md)
+                "flush_phases": {k: v for k, v in
+                                 server.last_flush_phases.items()
+                                 if k != "spans"},
                 "flush_transfers": dict(server.last_flush_transfers),
                 "micro_folds": server.last_micro_folds,
                 "compile_s": comp["s"],

@@ -439,6 +439,15 @@ struct Ctx {
   long long overload_dropped = 0;  // samples shed at the SoA spill caps
   size_t spill_cap = size_t{1} << 22;  // entries per pending SoA batch
 
+  // What the reader threads homed on this context did with their time
+  // (vn_reader_ns): nanoseconds inside recv, and nanoseconds outside it
+  // (parse + commit + lock wait). Two clock reads per recv of up to a
+  // chunk, nothing per line; lifetime totals, never reset, so a reader
+  // of them takes differences and a reader thread that has exited (one
+  // per TCP connection) leaves its share behind.
+  std::atomic<long long> rd_recv_ns{0};
+  std::atomic<long long> rd_busy_ns{0};
+
   // Commit-path lock contention stats (vn_lock_stats; recorded only
   // while vn_set_lock_stats(1) — the try_lock probe and clock reads cost
   // ~10-20% of per-line budget, so the hot path skips them by default).
@@ -1782,10 +1791,25 @@ struct Reader {
   std::vector<Ctx*> ctxs;
 };
 
+// One recv with the reader's clock around it: the time since the last
+// recv returned is busy, the time inside this one is recv.
+inline ssize_t timed_recv(Ctx* home, int64_t* t_back, int fd, char* buf,
+                          size_t len) {
+  int64_t t0 = now_ns();
+  ssize_t n = recv(fd, buf, len, 0);
+  int64_t t1 = now_ns();
+  home->rd_busy_ns.fetch_add(t0 - *t_back, std::memory_order_relaxed);
+  home->rd_recv_ns.fetch_add(t1 - t0, std::memory_order_relaxed);
+  *t_back = t1;
+  return n;
+}
+
 void reader_loop(Reader* r) {
   std::vector<char> buf(static_cast<size_t>(r->max_len) + 1);
+  int64_t t_back = now_ns();
   while (!r->stop.load(std::memory_order_acquire)) {
-    ssize_t n = recv(r->fd, buf.data(), buf.size(), 0);
+    ssize_t n = timed_recv(r->ctxs[r->home], &t_back, r->fd, buf.data(),
+                           buf.size());
     if (n < 0) {
       if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR)
         continue;  // SO_RCVTIMEO tick: poll the stop flag
@@ -1932,8 +1956,10 @@ void stream_reader_loop(StreamReader* r) {
   std::vector<char> chunk(64 << 10);
   std::string buf;
   bool skipping = false;  // inside an overlong line, waiting for \n
+  int64_t t_back = now_ns();
   while (!r->stop.load(std::memory_order_acquire)) {
-    ssize_t n = recv(r->fd, chunk.data(), chunk.size(), 0);
+    ssize_t n = timed_recv(r->ctxs[r->home], &t_back, r->fd, chunk.data(),
+                           chunk.size());
     if (n < 0) {
       if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR)
         continue;  // SO_RCVTIMEO tick: poll the stop flag
@@ -2160,6 +2186,14 @@ long long vn_overload_dropped(void* p) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> g(ctx->mu);
   return ctx->overload_dropped;
+}
+
+// out[0] = ns the readers homed here spent inside recv, out[1] = ns
+// outside it; lifetime, lock-free.
+void vn_reader_ns(void* p, long long* out) {
+  Ctx* ctx = static_cast<Ctx*>(p);
+  out[0] = ctx->rd_recv_ns.load(std::memory_order_relaxed);
+  out[1] = ctx->rd_busy_ns.load(std::memory_order_relaxed);
 }
 
 void vn_set_spill_cap(void* p, long long cap) {

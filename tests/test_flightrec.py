@@ -1,0 +1,394 @@
+"""The server's span record (core/flightrec.py): the recorder itself,
+the spans a served flush leaves and what last_flush_phases derives from
+them, the names on the device programs, the C++ reader's clock, and the
+lifetime sample tally the swap keeps under the native lock."""
+
+import json
+import socket
+import threading
+import time
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from veneur_tpu.core import flightrec
+from veneur_tpu.core.config import Config
+from veneur_tpu.core.server import Server
+from veneur_tpu.sinks.channel import ChannelMetricSink
+
+PHASE_SPANS = {"swap_s": "flush.begin", "extract_s": "flush.extract",
+               "generate_s": "flush.generate", "sink_flush_s": "emit.sinks"}
+
+
+def _wait_for(predicate, timeout=10.0):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if predicate():
+            return True
+        time.sleep(0.01)
+    return False
+
+
+# -- the recorder ----------------------------------------------------------
+
+def test_spans_nest_by_thread_and_inherit_the_flush_ordinal():
+    rec = flightrec.Recorder()
+    with rec.span("flush", flush=7) as root:
+        with rec.span("flush.extract") as ext:
+            with rec.span("extract.readback", wait=True) as rb:
+                assert rec.current() is rb
+        with rec.span("flush.emit", flush=8) as emit:
+            pass
+    assert (ext.parent, rb.parent, emit.parent) == (root.id, ext.id, root.id)
+    assert (root.flush, ext.flush, rb.flush, emit.flush) == (7, 7, 7, 8)
+    assert root.parent is None and rec.current() is None
+    # children close before their parents; as_list is JSON types only
+    assert [s.name for s in rec.closed()] == [
+        "extract.readback", "flush.extract", "flush.emit", "flush"]
+    assert rb.as_list()[6] == {"wait": True}
+    assert root.t0 <= ext.t0 <= rb.t0 <= rb.t1 <= ext.t1 <= root.t1
+    json.dumps(rec.of_flush(7))
+    assert [s[1] for s in rec.of_flush(8)] == ["flush.emit"]
+
+
+def test_parents_do_not_cross_threads_unless_handed_over():
+    rec = flightrec.Recorder()
+    seen = {}
+
+    def other(parent):
+        with rec.span("micro_fold") as mf:          # its own root
+            with rec.span("micro_fold.feed") as feed:
+                pass
+        with rec.span("emit.sink", parent=parent, sink="x") as sink:
+            pass
+        seen.update(mf=mf, feed=feed, sink=sink)
+
+    with rec.span("flush", flush=3) as root:
+        t = threading.Thread(target=other, args=(root,))
+        t.start()
+        t.join()
+    assert seen["mf"].parent is None and seen["mf"].flush is None
+    assert seen["feed"].parent == seen["mf"].id
+    assert seen["sink"].parent == root.id and seen["sink"].flush == 3
+
+
+def test_the_ring_is_bounded_and_add_sums_into_the_open_span():
+    rec = flightrec.Recorder(capacity=16)
+    for i in range(100):
+        with rec.span("s", flush=i):
+            rec.add("bytes", 3)
+            rec.add("bytes", 4)
+    kept = rec.closed()
+    assert len(kept) == 16 and kept[-1].flush == 99 and kept[0].flush == 84
+    assert kept[0].attrs == {"bytes": 7}
+    assert rec.last("s") is kept[-1] and rec.last("nope") is None
+    rec.add("bytes", 1)  # no span open: dropped, not raised
+
+
+def test_a_span_that_raises_closes_and_says_so():
+    rec = flightrec.Recorder()
+    with pytest.raises(ValueError):
+        with rec.span("flush", flush=1):
+            with rec.span("flush.extract"):
+                raise ValueError("boom")
+    by = {s.name: s for s in rec.closed()}
+    assert by["flush.extract"].attrs["error"] == "ValueError"
+    assert by["flush"].attrs["error"] == "ValueError"
+    assert by["flush"].t1 >= by["flush.extract"].t1 > 0
+    assert rec.current() is None
+
+
+# -- a served flush --------------------------------------------------------
+
+def _served(**kw):
+    cfg = Config(**{**dict(
+        statsd_listen_addresses=["tcp://127.0.0.1:0"], num_workers=1,
+        num_readers=1, interval="10s", percentiles=[0.5, 0.99],
+        tpu_native_ingest=True, micro_fold_rows=1,
+        micro_fold_max_age_s=0.02), **kw})
+    sink = ChannelMetricSink()
+    srv = Server(cfg, metric_sinks=[sink])
+    ports = srv.start()
+    return srv, sink, next(iter(ports.values()))
+
+
+def _lines(n_series=50, per=8):
+    out = []
+    for i in range(n_series):
+        out += [f"fr.t{i}:{v + 1}|ms" for v in range(per)]
+        out += [f"fr.c{i}:2|c", f"fr.g{i}:1.5|g", f"fr.s:{i}|s"]
+    return ("\n".join(out) + "\n").encode(), len(out)
+
+
+def _by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s[1], []).append(s)
+    return out
+
+
+def test_a_served_flush_leaves_its_spans_and_the_old_phase_keys():
+    srv, sink, port = _served()
+    if not srv.native_mode:
+        srv.shutdown()
+        pytest.skip("native ingest library unavailable")
+    try:
+        payload, n = _lines()
+        with socket.create_connection(("127.0.0.1", port)) as s:
+            s.sendall(payload)
+            assert _wait_for(
+                lambda: srv.ingress_stats()["samples_processed"] >= n)
+            # the scheduler thread drains what was staged: a micro-fold
+            assert _wait_for(lambda: srv.workers[0].micro_folds_epoch > 0)
+            srv.flush()
+        phases = srv.last_flush_phases
+        spans = phases["spans"]
+        json.dumps(spans)
+        by = _by_name(spans)
+        ordinal = srv.flush_count
+        assert {s[5] for s in spans} == {ordinal}
+
+        # every old key is the length of its span
+        for key, name in PHASE_SPANS.items():
+            (sp,) = by[name]
+            assert phases[key] == pytest.approx(sp[3] - sp[2], abs=1e-9)
+        assert phases["drain_s"] == pytest.approx(sum(
+            s[3] - s[2] for s in spans
+            if s[1] in ("swap.drain.residual", "swap.mirror_handoff")))
+        assert set(phases) >= set(PHASE_SPANS) | {"drain_s", "spans"}
+        # and ingress_stats keeps handing on numbers only
+        assert "spans" not in srv.ingress_stats()["last_flush_phases"]
+        assert srv.ingress_stats()["last_tick_s"] == pytest.approx(
+            by["flush"][0][3] - by["flush"][0][2])
+
+        # the tree: flush -> phases -> their children, on one thread
+        (root,) = by["flush"]
+        for name in ("flush.begin", "flush.extract", "flush.generate",
+                     "flush.emit"):
+            assert by[name][0][4] == root[0]
+        begin, extract = by["flush.begin"][0][0], by["flush.extract"][0][0]
+        assert by["swap.lock_wait"][0][4] == begin
+        (swap,) = by["swap"]
+        assert swap[4] == begin
+        for name in ("swap.drain", "swap.spill_fold", "swap.handoff",
+                     "swap.reset"):
+            assert all(s[4] == swap[0] for s in by[name]), name
+        assert by["swap.drain.residual"][0][4] == by["swap.drain"][0][0]
+        for name in ("extract.mirror_fold", "extract.quantiles",
+                     "extract.readback", "extract.unpack", "extract.sets",
+                     "extract.guard_tick"):
+            assert by[name][0][4] == extract, name
+        assert by["extract.readback"][0][6] == {"wait": True}
+        (sinks,) = by["emit.sinks"]
+        assert sinks[4] == by["flush.emit"][0][0]
+        assert [(s[4], s[6]["sink"]) for s in by["emit.sink"]] == [
+            (sinks[0], "channel")]
+
+        # the ingest side of the epoch: micro-folds on the scheduler's
+        # thread and adoption wherever it ran, under the same ordinal
+        assert by["micro_fold"] and by["adopt"]
+        mf = next(m for m in by["micro_fold"] if m[6]["samples"] > 0)
+        assert mf[4] is None and mf[6]["rows"] > 0
+        kids = {s[1] for s in spans if s[4] == mf[0]}
+        assert kids == {"micro_fold.lock_wait", "micro_fold.drain",
+                        "micro_fold.feed"}
+        assert sum(s[6]["series"] for s in by["adopt"]
+                   + by.get("swap.adopt", [])) == 50 * 3 + 1
+
+        # device dispatches take their span at the guard's seam; the
+        # fold's and the extract's say how many bytes they had to move
+        ops = {s[6]["op"]: s for s in by["dispatch"]}
+        assert {"micro", "staged", "extract"} <= set(ops)
+        s_eff, depth = 1024, srv.config.tpu_stage_depth
+        pool = 2 * s_eff * 128 * 4 + 12 * s_eff * 4
+        assert ops["staged"][6]["bytes"] == 2 * pool + 2 * s_eff * depth * 4
+        assert ops["staged"][4] == by["extract.mirror_fold"][0][0]
+        assert ops["extract"][6]["bytes"] > pool
+
+        # a second flush files under the next ordinal
+        srv.flush()
+        assert {s[5] for s in srv.last_flush_phases["spans"]} == {ordinal + 1}
+    finally:
+        srv.shutdown()
+
+
+def test_a_flush_that_raises_closes_its_spans(monkeypatch):
+    srv, sink, port = _served(tpu_native_ingest=False)
+    try:
+        srv.process_metric_packet(b"fr.boom:1|ms")
+        before = srv.last_flush_phases
+
+        def boom(job):
+            raise RuntimeError("generate failed")
+
+        monkeypatch.setattr(srv, "_flush_generate_batch", boom)
+        with pytest.raises(RuntimeError):
+            srv.flush()
+        assert srv.last_flush_phases is before  # nothing half-finished
+        by = {s.name: s for s in srv.rec.closed()
+              if s.flush == srv.flush_count}
+        assert by["flush.generate"].attrs["error"] == "RuntimeError"
+        assert by["flush"].attrs["error"] == "RuntimeError"
+        assert by["flush"].t1 >= by["flush.generate"].t1 > 0
+        assert "error" not in by["flush.extract"].attrs
+        assert srv.rec.current() is None
+        monkeypatch.undo()
+        srv.process_metric_packet(b"fr.boom:2|ms")
+        srv.flush()
+        assert "flush.emit" in {s[1] for s in srv.last_flush_phases["spans"]}
+    finally:
+        srv.shutdown()
+
+
+def test_a_pipelined_flush_publishes_its_phases_from_the_emit_stage():
+    srv, sink, port = _served(tpu_native_ingest=False, flush_pipeline=True,
+                              interval="1s")
+    try:
+        srv.process_metric_packet(b"fr.p:1|ms")
+        assert _wait_for(lambda: "spans" in srv.last_flush_phases
+                         and srv.last_flush_phases.get("sink_flush_s"), 15)
+        phases = srv.last_flush_phases
+        by = _by_name(phases["spans"])
+        # no root: each phase is the root of its own stage thread
+        assert "flush" not in by
+        for key, name in PHASE_SPANS.items():
+            (sp,) = by[name]
+            assert sp[4] is None or name == "emit.sinks"
+            assert phases[key] == pytest.approx(sp[3] - sp[2], abs=1e-9)
+        assert "late_s" in by["flush.begin"][0][6]
+    finally:
+        srv.shutdown()
+
+
+# -- names on the device ---------------------------------------------------
+
+def _fold_args(rows=256, depth=8):
+    f32 = jnp.float32
+    two = [jnp.zeros((rows, 128), f32)] * 2
+    return two + [jnp.zeros((rows,), f32)] * 12, depth
+
+
+def _lowered_text(name):
+    from veneur_tpu.core import worker as wk
+    from veneur_tpu.ops import hll, microfold as mf
+
+    fields, depth = _fold_args()
+    rows = fields[0].shape[0]
+    plane = jnp.zeros((rows, depth), jnp.float32)
+    i32 = jnp.zeros((64,), jnp.int32)
+    f32v = jnp.zeros((64,), jnp.float32)
+    regs = jnp.zeros((16, 1 << 14), jnp.int8)
+    lowered = {
+        "fold_staged": lambda: wk._histo_fold_staged.lower(
+            *fields, plane, plane, compression=100.0),
+        "ingest_step": lambda: wk._histo_ingest_step.lower(
+            *fields, i32, jnp.zeros((256,), jnp.int32),
+            jnp.zeros((256,), jnp.float32), jnp.zeros((256,), jnp.float32),
+            compression=100.0),
+        "flush_extract": lambda: wk._histo_flush_extract.lower(
+            *fields, jnp.asarray([0.5, 0.99], jnp.float32)),
+        "pack_extract": lambda: wk._pack_extract_columns.lower(
+            jnp.zeros((rows, 2), jnp.float32),
+            *[jnp.zeros((rows,), jnp.float32)] * 10),
+        "scatter_chunk": lambda: mf._scatter_chunk.lower(
+            plane, plane, i32, i32, f32v, f32v),
+        "hll_estimate": lambda: hll.estimate.lower(regs, 14),
+        "hll_insert": lambda: hll.insert_batch.lower(
+            regs, i32, i32, jnp.zeros((64,), jnp.int8)),
+    }[name]()
+    # the compiled program's own text carries op_name metadata
+    return lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("fold_staged", ["fold_staged.row_stats", "fold_staged.merge",
+                     "tdigest.compress.sort", "tdigest.compress.scan",
+                     "tdigest.k_bucket", "tdigest.compress.merge",
+                     "segments.last_marked_carry",
+                     "tdigest.compress.resort", "fold_staged.scalars"]),
+    ("ingest_step", ["ingest_step.gather", "tdigest.add_batch.sort",
+                     "tdigest.prefix_scans", "segments.segmented_cumsum",
+                     "tdigest.add_batch.row_stats",
+                     "tdigest.add_batch.batch_digest", "tdigest.k_bucket",
+                     "tdigest.compress.sort", "tdigest.compress.merge",
+                     "ingest_step.scatter"]),
+    ("flush_extract", ["tdigest.quantile", "flush_extract.sums"]),
+    ("pack_extract", ["pack_extract"]),
+    ("scatter_chunk", ["microfold.scatter"]),
+    ("hll_estimate", ["hll.estimate"]),
+    ("hll_insert", ["hll.insert.sort", "hll.insert.scatter_max"]),
+])
+def test_every_scope_is_named_in_its_compiled_program(program, scopes):
+    text = _lowered_text(program)
+    missing = [s for s in scopes if s + "/" not in text]
+    assert not missing, missing
+
+
+# -- the C++ reader's clock ------------------------------------------------
+
+def test_the_reader_counter_rises_with_traffic_and_fits_the_wall_clock():
+    srv, sink, port = _served()
+    if not srv.native_mode:
+        srv.shutdown()
+        pytest.skip("native ingest library unavailable")
+    try:
+        t_open = time.time()
+        payload, n = _lines(200, 16)
+        with socket.create_connection(("127.0.0.1", port)) as s:
+            time.sleep(0.3)                      # idle: inside recv
+            recv0, busy0 = srv._reader_ns()
+            s.sendall(payload)
+            assert _wait_for(
+                lambda: srv.ingress_stats()["samples_processed"] >= n)
+            time.sleep(0.05)
+            s.sendall(b"fr.last:1|c\n")          # ends the recv in flight
+            assert _wait_for(lambda: srv._reader_ns()[1] > busy0)
+            recv1, busy1 = srv._reader_ns()
+        wall_ns = (time.time() - t_open) * 1e9
+        assert recv0 >= 0 and busy0 >= 0
+        assert busy1 > busy0 and recv1 >= recv0 + 0.2e9
+        # one reader thread: its two shares cannot outrun the wall clock
+        assert recv1 + busy1 <= wall_ns
+        stats = srv.workers[0].reader_stats()
+        assert stats["recv_ns"][0] >= recv1 and stats["busy_ns"][0] >= busy1
+        srv.flush()
+        (begin,) = [s for s in srv.last_flush_phases["spans"]
+                    if s[1] == "flush.begin"]
+        assert begin[6]["reader_busy_ns"] >= busy1
+        assert begin[6]["reader_recv_ns"] >= recv1
+    finally:
+        srv.shutdown()
+
+
+# -- the lifetime tally ----------------------------------------------------
+
+def test_a_line_committed_just_before_the_swap_takes_the_lock_is_tallied():
+    from veneur_tpu.core.worker import DeviceWorker
+
+    w = DeviceWorker(stage_depth=8)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    w.ingest_datagram(b"pt.a:1|c\npt.b:2|ms")
+    native = w._native
+    lock = native.lock
+    committed = []
+
+    def lock_after_a_commit():
+        # a reader commits between the top of swap() and its lock
+        if not committed:
+            committed.append(native.ingest(b"pt.a:5|c\npt.c:1|g"))
+        lock()
+
+    native.lock = lock_after_a_commit
+    try:
+        snap = w.flush(np.asarray([0.5], np.float32))
+    finally:
+        del native.lock
+    assert committed == [2]
+    assert w.processed_total == 4 and w.processed == 0
+    assert float(snap.scalars.counter_values[0]) == 6.0
+    # and the next epoch starts from nothing
+    w.ingest_datagram(b"pt.a:1|c")
+    w.flush(np.asarray([0.5], np.float32))
+    assert w.processed_total == 5

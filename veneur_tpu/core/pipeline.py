@@ -67,6 +67,11 @@ class FlushJob:
     """
 
     seq: int = 0
+    # Server.flush_count of this flush: what its spans are filed under
+    ordinal: int = 0
+    # a serial flush holds the record's root span open around the four
+    # phases and publishes the phases itself, once the root has closed
+    rooted: bool = False
     ts: int = 0
     flush_start: float = 0.0
     qs: Any = None

@@ -24,7 +24,7 @@ import time
 from typing import Callable, Optional
 
 from veneur_tpu import __version__
-from veneur_tpu.core import crash
+from veneur_tpu.core import crash, flightrec
 from veneur_tpu.core.config import Config, parse_duration
 from veneur_tpu.core.flusher import device_quantiles, generate_inter_metrics
 from veneur_tpu.core.metrics import HistogramAggregates, InterMetric
@@ -142,6 +142,16 @@ class Server:
             for _ in range(cfg.num_workers)
         ]
         self._worker_locks = [threading.Lock() for _ in self.workers]
+        # the one span record of this server (core/flightrec.py): flush
+        # phases and their children, micro-folds, series adoption and
+        # every guarded device dispatch, on the time.time() clock.
+        # last_flush_phases is derived from it at the end of each flush
+        self.rec = flightrec.Recorder()
+        for w in self.workers:
+            w.set_recorder(self.rec)
+        # when the ticker meant to fire the tick it is firing (late_s of
+        # flush.begin); None for a flush() called by hand
+        self._tick_due: Optional[float] = None
         # device fault domain bookkeeping: last guard fault seen per
         # worker (so each new classified fault reaches the governor's
         # watchdog verdict exactly once) and the lifetime guard-counter
@@ -311,16 +321,15 @@ class Server:
         # when the most recent flush finished sink emission (== the tick
         # time on the serial path; trails it under the stage pipeline)
         self.last_emit_unix = 0.0
-        self.last_flush_phases: dict[str, float] = {}
+        # the last COMPLETED flush: its phase seconds (sums of the span
+        # record's spans) and, under "spans", the spans themselves
+        # (flightrec.Recorder.of_flush); read by bench/readers/
+        self.last_flush_phases: dict = {}
         # per-flush transfer-ledger totals and chunk report (health/),
-        # read by tools/bench_e2e_flush.py alongside the phase times
+        # read by bench/readers/ alongside the phase times
         self.last_flush_transfers: dict[str, int] = {}
         self.last_flush_chunks: dict = {}
         self.flush_count = 0
-        # wall time the last flush tick held the ticker thread: the
-        # serial flush duration, or (pipelined) just the swap+enqueue —
-        # the cadence decomposition the loadgen controller reports
-        self.last_tick_s = 0.0
         # stage-parallel flush executor (core/pipeline.py): extract,
         # generate and emit for successive intervals overlap on
         # dedicated stage threads while the tick stays a cheap swap.
@@ -426,7 +435,6 @@ class Server:
         # mode and honors the VENEUR_READER_SHARDS=0 legacy hatch.
         self._reader_shards = 0
         self._lock_stats_enabled = False
-        self.last_reader_stats = None
         if self.native_mode:
             from veneur_tpu.core.config import resolve_reader_shards
 
@@ -571,11 +579,16 @@ class Server:
             "flush_count": self.flush_count,
             "last_flush_unix": self.last_flush_unix,
             "last_emit_unix": self.last_emit_unix,
-            "last_flush_phases": dict(self.last_flush_phases),
-            # how long the last flush tick held the ticker thread: the
-            # ingest-stall component of the cadence decomposition (the
-            # loadgen controller reports it per interval)
-            "last_tick_s": self.last_tick_s,
+            # the phase seconds alone: the spans ride in
+            # last_flush_phases["spans"] for the benchmark's readers
+            "last_flush_phases": {k: v for k, v in
+                                  self.last_flush_phases.items()
+                                  if k != "spans"},
+            # how long the last flush tick held the ticker thread (the
+            # whole serial flush; pipelined, the swap + enqueue): the
+            # ingest-stall component of the cadence decomposition the
+            # loadgen controller reports per interval
+            "last_tick_s": self._last_tick_s(),
             # always-hot flush: lifetime micro-fold drains plus the last
             # closed interval's count (the controller's per-interval
             # micro_folds is a delta of the lifetime tally)
@@ -604,6 +617,11 @@ class Server:
         if self.shutdown_stats:
             out["shutdown"] = dict(self.shutdown_stats)
         return out
+
+    def _last_tick_s(self) -> float:
+        sp = self.rec.last(
+            "flush" if self.flush_pipeline is None else "flush.begin")
+        return sp.seconds if sp is not None else 0.0
 
     def _span_stats(self) -> dict:
         """Span conservation for the loadgen controller. On the columnar
@@ -1775,8 +1793,7 @@ class Server:
             for i, worker in enumerate(self.workers):
                 try:
                     if worker.micro_fold_due():
-                        with self._worker_locks[i]:
-                            worker.micro_fold_once()
+                        self._micro_fold(i, worker)
                 except Exception:
                     if self._shutdown.is_set():
                         return
@@ -1786,6 +1803,24 @@ class Server:
                     self.stats.count("micro_fold.errors_total", 1,
                                      tags=[f"worker:{i}"])
                     log.exception("micro-fold drain failed (worker %d)", i)
+
+    def _micro_fold(self, i: int, worker) -> None:
+        """One micro-fold of one worker, as a span: how long it waited
+        for the ingest lock, and how long it then held it against the
+        readers (micro_fold minus micro_fold.lock_wait)."""
+        lock = self._worker_locks[i]
+        with self.rec.span("micro_fold", worker=i) as sp:
+            with self.rec.span("micro_fold.lock_wait") as lw:
+                lock.acquire()
+            try:
+                # the epoch is known only under the lock: a swap may have
+                # closed one while this thread waited
+                sp.flush = lw.flush = worker.flight_epoch
+                sp.attrs["samples"] = int(worker.micro_fold_once())
+                sp.attrs["rows"] = int(
+                    getattr(worker._micro, "rows_hi", 0))
+            finally:
+                lock.release()
 
     def _flush_loop(self) -> None:
         """Interval ticker, optionally aligned to the wall clock
@@ -1799,10 +1834,11 @@ class Server:
             if delay > 0 and self._shutdown.wait(delay):
                 return
             try:
+                self._tick_due = next_tick
                 _t0 = time.perf_counter()
                 if self.flush_pipeline is not None:
                     outcome = self.flush_pipeline.tick()
-                    self.last_tick_s = time.perf_counter() - _t0
+                    tick_s = time.perf_counter() - _t0
                     if outcome == "ok":
                         # growth only: under overlap a stage may run
                         # most of the interval and still keep pace, so
@@ -1813,15 +1849,15 @@ class Server:
                         # shedding 94k lines of a 2.7M-line confirm run
                         # that the pipeline was absorbing fine.
                         self._adapt_spill_caps(
-                            max(self.last_tick_s,
-                                self.flush_pipeline.last_cycle_s),
+                            max(tick_s, self.flush_pipeline.last_cycle_s),
                             allow_shrink=False)
                 else:
                     self.flush()
-                    self.last_tick_s = time.perf_counter() - _t0
-                    self._adapt_spill_caps(self.last_tick_s)
+                    self._adapt_spill_caps(time.perf_counter() - _t0)
             except Exception:
                 log.exception("flush failed")
+            finally:
+                self._tick_due = None
 
     def _pipeline_overrun(self) -> None:
         """A flush-pipeline stage fell a full interval behind (deferred
@@ -1890,8 +1926,20 @@ class Server:
         # must run even when a phase raises
         self.flush_governor.begin_flush()
         try:
+            # the tracer's span is the operator's export and rejoins the
+            # span pipeline; the recorder's, of the same name, is the
+            # root of this flush in the span record and rejoins nothing
             with self.tracer.start_span("flush"):
-                return self._flush_inner(now=now)
+                with self.rec.span("flush", flush=self.flush_count + 1):
+                    job = self._flush_inner(now=now)
+                # after the root has closed, so that the record handed
+                # on (last_flush_phases["spans"]) holds it
+                self._flush_publish(job)
+            if job.batch is not None:
+                # columnar flush: the batch supports len(); callers
+                # needing objects use .materialize()
+                return job.batch
+            return job.final
         finally:
             self.flush_governor.end_flush()
 
@@ -1901,14 +1949,11 @@ class Server:
         # stage threads with up to an interval of overlap between them,
         # which is what keeps pipelined output bit-identical to this path
         job = self._flush_begin(now=now)
+        job.rooted = True
         self._flush_extract(job)
         self._flush_generate(job)
         self._flush_emit(job)
-        if job.batch is not None:
-            # columnar flush: the batch supports len(); callers needing
-            # objects use .materialize()
-            return job.batch
-        return job.final
+        return job
 
     def _flush_begin(self, now: float | None = None):
         """Tick-side flush phase: epoch close + device dispatches under
@@ -1923,16 +1968,47 @@ class Server:
         flush_start = time.time() if now is None else float(now)
         self.last_flush_unix = flush_start
         self.flush_count += 1
+        ordinal = self.flush_count
         self.stats.gauge("flush.flush_timestamp_ns", flush_start * 1e9)
         # per-phase wall times of this flush (reference tallyMetrics/
-        # generateInterMetrics timing samples, flusher.go:169-298);
-        # read by tools/bench_e2e_flush.py for the 1M-series artifact.
-        # last_flush_phases rebinds only when _flush_emit COMPLETES:
-        # observers polling mid-flush (the loadgen cadence decomposition)
-        # must see the last finished flush, not a half-filled dict
-        phases: dict[str, float] = {}
-        _t = time.perf_counter()
+        # generateInterMetrics timing samples, flusher.go:169-298), each
+        # the length of a span of the record (core/flightrec.py).
+        # last_flush_phases rebinds only when the flush COMPLETES
+        # (_flush_publish): observers polling mid-flush (the loadgen
+        # cadence decomposition) must see the last finished flush, not a
+        # half-filled dict
+        phases: dict = {}
+        begin = self.rec.span("flush.begin", flush=ordinal)
+        if self._tick_due is not None and now is None:
+            # fired minus scheduled: the ticker was held by the flush
+            # before, and that flush owes this one the time
+            begin.attrs["late_s"] = flush_start - self._tick_due
+        with begin:
+            qs, swapped, span_counts = self._flush_begin_swap(ordinal)
+        phases["swap_s"] = begin.seconds
+        # always-hot flush decomposition: how many micro-folds streamed
+        # the closed epoch to the device mirrors, and how much of the
+        # swap above was the final residual drain + mirror handoff (the
+        # loadgen controller reports both per interval as micro_folds /
+        # drain_ms)
+        micro_folds = sum(getattr(w, "micro_folds_swapped", 0)
+                          for w in self.workers)
+        phases["drain_s"] = sum(
+            sp.seconds for sp in self.rec.closed()
+            if sp.flush == ordinal and sp.name in (
+                "swap.drain.residual", "swap.mirror_handoff"))
+        self.last_micro_folds = micro_folds
+        if micro_folds:
+            self.stats.count("worker.micro_folds_total", micro_folds)
+        self.flush_governor.beat()  # swap complete: flush is live
+        return FlushJob(ordinal=ordinal, ts=int(flush_start),
+                        flush_start=flush_start, qs=qs, swapped=swapped,
+                        span_counts=span_counts, phases=phases)
 
+    def _flush_begin_swap(self, ordinal: int):
+        """The body of flush.begin: drain what is buffered beside the
+        workers, then close every worker's epoch under its ingest lock.
+        Returns (quantiles, swapped epochs, per-service span counts)."""
         if self.native_mode:
             # events/service checks buffered in C++ (native readers have
             # no Python on the datagram path; the pump drains every 100ms
@@ -1979,77 +2055,15 @@ class Server:
         swapped = []
         for i, (worker, lock) in enumerate(
                 zip(self.workers, self._worker_locks)):
-            with lock:
-                if i == 0 and self._native_ssf:
-                    # drained in the SAME lock hold as the worker swap —
-                    # the swap resets the C++ context, and a span landing
-                    # between a separate drain and the reset would lose
-                    # its service count
-                    for svc, n in (
-                            worker._native.drain_ssf_services().items()):
-                        span_counts[svc] = span_counts.get(svc, 0) + n
-                        # native-extracted spans derive on device and
-                        # never pass handle_ssf: fold them into the
-                        # conservation tallies here (same lock hold as
-                        # the context reset, so none are lost mid-swap)
-                        self._spans_native_total += n
-                # canonical per-worker tallies (README.md:292-294),
-                # captured before flush resets the epoch counters
-                self.stats.count("worker.metrics_processed_total",
-                                 worker.processed, tags=[f"worker:{i}"])
-                self.stats.count("worker.metrics_imported_total",
-                                 worker.imported, tags=[f"worker:{i}"])
-                dropped = worker.overload_dropped
-                if dropped:
-                    # samples shed at the native spill caps (overload;
-                    # drop-don't-block) — loud in self-telemetry, since
-                    # sustained nonzero means the host can't keep up
-                    self.stats.count("ingest.overload_dropped_total",
-                                     dropped, tags=[f"worker:{i}"])
-                    worker.overload_dropped = 0
-                swapped.append(worker.swap(qs))
-                n_staged = getattr(worker, "staged_samples_swapped", 0)
-                if n_staged:
-                    self.stats.count("worker.samples_staged_total",
-                                     n_staged, tags=[f"worker:{i}"])
-                if getattr(worker, "_reader_ctxs", None):
-                    # per-reader commit attribution (swap's fence just
-                    # settled reader_committed) + contention record:
-                    # emitted as lifetime-deltas per context, stashed
-                    # whole for ingress_stats/bench readers
-                    rs = worker.reader_stats(
-                        lock_stats=self._lock_stats_enabled)
-                    prev = getattr(self, "_reader_reported", None) or {}
-                    for kind, stat in (
-                            ("committed", "ingest.reader_committed_total"),
-                            ("dropped", "ingest.reader_dropped_total")):
-                        for j, total in enumerate(rs[kind]):
-                            delta = total - prev.get((kind, j), 0)
-                            if delta:
-                                self.stats.count(
-                                    stat, delta, tags=[f"reader:{j}"])
-                            prev[(kind, j)] = total
-                    self._reader_reported = prev
-                    self.last_reader_stats = rs
-                if self.tenant_ledger is not None:
-                    # per-tenant honest-drop counters, emitted as deltas
-                    # of the worker's LIFETIME tallies (read post-swap:
-                    # swap() folds the closing epoch — including any
-                    # swap-time shed attribution — into the totals before
-                    # resetting, exactly like processed_total). Lifetime
-                    # deltas survive the epoch swap; a pre-swap per-epoch
-                    # read would miss samples shed inside swap() itself.
-                    life = worker.tenant_lifetime()
-                    for kind, stat in (
-                            ("rejected", "tenant.samples_rejected_total"),
-                            ("dropped", "tenant.overload_dropped_total")):
-                        for t, total in life[kind].items():
-                            k = (i, kind, t)
-                            delta = total - self._tenant_reported.get(k, 0)
-                            if delta:
-                                self._tenant_reported[k] = total
-                                self.stats.count(
-                                    stat, delta, tags=[f"tenant:{t}"])
+            with self.rec.span("swap.lock_wait", worker=i):
+                lock.acquire()
+            try:
+                self._swap_worker(i, worker, qs, swapped, span_counts)
+                # ingest-side spans (micro-folds, adoption) from here on
+                # belong to the epoch the NEXT flush closes
+                worker.flight_epoch = ordinal + 1
+            finally:
+                lock.release()
         # event lines the swap caught at epoch close (would otherwise be
         # destroyed by the context reset): parse them into the NEW epoch,
         # OUTSIDE the worker locks — parsing re-enters _route
@@ -2064,72 +2078,105 @@ class Server:
                 worker.pending_ssf_fallback = []
                 for pkt in pkts:
                     self.handle_trace_packet(pkt)
-        phases["swap_s"] = time.perf_counter() - _t
-        # always-hot flush decomposition: how many micro-folds streamed
-        # the closed epoch to the device mirrors, and how much of the
-        # swap above was the final residual drain + mirror handoff (the
-        # loadgen controller reports both per interval as micro_folds /
-        # drain_ms)
-        micro_folds = sum(getattr(w, "micro_folds_swapped", 0)
-                          for w in self.workers)
-        phases["drain_s"] = sum(
-            getattr(w, "micro_drain_swapped_s", 0.0) for w in self.workers)
-        self.last_micro_folds = micro_folds
-        if micro_folds:
-            self.stats.count("worker.micro_folds_total", micro_folds)
-        self.flush_governor.beat()  # swap complete: flush is live
-        return FlushJob(ts=int(flush_start), flush_start=flush_start,
-                        qs=qs, swapped=swapped, span_counts=span_counts,
-                        phases=phases)
+        rd = self._reader_ns()
+        if rd is not None:
+            # the C++ readers' lifetime nanoseconds inside recv and
+            # outside it (parse + commit + lock wait): a reader of the
+            # record takes the difference between two flushes
+            cur = self.rec.current()
+            cur.attrs["reader_recv_ns"], cur.attrs["reader_busy_ns"] = rd
+        return qs, swapped, span_counts
+
+    def _reader_ns(self):
+        """(ns in recv, ns outside it) summed over every native context,
+        lifetime; None without native ingest or on a stale .so."""
+        per_ctx = [ns for w in self.workers
+                   for ns in (w.reader_ns() or ())]
+        if not per_ctx:
+            return None
+        return (sum(r for r, _ in per_ctx), sum(b for _, b in per_ctx))
+
+    def _swap_worker(self, i: int, worker, qs, swapped: list,
+                     span_counts: dict) -> None:
+        """Close one worker's epoch; the caller holds its ingest lock."""
+        with self.rec.span("swap", worker=i):
+            if i == 0 and self._native_ssf:
+                # drained in the SAME lock hold as the worker swap —
+                # the swap resets the C++ context, and a span landing
+                # between a separate drain and the reset would lose
+                # its service count
+                for svc, n in (
+                        worker._native.drain_ssf_services().items()):
+                    span_counts[svc] = span_counts.get(svc, 0) + n
+                    # native-extracted spans derive on device and
+                    # never pass handle_ssf: fold them into the
+                    # conservation tallies here (same lock hold as
+                    # the context reset, so none are lost mid-swap)
+                    self._spans_native_total += n
+            # canonical per-worker tallies (README.md:292-294),
+            # captured before flush resets the epoch counters
+            self.stats.count("worker.metrics_processed_total",
+                             worker.processed, tags=[f"worker:{i}"])
+            self.stats.count("worker.metrics_imported_total",
+                             worker.imported, tags=[f"worker:{i}"])
+            dropped = worker.overload_dropped
+            if dropped:
+                # samples shed at the native spill caps (overload;
+                # drop-don't-block) — loud in self-telemetry, since
+                # sustained nonzero means the host can't keep up
+                self.stats.count("ingest.overload_dropped_total",
+                                 dropped, tags=[f"worker:{i}"])
+                worker.overload_dropped = 0
+            swapped.append(worker.swap(qs))
+            n_staged = getattr(worker, "staged_samples_swapped", 0)
+            if n_staged:
+                self.stats.count("worker.samples_staged_total",
+                                 n_staged, tags=[f"worker:{i}"])
+            if getattr(worker, "_reader_ctxs", None):
+                # per-reader commit attribution (swap's fence just
+                # settled reader_committed) + contention record:
+                # emitted as lifetime-deltas per context, stashed
+                # whole for ingress_stats/bench readers
+                rs = worker.reader_stats(
+                    lock_stats=self._lock_stats_enabled)
+                prev = getattr(self, "_reader_reported", None) or {}
+                for kind, stat in (
+                        ("committed", "ingest.reader_committed_total"),
+                        ("dropped", "ingest.reader_dropped_total")):
+                    for j, total in enumerate(rs[kind]):
+                        delta = total - prev.get((kind, j), 0)
+                        if delta:
+                            self.stats.count(
+                                stat, delta, tags=[f"reader:{j}"])
+                        prev[(kind, j)] = total
+                self._reader_reported = prev
+            if self.tenant_ledger is not None:
+                # per-tenant honest-drop counters, emitted as deltas
+                # of the worker's LIFETIME tallies (read post-swap:
+                # swap() folds the closing epoch — including any
+                # swap-time shed attribution — into the totals before
+                # resetting, exactly like processed_total). Lifetime
+                # deltas survive the epoch swap; a pre-swap per-epoch
+                # read would miss samples shed inside swap() itself.
+                life = worker.tenant_lifetime()
+                for kind, stat in (
+                        ("rejected", "tenant.samples_rejected_total"),
+                        ("dropped", "tenant.overload_dropped_total")):
+                    for t, total in life[kind].items():
+                        k = (i, kind, t)
+                        delta = total - self._tenant_reported.get(k, 0)
+                        if delta:
+                            self._tenant_reported[k] = total
+                            self.stats.count(
+                                stat, delta, tags=[f"tenant:{t}"])
 
     def _flush_extract(self, job) -> None:
         """Device-readback flush phase: runs UNLOCKED, so next-interval
         ingest proceeds concurrently with a large extraction
         (SURVEY §7 "Latency budget")."""
-        _t = time.perf_counter()
-        snaps = job.snaps
-        for i, (worker, sw) in enumerate(zip(self.workers, job.swapped)):
-            try:
-                snaps.append(
-                    worker.extract_snapshot(sw, job.qs, self.interval))
-            except Exception:
-                # per-flush data is expendable by design (README.md:135-137)
-                # but a readback failure on one worker must not destroy the
-                # already-swapped intervals of the others
-                log.exception("flush extraction failed for worker %d", i)
-            self.flush_governor.beat()  # one worker's extraction done
-            # guard maintenance runs with the ingest lock held — it
-            # mutates LIVE state (quarantine to host / probe re-admit),
-            # unlike the extraction above which only reads swapped state
-            with self._worker_locks[i]:
-                worker.device_guard_tick()
-            g = worker.guard
-            if (g.last_fault is not None
-                    and g.last_fault != self._guard_last_fault.get(i)):
-                # surface each new classified fault to the governor, so
-                # a watchdog panic right after names the device error
-                self._guard_last_fault[i] = g.last_fault
-                desc = g.last_fault + (
-                    f" — {g.trip_reason}" if g.trip_reason else "")
-                self.flush_governor.note_fault(desc)
-        if self.query_engine is not None:
-            # commit AFTER every worker extracted: the query surface
-            # flips to the new epoch atomically across workers
-            self.query_engine.commit(job.ts)
-        for snap in snaps:
-            # per-type flushed-series counts (README.md:293)
-            d = snap.directory
-            for mtype, n in (
-                ("counter", len(snap.scalars.counter_meta)),
-                ("gauge", len(snap.scalars.gauge_meta)),
-                ("histogram", d.num_histo_rows),
-                ("set", d.num_set_rows),
-            ):
-                if n:
-                    self.stats.count("worker.metrics_flushed_total", n,
-                                     tags=[f"metric_type:{mtype}"])
-
-        job.phases["extract_s"] = time.perf_counter() - _t
+        with self.rec.span("flush.extract", flush=job.ordinal) as sp:
+            self._flush_extract_workers(job)
+        job.phases["extract_s"] = sp.seconds
         # per-flush transfer accounting (health/ledger.py): the byte
         # counts that pin the O(samples) upload/readback diet, surfaced
         # the same way the reference surfaces flush phase timings
@@ -2150,11 +2197,67 @@ class Server:
                 "flush.extract_chunk_max_ns",
                 chunk_report["chunk_max_s"] * 1e9)
 
+    def _flush_extract_workers(self, job) -> None:
+        snaps = job.snaps
+        for i, (worker, sw) in enumerate(zip(self.workers, job.swapped)):
+            try:
+                snaps.append(
+                    worker.extract_snapshot(sw, job.qs, self.interval))
+            except Exception:
+                # per-flush data is expendable by design (README.md:135-137)
+                # but a readback failure on one worker must not destroy the
+                # already-swapped intervals of the others
+                log.exception("flush extraction failed for worker %d", i)
+            self.flush_governor.beat()  # one worker's extraction done
+            # guard maintenance runs with the ingest lock held — it
+            # mutates LIVE state (quarantine to host / probe re-admit),
+            # unlike the extraction above which only reads swapped state
+            with self.rec.span("extract.guard_tick", worker=i):
+                with self._worker_locks[i]:
+                    worker.device_guard_tick()
+            g = worker.guard
+            if (g.last_fault is not None
+                    and g.last_fault != self._guard_last_fault.get(i)):
+                # surface each new classified fault to the governor, so
+                # a watchdog panic right after names the device error
+                self._guard_last_fault[i] = g.last_fault
+                desc = g.last_fault + (
+                    f" — {g.trip_reason}" if g.trip_reason else "")
+                self.flush_governor.note_fault(desc)
+        if self.query_engine is not None:
+            # commit AFTER every worker extracted: the query surface
+            # flips to the new epoch atomically across workers
+            with self.rec.span("extract.query_publish"):
+                self.query_engine.commit(job.ts)
+        for snap in snaps:
+            # per-type flushed-series counts (README.md:293)
+            d = snap.directory
+            for mtype, n in (
+                ("counter", len(snap.scalars.counter_meta)),
+                ("gauge", len(snap.scalars.gauge_meta)),
+                ("histogram", d.num_histo_rows),
+                ("set", d.num_set_rows),
+            ):
+                if n:
+                    self.stats.count("worker.metrics_flushed_total", n,
+                                     tags=[f"metric_type:{mtype}"])
+
     def _flush_generate(self, job) -> None:
         """InterMetric-generation flush phase (host work over the
         already-extracted snapshots). Stamps every metric with job.ts —
         the tick-time clock — on both the columnar and object paths."""
-        _t = time.perf_counter()
+        with self.rec.span("flush.generate", flush=job.ordinal) as sp:
+            self._flush_generate_batch(job)
+        job.phases["generate_s"] = sp.seconds
+
+        if self.is_local and self.forwarder is not None:
+            fwd_thread = threading.Thread(
+                target=self.forwarder, args=(job.snaps,), daemon=True,
+                name="forward",
+            )
+            fwd_thread.start()
+
+    def _flush_generate_batch(self, job) -> None:
         snaps = job.snaps
         # Columnar fast path: the flush never materializes per-metric
         # Python objects up front — at 1M series the object loop alone is
@@ -2196,20 +2299,41 @@ class Server:
             n_flushed = len(final)
         job.batch = batch
         job.n_flushed = n_flushed
-        job.phases["generate_s"] = time.perf_counter() - _t
-
-        if self.is_local and self.forwarder is not None:
-            fwd_thread = threading.Thread(
-                target=self.forwarder, args=(snaps,), daemon=True,
-                name="forward",
-            )
-            fwd_thread.start()
 
     def _flush_emit(self, job) -> None:
         """Sink-emission flush phase plus the flush's self-telemetry
-        tail. Rebinds last_flush_phases at the end so observers always
-        read the phases of the most recently COMPLETED flush."""
-        _t = time.perf_counter()
+        tail. A flush with no root span around it (a pipelined one)
+        publishes its phases here; a serial one does in flush()."""
+        with self.rec.span("flush.emit", flush=job.ordinal):
+            self._flush_emit_sinks(job)
+        if not job.rooted:
+            self._flush_publish(job)
+
+    def _flush_publish(self, job) -> None:
+        """Rebind last_flush_phases, so observers always read the phases
+        of the most recently COMPLETED flush, and beside them the spans
+        of this flush and of the ingest side of its epoch."""
+        job.phases["spans"] = self.rec.of_flush(job.ordinal)
+        self.last_flush_phases = job.phases
+        self.last_emit_unix = time.time()
+
+    def _run_sink_threads(self, phases: dict, targets) -> None:
+        """Start one thread per (name, function, args) and join them at
+        the interval, as the span emit.sinks: what sink_flush_s has
+        always timed. Each function is handed the span as its last
+        argument, to file its own work under (emit.sink)."""
+        with self.rec.span("emit.sinks") as sp:
+            threads = []
+            for name, fn, args in targets:
+                t = threading.Thread(target=fn, args=args + (sp,),
+                                     daemon=True, name=name)
+                t.start()
+                threads.append(t)
+            for t in threads:
+                t.join(timeout=self.interval)
+        phases["sink_flush_s"] = sp.seconds
+
+    def _flush_emit_sinks(self, job) -> None:
         phases = job.phases
         batch = job.batch
         final = job.final
@@ -2217,36 +2341,21 @@ class Server:
         snaps = job.snaps
         span_counts = job.span_counts
         if batch is not None and n_flushed:
-            threads = []
-            for sink in self.metric_sinks:
-                t = threading.Thread(
-                    target=self._flush_sink_columnar,
-                    args=(sink, batch,
-                          self.sink_excluded_tags.get(sink.name())),
-                    daemon=True, name=f"flush-{sink.name()}",
-                )
-                t.start()
-                threads.append(t)
-            for t in threads:
-                t.join(timeout=self.interval)
-            phases["sink_flush_s"] = time.perf_counter() - _t
+            self._run_sink_threads(phases, (
+                (f"flush-{sink.name()}", self._flush_sink_columnar,
+                 (sink, batch, self.sink_excluded_tags.get(sink.name())))
+                for sink in self.metric_sinks))
             if self.plugins:
                 self._run_plugins_clipped(batch, phases)
         elif final:
-            threads = []
-            for sink in self.metric_sinks:
-                routed = filter_routed(final, sink.name())
-                routed = strip_excluded_tags(
-                    routed, self.sink_excluded_tags.get(sink.name()))
-                t = threading.Thread(
-                    target=self._flush_sink, args=(sink, routed),
-                    daemon=True, name=f"flush-{sink.name()}",
-                )
-                t.start()
-                threads.append(t)
-            for t in threads:
-                t.join(timeout=self.interval)
-            phases["sink_flush_s"] = time.perf_counter() - _t
+            # a generator: each sink's routing runs inside the span,
+            # as its thread is about to start
+            self._run_sink_threads(phases, (
+                (f"flush-{sink.name()}", self._flush_sink,
+                 (sink, strip_excluded_tags(
+                     filter_routed(final, sink.name()),
+                     self.sink_excluded_tags.get(sink.name()))))
+                for sink in self.metric_sinks))
             if self.plugins:
                 self._run_plugins_clipped(final, phases)
         else:
@@ -2256,23 +2365,15 @@ class Server:
             # (and an open breaker would never get its half-open probe),
             # stranding recovered-journal backlogs and post-outage
             # retries until fresh traffic happens to arrive
-            threads = []
-            for rname, man in self._delivery_managers():
-                if not len(man.spill):
-                    continue
+            def _drain(man, _span) -> None:
+                man.begin_flush()
+                man.retry_spill()
 
-                def _drain(m=man):
-                    m.begin_flush()
-                    m.retry_spill()
-
-                t = threading.Thread(target=_drain, daemon=True,
-                                     name=f"spill-drain-{rname}")
-                t.start()
-                threads.append(t)
-            for t in threads:
-                t.join(timeout=self.interval)
-            if threads:
-                phases["sink_flush_s"] = time.perf_counter() - _t
+            draining = [(f"spill-drain-{rname}", _drain, (man,))
+                        for rname, man in self._delivery_managers()
+                        if len(man.spill)]
+            if draining:
+                self._run_sink_threads(phases, draining)
 
         # flush self-telemetry (reference flusher.go:38-47, worker.go:513)
         if self.config.count_unique_timeseries:
@@ -2551,8 +2652,6 @@ class Server:
         self.stats.time_in_nanoseconds(
             "flush.total_duration_ns",
             (time.time() - job.flush_start) * 1e9)
-        self.last_flush_phases = phases
-        self.last_emit_unix = time.time()
 
     @staticmethod
     def _tally_timeseries(snaps: list[FlushSnapshot]) -> int:
@@ -2578,16 +2677,16 @@ class Server:
         plugin (blocked PUT, full disk) can never stall the emit stage
         past its tick. The thread is daemon: an overrun finishes (or
         dies with the process) without wedging shutdown."""
-        t0 = time.perf_counter()
-        t = threading.Thread(
-            target=self._flush_plugins, args=(metrics,), daemon=True,
-            name="flush-plugins",
-        )
-        t.start()
-        t.join(timeout=self.interval)
-        if t.is_alive():
-            self.stats.count("plugins.flush_clipped_total", 1)
-        phases["plugin_flush_s"] = time.perf_counter() - t0
+        with self.rec.span("emit.plugins") as sp:
+            t = threading.Thread(
+                target=self._flush_plugins, args=(metrics,), daemon=True,
+                name="flush-plugins",
+            )
+            t.start()
+            t.join(timeout=self.interval)
+            if t.is_alive():
+                self.stats.count("plugins.flush_clipped_total", 1)
+        phases["plugin_flush_s"] = sp.seconds
 
     def _flush_plugins(self, metrics) -> None:
         """reference flusher.go:117-131: plugins run after the sinks.
@@ -2609,7 +2708,12 @@ class Server:
                     (time.time() - start) * 1e9, tags=tags)
 
     def _flush_sink_columnar(self, sink: MetricSink, batch,
-                             excluded_tags) -> None:
+                             excluded_tags, parent=None) -> None:
+        with self.rec.span("emit.sink", parent=parent, sink=sink.name()):
+            self._emit_sink_columnar(sink, batch, excluded_tags)
+
+    def _emit_sink_columnar(self, sink: MetricSink, batch,
+                            excluded_tags) -> None:
         start = time.time()
         tags = [f"sink:{sink.name()}"]
         try:
@@ -2645,7 +2749,12 @@ class Server:
                 (time.time() - start) * 1e9, tags=tags)
 
     def _flush_sink(self, sink: MetricSink,
-                    metrics: list[InterMetric]) -> None:
+                    metrics: list[InterMetric], parent=None) -> None:
+        with self.rec.span("emit.sink", parent=parent, sink=sink.name()):
+            self._emit_sink(sink, metrics)
+
+    def _emit_sink(self, sink: MetricSink,
+                   metrics: list[InterMetric]) -> None:
         start = time.time()
         tags = [f"sink:{sink.name()}"]
         try:

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veneur_tpu.core import columnar
+from veneur_tpu.core import columnar, flightrec
 from veneur_tpu.core.directory import ScopeClass, SeriesDirectory, classify
 from veneur_tpu.core.metrics import (DEFAULT_TENANT, MetricKey, UDPMetric,
                                      route_info, tenant_of)
@@ -127,36 +127,38 @@ def _histo_ingest_step(
     stats, so the gather→compensate→set round trip writes identical
     values at every duplicate.
     """
-    g_means = means[active]
-    g_w = weights[active]
-    g_min = dmin[active]
-    g_max = dmax[active]
-    g_recip = drecip[active]
+    with jax.named_scope("ingest_step.gather"):
+        g_means = means[active]
+        g_w = weights[active]
+        g_min = dmin[active]
+        g_max = dmax[active]
+        g_recip = drecip[active]
 
     n_means, n_w, n_min, n_max, _, stats = td.add_batch(
         g_means, g_w, g_min, g_max, g_recip, lids, values, wts,
         compression=compression,
     )
 
-    means = means.at[active].set(n_means, mode="drop")
-    weights = weights.at[active].set(n_w, mode="drop")
-    dmin = dmin.at[active].set(n_min, mode="drop")
-    dmax = dmax.at[active].set(n_max, mode="drop")
-    n_recip, n_recip_c = _comp_add(g_recip, drecip_c[active], stats.recip)
-    drecip = drecip.at[active].set(n_recip, mode="drop")
-    drecip_c = drecip_c.at[active].set(n_recip_c, mode="drop")
+    with jax.named_scope("ingest_step.scatter"):
+        means = means.at[active].set(n_means, mode="drop")
+        weights = weights.at[active].set(n_w, mode="drop")
+        dmin = dmin.at[active].set(n_min, mode="drop")
+        dmax = dmax.at[active].set(n_max, mode="drop")
+        n_recip, n_recip_c = _comp_add(g_recip, drecip_c[active], stats.recip)
+        drecip = drecip.at[active].set(n_recip, mode="drop")
+        drecip_c = drecip_c.at[active].set(n_recip_c, mode="drop")
 
-    lmin = lmin.at[active].min(stats.min, mode="drop")
-    lmax = lmax.at[active].max(stats.max, mode="drop")
-    n_lsum, n_lsum_c = _comp_add(lsum[active], lsum_c[active], stats.sum)
-    lsum = lsum.at[active].set(n_lsum, mode="drop")
-    lsum_c = lsum_c.at[active].set(n_lsum_c, mode="drop")
-    n_lw, n_lw_c = _comp_add(lweight[active], lweight_c[active], stats.weight)
-    lweight = lweight.at[active].set(n_lw, mode="drop")
-    lweight_c = lweight_c.at[active].set(n_lw_c, mode="drop")
-    n_lr, n_lr_c = _comp_add(lrecip[active], lrecip_c[active], stats.recip)
-    lrecip = lrecip.at[active].set(n_lr, mode="drop")
-    lrecip_c = lrecip_c.at[active].set(n_lr_c, mode="drop")
+        lmin = lmin.at[active].min(stats.min, mode="drop")
+        lmax = lmax.at[active].max(stats.max, mode="drop")
+        n_lsum, n_lsum_c = _comp_add(lsum[active], lsum_c[active], stats.sum)
+        lsum = lsum.at[active].set(n_lsum, mode="drop")
+        lsum_c = lsum_c.at[active].set(n_lsum_c, mode="drop")
+        n_lw, n_lw_c = _comp_add(lweight[active], lweight_c[active], stats.weight)
+        lweight = lweight.at[active].set(n_lw, mode="drop")
+        lweight_c = lweight_c.at[active].set(n_lw_c, mode="drop")
+        n_lr, n_lr_c = _comp_add(lrecip[active], lrecip_c[active], stats.recip)
+        lrecip = lrecip.at[active].set(n_lr, mode="drop")
+        lrecip_c = lrecip_c.at[active].set(n_lr_c, mode="drop")
     return (means, weights, dmin, dmax, drecip, drecip_c,
             lmin, lmax, lsum, lsum_c, lweight, lweight_c, lrecip, lrecip_c)
 
@@ -264,27 +266,30 @@ def _histo_fold_staged(
     [S, C+B]. Empty slots carry weight 0 (value ignored).
     """
     c = means.shape[1]
-    live = swts > 0
-    # Order-pinned tree sums (ops/exactnum.py): the host fallback engine
-    # replays this fold over the same staged plane bitwise.
-    s_w = exn.tsum(swts)
-    s_sum = exn.tsum(jnp.where(live, svals * swts, 0.0))
-    s_recip = exn.tsum(jnp.where(live, swts / svals, 0.0))
-    s_min = jnp.min(jnp.where(live, svals, jnp.inf), axis=-1)
-    s_max = jnp.max(jnp.where(live, svals, -jnp.inf), axis=-1)
+    with jax.named_scope("fold_staged.row_stats"):
+        live = swts > 0
+        # Order-pinned tree sums (ops/exactnum.py): the host fallback engine
+        # replays this fold over the same staged plane bitwise.
+        s_w = exn.tsum(swts)
+        s_sum = exn.tsum(jnp.where(live, svals * swts, 0.0))
+        s_recip = exn.tsum(jnp.where(live, swts / svals, 0.0))
+        s_min = jnp.min(jnp.where(live, svals, jnp.inf), axis=-1)
+        s_max = jnp.max(jnp.where(live, svals, -jnp.inf), axis=-1)
 
-    cat_means = jnp.concatenate([means, svals], axis=-1)
-    cat_w = jnp.concatenate([weights, swts], axis=-1)
+    with jax.named_scope("fold_staged.merge"):
+        cat_means = jnp.concatenate([means, svals], axis=-1)
+        cat_w = jnp.concatenate([weights, swts], axis=-1)
     means, weights = td._compress_rows(cat_means, cat_w, compression, c)
 
-    dmin = jnp.minimum(dmin, s_min)
-    dmax = jnp.maximum(dmax, s_max)
-    drecip, drecip_c = _comp_add(drecip, drecip_c, s_recip)
-    lmin = jnp.minimum(lmin, s_min)
-    lmax = jnp.maximum(lmax, s_max)
-    lsum, lsum_c = _comp_add(lsum, lsum_c, s_sum)
-    lweight, lweight_c = _comp_add(lweight, lweight_c, s_w)
-    lrecip, lrecip_c = _comp_add(lrecip, lrecip_c, s_recip)
+    with jax.named_scope("fold_staged.scalars"):
+        dmin = jnp.minimum(dmin, s_min)
+        dmax = jnp.maximum(dmax, s_max)
+        drecip, drecip_c = _comp_add(drecip, drecip_c, s_recip)
+        lmin = jnp.minimum(lmin, s_min)
+        lmax = jnp.maximum(lmax, s_max)
+        lsum, lsum_c = _comp_add(lsum, lsum_c, s_sum)
+        lweight, lweight_c = _comp_add(lweight, lweight_c, s_w)
+        lrecip, lrecip_c = _comp_add(lrecip, lrecip_c, s_recip)
     return (means, weights, dmin, dmax, drecip, drecip_c,
             lmin, lmax, lsum, lsum_c, lweight, lweight_c, lrecip, lrecip_c)
 
@@ -320,11 +325,12 @@ def _histo_flush_extract(means, weights, dmin, dmax, drecip, drecip_c,
 
     Compensated accumulators resolve to their true value (s + c) here."""
     quantiles = td.quantile(means, weights, dmin, dmax, qs)
-    dsum = td.row_sum(means, weights)
-    dcount = td.row_count(weights)
-    return (quantiles, dmin, dmax, dsum, dcount, drecip + drecip_c,
-            lmin, lmax, lsum + lsum_c, lweight + lweight_c,
-            lrecip + lrecip_c)
+    with jax.named_scope("flush_extract.sums"):
+        dsum = td.row_sum(means, weights)
+        dcount = td.row_count(weights)
+        return (quantiles, dmin, dmax, dsum, dcount, drecip + drecip_c,
+                lmin, lmax, lsum + lsum_c, lweight + lweight_c,
+                lrecip + lrecip_c)
 
 
 @jax.jit
@@ -342,8 +348,9 @@ def _pack_extract_columns(qv, *cols):
     series. Counters are unaffected (host-side exact f64 pools);
     integer-valued digest counts are exact below 2^24 per series per
     interval."""
-    return jnp.concatenate(
-        [qv] + [c[:, None].astype(jnp.float32) for c in cols], axis=1)
+    with jax.named_scope("pack_extract"):
+        return jnp.concatenate(
+            [qv] + [c[:, None].astype(jnp.float32) for c in cols], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("new_rows",), donate_argnums=(0,))
@@ -786,7 +793,6 @@ class DeviceWorker:
         self.micro_folds_total = 0
         self.micro_folds_epoch = 0
         self.micro_folds_swapped = 0
-        self.micro_drain_swapped_s = 0.0
         # cross-epoch series-metadata cache (see _sync_native_series);
         # deliberately NOT in _reset_epoch — surviving the per-flush
         # directory swap is its whole purpose
@@ -823,6 +829,13 @@ class DeviceWorker:
             streak_limit=device_fault_streak,
             probe_interval_s=device_probe_interval_s,
             enabled=bool(device_guard) and dg.guard_enabled_default())
+        # span record (core/flightrec.py): the server hands every worker
+        # its own (set_recorder); a worker alone keeps a private one.
+        # flight_epoch is the ordinal of the flush that will close the
+        # live epoch: the server advances it under the ingest lock right
+        # after swap(), so ingest-side spans name their flush exactly
+        self.rec = self.guard.rec = flightrec.Recorder()
+        self.flight_epoch = 1
         # live pools are host-side (HostHistoState / np registers)
         self._host_live = False
         # a device fault voided this epoch's micro-fold mirror: the
@@ -835,6 +848,9 @@ class DeviceWorker:
         # for the soak's conservation accounting)
         self.host_fallback_flushes = 0
         self._reset_epoch()
+
+    def set_recorder(self, rec: "flightrec.Recorder") -> None:
+        self.rec = self.guard.rec = rec
 
     def attach_mesh_pool(self, pool) -> None:
         """Shard histogram state over a device mesh
@@ -1005,14 +1021,22 @@ class DeviceWorker:
                        admitted=meta.admitted)
         return row
 
-    def _sync_native_series(self, ctx=None, ctx_i: int = 0) -> None:
-        from veneur_tpu.core.directory import RowMeta
-        from veneur_tpu.native import NativeIngest
-
+    def _sync_native_series(self, ctx=None, ctx_i: int = 0,
+                            span: str = "adopt") -> None:
         if ctx is None:
             ctx = self._native
         if not ctx.pending_new_series:
             return
+        with self.rec.span(span, flush=self.flight_epoch) as sp:
+            sp.attrs["series"] = self._adopt_pending(ctx, ctx_i)
+
+    def _adopt_pending(self, ctx, ctx_i: int) -> int:
+        """The adoption loop of _sync_native_series; returns the number
+        of series adopted."""
+        from veneur_tpu.core.directory import RowMeta
+        from veneur_tpu.native import NativeIngest
+
+        n_adopted = 0
         # reader-shard mode: context rows are LOCAL — reconcile each into
         # the worker's canonical directory (dedup by series identity, so
         # the same series arriving via several readers shares one
@@ -1029,6 +1053,7 @@ class DeviceWorker:
         for pool, row, kind, scope, name, joined in (
             ctx.drain_new_series()
         ):
+            n_adopted += 1
             ck = (pool, kind, scope, name, joined)
             meta = cache.get(ck)
             if meta is None:
@@ -1088,6 +1113,7 @@ class DeviceWorker:
                 self.scalars.gauges.adopt_row(
                     row, meta.key, meta.tags, meta.scope_class, meta.sinks,
                     frag=meta.wire_frag(), admitted=meta.admitted)
+        return n_adopted
 
     def sync_native_series(self) -> None:
         """Adopt pending new-series registrations mid-epoch.
@@ -1194,6 +1220,18 @@ class DeviceWorker:
                 out[m] = lookup[out[m]]
         return out
 
+    def reader_ns(self) -> Optional[list]:
+        """Per native context, [home] + reader shards: the lifetime
+        (ns inside recv, ns outside it: parse + commit + lock wait) of
+        the C++ reader threads homed on it. None without native ingest
+        or on a stale .so without the counter."""
+        if self._native is None:
+            return None
+        try:
+            return [ctx.reader_ns() for ctx in self._all_ctxs()]
+        except AttributeError:
+            return None
+
     def reader_stats(self, lock_stats: bool = False) -> dict:
         """Per-context ingest attribution for Server.ingress_stats /
         flush telemetry: context order is [home] + reader shards.
@@ -1204,6 +1242,10 @@ class DeviceWorker:
             "committed": list(self.reader_committed),
             "dropped": list(self.reader_dropped),
         }
+        ns = self.reader_ns()
+        if ns is not None:
+            out["recv_ns"] = [r for r, _ in ns]
+            out["busy_ns"] = [b for _, b in ns]
         if lock_stats and self._native is not None:
             locks = []
             for ctx in self._all_ctxs():
@@ -1305,7 +1347,9 @@ class DeviceWorker:
             except AttributeError:  # stale .so without the SSF reader API
                 pass
         if sync:
-            self._sync_native_series(ctx, ctx_i)
+            # the epoch close adopts under the context lock: "swap.adopt"
+            self._sync_native_series(
+                ctx, ctx_i, span="swap.adopt" if detach_stage else "adopt")
         return h, s, c, g, st, others, ssf_fb
 
     def _apply_native_raw(self, raw, defer_histo_spill: bool = False):
@@ -1431,10 +1475,16 @@ class DeviceWorker:
                 # drain order and gauges last-write-wins, so draining more
                 # often splits the stream into ordered deltas — the folded
                 # result is bitwise what one deadline-time drain produces
-                self.drain_native()
-                fed = self._micro_drain_native()
+                with self.rec.span("micro_fold.drain",
+                                   flush=self.flight_epoch):
+                    self.drain_native()
+                with self.rec.span("micro_fold.feed",
+                                   flush=self.flight_epoch):
+                    fed = self._micro_drain_native()
             else:
-                fed = self._micro_drain_python()
+                with self.rec.span("micro_fold.feed",
+                                   flush=self.flight_epoch):
+                    fed = self._micro_drain_python()
         except dg.DeviceFaultError as exc:
             # the mirror is a CACHE of the staging plane — the plane
             # retains every sample (watermarks advanced, counts did
@@ -2044,7 +2094,8 @@ class DeviceWorker:
         # policy as trace.Client backpressure).
         self._inflight_folds += 1
         if self._inflight_folds >= 8:
-            h.means.block_until_ready()
+            with self.rec.span("fold.fence", wait=True):
+                h.means.block_until_ready()
             self._inflight_folds = 0
 
     def _fold_spill_chunk(self, fields: tuple, rows: np.ndarray,
@@ -2619,13 +2670,16 @@ class DeviceWorker:
         # unlocked pre-fence read here would miss lines landing before
         # each context's locked fence read and break the exact
         # attribution books (sum(reader_committed) == processed_total).
-        if self._native is not None and self._reader_ctxs:
+        # The legacy native path does the same inside its one lock hold
+        # below (a line committed between an unlocked read here and that
+        # lock would be drained, reset away and never tallied).
+        if self._native is not None:
             self.processed_total += self._processed_py
         else:
             self.processed_total += self.processed
+        rec = self.rec
         native_stage = None
         spill_histo = None
-        micro_s = 0.0
         micro_coo: list = []
         native_mirrored = False
         reader_planes = None
@@ -2640,28 +2694,29 @@ class DeviceWorker:
             for i, ctx in enumerate(self._all_ctxs()):
                 seen = (self._native_proc_seen if i == 0
                         else self._reader_proc_seen[i - 1])
-                ctx.lock()
-                try:
-                    raw = self._drain_native_raw_ctx(
-                        ctx, i, detach_stage=True)
-                    # per-context committed attribution, read inside
-                    # the lock so the reset below can't race a commit;
-                    # the same locked delta feeds processed_total (see
-                    # the swap-top comment)
-                    delta = int(ctx.processed) - seen
-                    self.reader_committed[i] += delta
-                    self.processed_total += delta
-                    ctx.reset()
-                    if i == 0:
-                        self._native_errs_seen = 0
-                        self._native_proc_seen = 0
-                        self._native_drop_seen = 0
-                    else:
-                        self._reader_errs_seen[i - 1] = 0
-                        self._reader_proc_seen[i - 1] = 0
-                        self._reader_drop_seen[i - 1] = 0
-                finally:
-                    ctx.unlock()
+                with rec.span("swap.drain", ctx=i):
+                    ctx.lock()
+                    try:
+                        raw = self._drain_native_raw_ctx(
+                            ctx, i, detach_stage=True)
+                        # per-context committed attribution, read inside
+                        # the lock so the reset below can't race a
+                        # commit; the same locked delta feeds
+                        # processed_total (see the swap-top comment)
+                        delta = int(ctx.processed) - seen
+                        self.reader_committed[i] += delta
+                        self.processed_total += delta
+                        ctx.reset()
+                        if i == 0:
+                            self._native_errs_seen = 0
+                            self._native_proc_seen = 0
+                            self._native_drop_seen = 0
+                        else:
+                            self._reader_errs_seen[i - 1] = 0
+                            self._reader_proc_seen[i - 1] = 0
+                            self._reader_drop_seen[i - 1] = 0
+                    finally:
+                        ctx.unlock()
                 raws.append(raw)
             self._native_epoch_closed = True
             # off-lock: translate each context's SoA rows to canonical,
@@ -2676,7 +2731,9 @@ class DeviceWorker:
             planes: list = []
             for i, raw in enumerate(raws):
                 mapped = self._map_raw_rows(i, raw)
-                d = self._apply_native_raw(mapped, defer_histo_spill=True)
+                with rec.span("swap.spill_fold", ctx=i):
+                    d = self._apply_native_raw(mapped,
+                                               defer_histo_spill=True)
                 if d is not None and len(d[0]):
                     spills.append(d)
                 others.extend(raw[5])
@@ -2701,46 +2758,54 @@ class DeviceWorker:
             # under one lock hold: a routed commit can otherwise land
             # between the last drain and the reset and be destroyed with
             # the old epoch
-            self._native.lock()
-            try:
-                if self._micro_active():
-                    # residual micro-drain in the SAME critical section
-                    # as the detach: every staged sample is either
-                    # already mirrored or copied out here, and nothing
-                    # can land in between — the swap fence that makes
-                    # in-flight micro-folds lose or double-fold nothing.
-                    # Host memcpy only; the device feeds run after
-                    # unlock so reader commits aren't stalled.
-                    _t = time.perf_counter()
-                    try:
-                        cap = 1 << 18
-                        while True:
-                            coo = self._native.drain_stage_delta(cap)
-                            if not len(coo[0]):
-                                break
-                            micro_coo.append(coo)
-                            if len(coo[0]) < cap:
-                                break
-                        native_mirrored = self._native.stage_pending == 0
-                    except AttributeError:  # stale .so: plane path below
-                        native_mirrored = False
-                    micro_s += time.perf_counter() - _t
-                raw = self._drain_native_raw(detach_stage=True)
-                native_stage = raw[4]
-                # event/service-check lines + fallback SSF payloads caught
-                # at epoch close; the server parses them into the NEW
-                # epoch after swap
-                self.pending_other_lines = raw[5]
-                self.pending_ssf_fallback = raw[6]
-                self._native.reset()
-                self._native_errs_seen = 0
-                self._native_proc_seen = 0
-                self._native_drop_seen = 0
-                self._native_epoch_closed = True
-            finally:
-                self._native.unlock()
-            spill_histo = self._shed_spill_budget(
-                self._apply_native_raw(raw, defer_histo_spill=True))
+            with rec.span("swap.drain"):
+                self._native.lock()
+                try:
+                    if self._micro_active():
+                        # residual micro-drain in the SAME critical
+                        # section as the detach: every staged sample is
+                        # either already mirrored or copied out here, and
+                        # nothing can land in between — the swap fence
+                        # that makes in-flight micro-folds lose or
+                        # double-fold nothing. Host memcpy only; the
+                        # device feeds run after unlock so reader commits
+                        # aren't stalled.
+                        with rec.span("swap.drain.residual"):
+                            try:
+                                cap = 1 << 18
+                                while True:
+                                    coo = self._native.drain_stage_delta(
+                                        cap)
+                                    if not len(coo[0]):
+                                        break
+                                    micro_coo.append(coo)
+                                    if len(coo[0]) < cap:
+                                        break
+                                native_mirrored = (
+                                    self._native.stage_pending == 0)
+                            except AttributeError:  # stale .so: plane path
+                                native_mirrored = False
+                    raw = self._drain_native_raw(detach_stage=True)
+                    native_stage = raw[4]
+                    # event/service-check lines + fallback SSF payloads
+                    # caught at epoch close; the server parses them into
+                    # the NEW epoch after swap
+                    self.pending_other_lines = raw[5]
+                    self.pending_ssf_fallback = raw[6]
+                    # the lifetime tally's native share, inside the lock
+                    # hold that resets it (see the top of swap)
+                    self.processed_total += (int(self._native.processed)
+                                             - self._native_proc_seen)
+                    self._native.reset()
+                    self._native_errs_seen = 0
+                    self._native_proc_seen = 0
+                    self._native_drop_seen = 0
+                    self._native_epoch_closed = True
+                finally:
+                    self._native.unlock()
+            with rec.span("swap.spill_fold"):
+                spill_histo = self._shed_spill_budget(
+                    self._apply_native_raw(raw, defer_histo_spill=True))
             if native_stage is not None and self._mesh_pool is not None:
                 # samples staged before attach_mesh_pool() disabled
                 # staging belong to the mesh shards, not the local fold
@@ -2760,7 +2825,10 @@ class DeviceWorker:
                 # all samples may be staged: the device pool must still
                 # exist for the fold to land in
                 self._ensure_histo(self.directory.num_histo_rows)
-        self._flush_pending_histos()
+        # what is still pending host-side folds now, under the ingest
+        # lock: a program compiled here holds the readers for as long
+        with rec.span("swap.spill_fold"):
+            self._flush_pending_histos()
         if self._ph_rows:
             # a device fault during the pending-batch fold re-staged the
             # batch instead of folding it (_fold_batch_direct's failover
@@ -2774,8 +2842,9 @@ class DeviceWorker:
             self._ph_rows, self._ph_vals, self._ph_wts = [], [], []
             spill_histo = (ph if spill_histo is None else tuple(
                 np.concatenate([spill_histo[k], ph[k]]) for k in range(3)))
-        self._flush_pending_sets()
-        self._merge_imports()
+        with rec.span("swap.spill_fold"):
+            self._flush_pending_sets()
+            self._merge_imports()
 
         mesh_out = None
         if self._mesh_pool is not None and self.directory.num_histo_rows:
@@ -2794,104 +2863,105 @@ class DeviceWorker:
         device_stage = None
         micro_residual = None
         if self._micro_active():
-            _t = time.perf_counter()
-            if self._native is None:
-                coo = self._python_stage_delta()
-                if coo is not None:
-                    micro_coo.append(coo)
-            mirror, self._micro = self._micro, None
-            residual_n = sum(len(c[0]) for c in micro_coo)
-            if (mirror is not None and mirror.samples > 0) or residual_n:
-                if mirror is None:
-                    mirror = mf.MicroFoldMirror(
-                        self.stage_depth, ledger=self.ledger,
-                        initial_rows=self._initial_histo_rows,
-                        shard=self._shard, guard=self.guard)
-                mirror.book_in_flush = True
-                micro_residual = (mirror, micro_coo)
-                micro_samples = mirror.samples + residual_n
-            micro_s += time.perf_counter() - _t
-        self.micro_drain_swapped_s = micro_s
+            with rec.span("swap.mirror_handoff"):
+                if self._native is None:
+                    coo = self._python_stage_delta()
+                    if coo is not None:
+                        micro_coo.append(coo)
+                mirror, self._micro = self._micro, None
+                residual_n = sum(len(c[0]) for c in micro_coo)
+                if (mirror is not None and mirror.samples > 0) or residual_n:
+                    if mirror is None:
+                        mirror = mf.MicroFoldMirror(
+                            self.stage_depth, ledger=self.ledger,
+                            initial_rows=self._initial_histo_rows,
+                            shard=self._shard, guard=self.guard)
+                    mirror.book_in_flush = True
+                    micro_residual = (mirror, micro_coo)
+                    micro_samples = mirror.samples + residual_n
         self.micro_folds_swapped = self.micro_folds_epoch
         # micro-fold upload bytes belong to the flush that extracts this
         # epoch: queue the closed epoch's tally for its begin_flush
         self.ledger.roll_epoch()
 
-        staged = 0
-        staged_histo = []
-        # device-fault replay batch (ops/device_guard failover): when a
-        # staging plane is handed over as a MIRROR (micro_residual)
-        # instead of a host plane, the mirror is the only carrier of
-        # those samples — and the mirror is device state. micro_replay
-        # retains the host ground truth (the staging plane's content,
-        # which the mirror duplicates bit-for-bit) until the mirror's
-        # flush fold succeeds; if the mirror faults first, the replay
-        # batch folds through the host engine instead. Freed by
-        # extract_snapshot after a clean mirror fold.
-        micro_replay = None
-        # a mirrored plane is handed over as micro_residual (mirror +
-        # deferred COO) INSTEAD of a host plane — exactly one of the two
-        # carries a given sample
-        python_mirrored = micro_residual is not None and self._native is None
-        if self._stage_count is not None and self._stage_count.any():
-            if python_mirrored:
-                # the dense host pair IS the mirror's ground truth (the
-                # drains copied deltas out; the plane keeps everything)
-                micro_replay = StagedPlane(
-                    self._stage_vals, self._stage_wts, None, None)
-            else:
-                staged += int(self._stage_count.sum())
-                # hand the host staging planes to the closed epoch; the
-                # fold into the digest runs in extract_snapshot, OFF the
-                # ingest lock
-                self._ensure_stage()  # pool may have grown since staging
-                staged_histo.append(StagedPlane(
-                    self._stage_vals, self._stage_wts, None, None))
-        if native_stage is not None:
-            sv, sw, counts, unit, free = native_stage
-            if native_mirrored and micro_residual is not None:
-                # plane content fully captured by the mirror + residual
-                # COO (all copies): compact a host replay copy out of the
-                # C++ memory, then release it — nothing to upload at
-                # flush unless the mirror faults
-                B = sv.shape[1]
-                counts_np = np.minimum(counts, B).astype(np.int32)
-                r_mask = (np.arange(B, dtype=np.int32)[None, :]
-                          < counts_np[:, None])
-                flat_v = sv[r_mask]
-                flat_w = None if unit else sw[r_mask]
-                free()
-                micro_replay = StagedPlane(flat_v, flat_w, counts_np, None)
-            else:
-                staged += int(counts.sum())
-                # unit weights (no sampled metrics this epoch): skip the
-                # weights plane upload; the fold rebuilds it from counts
-                staged_histo.append(
-                    StagedPlane(sv, None if unit else sw, counts, free))
-        if micro_residual is not None:
-            staged += micro_samples
-        if reader_planes:
-            staged += sum(int(st[2].sum()) for st, _m in reader_planes)
-        staged_histo = staged_histo or None
-        # flush self-telemetry (veneur.worker.samples_staged_total)
-        self.staged_samples_swapped = staged
-        swapped = SwappedEpoch(
-            directory=self.directory, scalars=self.scalars,
-            histo=self._histo, sets=self._sets,
-            staged_sets=self._staged_sets, umts=self._umts,
-            mesh_out=mesh_out, staged_histo=staged_histo,
-            spill_histo=spill_histo, device_stage=device_stage,
-            micro_residual=micro_residual, reader_planes=reader_planes,
-            micro_replay=micro_replay,
-        )
+        with rec.span("swap.handoff"):
+            staged = 0
+            staged_histo = []
+            # device-fault replay batch (ops/device_guard failover): when a
+            # staging plane is handed over as a MIRROR (micro_residual)
+            # instead of a host plane, the mirror is the only carrier of
+            # those samples — and the mirror is device state. micro_replay
+            # retains the host ground truth (the staging plane's content,
+            # which the mirror duplicates bit-for-bit) until the mirror's
+            # flush fold succeeds; if the mirror faults first, the replay
+            # batch folds through the host engine instead. Freed by
+            # extract_snapshot after a clean mirror fold.
+            micro_replay = None
+            # a mirrored plane is handed over as micro_residual (mirror +
+            # deferred COO) INSTEAD of a host plane — exactly one of the two
+            # carries a given sample
+            python_mirrored = (micro_residual is not None
+                               and self._native is None)
+            if self._stage_count is not None and self._stage_count.any():
+                if python_mirrored:
+                    # the dense host pair IS the mirror's ground truth (the
+                    # drains copied deltas out; the plane keeps everything)
+                    micro_replay = StagedPlane(
+                        self._stage_vals, self._stage_wts, None, None)
+                else:
+                    staged += int(self._stage_count.sum())
+                    # hand the host staging planes to the closed epoch; the
+                    # fold into the digest runs in extract_snapshot, OFF the
+                    # ingest lock
+                    self._ensure_stage()  # pool may have grown since staging
+                    staged_histo.append(StagedPlane(
+                        self._stage_vals, self._stage_wts, None, None))
+            if native_stage is not None:
+                sv, sw, counts, unit, free = native_stage
+                if native_mirrored and micro_residual is not None:
+                    # plane content fully captured by the mirror + residual
+                    # COO (all copies): compact a host replay copy out of the
+                    # C++ memory, then release it — nothing to upload at
+                    # flush unless the mirror faults
+                    B = sv.shape[1]
+                    counts_np = np.minimum(counts, B).astype(np.int32)
+                    r_mask = (np.arange(B, dtype=np.int32)[None, :]
+                              < counts_np[:, None])
+                    flat_v = sv[r_mask]
+                    flat_w = None if unit else sw[r_mask]
+                    free()
+                    micro_replay = StagedPlane(flat_v, flat_w, counts_np, None)
+                else:
+                    staged += int(counts.sum())
+                    # unit weights (no sampled metrics this epoch): skip the
+                    # weights plane upload; the fold rebuilds it from counts
+                    staged_histo.append(
+                        StagedPlane(sv, None if unit else sw, counts, free))
+            if micro_residual is not None:
+                staged += micro_samples
+            if reader_planes:
+                staged += sum(int(st[2].sum()) for st, _m in reader_planes)
+            staged_histo = staged_histo or None
+            # flush self-telemetry (veneur.worker.samples_staged_total)
+            self.staged_samples_swapped = staged
+            swapped = SwappedEpoch(
+                directory=self.directory, scalars=self.scalars,
+                histo=self._histo, sets=self._sets,
+                staged_sets=self._staged_sets, umts=self._umts,
+                mesh_out=mesh_out, staged_histo=staged_histo,
+                spill_histo=spill_histo, device_stage=device_stage,
+                micro_residual=micro_residual, reader_planes=reader_planes,
+                micro_replay=micro_replay,
+            )
         # per-tenant lifetime fold, still under the caller's ingest lock
         # and BEFORE the epoch reset zeroes the per-epoch dicts — the
         # processed_total pattern above, per tenant per kind, so a
         # tenant's drops in this epoch survive a late pipelined extract
-        self.tenant_tallies.accumulate_into(self.tenant_tallies_total)
-        self.processed = 0
-        self.imported = 0
-        self._reset_epoch()
+        with rec.span("swap.reset"):
+            self.tenant_tallies.accumulate_into(self.tenant_tallies_total)
+            self.processed = 0
+            self.imported = 0
+            self._reset_epoch()
         return swapped
 
     def tenant_lifetime(self) -> dict:
@@ -3105,6 +3175,7 @@ class DeviceWorker:
         (honest degraded replay, logged). Returns (view_fields, s_eff)
         for the query-view publish."""
         directory = swapped.directory
+        rec = self.rec
         if spill is not None:
             # hot-row spill backlog deferred by swap(): chunked fold
             # off the ingest lock (plain numpy from drain_histo — no
@@ -3115,44 +3186,50 @@ class DeviceWorker:
             # than the fold itself (observed: 40s+ XLA compile under
             # 33x overload). Timed: the measured rate sizes the NEXT
             # swap's fold budget (closed-loop shedding).
-            sp_rows, sp_vals, sp_wts = spill
-            pool_rows = full[0].shape[0]
-            t_fold = time.perf_counter()
-            inflight = 0
-            for i in range(0, len(sp_rows), _FOLD_CHUNK):
-                full = self._fold_spill_chunk(
-                    full, sp_rows[i:i + _FOLD_CHUNK],
-                    sp_vals[i:i + _FOLD_CHUNK],
-                    sp_wts[i:i + _FOLD_CHUNK], pool_rows)
-                st["fields"] = full
-                st["spill_off"] = min(i + _FOLD_CHUNK, len(sp_rows))
-                inflight += 1
-                if inflight >= 8:  # bound the dispatch queue's memory
+            with rec.span("extract.spill_fold", samples=int(len(spill[0]))):
+                sp_rows, sp_vals, sp_wts = spill
+                pool_rows = full[0].shape[0]
+                t_fold = time.perf_counter()
+                inflight = 0
+                for i in range(0, len(sp_rows), _FOLD_CHUNK):
+                    full = self._fold_spill_chunk(
+                        full, sp_rows[i:i + _FOLD_CHUNK],
+                        sp_vals[i:i + _FOLD_CHUNK],
+                        sp_wts[i:i + _FOLD_CHUNK], pool_rows)
+                    st["fields"] = full
+                    st["spill_off"] = min(i + _FOLD_CHUNK, len(sp_rows))
+                    inflight += 1
+                    if inflight >= 8:  # bound the dispatch queue's memory
+                        with rec.span("extract.spill_fold.wait", wait=True):
+                            self.guard.call("spill",
+                                            full[0].block_until_ready)
+                        inflight = 0
+                        if gov is not None:
+                            gov.beat()
+                with rec.span("extract.spill_fold.wait", wait=True):
                     self.guard.call("spill", full[0].block_until_ready)
-                    inflight = 0
-                    if gov is not None:
-                        gov.beat()
-            self.guard.call("spill", full[0].block_until_ready)
-            t_fold = time.perf_counter() - t_fold
-            if t_fold > 0.01:
-                rate = len(sp_rows) / t_fold
-                self._fold_rate_ewma = (
-                    0.5 * self._fold_rate_ewma + 0.5 * rate)
+                t_fold = time.perf_counter() - t_fold
+                if t_fold > 0.01:
+                    rate = len(sp_rows) / t_fold
+                    self._fold_rate_ewma = (
+                        0.5 * self._fold_rate_ewma + 0.5 * rate)
         sh = self._shard
-        if sh is None:
-            fields = tuple(
-                a if a.shape[0] == s_eff else a[:s_eff] for a in full)
-        else:
-            # sharded shrink: each shard keeps its local prefix (the
-            # interleave closure property) — no resharding
-            fields = tuple(
-                a if a.shape[0] == s_eff else sh.slice_field(a, s_eff)
-                for a in full)
+        with rec.span("extract.shrink"):
+            if sh is None:
+                fields = tuple(
+                    a if a.shape[0] == s_eff else a[:s_eff] for a in full)
+            else:
+                # sharded shrink: each shard keeps its local prefix (the
+                # interleave closure property) — no resharding
+                fields = tuple(
+                    a if a.shape[0] == s_eff else sh.slice_field(a, s_eff)
+                    for a in full)
         st["fields"] = fields
         st["spill_off"] = len(spill[0]) if spill is not None else 0
         try:
             while pending:
-                fields = self._fold_one_plane(fields, pending, s_eff)
+                with rec.span("extract.plane_fold"):
+                    fields = self._fold_one_plane(fields, pending, s_eff)
                 st["fields"] = fields
                 if gov is not None:
                     gov.beat()
@@ -3178,11 +3255,12 @@ class DeviceWorker:
             # streamed by swap time lands on the device HERE, in the
             # extract stage, exactly like the batch path's upload —
             # the tick paid only the host-side COO memcpy
-            mirror, coos = swapped.micro_residual
-            swapped.micro_residual = None
-            for coo in coos:
-                mirror.feed(*coo)
-            swapped.device_stage = mirror.finish()
+            with rec.span("extract.micro_residual"):
+                mirror, coos = swapped.micro_residual
+                swapped.micro_residual = None
+                for coo in coos:
+                    mirror.feed(*coo)
+                swapped.device_stage = mirror.finish()
             if gov is not None:
                 gov.beat()
         dstage = swapped.device_stage
@@ -3203,10 +3281,15 @@ class DeviceWorker:
                           compression=self.compression))
 
             def _mirror_fold(fl):
-                return folder(*fl, dense(dstage.vals, s_eff),
-                              dense(dstage.wts, s_eff))
+                dv = dense(dstage.vals, s_eff)
+                dw = dense(dstage.wts, s_eff)
+                # the dispatch span's bytes: the planes the fold reads,
+                # which the guard cannot see among its arguments
+                rec.add("bytes", int(dv.nbytes) + int(dw.nbytes))
+                return folder(*fl, dv, dw)
 
-            fields = self.guard.call("staged", _mirror_fold, fields)
+            with rec.span("extract.mirror_fold"):
+                fields = self.guard.call("staged", _mirror_fold, fields)
             st["fields"] = fields
             if gov is not None:
                 gov.beat()
@@ -3221,27 +3304,35 @@ class DeviceWorker:
                                  replicas=sh.shards, put=sh.replicate)
         run = (gov.begin_extract(s_eff, sh.shards if sh else 1)
                if gov is not None and gov.enabled else None)
+        # extract.quantiles dispatches (extract + pack, both async);
+        # extract.readback is the packed D2H: the first call of the
+        # flush that blocks on the device, so it IS the device wait
         if run is None:
             if sh is not None:
                 # sharded extract bypasses the Pallas single-device
                 # kernel: the GSPMD XLA program runs shard-local and
                 # the one packed readback assembles all shards
-                out = self.guard.call("extract", sh.flush_extract,
-                                      *fields, qs, retryable=True)
-                packed = np.asarray(_pack_extract_columns(*out))
+                with rec.span("extract.quantiles"):
+                    out = self.guard.call("extract", sh.flush_extract,
+                                          *fields, qs, retryable=True)
+                    pk = _pack_extract_columns(*out)
+                with rec.span("extract.readback", wait=True):
+                    packed = np.asarray(pk)
                 self.ledger.count_d2h_shards(
                     [packed.nbytes // sh.shards] * sh.shards,
                     "extract_packed")
                 packed = packed[sh.perm_l2p(s_eff)]
             else:
-                out = self._extract(fields, qs)
+                with rec.span("extract.quantiles"):
+                    out = self._extract(fields, qs)
+                    pk = _pack_extract_columns(*out)
                 # ONE device→host transfer for the whole extraction:
                 # eleven per-array np.asarray calls are eleven
                 # synchronous D2H round-trips, and on a link with
                 # per-transfer latency the round-trips dominate the
                 # bytes at 1M rows
-                packed = self.ledger.d2h(
-                    _pack_extract_columns(*out), "extract_packed")
+                with rec.span("extract.readback", wait=True):
+                    packed = self.ledger.d2h(pk, "extract_packed")
             p = out[0].shape[1]
         else:
             # governed degraded mode: extract in row chunks sized to
@@ -3261,35 +3352,46 @@ class DeviceWorker:
                     # on every shard; the per-chunk inverse perm
                     # restores logical order, so the concat below is
                     # already logical end to end
-                    sub = tuple(sh.slice_chunk(a, run.start, c)
-                                for a in fields)
-                    out = self.guard.call("extract", sh.flush_extract,
-                                          *sub, qs, retryable=True)
-                    pk = np.asarray(_pack_extract_columns(*out))
+                    with rec.span("extract.quantiles"):
+                        sub = tuple(sh.slice_chunk(a, run.start, c)
+                                    for a in fields)
+                        out = self.guard.call(
+                            "extract", sh.flush_extract, *sub, qs,
+                            retryable=True)
+                        pk = _pack_extract_columns(*out)
+                    with rec.span("extract.readback", wait=True):
+                        pk = np.asarray(pk)
                     self.ledger.count_d2h_shards(
                         [pk.nbytes // sh.shards] * sh.shards,
                         "extract_packed")
                     parts.append(pk[sh.chunk_perm(c)])
                 else:
-                    sub = tuple(
-                        jax.lax.dynamic_slice_in_dim(a, run.start, c, 0)
-                        for a in fields)
-                    out = self._extract(sub, qs)
-                    parts.append(self.ledger.d2h(
-                        _pack_extract_columns(*out), "extract_packed"))
+                    with rec.span("extract.quantiles"):
+                        sub = tuple(
+                            jax.lax.dynamic_slice_in_dim(
+                                a, run.start, c, 0)
+                            for a in fields)
+                        out = self._extract(sub, qs)
+                        pk = _pack_extract_columns(*out)
+                    with rec.span("extract.readback", wait=True):
+                        parts.append(
+                            self.ledger.d2h(pk, "extract_packed"))
                 p = out[0].shape[1]
                 run.note(c, time.perf_counter() - t0)
             packed = (parts[0] if len(parts) == 1
                       else np.concatenate(parts, axis=0))
-        qv, (dmin, dmax, dsum, dcount, drecip, lmin, lmax, lsum,
-             lweight, lrecip) = columnar.unpack_extract_columns(
-                 packed, p)
-        snap.quantile_values = qv[:n]
-        snap.quantile_qs = np.asarray(quantiles, dtype=np.float64)
-        snap.dmin, snap.dmax = dmin[:n], dmax[:n]
-        snap.dsum, snap.dcount, snap.drecip = dsum[:n], dcount[:n], drecip[:n]
-        snap.lmin, snap.lmax = lmin[:n], lmax[:n]
-        snap.lsum, snap.lweight, snap.lrecip = lsum[:n], lweight[:n], lrecip[:n]
+        with rec.span("extract.unpack"):
+            qv, (dmin, dmax, dsum, dcount, drecip, lmin, lmax, lsum,
+                 lweight, lrecip) = columnar.unpack_extract_columns(
+                     packed, p)
+            snap.quantile_values = qv[:n]
+            snap.quantile_qs = np.asarray(quantiles, dtype=np.float64)
+            snap.dmin, snap.dmax = dmin[:n], dmax[:n]
+            snap.dsum, snap.dcount = dsum[:n], dcount[:n]
+            snap.drecip = drecip[:n]
+            snap.lmin, snap.lmax = lmin[:n], lmax[:n]
+            snap.lsum, snap.lweight = lsum[:n], lweight[:n]
+            snap.lrecip = lrecip[:n]
         sk = self.tenant_sketch
         if sk is not None and n:
             # heavy-hitter fold (core/tenancy.TenantSketch): one
@@ -3298,13 +3400,14 @@ class DeviceWorker:
             # the per-tenant count-min pool on device. Runs here —
             # off the ingest lock, extractions never overlap — so
             # detection costs the ingest path nothing.
-            hrows = directory.histo.rows
-            tenants = [m.tenant or DEFAULT_TENANT for m in hrows]
-            skeys = [m.key.key_string() for m in hrows]
-            kcounts = np.maximum(
-                np.nan_to_num(snap.dcount[:n]), 0).astype(np.int64)
-            sk.fold(tenants, skeys, kcounts,
-                    _next_pow2(min(len(skeys), 1 << 15), 256))
+            with rec.span("extract.tenant_sketch"):
+                hrows = directory.histo.rows
+                tenants = [m.tenant or DEFAULT_TENANT for m in hrows]
+                skeys = [m.key.key_string() for m in hrows]
+                kcounts = np.maximum(
+                    np.nan_to_num(snap.dcount[:n]), 0).astype(np.int64)
+                sk.fold(tenants, skeys, kcounts,
+                        _next_pow2(min(len(skeys), 1 << 15), 256))
         # the [S,C] centroid pools are read back ONLY where forwarding
         # can consume them (a local tier serializes digests upstream;
         # reference flusher.go:338-433). A terminal server — global or
@@ -3316,18 +3419,20 @@ class DeviceWorker:
         if self.is_local:
             if sh is not None:
                 l2p = sh.perm_l2p(s_eff)[:n]
-                dm = np.asarray(fields[0])
-                dw = np.asarray(fields[1])
+                with rec.span("extract.digest_readback", wait=True):
+                    dm = np.asarray(fields[0])
+                    dw = np.asarray(fields[1])
                 self.ledger.count_d2h_shards(
                     [(dm.nbytes + dw.nbytes) // sh.shards] * sh.shards,
                     "forward_digests")
                 snap.digest_means = dm[l2p]
                 snap.digest_weights = dw[l2p]
             else:
-                snap.digest_means = self.ledger.d2h(
-                    fields[0], "forward_digests")[:n]
-                snap.digest_weights = self.ledger.d2h(
-                    fields[1], "forward_digests")[:n]
+                with rec.span("extract.digest_readback", wait=True):
+                    snap.digest_means = self.ledger.d2h(
+                        fields[0], "forward_digests")[:n]
+                    snap.digest_weights = self.ledger.d2h(
+                        fields[1], "forward_digests")[:n]
         return fields, s_eff
 
     def _fields_to_host(self, fields) -> tuple:
@@ -3634,48 +3739,50 @@ class DeviceWorker:
             snap.lsum = np.zeros(n, np.float64)
             snap.lweight = np.zeros(n, np.float64)
             snap.lrecip = np.zeros(n, np.float64)
-        if staged_sets is not None and directory.num_set_rows:
-            n = directory.num_set_rows
-            snap.set_estimates = staged_sets.estimates(n)
-            # register materialization is [n, 2^p] host bytes — only pay
-            # it where forwarding can read it (locals forward mixed sets;
-            # a global is a terminal aggregator for them)
-            if self.is_local:
-                snap.set_registers = staged_sets.registers(n)
-            if staged_sets.host_mode:
-                # the store fell to (or started on) its host registers —
-                # the estimates above came from the np twin
-                _mark_degraded()
-        elif sets is not None and directory.num_set_rows:
-            n = directory.num_set_rows
-            if isinstance(sets, np.ndarray):
-                # quarantined epoch: host registers, np estimate twin
-                # (already in logical row order — _sets_to_host gathers)
-                _mark_degraded()
-                snap.set_estimates = he.np_hll_estimate_exact(
-                    sets, self.hll_precision)[:n]
-                snap.set_registers = sets[:n]
-            else:
-                try:
-                    if self._shard is not None:
-                        est = self.guard.call(
-                            "extract", self._shard.hll_estimate, sets,
-                            self.hll_precision, retryable=True)
-                        l2p = self._shard.perm_l2p(sets.shape[0])[:n]
-                        snap.set_estimates = np.asarray(est)[l2p]
-                        snap.set_registers = np.asarray(sets)[l2p]
-                    else:
-                        est = self.guard.call(
-                            "extract", hll_ops.estimate, sets,
-                            self.hll_precision, retryable=True)
-                        snap.set_estimates = np.asarray(est)[:n]
-                        snap.set_registers = np.asarray(sets)[:n]
-                except dg.DeviceFaultError:
+        with self.rec.span("extract.sets"):
+            if staged_sets is not None and directory.num_set_rows:
+                n = directory.num_set_rows
+                snap.set_estimates = staged_sets.estimates(n)
+                # register materialization is [n, 2^p] host bytes — only pay
+                # it where forwarding can read it (locals forward mixed sets;
+                # a global is a terminal aggregator for them)
+                if self.is_local:
+                    snap.set_registers = staged_sets.registers(n)
+                if staged_sets.host_mode:
+                    # the store fell to (or started on) its host registers —
+                    # the estimates above came from the np twin
                     _mark_degraded()
-                    regs = self._sets_to_host(sets)
+            elif sets is not None and directory.num_set_rows:
+                n = directory.num_set_rows
+                if isinstance(sets, np.ndarray):
+                    # quarantined epoch: host registers, np estimate twin
+                    # (already in logical row order — _sets_to_host gathers)
+                    _mark_degraded()
                     snap.set_estimates = he.np_hll_estimate_exact(
-                        regs, self.hll_precision)[:n]
-                    snap.set_registers = regs[:n]
+                        sets, self.hll_precision)[:n]
+                    snap.set_registers = sets[:n]
+                else:
+                    try:
+                        sh = self._shard
+                        est = self.guard.call(
+                            "extract",
+                            hll_ops.estimate if sh is None
+                            else sh.hll_estimate,
+                            sets, self.hll_precision, retryable=True)
+                        # logical row order: a sharded pool is permuted
+                        sel = (slice(n) if sh is None
+                               else sh.perm_l2p(sets.shape[0])[:n])
+                        with self.rec.span("extract.sets.readback",
+                                           wait=True):
+                            est, regs = np.asarray(est), np.asarray(sets)
+                        snap.set_estimates = est[sel]
+                        snap.set_registers = regs[sel]
+                    except dg.DeviceFaultError:
+                        _mark_degraded()
+                        regs = self._sets_to_host(sets)
+                        snap.set_estimates = he.np_hll_estimate_exact(
+                            regs, self.hll_precision)[:n]
+                        snap.set_registers = regs[:n]
         pub = self.query_publisher
         if pub is not None:
             # publish this epoch's read view. A publish failure must not
@@ -3684,9 +3791,10 @@ class DeviceWorker:
             self.query_epoch_seq += 1
             sk = self.tenant_sketch
             try:
-                pub(self.query_epoch_seq, snap,
-                    self._make_query_eval(view_fields, view_s_eff),
-                    sk.snapshot() if sk is not None else None)
+                with self.rec.span("extract.query_publish"):
+                    pub(self.query_epoch_seq, snap,
+                        self._make_query_eval(view_fields, view_s_eff),
+                        sk.snapshot() if sk is not None else None)
             except Exception:
                 log.exception("query view publish failed")
         return snap

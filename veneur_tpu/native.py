@@ -223,6 +223,12 @@ def load_library() -> Optional[ctypes.CDLL]:
             lib.vn_set_spill_cap.argtypes = [c.c_void_p, c.c_longlong]
         except AttributeError:
             pass
+        try:
+            lib.vn_reader_ns.restype = None
+            lib.vn_reader_ns.argtypes = [
+                c.c_void_p, c.POINTER(c.c_longlong)]
+        except AttributeError:
+            pass
         lib.vn_drain_histo.restype = c.c_int
         lib.vn_drain_histo.argtypes = [
             c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
@@ -467,6 +473,14 @@ class NativeIngest:
         """Samples shed at the pending-batch spill caps (overload)."""
         fn = getattr(self._lib, "vn_overload_dropped", None)
         return int(fn(self._ctx)) if fn is not None else 0
+
+    def reader_ns(self) -> tuple:
+        """(ns inside recv, ns outside it) of the C++ reader threads
+        homed on this context: lifetime totals, never reset. Raises
+        AttributeError on a stale .so (callers degrade)."""
+        out = (ctypes.c_longlong * 2)()
+        self._lib.vn_reader_ns(self._ctx, out)
+        return int(out[0]), int(out[1])
 
     def set_spill_cap(self, cap: int) -> None:
         """Entries per pending SoA batch before samples shed (tests /

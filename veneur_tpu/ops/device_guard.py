@@ -35,6 +35,7 @@ behavior for bisection.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import threading
@@ -117,6 +118,28 @@ def dispatch(op: str, fn: Callable, *args, **kwargs):
     return fn(*args, **kwargs)
 
 
+#: ops whose dispatch span carries ``bytes`` (the fold and the extract:
+#: the two a roofline share is asked of)
+_BYTES_OPS = frozenset(("staged", "extract"))
+
+
+def _nbytes(tree) -> int:
+    """Bytes of every array in a tuple/list nest (non-arrays count 0)."""
+    if isinstance(tree, (tuple, list)):
+        return sum(_nbytes(x) for x in tree)
+    return int(getattr(tree, "nbytes", 0))
+
+
+def wait_span(guard, name: str):
+    """A ``wait: true`` span of the guard's record around a call that
+    blocks on the device; a no-op where there is no guard or no record
+    (the mirror and the set store are also built without one)."""
+    rec = getattr(guard, "rec", None)
+    if rec is None:
+        return contextlib.nullcontext()
+    return rec.span(name, wait=True)
+
+
 class DeviceGuard:
     """Per-worker breaker over the guarded device path."""
 
@@ -136,6 +159,9 @@ class DeviceGuard:
         self._counters: dict[str, int] = {}
         # last classified fault, for the governor's panic verdict
         self.last_fault: Optional[str] = None
+        # the owning worker's span recorder (core/flightrec.py): every
+        # guarded dispatch is a span taken here, at the seam
+        self.rec = None
 
     # -- state reads ------------------------------------------------------
 
@@ -174,6 +200,19 @@ class DeviceGuard:
         worker replays the retained HOST inputs through the fallback
         engine instead (the no-epoch-lost contract).
         """
+        rec = self.rec
+        if rec is None:
+            return self._call(op, fn, args, retryable, kwargs)
+        with rec.span("dispatch", op=op):
+            out = self._call(op, fn, args, retryable, kwargs)
+            if op in _BYTES_OPS:
+                # operands read plus results written: the least HBM
+                # traffic the program can have
+                rec.add("bytes", _nbytes(args) + _nbytes(out))
+            return out
+
+    def _call(self, op: str, fn: Callable, args: tuple, retryable: bool,
+              kwargs: dict):
         if not self.enabled:
             return fn(*args, **kwargs)
         try:

@@ -93,13 +93,16 @@ def insert_batch(
     s, m = registers.shape
     flat = rows * m + reg_idx  # fits i32 for s·m < 2^31 (s ≤ 2^17 at p=14)
     rank32 = rank.astype(jnp.int32)
-    sflat, srank = jax.lax.sort((flat, rank32), dimension=0, num_keys=2)
-    is_end = jnp.concatenate(
-        [sflat[1:] != sflat[:-1], jnp.ones((1,), bool)])
-    vals = jnp.where(is_end, srank, 0)  # non-run-end → no-op max(·, 0)
-    out = registers.reshape(-1).at[sflat].max(
-        vals.astype(registers.dtype), mode="drop",
-        indices_are_sorted=True, unique_indices=False)
+    with jax.named_scope("hll.insert.sort"):
+        sflat, srank = jax.lax.sort((flat, rank32), dimension=0,
+                                    num_keys=2)
+    with jax.named_scope("hll.insert.scatter_max"):
+        is_end = jnp.concatenate(
+            [sflat[1:] != sflat[:-1], jnp.ones((1,), bool)])
+        vals = jnp.where(is_end, srank, 0)  # non-run-end → no-op max(·, 0)
+        out = registers.reshape(-1).at[sflat].max(
+            vals.astype(registers.dtype), mode="drop",
+            indices_are_sorted=True, unique_indices=False)
     return out.reshape(s, m)
 
 
@@ -137,14 +140,15 @@ def estimate(registers: jax.Array, precision: int = DEFAULT_PRECISION
     estimate bitwise.
     """
     m = float(num_registers(precision))
-    ranks = registers.astype(jnp.int32)
-    ept = jnp.asarray(exn.exp2_neg_table())
-    inv_sum = exn.tsum(ept[ranks])  # Σ 2^-reg, fixed association
-    zeros = jnp.sum((registers == 0).astype(jnp.int32), axis=-1)
-    raw = jnp.asarray(exn.hll_alpha_m2(precision)) / inv_sum
-    linear = jnp.asarray(exn.hll_linear_table(precision))[zeros]
-    use_linear = (raw <= jnp.float32(2.5 * m)) & (zeros > 0)
-    return jnp.where(use_linear, linear, raw)
+    with jax.named_scope("hll.estimate"):
+        ranks = registers.astype(jnp.int32)
+        ept = jnp.asarray(exn.exp2_neg_table())
+        inv_sum = exn.tsum(ept[ranks])  # Σ 2^-reg, fixed association
+        zeros = jnp.sum((registers == 0).astype(jnp.int32), axis=-1)
+        raw = jnp.asarray(exn.hll_alpha_m2(precision)) / inv_sum
+        linear = jnp.asarray(exn.hll_linear_table(precision))[zeros]
+        use_linear = (raw <= jnp.float32(2.5 * m)) & (zeros > 0)
+        return jnp.where(use_linear, linear, raw)
 
 
 # ---------------------------------------------------------------------------

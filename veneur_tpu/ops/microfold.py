@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from veneur_tpu.ops.device_guard import wait_span
+
 # COO entries per upload chunk. 65536 x 16B = 1 MB per dispatch: large
 # enough to amortize dispatch overhead (and, on backends that cannot
 # honor the scatter's donation — XLA-CPU copies the whole [M, B] mirror
@@ -62,8 +64,9 @@ DROP_ROW = np.int32(np.iinfo(np.int32).max)
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_chunk(dvals, dwts, rows, slots, vals, wts):
     """Scatter one COO chunk into the mirror (padding rows dropped)."""
-    dvals = dvals.at[rows, slots].set(vals, mode="drop")
-    dwts = dwts.at[rows, slots].set(wts, mode="drop")
+    with jax.named_scope("microfold.scatter"):
+        dvals = dvals.at[rows, slots].set(vals, mode="drop")
+        dwts = dwts.at[rows, slots].set(wts, mode="drop")
     return dvals, dwts
 
 
@@ -71,7 +74,8 @@ def _scatter_chunk(dvals, dwts, rows, slots, vals, wts):
                    donate_argnums=(0,))
 def _grow_mirror(old, new_rows: int):
     s, b = old.shape
-    return jnp.zeros((new_rows, b), old.dtype).at[:s].set(old)
+    with jax.named_scope("microfold.grow"):
+        return jnp.zeros((new_rows, b), old.dtype).at[:s].set(old)
 
 
 def mirror_dense(arr, s_eff: int):
@@ -247,7 +251,8 @@ class MicroFoldMirror:
         # double-buffer fence: at most two unsynced scatters queued
         self._unsynced += 1
         if self._unsynced > 2:
-            jax.block_until_ready(self._dvals)
+            with wait_span(self._guard, "micro.fence"):
+                jax.block_until_ready(self._dvals)
             self._unsynced = 1
         scatter = _scatter_chunk if sh is None else sh.scatter_chunk
         if self._guard is not None:

@@ -64,45 +64,46 @@ def segmented_cumsum(values: jax.Array, starts: jax.Array) -> jax.Array:
 
     values: f32[N]; starts: bool[N]. Returns f32[N].
     """
-    n = values.shape[0]
-    v2 = _pad_to_chunks(values, 0.0)
-    s2 = _pad_to_chunks(starts, False)
-    s2 = s2.at[0, 0].set(True)
-    g, l = v2.shape
+    with jax.named_scope("segments.segmented_cumsum"):
+        n = values.shape[0]
+        v2 = _pad_to_chunks(values, 0.0)
+        s2 = _pad_to_chunks(starts, False)
+        s2 = s2.at[0, 0].set(True)
+        g, l = v2.shape
 
-    # Per-chunk segmented Hillis-Steele scan (col 0 treated as a reset;
-    # the true cross-chunk carry is stitched below).
-    v = v2
-    f = s2.at[:, 0].set(True)
-    shift = 1
-    while shift < l:
-        vs = jnp.pad(v, ((0, 0), (shift, 0)))[:, :l]
-        fs = jnp.pad(f, ((0, 0), (shift, 0)), constant_values=True)[:, :l]
-        v = jnp.where(f, v, v + vs)
-        f = f | fs
-        shift *= 2
+        # Per-chunk segmented Hillis-Steele scan (col 0 treated as a reset;
+        # the true cross-chunk carry is stitched below).
+        v = v2
+        f = s2.at[:, 0].set(True)
+        shift = 1
+        while shift < l:
+            vs = jnp.pad(v, ((0, 0), (shift, 0)))[:, :l]
+            fs = jnp.pad(f, ((0, 0), (shift, 0)), constant_values=True)[:, :l]
+            v = jnp.where(f, v, v + vs)
+            f = f | fs
+            shift *= 2
 
-    # Cross-chunk carry: the open-run total entering chunk g is itself a
-    # segmented inclusive cumsum of the chunks' last-column values,
-    # restarting at any chunk that contains a real start — the SAME
-    # Hillis loop as above, run once at the chunk level.
-    has_start = jnp.any(s2, axis=1)
-    cv = v[:, -1]
-    cf = has_start.at[0].set(True)
-    shift = 1
-    while shift < g:
-        cvs = jnp.pad(cv, (shift, 0))[:g]
-        cfs = jnp.pad(cf, (shift, 0), constant_values=True)[:g]
-        cv = jnp.where(cf, cv, cv + cvs)
-        cf = cf | cfs
-        shift *= 2
-    carry_in = _shift_right(cv, jnp.zeros((), values.dtype))
-    # carry applies to the head run only: elements before the first real
-    # start of the chunk. (Select, not multiply-by-mask: the add order
-    # stays pinned and nothing invites contraction.)
-    before_first = jnp.cumsum(s2.astype(jnp.int32), axis=1) == 0
-    out = jnp.where(before_first, v + carry_in[:, None], v)
-    return out.reshape(-1)[:n]
+        # Cross-chunk carry: the open-run total entering chunk g is itself a
+        # segmented inclusive cumsum of the chunks' last-column values,
+        # restarting at any chunk that contains a real start — the SAME
+        # Hillis loop as above, run once at the chunk level.
+        has_start = jnp.any(s2, axis=1)
+        cv = v[:, -1]
+        cf = has_start.at[0].set(True)
+        shift = 1
+        while shift < g:
+            cvs = jnp.pad(cv, (shift, 0))[:g]
+            cfs = jnp.pad(cf, (shift, 0), constant_values=True)[:g]
+            cv = jnp.where(cf, cv, cv + cvs)
+            cf = cf | cfs
+            shift *= 2
+        carry_in = _shift_right(cv, jnp.zeros((), values.dtype))
+        # carry applies to the head run only: elements before the first real
+        # start of the chunk. (Select, not multiply-by-mask: the add order
+        # stays pinned and nothing invites contraction.)
+        before_first = jnp.cumsum(s2.astype(jnp.int32), axis=1) == 0
+        out = jnp.where(before_first, v + carry_in[:, None], v)
+        return out.reshape(-1)[:n]
 
 
 def np_segmented_cumsum(values: np.ndarray, starts: np.ndarray) -> np.ndarray:
@@ -157,24 +158,25 @@ def last_marked_carry(mask: jax.Array, *values: jax.Array
     this loop, the same shape as segmented_cumsum's, compiles in
     seconds.)
     """
-    pad = [(0, 0)] * (mask.ndim - 1) + [(1, 0)]
-    m = jnp.pad(mask, pad)[..., :-1]
-    vs = [jnp.pad(v, pad)[..., :-1] for v in values]
-    n = m.shape[-1]
+    with jax.named_scope("segments.last_marked_carry"):
+        pad = [(0, 0)] * (mask.ndim - 1) + [(1, 0)]
+        m = jnp.pad(mask, pad)[..., :-1]
+        vs = [jnp.pad(v, pad)[..., :-1] for v in values]
+        n = m.shape[-1]
 
-    def shift_right(x, k, fill=False):
-        p = [(0, 0)] * (x.ndim - 1) + [(k, 0)]
-        return jnp.pad(x, p, constant_values=fill)[..., :n]
+        def shift_right(x, k, fill=False):
+            p = [(0, 0)] * (x.ndim - 1) + [(k, 0)]
+            return jnp.pad(x, p, constant_values=fill)[..., :n]
 
-    shift = 1
-    while shift < n:
-        # invariant: (m, vs) at i reflect the last mark in (i-2^k, i]
-        m_s = shift_right(m, shift)
-        vs = [jnp.where(m, v, shift_right(v, shift, 0))
-              for v in vs]
-        m = m | m_s
-        shift *= 2
-    return tuple(vs)
+        shift = 1
+        while shift < n:
+            # invariant: (m, vs) at i reflect the last mark in (i-2^k, i]
+            m_s = shift_right(m, shift)
+            vs = [jnp.where(m, v, shift_right(v, shift, 0))
+                  for v in vs]
+            m = m | m_s
+            shift *= 2
+        return tuple(vs)
 
 
 def np_last_marked_carry(mask: np.ndarray, *values: np.ndarray

@@ -37,7 +37,7 @@ import numpy as np
 
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import host_engine as he
-from veneur_tpu.ops.device_guard import DeviceFaultError
+from veneur_tpu.ops.device_guard import DeviceFaultError, wait_span
 
 
 class StagedSetStore:
@@ -361,16 +361,15 @@ class StagedSetStore:
             dense_est = None
             if not self._host:
                 try:
-                    if self._shard is not None:
-                        sh = self._shard
-                        dense_est = np.asarray(self._dev_call(
-                            sh.hll_estimate, self._dense, self.precision,
-                            retryable=True
-                        ))[sh.perm_l2p(self._dense.shape[0])]
-                    else:
-                        dense_est = np.asarray(self._dev_call(
-                            hll_ops.estimate, self._dense, self.precision,
-                            retryable=True))
+                    sh = self._shard
+                    est = self._dev_call(
+                        hll_ops.estimate if sh is None else sh.hll_estimate,
+                        self._dense, self.precision, retryable=True)
+                    with wait_span(self._guard, "sets.readback"):
+                        dense_est = np.asarray(est)
+                    if sh is not None:
+                        dense_est = dense_est[
+                            sh.perm_l2p(self._dense.shape[0])]
                 except DeviceFaultError:
                     self.to_host()
             if dense_est is None:
@@ -398,7 +397,8 @@ class StagedSetStore:
             if self._host:
                 dense_np = self._dense
             else:
-                dense_np = np.asarray(self._dense)
+                with wait_span(self._guard, "sets.readback"):
+                    dense_np = np.asarray(self._dense)
                 if self._shard is not None:
                     dense_np = dense_np[
                         self._shard.perm_l2p(self._dense.shape[0])]

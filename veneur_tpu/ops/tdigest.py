@@ -66,16 +66,17 @@ def _prefix_scans_xla(srows, svals, sw, n):
     spill and import merge paths, whose batches are too small for a
     custom kernel to pay for itself. The Pallas kernel that remains on a
     hot path is flush_extract (ops/pallas_kernels.py)."""
-    zero1 = jnp.zeros((1,), sw.dtype)
-    pre_w = jnp.concatenate([zero1, exn.cumsum(sw)])  # [N+1]
-    pre_vw = jnp.concatenate([zero1, exn.cumsum(exn.block(svals * sw))])
-    pre_recip = jnp.concatenate(
-        [zero1, exn.cumsum(jnp.where(sw > 0, sw / svals, 0.0))])
-    row_starts = jnp.concatenate(
-        [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
-    seg_cum = segments.segmented_cumsum(sw, row_starts)
-    row_ends = jnp.concatenate([row_starts[1:], jnp.ones((1,), bool)])
-    suffix = segments.segmented_cumsum(sw[::-1], row_ends[::-1])[::-1]
+    with jax.named_scope("tdigest.prefix_scans"):
+        zero1 = jnp.zeros((1,), sw.dtype)
+        pre_w = jnp.concatenate([zero1, exn.cumsum(sw)])  # [N+1]
+        pre_vw = jnp.concatenate([zero1, exn.cumsum(exn.block(svals * sw))])
+        pre_recip = jnp.concatenate(
+            [zero1, exn.cumsum(jnp.where(sw > 0, sw / svals, 0.0))])
+        row_starts = jnp.concatenate(
+            [jnp.ones((1,), bool), srows[1:] != srows[:-1]])
+        seg_cum = segments.segmented_cumsum(sw, row_starts)
+        row_ends = jnp.concatenate([row_starts[1:], jnp.ones((1,), bool)])
+        suffix = segments.segmented_cumsum(sw[::-1], row_ends[::-1])[::-1]
     return pre_w, pre_vw, pre_recip, seg_cum, suffix
 
 
@@ -136,7 +137,8 @@ def _k_bucket(q: jax.Array, compression: float, capacity: int) -> jax.Array:
     the device does a comparison-exact searchsorted — bitwise
     reproducible by the host engine's NumPy twin, and cheaper than a
     transcendental on every element."""
-    return jnp.clip(exn.kscale_bucket(q, compression), 0, capacity - 1)
+    with jax.named_scope("tdigest.k_bucket"):
+        return jnp.clip(exn.kscale_bucket(q, compression), 0, capacity - 1)
 
 
 def _compress_rows(
@@ -150,9 +152,10 @@ def _compress_rows(
     s, m = means.shape
     # 1. Sort each row by mean, carrying weights. Zero-weight slots are
     #    keyed to +inf so they sort to the end.
-    sort_keys = jnp.where(weights > 0, means, _INF)
-    sorted_means, sorted_w = jax.lax.sort(
-        (sort_keys, weights), dimension=-1, num_keys=1
+    with jax.named_scope("tdigest.compress.sort"):
+        sort_keys = jnp.where(weights > 0, means, _INF)
+        sorted_means, sorted_w = jax.lax.sort(
+            (sort_keys, weights), dimension=-1, num_keys=1
     )
     # Stage barriers: each stage's outputs feed several consumers below;
     # without them XLA's fusion duplicates whole producer chains into
@@ -162,13 +165,14 @@ def _compress_rows(
         (sorted_means, sorted_w))
     # 2. Per-row cumulative weight and left-edge quantile. (Order-pinned
     #    Hillis scan — the host engine twin mirrors it bitwise.)
-    w_cum = exn.cumsum(sorted_w)
-    total = w_cum[:, -1:]
-    q_left = (w_cum - sorted_w) / jnp.maximum(total, 1e-30)
-    # 3. Quantize to k-function buckets. (Zero-weight padding slots land in
-    #    whatever bucket q=1 maps to; they only ever extend a run with zero
-    #    weight, so the sums below are unaffected.)
-    bucket = _k_bucket(q_left, compression, capacity)
+    with jax.named_scope("tdigest.compress.scan"):
+        w_cum = exn.cumsum(sorted_w)
+        total = w_cum[:, -1:]
+        q_left = (w_cum - sorted_w) / jnp.maximum(total, 1e-30)
+        # 3. Quantize to k-function buckets. (Zero-weight padding slots land in
+        #    whatever bucket q=1 maps to; they only ever extend a run with zero
+        #    weight, so the sums below are unaffected.)
+        bucket = _k_bucket(q_left, compression, capacity)
     w_cum, bucket = jax.lax.optimization_barrier((w_cum, bucket))
     # 4. Bucket accumulation, scatter- AND broadcast-free: buckets are
     #    non-decreasing along a sorted row, so each bucket is one
@@ -177,23 +181,25 @@ def _compress_rows(
     #    so results stay where the run ends and a sort compacts them.
     #    (The previous [S, M, C] compare+select+reduce formulation was
     #    fused but compute-bound: ~34G lane-ops at S=1M; this is O(S·M).)
-    mw_cum = exn.cumsum(
-        jnp.where(sorted_w > 0, sorted_means * sorted_w, 0.0))
-    nxt = jnp.concatenate(
-        [bucket[:, 1:], jnp.full((s, 1), -1, jnp.int32)], axis=-1)
-    is_end = bucket != nxt  # last slot of each bucket run (row end included)
-    w_before, mw_before = segments.last_marked_carry(is_end, w_cum, mw_cum)
-    seg_w = w_cum - w_before
-    seg_mw = mw_cum - mw_before
-    live = is_end & (seg_w > 0)
-    new_means = jnp.where(live, seg_mw / jnp.maximum(seg_w, 1e-30), _INF)
-    new_w = jnp.where(live, seg_w, 0.0)
+    with jax.named_scope("tdigest.compress.merge"):
+        mw_cum = exn.cumsum(
+            jnp.where(sorted_w > 0, sorted_means * sorted_w, 0.0))
+        nxt = jnp.concatenate(
+            [bucket[:, 1:], jnp.full((s, 1), -1, jnp.int32)], axis=-1)
+        is_end = bucket != nxt  # last slot of each bucket run (row end included)
+        w_before, mw_before = segments.last_marked_carry(is_end, w_cum, mw_cum)
+        seg_w = w_cum - w_before
+        seg_mw = mw_cum - mw_before
+        live = is_end & (seg_w > 0)
+        new_means = jnp.where(live, seg_mw / jnp.maximum(seg_w, 1e-30), _INF)
+        new_w = jnp.where(live, seg_w, 0.0)
     new_means, new_w = jax.lax.optimization_barrier((new_means, new_w))
     # 5. Sort by mean (empties keyed +inf sort last) and keep the first
     #    `capacity` slots — the k-function emits ≤ δ+1 ≤ capacity buckets,
     #    so the slice only ever drops padding.
-    new_means, new_w = jax.lax.sort((new_means, new_w), dimension=-1,
-                                    num_keys=1)
+    with jax.named_scope("tdigest.compress.resort"):
+        new_means, new_w = jax.lax.sort((new_means, new_w), dimension=-1,
+                                        num_keys=1)
     return new_means[:, :capacity], new_w[:, :capacity]
 
 
@@ -257,9 +263,10 @@ def add_batch(
     safe_vals = jnp.where(live, values, 1.0)
 
     # --- 1. Sort the batch by (row, value). Padding is the tail run.
-    srows, svals, sw = jax.lax.sort(
-        (rows, safe_vals, sample_weights), dimension=0, num_keys=2
-    )
+    with jax.named_scope("tdigest.add_batch.sort"):
+        srows, svals, sw = jax.lax.sort(
+            (rows, safe_vals, sample_weights), dimension=0, num_keys=2
+        )
 
     # --- 2. Per-row stats, scatter-free (TPU-first): rows are contiguous
     #        runs in the sorted order, so every per-row reduction is either
@@ -269,22 +276,23 @@ def add_batch(
     pre_w, pre_vw, pre_recip, seg_cum, suffix = _prefix_scans_xla(
         srows, svals, sw, n)
 
-    kbins = jnp.arange(k, dtype=jnp.int32)
-    row_upper = jnp.searchsorted(srows, kbins, side="right").astype(jnp.int32)
-    row_lower = jnp.concatenate([jnp.zeros((1,), jnp.int32), row_upper[:-1]])
+    with jax.named_scope("tdigest.add_batch.row_stats"):
+        kbins = jnp.arange(k, dtype=jnp.int32)
+        row_upper = jnp.searchsorted(srows, kbins, side="right").astype(jnp.int32)
+        row_lower = jnp.concatenate([jnp.zeros((1,), jnp.int32), row_upper[:-1]])
 
-    seg_w = (jnp.take(pre_w, row_upper) - jnp.take(pre_w, row_lower))
-    seg_sum = (jnp.take(pre_vw, row_upper) - jnp.take(pre_vw, row_lower))
-    seg_recip = (jnp.take(pre_recip, row_upper)
-                 - jnp.take(pre_recip, row_lower))
-    # min/max: every sample inside a real row's run is live (padding was
-    # keyed past row k-1) and values sort ascending within the row, so the
-    # row min/max are the run's first/last elements — two boundary gathers.
-    has = seg_w > 0
-    seg_min = jnp.where(has, jnp.take(svals, row_lower), _INF)
-    seg_max = jnp.where(
-        has, jnp.take(svals, jnp.maximum(row_upper - 1, 0)), -_INF)
-    stats = BatchStats(seg_w, seg_min, seg_max, seg_sum, seg_recip)
+        seg_w = (jnp.take(pre_w, row_upper) - jnp.take(pre_w, row_lower))
+        seg_sum = (jnp.take(pre_vw, row_upper) - jnp.take(pre_vw, row_lower))
+        seg_recip = (jnp.take(pre_recip, row_upper)
+                     - jnp.take(pre_recip, row_lower))
+        # min/max: every sample inside a real row's run is live (padding was
+        # keyed past row k-1) and values sort ascending within the row, so the
+        # row min/max are the run's first/last elements — two boundary gathers.
+        has = seg_w > 0
+        seg_min = jnp.where(has, jnp.take(svals, row_lower), _INF)
+        seg_max = jnp.where(
+            has, jnp.take(svals, jnp.maximum(row_upper - 1, 0)), -_INF)
+        stats = BatchStats(seg_w, seg_min, seg_max, seg_sum, seg_recip)
 
     # --- 3. Batch digest: segmented cumulative weight → k-bucket per
     #        sample → per-(row, bucket) run sums. Scatter-free and
@@ -295,51 +303,52 @@ def add_batch(
     #        previous run-sum scheme resolved runs with a searchsorted over
     #        chunk offsets — a [K·C]-sized gather-chain binary search that
     #        alone cost ~80% of add_batch on v5e.)
-    row_total = seg_cum + suffix - sw  # per-sample total weight of its row
-    q_left = (seg_cum - sw) / jnp.maximum(row_total, 1e-30)
-    bucket = _k_bucket(q_left, compression, c)
-    # Non-decreasing run id; padding (row k) forms its own tail runs that
-    # no real row's run window reaches.
-    seg_id = srows * c + bucket
-    starts = jnp.concatenate(
-        [jnp.ones((1,), bool), seg_id[1:] != seg_id[:-1]])
-    grank = jnp.cumsum(starts.astype(jnp.int32)) - 1  # global run index [N]
-    # Dense run-start position table: ascending sort compacts the R true
-    # start positions to the front, sentinel n after — so pos_ext[r] is
-    # run r's first element and pos_ext[r+1] its end (the next run's
-    # start, or n for the last run).
-    pos = jnp.where(starts, jnp.arange(n, dtype=jnp.int32), n)
-    pos_ext = jnp.concatenate(
-        [jax.lax.sort(pos), jnp.full((1,), n, jnp.int32)])
-    run_lo = jnp.take(grank, jnp.clip(row_lower, 0, n - 1))  # [K]
-    run_hi = jnp.take(grank, jnp.maximum(row_upper - 1, 0)) + 1
-    n_runs_row = jnp.where(has, run_hi - run_lo, 0)  # [K]
-    j = jnp.arange(c, dtype=jnp.int32)
-    runs = jnp.clip(run_lo[:, None] + j[None, :], 0, n - 1)  # [K, C]
-    valid = j[None, :] < n_runs_row[:, None]
-    # every [K, C]-shaped gather below is ~2M probes at fixed per-element
-    # cost — the dominant fixed cost of this function on TPU — so: fetch
-    # run starts once; run ends are the NEXT run's start (shift within
-    # the row window), and the last run of a row ends where the row does
-    # (row_upper — already known, no gather)
-    r_start = jnp.take(pos_ext, runs)
-    last = j[None, :] == (n_runs_row - 1)[:, None]
-    # prefix sums fetched as 2-lane pairs: one gather of [K, C, 2]
-    # instead of two of [K, C] per endpoint
-    pre = jnp.stack([pre_w, pre_vw], axis=-1)  # [N+1, 2]
-    at_start = jnp.take(pre, r_start, axis=0)  # [K, C, 2]
-    # run ends need no second [K, C, 2] gather: a run ends where the NEXT
-    # run starts, so at_end is at_start shifted one lane left — except a
-    # row's last run, which ends at the row end (pre[row_upper], a plain
-    # [K, 2] gather). Halves the dominant gather volume of this step.
-    at_row_end = jnp.take(pre, row_upper, axis=0)  # [K, 2]
-    at_next = jnp.concatenate(
-        [at_start[:, 1:, :], jnp.zeros((k, 1, 2), at_start.dtype)], axis=1)
-    at_end = jnp.where(last[:, :, None], at_row_end[:, None, :], at_next)
-    diff = at_end - at_start
-    bd_w = jnp.where(valid, diff[..., 0], 0.0)
-    bd_mw = jnp.where(valid, diff[..., 1], 0.0)
-    bd_means = jnp.where(bd_w > 0, bd_mw / jnp.maximum(bd_w, 1e-30), _INF)
+    with jax.named_scope("tdigest.add_batch.batch_digest"):
+        row_total = seg_cum + suffix - sw  # per-sample total weight of its row
+        q_left = (seg_cum - sw) / jnp.maximum(row_total, 1e-30)
+        bucket = _k_bucket(q_left, compression, c)
+        # Non-decreasing run id; padding (row k) forms its own tail runs that
+        # no real row's run window reaches.
+        seg_id = srows * c + bucket
+        starts = jnp.concatenate(
+            [jnp.ones((1,), bool), seg_id[1:] != seg_id[:-1]])
+        grank = jnp.cumsum(starts.astype(jnp.int32)) - 1  # global run index [N]
+        # Dense run-start position table: ascending sort compacts the R true
+        # start positions to the front, sentinel n after — so pos_ext[r] is
+        # run r's first element and pos_ext[r+1] its end (the next run's
+        # start, or n for the last run).
+        pos = jnp.where(starts, jnp.arange(n, dtype=jnp.int32), n)
+        pos_ext = jnp.concatenate(
+            [jax.lax.sort(pos), jnp.full((1,), n, jnp.int32)])
+        run_lo = jnp.take(grank, jnp.clip(row_lower, 0, n - 1))  # [K]
+        run_hi = jnp.take(grank, jnp.maximum(row_upper - 1, 0)) + 1
+        n_runs_row = jnp.where(has, run_hi - run_lo, 0)  # [K]
+        j = jnp.arange(c, dtype=jnp.int32)
+        runs = jnp.clip(run_lo[:, None] + j[None, :], 0, n - 1)  # [K, C]
+        valid = j[None, :] < n_runs_row[:, None]
+        # every [K, C]-shaped gather below is ~2M probes at fixed per-element
+        # cost — the dominant fixed cost of this function on TPU — so: fetch
+        # run starts once; run ends are the NEXT run's start (shift within
+        # the row window), and the last run of a row ends where the row does
+        # (row_upper — already known, no gather)
+        r_start = jnp.take(pos_ext, runs)
+        last = j[None, :] == (n_runs_row - 1)[:, None]
+        # prefix sums fetched as 2-lane pairs: one gather of [K, C, 2]
+        # instead of two of [K, C] per endpoint
+        pre = jnp.stack([pre_w, pre_vw], axis=-1)  # [N+1, 2]
+        at_start = jnp.take(pre, r_start, axis=0)  # [K, C, 2]
+        # run ends need no second [K, C, 2] gather: a run ends where the NEXT
+        # run starts, so at_end is at_start shifted one lane left — except a
+        # row's last run, which ends at the row end (pre[row_upper], a plain
+        # [K, 2] gather). Halves the dominant gather volume of this step.
+        at_row_end = jnp.take(pre, row_upper, axis=0)  # [K, 2]
+        at_next = jnp.concatenate(
+            [at_start[:, 1:, :], jnp.zeros((k, 1, 2), at_start.dtype)], axis=1)
+        at_end = jnp.where(last[:, :, None], at_row_end[:, None, :], at_next)
+        diff = at_end - at_start
+        bd_w = jnp.where(valid, diff[..., 0], 0.0)
+        bd_mw = jnp.where(valid, diff[..., 1], 0.0)
+        bd_means = jnp.where(bd_w > 0, bd_mw / jnp.maximum(bd_w, 1e-30), _INF)
 
     # --- 4. Merge with the existing rows and recompress.
     cat_means = jnp.concatenate([means, bd_means], axis=-1)
@@ -421,46 +430,47 @@ def _quantile_impl(
     qs: jax.Array,
     use_gather: bool,
 ) -> jax.Array:
-    s, c = means.shape
-    ub, count = _row_bounds(means, weights, dmax)  # [S, C], [S]
-    w_cum = exn.cumsum(weights)  # [S, C]
-    total = w_cum[:, -1]  # [S]
-    lb = jnp.concatenate([dmin[:, None], ub[:, :-1]], axis=-1)  # [S, C]
+    with jax.named_scope("tdigest.quantile"):
+        s, c = means.shape
+        ub, count = _row_bounds(means, weights, dmax)  # [S, C], [S]
+        w_cum = exn.cumsum(weights)  # [S, C]
+        total = w_cum[:, -1]  # [S]
+        lb = jnp.concatenate([dmin[:, None], ub[:, :-1]], axis=-1)  # [S, C]
 
-    target = exn.block(qs[None, :] * total[:, None])  # [S, P]
-    # first slot whose cumulative weight reaches the target
-    # (reference: q <= weightSoFar + c.Weight), then interpolate inside
-    # it. Two equivalent formulations (bit-identical — pinned by
-    # test_quantile_gather_and_mask_forms_agree):
-    if use_gather:
-        # hosts (CPU fallback): per-row binary search + gather is 4.4x
-        # the masked-reduce form at 64k series — no [S, C, P]
-        # materialization, O(P log C) per row instead of O(C·P)
-        first_idx = jax.vmap(
-            lambda cw, t: jnp.searchsorted(cw, t, side="left"))(
-                w_cum, target)  # [S, P]
-        first_idx = jnp.minimum(first_idx, c - 1)
+        target = exn.block(qs[None, :] * total[:, None])  # [S, P]
+        # first slot whose cumulative weight reaches the target
+        # (reference: q <= weightSoFar + c.Weight), then interpolate inside
+        # it. Two equivalent formulations (bit-identical — pinned by
+        # test_quantile_gather_and_mask_forms_agree):
+        if use_gather:
+            # hosts (CPU fallback): per-row binary search + gather is 4.4x
+            # the masked-reduce form at 64k series — no [S, C, P]
+            # materialization, O(P log C) per row instead of O(C·P)
+            first_idx = jax.vmap(
+                lambda cw, t: jnp.searchsorted(cw, t, side="left"))(
+                    w_cum, target)  # [S, P]
+            first_idx = jnp.minimum(first_idx, c - 1)
 
-        def _at(x):  # [S, C] → [S, P] value at the found slot
-            return jnp.take_along_axis(x, first_idx, axis=1)
-    else:
-        # one-hot + masked reduces over [S, C, P]: at S=1M the
-        # [S, P]-shaped take_along_axis gathers are the slow path on
-        # TPU, while select+reduce streams through the VPU
-        reached = target[:, None, :] <= w_cum[:, :, None]  # [S, C, P]
-        first = reached & ~jnp.pad(
-            reached[:, :-1, :], ((0, 0), (1, 0), (0, 0)))  # one-hot
+            def _at(x):  # [S, C] → [S, P] value at the found slot
+                return jnp.take_along_axis(x, first_idx, axis=1)
+        else:
+            # one-hot + masked reduces over [S, C, P]: at S=1M the
+            # [S, P]-shaped take_along_axis gathers are the slow path on
+            # TPU, while select+reduce streams through the VPU
+            reached = target[:, None, :] <= w_cum[:, :, None]  # [S, C, P]
+            first = reached & ~jnp.pad(
+                reached[:, :-1, :], ((0, 0), (1, 0), (0, 0)))  # one-hot
 
-        def _at(x):  # [S, C] → [S, P] value at the one-hot slot
-            return jnp.sum(jnp.where(first, x[:, :, None], 0.0), axis=1)
+            def _at(x):  # [S, C] → [S, P] value at the one-hot slot
+                return jnp.sum(jnp.where(first, x[:, :, None], 0.0), axis=1)
 
-    w_at = _at(weights)
-    w_before = _at(w_cum) - w_at
-    lb_at = _at(lb)
-    ub_at = _at(ub)
-    proportion = (target - w_before) / jnp.maximum(w_at, 1e-30)
-    out = lb_at + exn.block(proportion * (ub_at - lb_at))
-    return jnp.where((total[:, None] > 0) & (count[:, None] > 0), out, jnp.nan)
+        w_at = _at(weights)
+        w_before = _at(w_cum) - w_at
+        lb_at = _at(lb)
+        ub_at = _at(ub)
+        proportion = (target - w_before) / jnp.maximum(w_at, 1e-30)
+        out = lb_at + exn.block(proportion * (ub_at - lb_at))
+        return jnp.where((total[:, None] > 0) & (count[:, None] > 0), out, jnp.nan)
 
 
 def quantile(
