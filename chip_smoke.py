@@ -19,7 +19,7 @@ it runs the same path (the rehearsal) and can only end ``"ok": false``.
 Every earlier line of standard output is one JSON object with an
 ``event`` key; the last line is the verdict and nothing else.
 
-    python chip_smoke.py                  # one chip, 2^18 timer series
+    python chip_smoke.py                  # one chip, 2^20 timer series
     python chip_smoke.py --chips 4        # only the series_shards=4 path
     JAX_PLATFORMS=cpu python chip_smoke.py --series 4096   # rehearsal
 """
@@ -313,27 +313,36 @@ class CompileClock(logging.Handler):
                 "programs": progs}
 
 
-def await_ticker(server, last: dict, first_tick: float) -> tuple[float, int]:
+def await_ticker(server, last: dict) -> tuple[float, int]:
     """The time of the next window's tick, and how many quiet ticks went
     by first. After a warm flush that is one interval after its own
     tick. A flush that compiled may have run past its interval (a warm
     one that does has already failed the run); the ticker then fires the
     ticks it missed back to back, some of them slowly, and a window that
     started among them would be cut at once. So after a cold flush, wait
-    for a quiet flush that began on the ticker's schedule (the first
-    tick plus whole intervals) and has ended, and start behind it."""
+    for a quiet flush that began one interval after the flush before it,
+    as only a tick the ticker waited for does, and has ended, and start
+    behind it. (Not "the first window's tick plus whole intervals": one
+    tick that the send's adoption made 0.09 s late was the phase every
+    later tick missed, at 2^20 series in PR 26.)"""
     if not last["cold"]:
         return last["tick"] + INTERVAL_S, 0
     fc0 = server.flush_count
     t_in = time.time()
+    before = began = server.last_flush_unix
     while True:
-        began = server.last_flush_unix
-        off = (began - first_tick) % INTERVAL_S
-        if (began > t_in and min(off, INTERVAL_S - off) < 0.05
+        latest = server.last_flush_unix
+        if latest != began:
+            before, began = began, latest
+        if (began > t_in and abs(began - before - INTERVAL_S) < 0.05
                 and server.last_emit_unix >= began):
             break
         if time.time() > t_in + 30 * INTERVAL_S:
-            raise SmokeFailure("the ticker did not resume its schedule")
+            raise SmokeFailure(
+                "the ticker did not resume its schedule: "
+                f"{server.flush_count - fc0} flushes in {30 * INTERVAL_S:.0f}s,"
+                f" the last began {began - before:.3f}s after the one before "
+                f"it and {'ended' if server.last_emit_unix >= began else 'runs'}")
         time.sleep(0.01)
     emit("ticker_resumed", after_flush_s=last["flush_tick_to_sink_s"],
          waited_s=time.time() - t_in, quiet_flush_s=time.time() - began)
@@ -361,8 +370,7 @@ def run_windows(server, collector, traffic, port: int, t_start: float,
         for k in range(1, WINDOWS + 1):
             quiet = 0
             if k > 1:
-                next_tick, quiet = await_ticker(server, windows[-1],
-                                                windows[0]["tick"])
+                next_tick, quiet = await_ticker(server, windows[-1])
             fc0 = server.flush_count
             comp0 = clock.read()
             t0 = time.time()
@@ -753,16 +761,13 @@ def placement_faults(server, chips: int, pools: dict) -> list[str]:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    # 2^18, not the north star's 2^20 (PR 22's chip runs, ROADMAP S-1).
-    # At 2^20 everything compares equal and peak HBM is 4.4 GB, but a
-    # warm flush takes 11.4 s tick -> sink, longer than the interval. At
-    # 2^19 it takes 7.0-7.3 s and the accept 1.8 s: the run passed with
-    # 0.10 s to spare of the 9 s the windows allow, one jitter away from
-    # "sender too slow". The default is the largest power of two that
-    # passes with room.
-    ap.add_argument("--series", type=int, default=1 << 18,
+    # The north star's 2^20. A warm flush there takes 0.62 s tick -> sink
+    # and the accept 3.2-3.6 s of the 9 s a window allows (PR 26's chip
+    # runs; 11.4 s a flush while the k-bucket was a search, when this
+    # default stood at 2^18).
+    ap.add_argument("--series", type=int, default=1 << 20,
                     help="distinct timer series per chip, the 1,024 hot "
-                         "ones included (default 2^18)")
+                         "ones included (default 2^20)")
     ap.add_argument("--seed", type=int, default=22)
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: run only the series_shards=4 path, at 4x "

@@ -68,6 +68,17 @@ log = logging.getLogger("veneur_tpu.core.worker")
 # bounds drain memory to O(chunk) x the in-flight window, not O(backlog)
 _FOLD_CHUNK = 1 << 18
 
+# The spill fold's shape ladder (see _pad_spill_batch): active rows in
+# powers of four from 256, samples in powers of two from 16,384. Every
+# (rows, samples) pair is a program of its own — 37-66 s to compile on a
+# v5e, 0.15-0.5 s to load, under the ingest lock — and a drain's spill
+# batch is whatever arrived since the last micro-fold, so a fine ladder
+# keeps meeting new shapes for many intervals (PERF.md section 6, PR 26).
+# Padding is cheap since the k-bucket is a count: the step takes 13 ms on
+# the chip at 4096 x 32,768, against 74 with the search.
+_SPILL_MIN_ROWS = 256
+_SPILL_MIN_SAMPLES = 1 << 14
+
 # HBM valve threshold (see _ensure_histo): pool growths whose estimated
 # device footprint stays under this skip the allocation pre-flight — a
 # kB-scale grow cannot exhaust HBM, and pre-flighting it would put an
@@ -2010,13 +2021,16 @@ class DeviceWorker:
     @staticmethod
     def _pad_spill_batch(rows: np.ndarray, vals: np.ndarray,
                          wts: np.ndarray, scratch: int):
-        """Pow2-pad one spill batch for the ingest step: padding sample
-        slots point at `scratch` with weight 0, which the step treats
-        as absent. Shared by the live-pool and swapped-epoch folds so
-        their jit shapes (and semantics) cannot drift."""
+        """Pad one spill batch to the ingest step's shape ladder
+        (_SPILL_MIN_ROWS): padding sample slots point at `scratch` with
+        weight 0, which the step treats as absent. Shared by the
+        live-pool and swapped-epoch folds so their jit shapes (and
+        semantics) cannot drift."""
         uniq, inverse = np.unique(rows, return_inverse=True)
-        k = _next_pow2(len(uniq), 64)
-        n = _next_pow2(len(vals), 256)
+        k = _SPILL_MIN_ROWS
+        while k < len(uniq):
+            k *= 4
+        n = _next_pow2(len(vals), _SPILL_MIN_SAMPLES)
         active = np.full(k, scratch, dtype=np.int32)
         active[: len(uniq)] = uniq
         lids = np.full(n, k - 1, dtype=np.int32)

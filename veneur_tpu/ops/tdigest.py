@@ -134,9 +134,9 @@ def _k_bucket(q: jax.Array, compression: float, capacity: int) -> jax.Array:
     (reference tdigest/merging_digest.go:259-262), clipped to the row
     capacity. Table form (exactnum.kscale_bucket): the arcsin is
     inverted once on the host into the δ bucket-boundary quantiles and
-    the device does a comparison-exact searchsorted — bitwise
-    reproducible by the host engine's NumPy twin, and cheaper than a
-    transcendental on every element."""
+    the device counts the boundaries ≤ q, ⌊δ⌋ elementwise compares —
+    bitwise what the host engine's NumPy twin finds by searchsorted,
+    with no transcendental and no gather on any element."""
     with jax.named_scope("tdigest.k_bucket"):
         return jnp.clip(exn.kscale_bucket(q, compression), 0, capacity - 1)
 
