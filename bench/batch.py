@@ -22,7 +22,7 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KEEP = ("window", "limits", "control", "failure", "counters", "sender",
-        "log_warnings", "trace")
+        "cpu", "log_warnings", "trace")
 
 
 def main() -> int:

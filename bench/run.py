@@ -40,7 +40,7 @@ sys.path.insert(0, ROOT)
 
 import numpy as np  # noqa: E402
 
-from bench import reference, senderlog, stream, trace_reduce  # noqa: E402
+from bench import cpu, reference, senderlog, stream, trace_reduce  # noqa: E402
 
 OUT = os.path.join(ROOT, "bench", "out")
 WARMUP_INTERVALS = 90   # at most this many intervals before the window
@@ -239,7 +239,8 @@ class Sender:
         self.proc = subprocess.Popen(argv, stdin=subprocess.PIPE,
                                      stdout=subprocess.PIPE, text=True)
         self.said: queue.Queue = queue.Queue()
-        threading.Thread(target=self._listen, daemon=True).start()
+        threading.Thread(target=self._listen, name="bench-sender",
+                         daemon=True).start()
 
     def _listen(self) -> None:
         for raw in self.proc.stdout:
@@ -391,6 +392,30 @@ def trace_thread(trace_dir: str, t_start: float, t_stop: float, out: dict):
     return th
 
 
+def cpu_record(sampler, stages, interval: float, cell: str, raw_tag) -> dict:
+    """What the samples of bench/cpu.py come to: ``run["cpu"]``. Writes
+    the table by thread to bench/out/<cell>.cpu_by_thread.json, and with
+    ``raw_tag`` (a traced run) /proc as it was read at the first and the
+    last sample to bench/out/<raw_tag>.proc_tasks.json (bench/testdata
+    keeps one)."""
+    table = cpu.by_thread(sampler.threads, stages.first, interval)
+    if table is not None:
+        with open(os.path.join(OUT, cell + ".cpu_by_thread.json"), "w") as f:
+            json.dump(table, f, indent=1)
+    if raw_tag and sampler.raw:
+        with open(os.path.join(OUT, raw_tag + ".proc_tasks.json"), "w") as f:
+            json.dump({"interval_s": interval, "stages": stages.first,
+                       "samples": [{**{k: sampler.threads[i][k]
+                                       for k in ("t", "process", "python")},
+                                    "stat": sampler.raw[i]}
+                                   for i in sorted(sampler.raw)]}, f)
+    emit("cpu", ticks=sampler.ticks, sampled_late_s=sampler.late_s,
+         groups=table and table["groups"],
+         top=table and dict(list(table["threads"].items())[:12]))
+    return {"ticks": sampler.ticks, "interval_s": interval,
+            "by_thread": table}
+
+
 def run_cell(args, holder: dict, fault: str = "") -> dict:
     """The whole run. Returns {"result": the contract's line or None,
     "correct", "reasons", ...}; raises BenchFailure where it cannot."""
@@ -416,10 +441,16 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
         + (["--fault", "double"] if fault == "double" else []))
     server = None
     tracer = None
+    sampler = None
+    # when each thread is first seen: tells the program's C++ reader
+    # from the runtime's threads in the table by thread (bench/cpu.py)
+    stages = cpu.Stages()
+    stages.mark("import")
     try:
         import jax
 
         devs = jax.devices()
+        stages.mark("backend")
         dev = devs[0]
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": len(devs)}
@@ -445,6 +476,7 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
                 "native ingest is off: the C++ library did not build or "
                 "load, and the Python parser is not the served path")
         ports = server.start()
+        stages.mark("server")
         port = next(iter(ports.values()))
         emit("server", config=written, port=port,
              compilation_cache_dir=server.compilation_cache_dir,
@@ -477,6 +509,7 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
             return base + max(0, m) * interval + phase
 
         s0 = next_start()
+        stages.mark("first_tick")
         child.tell(port=port, s0=s0)
         # the first flush with traffic loads or compiles most of the
         # programs and outlasts its interval: the sender stands still
@@ -485,6 +518,10 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
         emit("schedule", tick1=tick1, s0=s0, interval_s=interval)
         emit("hold", at=time.time(), flush_count=server.flush_count,
              after_first_cycle=True)
+        # the sender connects as soon as it has the port, and the accept
+        # loop hands the connection to a C++ reader thread of its own
+        emit("reader_threads",
+             tids=stages.wait_for_native(cpu.READER_STAGE, 1.0))
 
         # ---- warm-up, under the cell's own traffic: all of it set-up ----
         # The window opens at the first scheduled tick after two flushes
@@ -559,6 +596,9 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
         n_counted = math.ceil(args.seconds / interval)
         t1 = t0 + args.seconds
         setup_s = t0 - T_PROCESS
+        stages.mark("warmup")
+        sampler = cpu.Sampler(
+            [t0 + k * interval for k in range(n_counted)]).start()
         warm = clock.between(0, time.time())
         emit("window", opens=t0, seconds=args.seconds, setup_s=setup_s,
              first_ordinal=n0, flushes_counted=n_counted,
@@ -623,6 +663,7 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
              lines_written=stopped["lines_written"])
 
         # ---- nothing below is timed: the reference and the comparison ----
+        sampler.close()  # its last sample was due inside the window
         t_ref = time.time()
         strm = reference.Stream(stream.build_ring(config, args.seed))
         held_to = reference.compare_record(
@@ -658,7 +699,9 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
         run = {"cell": cell, "config": config, "traffic": traffic,
                "window": (t0, t1), "flushes": counted, "sender_log": slog,
                "memory_peaks": peaks, "trace": None,
-               "window_compiles": inside}
+               "window_compiles": inside,
+               "cpu": cpu_record(sampler, stages, interval, cell["name"],
+                                 tag if args.trace else None)}
         if args.trace and "stopped" in trace_out:
             events = trace_reduce.load_xplane(trace_dir)
             offset = trace_reduce.anchor_offset(events, trace_out["anchor"])
@@ -706,11 +749,17 @@ def run_cell(args, holder: dict, fault: str = "") -> dict:
                 trace_reduce.cut_slice(
                     tr["events"], at, at + 0.5,
                     os.path.join(OUT, tag + ".slice.json.gz"))
+        # each number compared beside its limit: the line's last key
+        result["compared"] = {
+            k: {"value": numbers[k], "limit": reference.LIMITS[k]}
+            for k in reference.LIMITS if k in numbers}
         return {"result": result if on_chip else None, "correct": correct,
                 "reasons": reasons, "numbers": numbers, "control": control,
                 "rehearsal": result}
     finally:
         child.close()
+        if sampler is not None:
+            sampler.close()
         if tracer is not None:
             tracer.join()
 
@@ -729,6 +778,9 @@ def read_metrics(bench: dict, cell: dict, run: dict, setup_s: float,
         "lines_per_s": senderlog.lines_per_s(run["sender_log"], t0, t1),
         "flush_s.mean": statistics.fmean(flush_s) if flush_s else None,
         "setup_s": setup_s,
+        "host_cpu_s.interval": cpu.per_interval(
+            run["cpu"]["ticks"], run["cpu"]["interval_s"])
+        if run.get("cpu") else None,
     }
     out = {}
     if not traced:
@@ -783,6 +835,12 @@ def main(argv=None) -> int:
     clean = True
     if holder.get("server") is not None:
         clean = holder["server"].shutdown()
+    if done is not None:
+        # the last lines of standard error: each number compared, beside
+        # its limit (the result line carries the same under `compared`)
+        for k, v in done["rehearsal"]["compared"].items():
+            print(f"compared {k} {v['value']} limit {v['limit']}",
+                  file=sys.stderr)
     sys.stdout.flush()
     sys.stderr.flush()
     if not clean:
