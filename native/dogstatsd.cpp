@@ -255,13 +255,30 @@ ScopeClass classify(MetricKind kind, int scope /*0 mixed,1 local,2 global*/) {
   return SCOPE_MIXED;
 }
 
-struct NewSeries {
-  int32_t pool;  // 0 histo, 1 set, 2 counter, 3 gauge
-  int32_t row;
-  int32_t kind;
-  int32_t scope_class;
-  std::string name;
-  std::string joined_tags;
+// Series created this interval and not yet handed to Python
+// (vn_drain_new_series), as parallel arrays: every record is three
+// integers, and only a series whose strings Python does not hold yet
+// (a first-seen `sid`, see Ctx::interned) also carries its kind, scope
+// class and a "name \x1f joined_tags \x1e" record in `strs`.
+struct NewSeriesQueue {
+  std::vector<int32_t> pools;  // 0 histo, 1 set, 2 counter, 3 gauge
+  std::vector<int32_t> rows;
+  std::vector<int32_t> sids;
+  std::vector<int32_t> first_at;  // positions of the first-seen records
+  std::vector<int32_t> first_kinds;
+  std::vector<int32_t> first_scopes;
+  std::string strs;
+
+  size_t size() const { return rows.size(); }
+  void clear() {
+    pools.clear();
+    rows.clear();
+    sids.clear();
+    first_at.clear();
+    first_kinds.clear();
+    first_scopes.clear();
+    strs.clear();
+  }
 };
 
 // Open-addressing directory: identity = (kind-type string, scope class,
@@ -431,7 +448,23 @@ struct Ctx {
   std::vector<int32_t> s_idx;
   std::vector<int8_t> s_rank;
 
-  std::vector<NewSeries> new_series;
+  // Lifetime series ids. `dir` forgets every series at each flush (row
+  // numbers are per interval, in first-seen order); this table does not:
+  // (kind, scope class, name, joined tags) -> sid, dense and append-only,
+  // so a series that re-registers in a later interval is queued for
+  // Python as integers and its strings cross once in its lifetime.
+  // sid_handed[sid] is set once a drain has handed the strings over.
+  // Past intern_cap entries the table is dropped at the next reset (the
+  // queue is empty there, so no drain mixes two generations) and learnt
+  // again; the drain reports intern_generation so Python drops its side.
+  Directory interned;
+  std::vector<uint8_t> sid_handed;
+  uint32_t intern_generation = 0;
+  size_t intern_cap = 4000000;
+  NewSeriesQueue new_series;
+  // the last drain's records: vn_drain_new_series swaps the queue in
+  // here and hands out pointers, valid until the next drain
+  NewSeriesQueue drained_series;
   std::string other_lines;  // events/_sc handed back to Python, \n-joined
 
   long long processed = 0;
@@ -479,6 +512,39 @@ struct Ctx {
   // DogStatsD tag parsing uses the thread-local Scratch instead)
   std::string joined;
 };
+
+// Queue a series the directory just created for vn_drain_new_series.
+// key_hash is the directory's own (dir_key_hash over the same parts).
+// Caller holds ctx->mu.
+void queue_created(Ctx* ctx, int32_t pool, int32_t row, int32_t kind,
+                   int32_t scope_class, uint64_t key_hash,
+                   std::string_view name, std::string_view type_str,
+                   std::string_view joined) {
+  bool added = false;
+  int32_t sid = ctx->interned.upsert_parts(
+      key_hash, name, type_str, joined, static_cast<char>('0' + scope_class),
+      static_cast<int32_t>(ctx->interned.used), &added);
+  if (added) ctx->sid_handed.push_back(0);
+  NewSeriesQueue& q = ctx->new_series;
+  if (!ctx->sid_handed[sid]) {
+    q.first_at.push_back(static_cast<int32_t>(q.rows.size()));
+    q.first_kinds.push_back(kind);
+    q.first_scopes.push_back(scope_class);
+    // the record is framed with the \x1e/\x1f unit separators; no
+    // legitimate name/tag contains them, but wire input is untrusted —
+    // substitute so framing can't break
+    auto append_clean = [&q](std::string_view part, char end) {
+      for (char ch : part)
+        q.strs.push_back(ch == '\x1e' || ch == '\x1f' ? '_' : ch);
+      q.strs.push_back(end);
+    };
+    append_clean(name, '\x1f');
+    append_clean(joined, '\x1e');
+  }
+  q.pools.push_back(pool);
+  q.rows.push_back(row);
+  q.sids.push_back(sid);
+}
 
 bool route_metric(Ctx* ctx, std::string_view name, MetricKind kind,
                   double value, std::string_view set_value,
@@ -769,8 +835,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
   uint64_t key_hash = dir_key_hash(p.digest, name, type_str, joined, cls);
 
   bool created = false;
-  int32_t row;
-  int32_t pool;
+  int32_t row = 0;
+  int32_t pool = 0;
   // Overload shedding: the pending SoA batches are normally drained
   // every ~100ms (Server's native pump / strided ingest checks), but a
   // host whose aggregate throughput is below the offered load can't
@@ -865,23 +931,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
       break;
     }
   }
-  if (created) {
-    NewSeries ns;
-    ns.pool = pool;
-    ns.row = row;
-    ns.kind = kind;
-    ns.scope_class = cls;
-    ns.name.assign(name);
-    ns.joined_tags = joined;
-    // the drain protocol (vn_drain_new_series) frames records with the
-    // \x1e/\x1f unit separators; no legitimate name/tag contains them,
-    // but wire input is untrusted — substitute so framing can't break
-    for (char& ch : ns.name)
-      if (ch == '\x1e' || ch == '\x1f') ch = '_';
-    for (char& ch : ns.joined_tags)
-      if (ch == '\x1e' || ch == '\x1f') ch = '_';
-    ctx->new_series.push_back(std::move(ns));
-  }
+  if (created)
+    queue_created(ctx, pool, row, kind, cls, key_hash, name, type_str, joined);
   return true;
 }
 
@@ -1652,7 +1703,15 @@ void vn_ctx_reset(void* p) {
   ctx->s_rows.clear();
   ctx->s_idx.clear();
   ctx->s_rank.clear();
+  // a first-seen record dropped here was never handed over, so its
+  // series queues its strings again when it comes back
   ctx->new_series.clear();
+  if (ctx->interned.used >= ctx->intern_cap ||
+      ctx->interned.arena.size() >= (size_t{1} << 30)) {
+    ctx->interned.reset();
+    ctx->sid_handed.clear();
+    ++ctx->intern_generation;
+  }
   ctx->other_lines.clear();
   ctx->processed = 0;
   ctx->errors = 0;
@@ -2267,36 +2326,47 @@ int vn_pending_new_series(void* p) {
   return static_cast<int>(ctx->new_series.size());
 }
 
-// Drain new-series records: fills parallel arrays plus a packed string
-// buffer of "name\x1fjoined_tags\x1e" records. Returns the count drained
-// (0 if strbuf is too small for the next record).
-int vn_drain_new_series(void* p, int32_t* pools, int32_t* rows,
-                        int32_t* kinds, int32_t* scopes, char* strbuf,
-                        int strcap, int* strlen_out, int max) {
+// Drain every pending new-series record in one call. The queue is
+// swapped out whole (nothing is copied or shifted under the lock) and
+// the caller is handed pointers into it: pools/rows/sids for all n
+// records, and for the *n_first of them whose strings Python has not
+// been given yet, their positions in those arrays, kinds, scope classes
+// and packed "name\x1fjoined_tags\x1e" records. The pointers stay valid
+// until the next drain of this context; one thread drains a context at
+// a time (NativeIngest.drain_new_series holds the context lock across
+// its copy). *generation changes when the intern table was dropped:
+// every sid the caller holds is then void.
+int vn_drain_new_series(void* p, const int32_t** pools, const int32_t** rows,
+                        const int32_t** sids, const int32_t** first_at,
+                        const int32_t** first_kinds,
+                        const int32_t** first_scopes, int* n_first,
+                        const char** strs, long long* strs_len,
+                        unsigned* generation) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> ctx_guard(ctx->mu);
-  int n = 0;
-  int off = 0;
-  while (n < max && n < static_cast<int>(ctx->new_series.size())) {
-    const NewSeries& ns = ctx->new_series[n];
-    int need = static_cast<int>(ns.name.size() + ns.joined_tags.size() + 2);
-    if (off + need > strcap) break;
-    pools[n] = ns.pool;
-    rows[n] = ns.row;
-    kinds[n] = ns.kind;
-    scopes[n] = ns.scope_class;
-    std::memcpy(strbuf + off, ns.name.data(), ns.name.size());
-    off += static_cast<int>(ns.name.size());
-    strbuf[off++] = '\x1f';
-    std::memcpy(strbuf + off, ns.joined_tags.data(), ns.joined_tags.size());
-    off += static_cast<int>(ns.joined_tags.size());
-    strbuf[off++] = '\x1e';
-    ++n;
-  }
-  ctx->new_series.erase(ctx->new_series.begin(),
-                        ctx->new_series.begin() + n);
-  *strlen_out = off;
-  return n;
+  NewSeriesQueue& q = ctx->drained_series;
+  q.clear();
+  std::swap(q, ctx->new_series);
+  for (int32_t at : q.first_at) ctx->sid_handed[q.sids[at]] = 1;
+  *pools = q.pools.data();
+  *rows = q.rows.data();
+  *sids = q.sids.data();
+  *first_at = q.first_at.data();
+  *first_kinds = q.first_kinds.data();
+  *first_scopes = q.first_scopes.data();
+  *n_first = static_cast<int>(q.first_at.size());
+  *strs = q.strs.data();
+  *strs_len = static_cast<long long>(q.strs.size());
+  *generation = ctx->intern_generation;
+  return static_cast<int>(q.size());
+}
+
+// Entries the intern table holds before a reset drops it (4,000,000;
+// the tests lower it to reach the bound).
+void vn_set_intern_cap(void* p, long long cap) {
+  Ctx* ctx = static_cast<Ctx*>(p);
+  std::lock_guard<std::recursive_mutex> ctx_guard(ctx->mu);
+  ctx->intern_cap = cap > 0 ? static_cast<size_t>(cap) : 1;
 }
 
 // Directory upsert for the Python-side ingest paths (SSF-derived metrics,
@@ -2345,14 +2415,8 @@ int vn_upsert(void* p, const char* name, int name_len, int kind,
       static_cast<char>('0' + scope_class), *next, &created);
   if (created) {
     ++*next;
-    NewSeries ns;
-    ns.pool = pool;
-    ns.row = row;
-    ns.kind = kind;
-    ns.scope_class = scope_class;
-    ns.name.assign(name_sv);
-    ns.joined_tags.assign(tags_sv);
-    ctx->new_series.push_back(std::move(ns));
+    queue_created(ctx, pool, row, kind, scope_class, key_hash, name_sv,
+                  type_str, tags_sv);
   }
   return row;
 }
@@ -2846,14 +2910,8 @@ long long vn_upsert_many(void* p, const char* meta, long long meta_len,
         static_cast<char>('0' + scopes[i]), *next, &created);
     if (created) {
       ++*next;
-      NewSeries ns;
-      ns.pool = pool;
-      ns.row = row;
-      ns.kind = static_cast<int>(kinds[i]);
-      ns.scope_class = static_cast<int>(scopes[i]);
-      ns.name.assign(name);
-      ns.joined_tags.assign(joined);
-      ctx->new_series.push_back(std::move(ns));
+      queue_created(ctx, pool, row, kinds[i], scopes[i], key_hash, name,
+                    type_str, joined);
     }
     out_rows[i] = row;
     ++done;

@@ -35,9 +35,12 @@ int vn_drain_histo(void* p, int32_t* rows, float* vals, float* wts, int cap);
 int vn_drain_set(void* p, int32_t* rows, int32_t* idx, int8_t* rank, int cap);
 int vn_drain_counter(void* p, int32_t* rows, double* contribs, int cap);
 int vn_drain_gauge(void* p, int32_t* rows, double* vals, int cap);
-int vn_drain_new_series(void* p, int32_t* pools, int32_t* rows,
-                        int32_t* kinds, int32_t* scopes, char* strbuf,
-                        int strcap, int* strlen_out, int max);
+int vn_drain_new_series(void* p, const int32_t** pools, const int32_t** rows,
+                        const int32_t** sids, const int32_t** first_at,
+                        const int32_t** first_kinds,
+                        const int32_t** first_scopes, int* n_first,
+                        const char** strs, long long* strs_len,
+                        unsigned* generation);
 int vn_drain_ssf_services(void* p, char* buf, int cap);
 int vn_drain_other(void* p, char* buf, int cap);
 int vn_upsert(void* p, const char* name, int name_len, int kind,
@@ -162,13 +165,11 @@ void ssf_thread(void* ctx, int tid) {
 // still committing — the exact overlap the two-phase flush runs.
 void drain_thread(std::vector<void*>* all_ctxs) {
   constexpr int kCap = 8192;
-  std::vector<int32_t> rows(kCap), idx(kCap), pools(kCap), kinds(kCap),
-      scopes(kCap);
+  std::vector<int32_t> rows(kCap), idx(kCap);
   std::vector<float> vals(kCap), wts(kCap);
   std::vector<double> dvals(kCap);
   std::vector<int8_t> rank(kCap);
   std::vector<char> namebuf(kCap * 64);
-  int stroff = 0;
   long long detaches = 0;
   while (!done.load(std::memory_order_acquire)) {
     for (void* c : *all_ctxs) {
@@ -189,9 +190,21 @@ void drain_thread(std::vector<void*>* all_ctxs) {
       vn_drain_set(c, rows.data(), idx.data(), rank.data(), kCap);
       vn_drain_counter(c, rows.data(), dvals.data(), kCap);
       vn_drain_gauge(c, rows.data(), dvals.data(), kCap);
-      vn_drain_new_series(c, pools.data(), rows.data(), kinds.data(),
-                          scopes.data(), namebuf.data(),
-                          static_cast<int>(namebuf.size()), &stroff, kCap);
+      {
+        // read the handed-out queue like the Python drain's copy does
+        const int32_t *np, *nr, *ns, *fa, *fk, *fs;
+        const char* strs;
+        int n_first = 0;
+        long long strs_len = 0;
+        unsigned gen = 0;
+        int n = vn_drain_new_series(c, &np, &nr, &ns, &fa, &fk, &fs,
+                                    &n_first, &strs, &strs_len, &gen);
+        volatile long long probe = gen;
+        for (int i = 0; i < n; ++i) probe += np[i] + nr[i] + ns[i];
+        for (int i = 0; i < n_first; ++i) probe += fa[i] + fk[i] + fs[i];
+        for (long long i = 0; i < strs_len; ++i) probe += strs[i];
+        (void)probe;
+      }
       vn_drain_ssf_services(c, namebuf.data(),
                             static_cast<int>(namebuf.size()));
       vn_drain_other(c, namebuf.data(), static_cast<int>(namebuf.size()));

@@ -109,7 +109,8 @@ def test_parser_parity_property():
     # the python parser's view
     records = {
         (name, native_mod.NativeIngest.TYPE_BY_KIND[kind]): (joined, scope)
-        for _, _, kind, scope, name, joined in ni.drain_new_series()
+        for _, _, kind, scope, name, joined
+        in ni.drain_new_series().first_records()
     }
     py_t = parse_metric(b"t:3|ms|@0.5|#b:2,a:1")
     assert records[("t", "timer")] == ("a:1,b:2", 0)
@@ -284,7 +285,8 @@ def test_native_ssf_extraction_matches_python():
     assert ni.ssf_invalid == 0
 
     got = {(p, k, name, joined, scope)
-           for p, _row, k, scope, name, joined in ni.drain_new_series()}
+           for p, _row, k, scope, name, joined
+           in ni.drain_new_series().first_records()}
 
     # expected series via the Python path
     span = parse_ssf(payload)
@@ -337,7 +339,7 @@ def test_native_ssf_name_tag_fallback():
         service="svc", indicator=True, tags={"name": "from-tag"})
     ni = native_mod.NativeIngest()
     assert ni.ingest_ssf(payload, b"ind.t", b"obj.t") == 1
-    series = ni.drain_new_series()
+    series = ni.drain_new_series().first_records()
     objs = [(name, joined) for _p, _r, _k, _s, name, joined in series
             if name == "obj.t"]
     assert objs and "objective:from-tag" in objs[0][1]
@@ -394,7 +396,7 @@ def test_native_ssf_non_ascii_tag_order_matches_python():
         metrics=[{"metric": 2, "name": "m", "value": 1.0, "tags": tags}])
     ni = native_mod.NativeIngest()
     assert ni.ingest_ssf(payload, b"", b"") == 1
-    (_, _, _, _, _name, joined), = ni.drain_new_series()
+    (_, _, _, _, _name, joined), = ni.drain_new_series().first_records()
 
     pym = parse_metric_ssf(ssf_model.SSFSample(
         metric=ssf_model.SSFMetricType.HISTOGRAM, name="m", value=1.0,
@@ -702,22 +704,145 @@ def test_native_ssf_decode_fuzz_agrees_with_python():
     assert checked == 3000
 
 
-def test_drain_new_series_survives_full_string_buffer():
-    """A drain round that fills the 1MB string scratch mid-batch must
-    keep going until the queue is empty — stranded records would leave
-    device rows without directory metadata."""
+def test_drain_new_series_hands_every_record_in_one_call():
+    """One drain empties the queue however many bytes of strings it
+    holds (the drain that filled a 1MB scratch a round at a time left
+    records stranded when one did not fit) — stranded records would
+    leave device rows without directory metadata."""
     ni = native_mod.NativeIngest()
     long_tag = "env:" + "x" * 400
-    n = 4000  # ~1.6MB of packed records: forces >1 drain round
+    n = 4000  # ~1.6MB of packed records
     for i in range(n):
         ni.upsert(f"long.series.{i}", "histogram", long_tag, 0)
     assert ni.pending_new_series == n
-    records = ni.drain_new_series()
+    records = ni.drain_new_series().first_records()
     assert len(records) == n
     assert ni.pending_new_series == 0
     assert records[0][4] == "long.series.0"
     assert records[-1][4] == f"long.series.{n - 1}"
     assert records[0][5] == long_tag
+
+
+def test_drain_new_series_one_record_larger_than_a_megabyte():
+    """A single record past the old 1MB scratch was stranded until the
+    reset; the queue has no scratch now and hands it over."""
+    ni = native_mod.NativeIngest()
+    huge = "k:" + "v" * (2 << 20)
+    ni.upsert("huge.series", "counter", huge, 0)
+    ni.upsert("after", "counter", "", 0)
+    batch = ni.drain_new_series()
+    assert batch.first_records() == [
+        (2, 0, 0, 0, "huge.series", huge), (2, 1, 0, 0, "after", "")]
+    assert ni.pending_new_series == 0
+
+
+# -- lifetime series ids (Ctx::interned) ------------------------------------
+
+
+def _lines(names, fmt=b"%s:1|ms|#a:1"):
+    return b"\n".join(fmt % n for n in names)
+
+
+def test_series_keeps_its_sid_across_reset_and_rows_restart():
+    """reset() forgets rows, not series: the row follows this
+    interval's first-seen order, the sid is the one given at first
+    sight, per (kind, scope class, name, tags)."""
+    ni = native_mod.NativeIngest()
+    ni.ingest(_lines([b"a", b"b", b"c"]) + b"\na:1|c\nb:2|ms|#a:1")
+    first = ni.drain_new_series()
+    assert first.rows.tolist() == [0, 1, 2, 0]
+    assert first.pools.tolist() == [0, 0, 0, 2]
+    assert first.sids.tolist() == [0, 1, 2, 3]
+    sid = dict(zip([r[2:] for r in first.first_records()],
+                   first.sids.tolist()))
+    ni.reset()
+    # another order, one new series, one series gone, a scope twin
+    ni.ingest(b"a:1|c\n" + _lines([b"c", b"new", b"a"])
+              + b"\nc:1|ms|#a:1,veneurlocalonly")
+    second = ni.drain_new_series()
+    assert second.pools.tolist() == [2, 0, 0, 0, 0]
+    assert second.rows.tolist() == [0, 0, 1, 2, 3]
+    assert second.sids.tolist() == [
+        sid[(0, 0, "a", "")], sid[(3, 0, "c", "a:1")], 4,
+        sid[(3, 0, "a", "a:1")], 5]
+    assert second.first_records() == [
+        (0, 1, 3, 0, "new", "a:1"), (0, 3, 3, 1, "c", "a:1")]
+    assert second.generation == first.generation
+
+
+def test_known_series_cross_the_drain_as_integers():
+    """From the second interval on a known series brings no string
+    with it: nothing is decoded, split or hashed for it in Python."""
+    ni = native_mod.NativeIngest()
+    names = [b"s%d" % i for i in range(500)]
+    ni.ingest(_lines(names))
+    batch = ni.drain_new_series()
+    assert len(batch) == 500 and len(batch.first_names) == 500
+    sids = dict(zip(batch.first_names, batch.sids.tolist()))
+    for interval in range(2):
+        ni.reset()
+        order = names[::-1] if interval else names[250:] + names[:250]
+        ni.ingest(_lines(order))
+        batch = ni.drain_new_series()
+        assert len(batch) == 500
+        assert batch.first_names == [] and batch.first_tags == []
+        assert len(batch.first_at) == 0
+        assert batch.rows.tolist() == list(range(500))
+        assert batch.sids.tolist() == [sids[n.decode()] for n in order]
+
+
+def test_a_first_seen_record_lost_to_reset_is_handed_over_again():
+    """Strings queued but never drained go with the reset; the sid is
+    not marked handed, so the series brings them again."""
+    ni = native_mod.NativeIngest()
+    ni.ingest(_lines([b"x", b"y"]))
+    ni.reset()  # nobody drained
+    ni.ingest(_lines([b"y", b"z", b"x"]))
+    batch = ni.drain_new_series()
+    assert batch.sids.tolist() == [1, 2, 0]
+    assert batch.first_names == ["y", "z", "x"]
+    ni.reset()
+    ni.ingest(_lines([b"x", b"y", b"z"]))
+    batch = ni.drain_new_series()
+    assert batch.sids.tolist() == [0, 1, 2] and batch.first_names == []
+
+
+def test_separators_in_a_name_or_tag_do_not_break_the_framing():
+    """Wire input is untrusted: \\x1e/\\x1f in a name or tag are
+    substituted in the strings handed over; the two spellings stay two
+    series (two rows, two sids) as they always were."""
+    ni = native_mod.NativeIngest()
+    ni.ingest(b"a\x1eb:1|c|#t:\x1fv\na_b:1|c|#t:_v\nplain:1|c")
+    batch = ni.drain_new_series()
+    assert batch.first_records() == [
+        (2, 0, 0, 0, "a_b", "t:_v"), (2, 1, 0, 0, "a_b", "t:_v"),
+        (2, 2, 0, 0, "plain", "")]
+    assert batch.sids.tolist() == [0, 1, 2]
+    # the Python-side upsert substitutes before the call and lands on
+    # the second spelling's series
+    assert ni.upsert("a\x1eb", "counter", "t:\x1fv", 0) == 1
+    assert ni.pending_new_series == 0
+
+
+def test_intern_table_is_dropped_at_a_reset_past_its_bound():
+    """Past the bound (4,000,000; 8 here) the table goes at the next
+    reset, never mid-interval: a drain holds one generation's sids."""
+    ni = native_mod.NativeIngest()
+    ni.set_intern_cap(8)
+    ni.ingest(_lines([b"n%d" % i for i in range(6)]))
+    assert ni.drain_new_series().generation == 0
+    ni.reset()  # 6 < 8: kept
+    ni.ingest(_lines([b"n%d" % i for i in range(3, 12)]))
+    batch = ni.drain_new_series()
+    assert batch.generation == 0  # over the bound now, dropped later
+    assert batch.sids.tolist() == list(range(3, 12))
+    assert batch.first_names == ["n%d" % i for i in range(6, 12)]
+    ni.reset()
+    ni.ingest(_lines([b"n11", b"n0"]))
+    batch = ni.drain_new_series()
+    assert batch.generation == 1
+    assert batch.sids.tolist() == [0, 1]
+    assert batch.first_names == ["n11", "n0"]
 
 
 # -- raw-sample staging plane (vn_set_stage_depth / vn_stage_detach) --------

@@ -146,6 +146,54 @@ def _assert_series_identical(a, b, path: str) -> None:
     assert not diff, (path, diff)
 
 
+# -- reconciliation by the batch ---------------------------------------------
+
+
+def test_one_series_through_two_contexts_shares_one_canonical_row():
+    """Each context names a series by a lifetime id of its own; the
+    batch reconcile still folds the same series arriving through two
+    readers, or through the Python path, onto one canonical row — in
+    the interval the strings arrive and in the ones after, when a known
+    series is three integers."""
+    from veneur_tpu.protocol.dogstatsd import parse_metric
+
+    w = _mk_worker(True)
+    a, b = w._reader_ctxs[0], w._reader_ctxs[1]
+    for interval in range(3):
+        w.process_metric(parse_metric(b"py.t:1|ms|#k:v"))  # Python path
+        a.ingest_owned(b"shared.t:1|ms\nonly.a:1|ms\nshared.c:1|c"
+                       b"\npy.t:2|ms|#k:v")
+        w.sync_native_series()  # mid-epoch: b's batch meets a's rows
+        b.ingest_owned(b"only.b:1|ms\nshared.t:2|ms\nshared.c:2|c"
+                       b"\nshared.s:x|s|#veneurlocalonly")
+        a.ingest_owned(b"shared.s:y|s|#veneurlocalonly")
+        w.sync_native_series()
+        histo = w.directory.histo
+        names = [m.key.name for m in histo.rows]
+        assert sorted(names) == ["only.a", "only.b", "py.t", "shared.t"]
+        maps_a, maps_b = w._ctx_maps[1], w._ctx_maps[2]
+        # local rows follow each context's own first-seen order
+        assert [names[r] for r in maps_a[0]] == ["shared.t", "only.a",
+                                                 "py.t"]
+        assert [names[r] for r in maps_b[0]] == ["only.b", "shared.t"]
+        assert len(w.scalars.counters.meta) == 1
+        assert list(maps_a[2]) == list(maps_b[2]) == [0]
+        assert len(w.directory.sets.rows) == 1
+        assert list(maps_a[1]) == list(maps_b[1]) == [0]
+        assert histo.index == {(m.key, m.scope_class): r
+                               for r, m in enumerate(histo.rows)}
+        if interval:  # nothing learnt twice
+            assert [len(k) for k in w._adopt_cache] == learnt
+        learnt = [len(k) for k in w._adopt_cache]
+        assert learnt == [0, 5, 4, 0]
+        snap = w.flush(QS)
+        got = _keyed(snap)
+        assert got[("shared.t.count", MetricType.COUNTER, ())] == 2.0
+        assert got[("py.t.count", MetricType.COUNTER, ("k:v",))] == 2.0
+        assert got[("shared.c", MetricType.COUNTER, ())] == 3.0
+        assert round(got[("shared.s", MetricType.GAUGE, ())], 2) == 2.0
+
+
 # -- the golden matrix ------------------------------------------------------
 
 

@@ -515,6 +515,74 @@ def test_canonical_self_telemetry_names():
         srv.shutdown()
 
 
+def test_adoption_spans_and_gauge_tell_known_from_first_seen():
+    """`adopt` / `swap.adopt` keep their names and their `series`
+    attribute (bench/layer_metrics/adopt_ms.flush.json reads them) and
+    say how many of the series were known; `directory.interned` gauges
+    what is kept for the series' lifetimes."""
+    from veneur_tpu import scopedstatsd
+
+    srv, sink, ports = _server(num_workers=1, interval="600s")
+    try:
+        if not srv.native_mode:
+            pytest.skip("native ingest library unavailable")
+        cap = scopedstatsd.CaptureSender()
+        srv.stats = scopedstatsd.ScopedClient(cap, namespace="veneur.")
+        port = next(iter(ports.values()))
+        lines = [b"ad.t%d:1|ms" % i for i in range(30)]
+        lines += [b"ad.c%d:1|c" % i for i in range(10)]
+        for interval in range(2):
+            extra = [b"ad.late%d:1|g" % i for i in range(5 * interval)]
+            _send_udp(port, b"\n".join(lines + extra))
+            assert _wait_for(
+                lambda: sum(c.processed
+                            for c in srv.workers[0]._all_ctxs())
+                >= len(lines + extra))
+            srv.flush()
+            spans = [s for s in srv.rec.closed()
+                     if s.name in ("adopt", "swap.adopt")
+                     and s.flush == srv.workers[0].flight_epoch - 1]
+            assert spans, [s.name for s in srv.rec.closed()]
+            for sp in spans:
+                assert sp.attrs["series"] == (sp.attrs["known"]
+                                              + sp.attrs["first_seen"])
+            total = {k: sum(sp.attrs[k] for sp in spans)
+                     for k in ("series", "known", "first_seen")}
+            if interval == 0:
+                assert total == {"series": 40, "known": 0, "first_seen": 40}
+            else:
+                assert total == {"series": 45, "known": 40, "first_seen": 5}
+        assert "veneur.directory.interned:45" in "\n".join(cap.lines)
+    finally:
+        srv.shutdown()
+
+
+def test_the_heap_is_frozen_once_the_series_table_has_grown(monkeypatch):
+    """What adoption keeps lives as long as the series: once a flush
+    ends with the table a quarter (and 4,096 series) past what was last
+    frozen, gc.freeze() takes it out of the collector's walk; a steady
+    table freezes nothing again, a dropped one starts over."""
+    import gc
+
+    srv, sink, ports = _server(num_workers=1, interval="600s")
+    try:
+        calls = []
+        monkeypatch.setattr(gc, "freeze", lambda: calls.append(1))
+        for interned, frozen_calls in ((100, 0), (4096, 0), (5000, 1),
+                                       (5000, 1), (9000, 1), (11000, 2),
+                                       (300, 2), (4500, 3)):
+            srv._freeze_series(interned)
+            assert len(calls) == frozen_calls, (interned, calls)
+        # and the flush is what calls it, with the workers' count
+        seen = []
+        monkeypatch.setattr(srv, "_freeze_series", seen.append)
+        srv.process_metric_packet(b"fz.t:1|ms")
+        srv.flush()
+        assert seen == [srv.workers[0].interned_series]
+    finally:
+        srv.shutdown()
+
+
 def test_listener_fd_handoff_ssf_listener():
     """SSF UDP listeners ride the handoff too."""
     cfg = Config(ssf_listen_addresses=["udp://127.0.0.1:0"],

@@ -619,3 +619,227 @@ def test_scalar_pool_growth_at_capacity_boundary():
     assert pool.used == 20
     assert list(pool.values[:20]) == [float(i + 1) for i in range(20)]
     assert pool.present[:20].all()
+
+
+# -- adoption by the batch (worker._adopt_pending) --------------------------
+#
+# The native directory forgets its rows at every flush and the same series
+# register again; the worker adopts them a batch at a time by lifetime
+# series id. The plain reference below is the per-series loop the program
+# had before (one RowMeta lookup, one adopt call per series), kept here so
+# that the batch path is held to its answers field by field.
+
+import pytest
+
+from veneur_tpu.core.directory import RowMeta, SeriesDirectory
+from veneur_tpu.core.metrics import MetricKey, route_info, tenant_of
+from veneur_tpu.core.tenancy import TenantLedger
+from veneur_tpu.core.worker import HostScalars, _series_budget_id
+
+
+class _PerSeriesReference:
+    """Adopts (pool, row, kind, scope, name, joined) records one at a
+    time, with the cross-epoch cache keyed by the strings."""
+
+    def __init__(self, budget: int = 0) -> None:
+        self.cache: dict = {}
+        self.tenancy = (TenantLedger(default_budget=budget, budgets={})
+                        if budget else None)
+        self.new_epoch()
+
+    def new_epoch(self) -> None:
+        self.directory = SeriesDirectory()
+        self.scalars = HostScalars()
+
+    def adopt(self, records) -> None:
+        from veneur_tpu.native import NativeIngest
+
+        for pool, row, kind, scope, name, joined in records:
+            ck = (pool, kind, scope, name, joined)
+            meta = self.cache.get(ck)
+            if meta is None:
+                key = MetricKey(name=name,
+                                type=NativeIngest.TYPE_BY_KIND[kind],
+                                joined_tags=joined)
+                tags = joined.split(",") if joined else []
+                tenant, admitted = "", True
+                if self.tenancy is not None:
+                    tenant = tenant_of(tags, self.tenancy.tag_key)
+                    admitted = self.tenancy.admit(
+                        tenant, _series_budget_id(ScopeClass(scope), key))
+                meta = self.cache[ck] = RowMeta(
+                    key=key, tags=tags, scope_class=ScopeClass(scope),
+                    sinks=route_info(tags), tenant=tenant,
+                    admitted=admitted)
+            if pool == 0:
+                self.directory.histo.adopt_meta(row, meta)
+            elif pool == 1:
+                self.directory.sets.adopt_meta(row, meta)
+            else:
+                spool = (self.scalars.counters if pool == 2
+                         else self.scalars.gauges)
+                spool._append(
+                    row, (meta.key, meta.tags, meta.scope_class, meta.sinks),
+                    meta.scope_class, meta.sinks, meta.admitted,
+                    meta.wire_frag())
+                spool.ensure(row + 1)
+                spool.used = row + 1
+
+
+def _pool_fields(pool) -> dict:
+    entries = [
+        (e.key, e.tags, e.scope_class, e.sinks, e.tenant, e.admitted)
+        if isinstance(e, RowMeta) else e for e in pool.entries]
+    blob = pool.frag_blob()
+    return {
+        "entries": entries,
+        "scope_codes": pool.scope_codes.tobytes(),
+        "admit_codes": pool.admit_codes.tobytes(),
+        "routed_rows": pool.routed_rows,
+        "rejected_rows": pool.rejected_rows,
+        "frag_blob": None if blob is None else bytes(blob),
+        "index": dict(pool.index),
+    }
+
+
+def _assert_directories_equal(w, ref) -> None:
+    for name, got, want in (
+            ("histo", w.directory.histo, ref.directory.histo),
+            ("sets", w.directory.sets, ref.directory.sets),
+            ("counters", w.scalars.counters, ref.scalars.counters),
+            ("gauges", w.scalars.gauges, ref.scalars.gauges)):
+        got_f, want_f = _pool_fields(got), _pool_fields(want)
+        for field_name in want_f:
+            assert got_f[field_name] == want_f[field_name], (name,
+                                                             field_name)
+    for got, want in ((w.scalars.counters, ref.scalars.counters),
+                      (w.scalars.gauges, ref.scalars.gauges)):
+        assert got.used == want.used == len(got.meta)
+        assert len(got.values) >= got.used
+
+
+def _seeded_lines(rng, n: int) -> list[bytes]:
+    """n series of every class, some of them the odd ones: a routed
+    series (veneursinkonly:), scope twins, tenants over a budget of 5,
+    separators in a name and in a tag (the Python formatter's rows)."""
+    lines = []
+    for i in range(n):
+        tenant = b"|#tenant:t%d" % (i % 3)
+        lines += [b"ad.t%d:%d|ms%s" % (i, i, tenant),
+                  b"ad.c%d:1|c%s" % (i, tenant),
+                  b"ad.g%d:%d|g" % (i, i),
+                  b"ad.s%d:m%d|s%s" % (i, i, tenant)]
+    lines += [b"ad.routed:1|ms|#veneursinkonly:datadog",
+              b"ad.routed:1|c|#veneursinkonly:datadog,x:y",
+              b"ad.t0:1|ms|#tenant:t0,veneurlocalonly",
+              b"ad.c0:1|c|#tenant:t0,veneurglobalonly",
+              b"ad.sep\x1ename:1|c", b"ad.sep:1|g|#k:\x1fv",
+              b"ad.sep\x1ename:3|ms"]
+    order = rng.permutation(len(lines))
+    return [lines[i] for i in order]
+
+
+@pytest.mark.parametrize("budget", [0, 5], ids=["no-tenancy", "budget-5"])
+def test_batch_adoption_equals_the_per_series_loop(budget):
+    """Three intervals of the same seeded series in three orders, adopted
+    in two batches an interval: every field of the four pools equals what
+    the per-series loop gives, the lazily built index included."""
+    from veneur_tpu.native import NativeIngest
+
+    w = DeviceWorker(stage_depth=8, batch_size=1 << 12)
+    if budget:
+        w.tenancy = TenantLedger(default_budget=budget, budgets={})
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    ref = _PerSeriesReference(budget)
+    rng = np.random.default_rng(29)
+    qs = device_quantiles(PCTS, AGGS)
+    for interval in range(3):
+        lines = _seeded_lines(rng, 40)
+        # a context of the interval's own sees every series for the
+        # first time: its drain is the per-series loop's input
+        plain = NativeIngest()
+        half = len(lines) // 2
+        for part in (lines[:half], lines[half:]):
+            w.ingest_datagram(b"\n".join(part))
+            w.sync_native_series()
+            plain.ingest(b"\n".join(part))
+            ref.adopt(plain.drain_new_series().first_records())
+        known = w._adopt_cache[0]
+        if interval:
+            assert len(known) == learnt  # nothing is learnt twice
+        learnt = len(known)
+        _assert_directories_equal(w, ref)
+        if budget:
+            assert w.directory.histo.rejected_rows > 0
+            assert w.scalars.counters.rejected_rows > 0
+        assert w.directory.histo.routed_rows == 1
+        assert w.scalars.counters.routed_rows == 1
+        assert w.scalars.counters.frag_blob() is not None
+        snap = w.flush(qs)
+        assert len(snap.directory.histo.rows) == len(
+            ref.directory.histo.rows)
+        ref.new_epoch()
+
+
+def test_unique_timeseries_tally_is_fed_from_the_batch():
+    """count_unique_timeseries: the batch feeds the HLL what the
+    per-series insert fed it, in the first interval (strings arrive) and
+    in the ones after (integers only)."""
+    from veneur_tpu.utils.hashing import fmix64, metric_digest
+    from veneur_tpu.ops import hll as hll_ops
+
+    w = DeviceWorker(count_unique_timeseries=True, is_local=True)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    lines = ([b"u.t%d:1|ms|#veneurlocalonly" % i for i in range(50)]
+             + [b"u.fwd%d:1|ms" % i for i in range(50)]  # forwarded: not
+             + [b"u.c%d:1|c" % i for i in range(50)])    # counted locally
+    want = np.zeros_like(w._umts)
+    hashes = np.array(
+        [fmix64(metric_digest("u.t%d" % i, "timer", "")) for i in range(50)]
+        + [fmix64(metric_digest("u.c%d" % i, "counter", ""))
+           for i in range(50)], dtype=np.uint64)
+    idx, rank = hll_ops.split_hashes(hashes, w.hll_precision)
+    np.maximum.at(want, idx, rank)
+    qs = device_quantiles(PCTS, AGGS)
+    for interval in range(2):
+        w.ingest_datagram(b"\n".join(lines[::-1] if interval else lines))
+        w.sync_native_series()
+        assert (w._umts == want).all() and want.any()
+        w.flush(qs)
+
+
+def test_the_intern_bound_clears_both_sides():
+    """Past the bound the context drops its table at the reset and says
+    so by the generation; the worker drops its side with it, so no sid
+    names another series' RowMeta: each interval's directory still reads
+    as the per-series loop's."""
+    from veneur_tpu.native import NativeIngest
+
+    w = DeviceWorker(stage_depth=8)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    w._native.set_intern_cap(100)
+    ref = _PerSeriesReference()
+    qs = device_quantiles(PCTS, AGGS)
+    generations = []
+    for interval in range(4):
+        # a sliding window of 80 series: 40 known, 40 new each interval
+        names = range(interval * 40, interval * 40 + 80)
+        lines = ([b"ib.t%d:1|ms" % i for i in names]
+                 + [b"ib.c%d:1|c|#k:%d" % (i, i) for i in names])
+        plain = NativeIngest()
+        w.ingest_datagram(b"\n".join(lines))
+        w.sync_native_series()
+        plain.ingest(b"\n".join(lines))
+        ref.adopt(plain.drain_new_series().first_records())
+        _assert_directories_equal(w, ref)
+        generations.append((w._adopt_cache[0].generation,
+                            len(w._adopt_cache[0])))
+        w.flush(qs)
+        ref.new_epoch()
+    # 160 series after interval 0 is past 100: dropped at that flush,
+    # and again every interval (each learns 160 anew)
+    assert generations == [(0, 160), (1, 160), (2, 160), (3, 160)]
+    assert w.interned_series == 160

@@ -417,7 +417,7 @@ def fuzz_reader_commit(rng, t_end) -> int:
     def drain_keyed(ctx):
         names = name_maps[id(ctx)]
         names.update({(p, r): (nm, tg) for p, r, _k, _s, nm, tg
-                      in ctx.drain_new_series()})
+                      in ctx.drain_new_series().first_records()})
         out = {"h": {}, "c": {}, "g": {}, "s": {}}
         while True:
             hr, hv, hw = ctx.drain_histo(4096)

@@ -45,7 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from veneur_tpu.core import columnar, flightrec
-from veneur_tpu.core.directory import ScopeClass, SeriesDirectory, classify
+from veneur_tpu.core.directory import (LifetimeSeries, RowBook, RowMeta,
+                                       ScopeClass, SeriesDirectory,
+                                       build_frag, classify)
 from veneur_tpu.core.metrics import (DEFAULT_TENANT, MetricKey, UDPMetric,
                                      route_info, tenant_of)
 from veneur_tpu.core.tenancy import TenantTallies
@@ -382,35 +384,21 @@ def _grow_1d(old, new_rows: int, fill: float):
 # Host-side state containers
 
 
-class ScalarPool:
+class ScalarPool(RowBook):
     """Growable f64 value array + per-row metadata; row ids are
     append-ordered so the Python dict path and the native directory agree
     on assignment."""
 
     def __init__(self, initial: int = 256) -> None:
-        self.index: dict = {}  # (key, class) → row (python path only)
-        self.meta: list = []  # (key, tags, scope_class, sinks)
-        # packed per-row scope codes + routed-row count, maintained
-        # incrementally for the columnar flush (see directory._Pool)
-        from array import array as _array
-
-        self.scope_codes = _array("b")
-        self.routed_rows = 0
-        # per-row admission codes + rejected-row count (per-tenant QoS,
-        # see directory._Pool): only the native path can produce a
-        # rejected scalar row (C++ assigns rows before the ledger runs)
-        self.admit_codes = _array("b")
-        self.rejected_rows = 0
-        # incremental \x1e-joined wire-frag arena (see directory._Pool):
-        # the native emit tier reads this buffer zero-copy at flush
-        self.frag_arena = bytearray()
-        self.frag_clean = True
+        super().__init__()
+        self.meta: list = self.entries  # (key, tags, scope_class, sinks)
         self.values = np.zeros(initial, np.float64)
         self.present = np.zeros(initial, bool)
         self.used = 0
 
-    def frag_blob(self):
-        return self.frag_arena if self.frag_clean else None
+    @staticmethod
+    def _ikey(entry) -> tuple:
+        return (entry[0], entry[2])
 
     def ensure(self, rows: int) -> None:
         if rows > len(self.values):
@@ -424,44 +412,31 @@ class ScalarPool:
             self.present = newp
 
     def upsert(self, key, scope_class, tags, sinks) -> int:
-        k = (key, scope_class)
-        row = self.index.get(k)
+        row = self.index.get((key, scope_class))
         if row is None:
             row = self.used
-            self.index[k] = row
             self.adopt_row(row, key, tags, scope_class, sinks)
         return row
 
-    def adopt_row(self, row: int, key, tags, scope_class, sinks,
-                  frag=False, admitted=True) -> None:
-        """Register metadata for a row assigned externally (native path).
-        ``frag`` carries a prebuilt wire_frag (the worker's cross-epoch
-        RowMeta cache); False = build here (the Python upsert path)."""
-        assert row == len(self.meta), "rows must be adopted in order"
-        self.meta.append((key, tags, scope_class, sinks))
-        self.scope_codes.append(int(scope_class))
-        self.admit_codes.append(1 if admitted else 0)
-        if not admitted:
-            self.rejected_rows += 1
-        if sinks is not None:
-            self.routed_rows += 1
+    def adopt_row(self, row: int, key, tags, scope_class, sinks) -> None:
+        """One row of the Python upsert path."""
+        frag = None
         if self.frag_clean:
-            if frag is False:
-                from veneur_tpu.core.directory import build_frag
-
-                frag = build_frag(getattr(key, "name", key), list(tags))
-            if frag is None:
-                self.frag_clean = False
-            else:
-                if row:
-                    self.frag_arena += b"\x1e"
-                self.frag_arena += frag
+            frag = build_frag(getattr(key, "name", key), list(tags))
+        self._append(row, (key, tags, scope_class, sinks), scope_class,
+                     sinks, True, frag)
         # grow BEFORE bumping used: ensure() copies/zeroes relative to
         # self.used, and with used already including the new row it
         # copies one element past the old arrays (crash at a capacity
         # boundary) and leaves np.resize's recycled junk in the new row
         self.ensure(row + 1)
         self.used = row + 1
+
+    def adopt_batch(self, first_row: int, entries: list, codes,
+                    frags: list) -> None:
+        super().adopt_batch(first_row, entries, codes, frags)
+        self.ensure(len(self.entries))  # before used moves: see adopt_row
+        self.used = len(self.entries)
 
 
 @dataclass
@@ -804,10 +779,10 @@ class DeviceWorker:
         self.micro_folds_total = 0
         self.micro_folds_epoch = 0
         self.micro_folds_swapped = 0
-        # cross-epoch series-metadata cache (see _sync_native_series);
-        # deliberately NOT in _reset_epoch — surviving the per-flush
-        # directory swap is its whole purpose
-        self._adopt_cache: dict = {}
+        # what is kept per lifetime series id, one table per native
+        # context (see _adopt_pending); deliberately NOT in _reset_epoch —
+        # surviving the per-flush directory swap is its whole purpose
+        self._adopt_cache = [LifetimeSeries()]
         # per-tenant QoS (core/tenancy.py), installed by the server when
         # tenancy is configured; None keeps every tenant path dormant.
         # The ledger is SHARED across workers (admission is a host-global
@@ -985,6 +960,7 @@ class DeviceWorker:
         # the maps were sized for zero reader contexts at construction
         self._ctx_maps = [tuple(array("i") for _ in range(4))
                           for _ in range(n + 1)]
+        self._adopt_cache += [LifetimeSeries() for _ in range(n)]
         return True
 
     def _all_ctxs(self) -> list:
@@ -1017,21 +993,6 @@ class DeviceWorker:
                 self.drain_native()
         return rc
 
-    def _scalar_upsert_meta(self, pool, meta) -> int:
-        """ScalarPool twin of _Pool.upsert_meta: dedup by (key, class),
-        adopting a fresh row only for a genuinely new series (adopt_row
-        leaves index maintenance to its caller)."""
-        k = (meta.key, meta.scope_class)
-        row = pool.index.get(k)
-        if row is not None:
-            return row
-        row = len(pool.meta)
-        pool.index[k] = row
-        pool.adopt_row(row, meta.key, meta.tags, meta.scope_class,
-                       meta.sinks, frag=meta.wire_frag(),
-                       admitted=meta.admitted)
-        return row
-
     def _sync_native_series(self, ctx=None, ctx_i: int = 0,
                             span: str = "adopt") -> None:
         if ctx is None:
@@ -1039,15 +1000,29 @@ class DeviceWorker:
         if not ctx.pending_new_series:
             return
         with self.rec.span(span, flush=self.flight_epoch) as sp:
-            sp.attrs["series"] = self._adopt_pending(ctx, ctx_i)
+            n, first_seen = self._adopt_pending(ctx, ctx_i)
+            sp.attrs["series"] = n
+            sp.attrs["known"] = n - first_seen
+            sp.attrs["first_seen"] = first_seen
 
-    def _adopt_pending(self, ctx, ctx_i: int) -> int:
-        """The adoption loop of _sync_native_series; returns the number
-        of series adopted."""
-        from veneur_tpu.core.directory import RowMeta
-        from veneur_tpu.native import NativeIngest
+    def _adopt_pending(self, ctx, ctx_i: int) -> tuple[int, int]:
+        """Give every series the context created since the last drain its
+        row in the pools, a batch per pool; returns (series adopted, how
+        many of them the context handed over for the first time).
 
-        n_adopted = 0
+        Every flush resets the native directory and the same series
+        re-register next interval, so the drain names a series by its
+        lifetime id: what a row needs (entry, codes, frag) was built when
+        the series' strings first arrived (_learn_series) and is taken
+        from the context's LifetimeSeries by integer here."""
+        batch = ctx.drain_new_series()
+        if not len(batch):
+            return 0, 0
+        known = self._adopt_cache[ctx_i]
+        if batch.generation != known.generation:
+            known.clear(batch.generation)
+        if len(batch.first_at):
+            self._learn_series(known, batch)
         # reader-shard mode: context rows are LOCAL — reconcile each into
         # the worker's canonical directory (dedup by series identity, so
         # the same series arriving via several readers shares one
@@ -1055,92 +1030,100 @@ class DeviceWorker:
         # local→canonical map. The home context (ctx_i 0) reconciles the
         # same way so every native row space is treated uniformly.
         shard_maps = self._ctx_maps[ctx_i] if self._reader_ctxs else None
-        # cross-epoch adopt cache: every flush resets the directory and
-        # the same series re-register next interval; their RowMeta
-        # (key, tags, routing) is identical every time, so build it once
-        # per series lifetime instead of per epoch — the dominant cost
-        # of the global tier's steady-state import before this cache
-        cache = self._adopt_cache
-        for pool, row, kind, scope, name, joined in (
-            ctx.drain_new_series()
-        ):
-            n_adopted += 1
-            ck = (pool, kind, scope, name, joined)
-            meta = cache.get(ck)
-            if meta is None:
-                mtype = NativeIngest.TYPE_BY_KIND[kind]
-                key = MetricKey(name=name, type=mtype, joined_tags=joined)
-                tags = joined.split(",") if joined else []
-                tenant = ""
-                admitted = True
-                if self.tenancy is not None:
-                    # native-path budget gate: C++ already assigned the
-                    # row, so a rejected series keeps its row but is
-                    # marked admitted=False — the flusher skips it on
-                    # both emit paths. The decision caches with the
-                    # RowMeta (admission is per series lifetime).
-                    tenant = tenant_of(tags, self.tenancy.tag_key)
-                    admitted = self.tenancy.admit(
-                        tenant, _series_budget_id(ScopeClass(scope), key))
-                meta = RowMeta(key=key, tags=tags,
-                               scope_class=ScopeClass(scope),
-                               sinks=route_info(tags),
-                               tenant=tenant, admitted=admitted)
-                if len(cache) >= 4_000_000:
-                    # unbounded series churn: drop the cache rather than
-                    # grow without limit (steady workloads never hit it)
-                    cache.clear()
-                cache[ck] = meta
-            if self.count_unique_timeseries:
-                # feed the unique-timeseries HLL once per new series; the
-                # HLL insert is idempotent so per-sample feeding (the Python
-                # path, worker.go:300-341) and per-series feeding agree
-                self._sample_timeseries_key(name, meta.key.type, joined,
-                                            meta.scope_class)
-            if shard_maps is not None:
-                arr = shard_maps[pool]
-                assert row == len(arr), \
-                    "reader-shard series must drain in row order"
-                if pool == 0:
-                    crow, _ = self.directory.histo.upsert_meta(meta)
-                elif pool == 1:
-                    crow, _ = self.directory.sets.upsert_meta(meta)
-                elif pool == 2:
-                    crow = self._scalar_upsert_meta(
-                        self.scalars.counters, meta)
-                else:
-                    crow = self._scalar_upsert_meta(
-                        self.scalars.gauges, meta)
-                arr.append(crow)
-            elif pool == 0:
-                self.directory.histo.adopt_meta(row, meta)
-            elif pool == 1:
-                self.directory.sets.adopt_meta(row, meta)
-            elif pool == 2:
-                self.scalars.counters.adopt_row(
-                    row, meta.key, meta.tags, meta.scope_class, meta.sinks,
-                    frag=meta.wire_frag(), admitted=meta.admitted)
+        pools = (self.directory.histo, self.directory.sets,
+                 self.scalars.counters, self.scalars.gauges)
+        per_pool = np.bincount(batch.pools, minlength=4)
+        for pool_i, pool in enumerate(pools):
+            if not per_pool[pool_i]:
+                continue
+            rows, sids = batch.rows, batch.sids
+            if per_pool[pool_i] < len(batch):
+                at = np.flatnonzero(batch.pools == pool_i)
+                rows, sids = rows[at], sids[at]
+            first_row = (len(pool.entries) if shard_maps is None
+                         else len(shard_maps[pool_i]))
+            assert (rows[0] == first_row
+                    and (rows[1:] - rows[:-1] == 1).all()), \
+                "native series must drain in row order"
+            entries, codes, frags = known.take(sids)
+            if shard_maps is None:
+                pool.adopt_batch(first_row, entries, codes, frags)
             else:
-                self.scalars.gauges.adopt_row(
-                    row, meta.key, meta.tags, meta.scope_class, meta.sinks,
-                    frag=meta.wire_frag(), admitted=meta.admitted)
-        return n_adopted
+                shard_maps[pool_i].extend(
+                    pool.upsert_batch(entries, codes, frags))
+            if self._umts is not None:
+                # feed the unique-timeseries HLL once per new series; the
+                # HLL insert is idempotent so per-sample feeding (the
+                # Python path, worker.go:300-341) and per-series feeding
+                # agree
+                counted = codes[LifetimeSeries.COUNTED] != 0
+                idx, rank = hll_ops.split_hashes(
+                    known.ts_hash[sids[counted]], self.hll_precision)
+                np.maximum.at(self._umts, idx, rank)
+        return len(batch), len(batch.first_at)
+
+    def _learn_series(self, known: LifetimeSeries, batch) -> None:
+        """The once-in-a-lifetime part of adoption: build what the pools
+        keep for each series whose strings just arrived."""
+        from veneur_tpu.native import NativeIngest
+
+        at = batch.first_at
+        sids = batch.sids[at].tolist()
+        known.reserve(max(sids) + 1)
+        for sid, pool_i, kind, scope, name, joined in zip(
+                sids, batch.pools[at].tolist(), batch.first_kinds.tolist(),
+                batch.first_scopes.tolist(), batch.first_names,
+                batch.first_tags):
+            mtype = NativeIngest.TYPE_BY_KIND[kind]
+            key = MetricKey(name=name, type=mtype, joined_tags=joined)
+            tags = joined.split(",") if joined else []
+            scope_class = ScopeClass(scope)
+            sinks = route_info(tags)
+            tenant = ""
+            admitted = True
+            if self.tenancy is not None:
+                # native-path budget gate: C++ already assigned the
+                # row, so a rejected series keeps its row but is
+                # marked admitted=False — the flusher skips it on
+                # both emit paths. The decision is kept with the
+                # series (admission is per series lifetime).
+                tenant = tenant_of(tags, self.tenancy.tag_key)
+                admitted = self.tenancy.admit(
+                    tenant, _series_budget_id(scope_class, key))
+            if pool_i < 2:
+                entry = RowMeta(key=key, tags=tags, scope_class=scope_class,
+                                sinks=sinks, tenant=tenant,
+                                admitted=admitted)
+                frag = entry.wire_frag()
+            else:
+                entry = (key, tags, scope_class, sinks)
+                frag = build_frag(name, tags)
+            ts_hash = None
+            if (self._umts is not None
+                    and self._should_count_timeseries(mtype, scope_class)):
+                ts_hash = fmix64(metric_digest(name, mtype, joined))
+            known.put(sid, entry, frag, scope, admitted, sinks is not None,
+                      ts_hash)
+
+    @property
+    def interned_series(self) -> int:
+        """Lifetime series ids held, over every native context."""
+        return sum(len(known) for known in self._adopt_cache)
 
     def sync_native_series(self) -> None:
         """Adopt pending new-series registrations mid-epoch.
 
-        Directory adoption is per-series Python work — ~0.9s per 131k
-        fresh series — and every interval re-registers every series
-        (metrics expire at flush, reference README.md:135-137). Left to
-        epoch close it all lands in swap(), UNDER the server's ingest
-        lock; called periodically (Server._series_sync_loop) it spreads
-        across the interval and swap only adopts the last cadence
+        Every interval re-registers every series (metrics expire at
+        flush, reference README.md:135-137). Left to epoch close the
+        whole interval's adoption lands in swap(), UNDER the server's
+        ingest lock; called periodically (Server._series_sync_loop) it
+        spreads across the interval and swap only adopts the last cadence
         window's tail. Caller holds the worker lock, which is what guards
         the directory; the native context lock is NOT held across the
-        adoption (each drain call takes it for its own batch): holding
-        it locked the C++ readers out for the whole of the Python loop,
-        and at 1M fresh series a window that is accepted in 3.5s took
-        more than an interval (chip_smoke.py's first finding, PR 22)."""
+        adoption (the drain takes it for its own copy): holding it locks
+        the C++ readers out, and with the per-series loop of the time a
+        window of 1M fresh series that is accepted in 3.5s took more
+        than an interval (chip_smoke.py's first finding, PR 22)."""
         if self._native is None:
             return
         for i, ctx in enumerate(self._all_ctxs()
@@ -1894,13 +1877,6 @@ class DeviceWorker:
             np.array([h], dtype=np.uint64), self.hll_precision
         )
         self._umts[idx[0]] = max(self._umts[idx[0]], rank[0])
-
-    def _sample_timeseries_key(self, name: str, mtype: str, joined: str,
-                               cls: ScopeClass) -> None:
-        """Native-path unique-timeseries sampling, keyed by series identity
-        (idempotent, so per-series feeding agrees with per-sample)."""
-        if self._umts is not None and self._should_count_timeseries(mtype, cls):
-            self._insert_timeseries(metric_digest(name, mtype, joined))
 
     def _sample_timeseries(self, m: UDPMetric, mtype: str,
                            cls: ScopeClass) -> None:

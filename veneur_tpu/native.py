@@ -11,6 +11,7 @@ import logging
 import os
 import subprocess
 import threading
+from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
@@ -242,9 +243,12 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.vn_drain_gauge.argtypes = [
             c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
         lib.vn_drain_new_series.restype = c.c_int
-        lib.vn_drain_new_series.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
-            c.c_char_p, c.c_int, c.POINTER(c.c_int), c.c_int]
+        lib.vn_drain_new_series.argtypes = (
+            [c.c_void_p] + [c.POINTER(c.c_void_p)] * 6
+            + [c.POINTER(c.c_int), c.POINTER(c.c_void_p),
+               c.POINTER(c.c_longlong), c.POINTER(c.c_uint)])
+        lib.vn_set_intern_cap.restype = None
+        lib.vn_set_intern_cap.argtypes = [c.c_void_p, c.c_longlong]
         lib.vn_pending_new_series.restype = c.c_int
         lib.vn_pending_new_series.argtypes = [c.c_void_p]
         lib.vn_drain_other.restype = c.c_int
@@ -348,6 +352,45 @@ def _ptr(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.c_void_p)
 
 
+_NO_INTS = np.zeros(0, np.int32)
+
+
+@dataclass
+class NewSeriesBatch:
+    """One drain of a context's new-series queue
+    (NativeIngest.drain_new_series). Record i is the series that took
+    row ``rows[i]`` of pool ``pools[i]`` (0 histo, 1 set, 2 counter, 3
+    gauge) this interval; ``sids[i]`` names it for the context's
+    lifetime. The records at positions ``first_at`` are the ones whose
+    strings this context hands over for the first time: their
+    MetricKind ints, scope classes, names and joined tags ride along,
+    in the same order. ``generation`` changes when the context dropped
+    its table: every sid learnt under another generation is void."""
+
+    generation: int
+    pools: np.ndarray
+    rows: np.ndarray
+    sids: np.ndarray
+    first_at: np.ndarray
+    first_kinds: np.ndarray
+    first_scopes: np.ndarray
+    first_names: list
+    first_tags: list
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def first_records(self) -> list:
+        """The first-seen records as (pool, row, kind, scope_class,
+        name, joined_tags) tuples: every record of a context that was
+        never reset (tests, tools/fuzz_differential.py)."""
+        at = self.first_at
+        return list(zip(self.pools[at].tolist(), self.rows[at].tolist(),
+                        self.first_kinds.tolist(),
+                        self.first_scopes.tolist(),
+                        self.first_names, self.first_tags))
+
+
 class NativeIngest:
     """One epoch-scoped native parser+directory context."""
 
@@ -360,15 +403,13 @@ class NativeIngest:
         self._ctx = lib.vn_ctx_new(hll_precision)
         if set_hash == "metro":
             lib.vn_ctx_set_metro(self._ctx, 1)
-        # drain_new_series scratch, allocated once: the import path calls
-        # it per upsert, and a fresh 1MB ctypes buffer per call was most
-        # of the global tier's per-metric cost
-        self._ns_pools = np.empty(4096, np.int32)
-        self._ns_rows = np.empty(4096, np.int32)
-        self._ns_kinds = np.empty(4096, np.int32)
-        self._ns_scopes = np.empty(4096, np.int32)
-        self._ns_strcap = 1 << 20
-        self._ns_strbuf = ctypes.create_string_buffer(self._ns_strcap)
+        # drain_new_series out-parameters, allocated once: the import
+        # path drains per upsert
+        c = ctypes
+        ptrs = [c.c_void_p() for _ in range(6)]
+        outs = (c.c_int(0), c.c_void_p(), c.c_longlong(0), c.c_uint(0))
+        self._ns_out = (ptrs, *outs,
+                        [c.byref(o) for o in (*ptrs, *outs)])
 
     def __del__(self):
         if getattr(self, "_ctx", None):
@@ -602,49 +643,47 @@ class NativeIngest:
         per-upsert sync skips the drain entirely when 0)."""
         return self._lib.vn_pending_new_series(self._ctx)
 
-    def drain_new_series(self, max_records: int = 4096):
-        """Returns list of (pool, row, kind, scope_class, name, joined_tags).
-        pool: 0 histo, 1 set, 2 counter, 3 gauge; kind: MetricKind int."""
-        max_records = min(max_records, 4096)
-        pools = self._ns_pools
-        rows = self._ns_rows
-        kinds = self._ns_kinds
-        scopes = self._ns_scopes
-        strcap = self._ns_strcap
-        strbuf = self._ns_strbuf
-        strlen = ctypes.c_int(0)
-        out = []
-        while True:
-            n = self._lib.vn_drain_new_series(
-                self._ctx, _ptr(pools), _ptr(rows), _ptr(kinds),
-                _ptr(scopes), strbuf, strcap, ctypes.byref(strlen),
-                max_records)
-            if n == 0:
-                stranded = self._lib.vn_pending_new_series(self._ctx)
-                if stranded:
-                    # a single record larger than the 1MB scratch cannot
-                    # make progress; drop the drain rather than spin
-                    # (series names and tag sets are bounded far below
-                    # this in practice)
-                    log.error("new-series record exceeds drain buffer; "
-                              "%d records stranded until reset", stranded)
-                break
-            # copy only the used bytes, not the whole scratch buffer
-            packed = ctypes.string_at(strbuf, strlen.value)
-            records = packed.split(b"\x1e")[:n]
-            for i, rec in enumerate(records):
-                name, _, joined = rec.partition(b"\x1f")
-                out.append((
-                    int(pools[i]), int(rows[i]), int(kinds[i]),
-                    int(scopes[i]),
-                    name.decode("utf-8", "replace"),
-                    joined.decode("utf-8", "replace"),
-                ))
-            # n < max_records can mean the string buffer filled mid-batch,
-            # not queue-empty: keep draining until the queue reports empty
-            if self._lib.vn_pending_new_series(self._ctx) == 0:
-                break
-        return out
+    def drain_new_series(self) -> "NewSeriesBatch":
+        """Every series created since the last drain, in one call: the
+        whole pending queue as int32 arrays, and strings only for the
+        series this context has not handed over before (first-seen
+        `sid`s; a series keeps its sid across reset())."""
+        c = ctypes
+        ptrs, n_first, strs, strs_len, generation, args = self._ns_out
+        # the pointers are good until this context's next drain: hold
+        # its lock across the copy so a second drainer cannot get there
+        # (the lock also makes the shared out-parameters safe)
+        self.lock()
+        try:
+            n = self._lib.vn_drain_new_series(self._ctx, *args)
+            nf = n_first.value
+            # (string_at + frombuffer: a read-only copy, at a tenth of
+            # np.ctypeslib's cost for the short drains of the upsert path)
+            pools, rows, sids, first_at, first_kinds, first_scopes = (
+                np.frombuffer(c.string_at(ptr, 4 * count), np.int32)
+                if count else _NO_INTS
+                for ptr, count in zip(ptrs, (n, n, n, nf, nf, nf)))
+            packed = c.string_at(strs, strs_len.value) if nf else b""
+            gen = generation.value
+        finally:
+            self.unlock()
+        names: list[str] = []
+        tags: list[str] = []
+        if nf:
+            # one decode of the whole buffer: the separators are ASCII,
+            # so a byte that does not decode never swallows one
+            for rec in packed.decode("utf-8", "replace").split("\x1e")[:nf]:
+                name, _, joined = rec.partition("\x1f")
+                names.append(name)
+                tags.append(joined)
+        return NewSeriesBatch(gen, pools, rows, sids, first_at,
+                              first_kinds, first_scopes, names, tags)
+
+    def set_intern_cap(self, cap: int) -> None:
+        """Bound on the lifetime series table (4,000,000; past it the
+        next reset() drops the table and bumps the generation the drain
+        reports). Only the tests move it."""
+        self._lib.vn_set_intern_cap(self._ctx, int(cap))
 
     KIND_BY_TYPE = {"counter": 0, "gauge": 1, "histogram": 2, "timer": 3,
                     "set": 4}
