@@ -31,10 +31,13 @@
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <memory>
 #include <mutex>
+#include <new>
 #include <string>
 #include <string_view>
 #include <thread>
+#include <type_traits>
 #include <unordered_map>
 #include <vector>
 
@@ -281,23 +284,35 @@ struct NewSeriesQueue {
   }
 };
 
-// Open-addressing directory: identity = (kind-type string, scope class,
-// name, joined tags), hashed with the same fnv1a32 digest as parse time.
+// Open-addressing directory, one per context for the context's lifetime:
+// identity = (kind-type string, scope class, name, joined tags), hashed
+// with dir_key_hash below -> a dense, append-only series id (`sid`).
+// The slot also carries the series' row of the interval it was last
+// written in and that interval's epoch (Ctx::epoch): a row is good only
+// while the two epochs agree, so a flush forgets every row by bumping
+// one integer and the table, its arena and its capacity stay.
 struct Directory {
-  struct Slot {
+  struct alignas(32) Slot {  // two to a cache line, never astride one
     uint64_t key_hash = 0;
-    int32_t row = -1;
     uint32_t key_off = 0;
     uint32_t key_len = 0;
+    int32_t sid = -1;  // -1: empty
+    int32_t row = 0;
+    uint32_t epoch = 0;  // Ctx::epoch starts at 1: a new slot is stale
   };
+  static constexpr size_t kMinSlots = size_t{1} << 12;
   std::vector<Slot> slots;
   std::string arena;
   size_t used = 0;
 
-  Directory() : slots(1 << 12) {}
+  Directory() : slots(kMinSlots) {}
 
-  void reset() {
-    slots.assign(1 << 12, Slot{});
+  // Forget every series; sized for `expect` of them (what the caller
+  // saw live), not for what the dropped table had grown to.
+  void reset(size_t expect) {
+    size_t n = kMinSlots;
+    while (expect * 4 >= n * 3) n *= 2;
+    slots.assign(n, Slot{});
     arena.clear();
     used = 0;
   }
@@ -307,31 +322,31 @@ struct Directory {
     old.swap(slots);
     slots.assign(old.size() * 2, Slot{});
     for (const Slot& s : old) {
-      if (s.row >= 0) {
+      if (s.sid >= 0) {
         size_t mask = slots.size() - 1;
         size_t i = s.key_hash & mask;
-        while (slots[i].row >= 0) i = (i + 1) & mask;
+        while (slots[i].sid >= 0) i = (i + 1) & mask;
         slots[i] = s;
       }
     }
   }
 
-  // returns row; *created set when the series is new. next_row supplies
-  // the row id for a new series. Identity is passed as PARTS — compared
+  // The series' slot, inserted (sid = used, *inserted set) when the
+  // table has not seen it. Identity is passed as PARTS — compared
   // piecewise against the arena and appended with the canonical
   // `name \x1f type \x1f joined \x1f cls` layout only on a miss, so the
   // per-line hot path never builds a key string (round-5 parse bench:
   // the key build + byte-serial fnv1a64 full-key pass were ~25% of
-  // commit cost).
-  int32_t upsert_parts(uint64_t key_hash, std::string_view name,
+  // commit cost). The pointer is good until the next insert.
+  Slot* find_or_insert(uint64_t key_hash, std::string_view name,
                        std::string_view type_str, std::string_view joined,
-                       char cls_char, int32_t next_row, bool* created) {
+                       char cls_char, bool* inserted) {
     if (used * 4 >= slots.size() * 3) grow();
     size_t mask = slots.size() - 1;
     const size_t nn = name.size(), nt = type_str.size(), nj = joined.size();
     const size_t want = nn + nt + nj + 4;
     size_t i = key_hash & mask;
-    while (slots[i].row >= 0) {
+    while (slots[i].sid >= 0) {
       if (slots[i].key_hash == key_hash && slots[i].key_len == want) {
         const char* k = arena.data() + slots[i].key_off;
         if (std::memcmp(k, name.data(), nn) == 0 && k[nn] == '\x1f' &&
@@ -339,14 +354,14 @@ struct Directory {
             k[nn + 1 + nt] == '\x1f' &&
             std::memcmp(k + nn + 2 + nt, joined.data(), nj) == 0 &&
             k[want - 2] == '\x1f' && k[want - 1] == cls_char) {
-          *created = false;
-          return slots[i].row;
+          *inserted = false;
+          return &slots[i];
         }
       }
       i = (i + 1) & mask;
     }
     slots[i].key_hash = key_hash;
-    slots[i].row = next_row;
+    slots[i].sid = static_cast<int32_t>(used);
     slots[i].key_off = static_cast<uint32_t>(arena.size());
     slots[i].key_len = static_cast<uint32_t>(want);
     arena.append(name);
@@ -357,17 +372,17 @@ struct Directory {
     arena.push_back('\x1f');
     arena.push_back(cls_char);
     ++used;
-    *created = true;
-    return next_row;
+    *inserted = true;
+    return &slots[i];
   }
 };
 
 // Directory key hash from the identity PARTS — no key-string build.
 // metro64 (8 bytes/step) replaces the old byte-serial fnv1a64 pass over
 // the built key on the per-line hot path. Purely internal (the
-// directory lives one interval and the hash is never serialized), but
-// every producer must agree — ingest commit, vn_upsert, vn_upsert_many
-// — since the directory dedupes by this hash + piecewise compare.
+// hash is never serialized), but every producer must agree — ingest
+// commit, vn_upsert, vn_upsert_many — since the directory dedupes by
+// this hash + piecewise compare.
 inline uint64_t dir_key_hash(uint32_t digest, std::string_view name,
                              std::string_view type_str,
                              std::string_view joined, int cls) {
@@ -394,7 +409,10 @@ struct Ctx {
   // destroyed with the old epoch.
   std::recursive_mutex mu;
 
-  Directory dir;
+  // Rows are per interval, in first-seen order; `epoch` names the
+  // interval (vn_ctx_reset increments it) and `interned` stamps each
+  // series' row with it.
+  uint32_t epoch = 1;
   int32_t next_histo_row = 0;
   int32_t next_set_row = 0;
   int32_t next_counter_row = 0;
@@ -406,10 +424,17 @@ struct Ctx {
   // whose staging is full spill into the h_* SoA batch below, which
   // Python drains mid-interval and folds directly (hot rows keep the
   // gathered per-batch fold cheap). Heap-allocated so detach is a
-  // pointer handoff: Python wraps the vectors' memory as numpy, uploads,
-  // then vn_stage_free()s the plane.
+  // pointer handoff: Python wraps the planes' memory as numpy, uploads,
+  // then vn_stage_free()s the plane, which wipes it and puts it back on
+  // its context's shelf: in a steady state two planes of the size the
+  // interval needs take turns, and the reader neither allocates nor
+  // meets a page for the first time. A new plane comes from calloc
+  // (zeroed lazily by the kernel at the sizes that matter), a used one
+  // is wiped slot by slot: either way every slot a row has not filled
+  // reads zero (slot validity is gated on wts > 0).
+  struct PlaneShelf;
   struct StagePlane {
-    int32_t rows = 0;   // allocated rows (pow2-grown)
+    int32_t rows = 0;   // allocated rows (pow2)
     int32_t depth = 0;  // slots per row (B)
     long long total = 0;  // staged samples since allocation
     // true while every staged weight is exactly 1.0 (unsampled metrics,
@@ -417,18 +442,74 @@ struct Ctx {
     // weights plane entirely and rebuild it on device from `count` —
     // halving the host->device upload at flush
     bool unit_wts = true;
-    std::vector<float> vals;     // [rows * depth]
-    std::vector<float> wts;      // [rows * depth]
-    std::vector<int32_t> count;  // [rows]
+    float* vals = nullptr;     // [rows * depth]
+    float* wts = nullptr;      // [rows * depth]
+    int32_t* count = nullptr;  // [rows]
     // micro-fold watermark: slots [drained[r], count[r]) are staged but
     // not yet copied out by vn_stage_drain_delta. `count` itself is
     // never rewound by a drain — the per-epoch depth cap (and hence the
     // spill partitioning) is identical whether or not micro-folds ran.
     std::vector<int32_t> drained;  // [rows], lazily sized
     long long drained_total = 0;
+    std::shared_ptr<PlaneShelf> shelf;  // where vn_stage_free puts it back
+
+    StagePlane() = default;
+    StagePlane(const StagePlane&) = delete;
+    StagePlane& operator=(const StagePlane&) = delete;
+    ~StagePlane() {
+      std::free(vals);
+      std::free(wts);
+      std::free(count);
+    }
+
+    // Row-major [rows, depth]: existing rows keep their offsets, the
+    // rows past them are zero.
+    void grow_to(int32_t nr) {
+      auto regrow = [this, nr](auto** arr, size_t per_row) {
+        using T = std::remove_reference_t<decltype(**arr)>;
+        T* fresh = static_cast<T*>(std::calloc(nr * per_row, sizeof(T)));
+        if (fresh == nullptr) throw std::bad_alloc();
+        if (rows > 0) std::memcpy(fresh, *arr, rows * per_row * sizeof(T));
+        std::free(*arr);
+        *arr = fresh;
+      };
+      regrow(&vals, depth);
+      regrow(&wts, depth);
+      regrow(&count, 1);
+      rows = nr;
+    }
+
+    // Back to what a new plane of these rows holds: only the slots the
+    // counts say were written are touched.
+    void wipe() {
+      for (int32_t r = 0; r < rows; ++r) {
+        const size_t filled = static_cast<size_t>(count[r]);
+        if (filled == 0) continue;
+        const size_t at = static_cast<size_t>(r) * depth;
+        std::memset(vals + at, 0, filled * sizeof(float));
+        std::memset(wts + at, 0, filled * sizeof(float));
+        count[r] = 0;
+      }
+      std::fill(drained.begin(), drained.end(), 0);
+      total = drained_total = 0;
+      unit_wts = true;
+    }
+  };
+  // A context's spare plane. It has a lock of its own because a plane
+  // comes back from the flush's thread with no context lock held, and
+  // shared ownership because a detached plane may outlive its context.
+  struct PlaneShelf {
+    std::mutex mu;
+    StagePlane* spare = nullptr;
+    // the pow2 that held the histo rows of the interval before
+    // (note_stage_rows): a new plane starts there, so a steady interval
+    // never grows its plane, and a plane of another size is not kept
+    int32_t want_rows = 0;
+    bool open = true;  // false once the context is gone (vn_ctx_free)
   };
   int stage_depth = 0;  // 0 = staging disabled (legacy SoA only)
   StagePlane* stage = nullptr;
+  std::shared_ptr<PlaneShelf> shelf = std::make_shared<PlaneShelf>();
 
   // pending SoA batches
   std::vector<int32_t> h_rows;
@@ -448,15 +529,15 @@ struct Ctx {
   std::vector<int32_t> s_idx;
   std::vector<int8_t> s_rank;
 
-  // Lifetime series ids. `dir` forgets every series at each flush (row
-  // numbers are per interval, in first-seen order); this table does not:
-  // (kind, scope class, name, joined tags) -> sid, dense and append-only,
-  // so a series that re-registers in a later interval is queued for
-  // Python as integers and its strings cross once in its lifetime.
-  // sid_handed[sid] is set once a drain has handed the strings over.
-  // Past intern_cap entries the table is dropped at the next reset (the
-  // queue is empty there, so no drain mixes two generations) and learnt
-  // again; the drain reports intern_generation so Python drops its side.
+  // The series directory, for the context's lifetime: (kind, scope
+  // class, name, joined tags) -> sid, dense and append-only, plus the
+  // series' row of the current interval (series_row). A series that
+  // re-registers in a later interval is queued for Python as integers
+  // and its strings cross once in its lifetime. sid_handed[sid] is set
+  // once a drain has handed the strings over. Past intern_cap entries
+  // the table is dropped at the next reset (the queue is empty there,
+  // so no drain mixes two generations) and learnt again; the drain
+  // reports intern_generation so Python drops its side.
   Directory interned;
   std::vector<uint8_t> sid_handed;
   uint32_t intern_generation = 0;
@@ -480,6 +561,21 @@ struct Ctx {
   // per TCP connection) leaves its share behind.
   std::atomic<long long> rd_recv_ns{0};
   std::atomic<long long> rd_busy_ns{0};
+
+  // What the commit path met (vn_commit_counters), lifetime totals like
+  // the two above, written under `mu`. A committed sample or an upsert
+  // is one of: dir_hits (the series has a row of this interval),
+  // dir_restamped (known series, first write of the interval: a row is
+  // stamped and queued as integers), dir_first_seen (inserted, strings
+  // queued). commit_batches counts lock holds of ingest_buffer and
+  // commit_lines the metric lines committed inside them; plane_grows
+  // the staging plane's reallocations.
+  long long dir_hits = 0;
+  long long dir_restamped = 0;
+  long long dir_first_seen = 0;
+  long long commit_batches = 0;
+  long long commit_lines = 0;
+  long long plane_grows = 0;
 
   // Commit-path lock contention stats (vn_lock_stats; recorded only
   // while vn_set_lock_stats(1) — the try_lock probe and clock reads cost
@@ -513,18 +609,35 @@ struct Ctx {
   std::string joined;
 };
 
-// Queue a series the directory just created for vn_drain_new_series.
-// key_hash is the directory's own (dir_key_hash over the same parts).
-// Caller holds ctx->mu.
-void queue_created(Ctx* ctx, int32_t pool, int32_t row, int32_t kind,
+// The series' row of this interval, from one probe of the lifetime
+// directory: a slot stamped with this epoch has it; a known series
+// written for the first time this interval takes the pool's next row and
+// is queued for vn_drain_new_series as three integers; a series the
+// table has never seen is inserted and also queues its kind, scope
+// class and strings. `next_row` is the pool's counter (Ctx::next_*_row),
+// key_hash is dir_key_hash over the same parts. Caller holds ctx->mu.
+int32_t series_row(Ctx* ctx, int32_t pool, int32_t* next_row, int32_t kind,
                    int32_t scope_class, uint64_t key_hash,
                    std::string_view name, std::string_view type_str,
                    std::string_view joined) {
-  bool added = false;
-  int32_t sid = ctx->interned.upsert_parts(
+  bool inserted = false;
+  Directory::Slot* slot = ctx->interned.find_or_insert(
       key_hash, name, type_str, joined, static_cast<char>('0' + scope_class),
-      static_cast<int32_t>(ctx->interned.used), &added);
-  if (added) ctx->sid_handed.push_back(0);
+      &inserted);
+  if (slot->epoch == ctx->epoch) {
+    ++ctx->dir_hits;
+    return slot->row;
+  }
+  const int32_t row = (*next_row)++;
+  const int32_t sid = slot->sid;
+  slot->row = row;
+  slot->epoch = ctx->epoch;
+  if (inserted) {
+    ctx->sid_handed.push_back(0);
+    ++ctx->dir_first_seen;
+  } else {
+    ++ctx->dir_restamped;
+  }
   NewSeriesQueue& q = ctx->new_series;
   if (!ctx->sid_handed[sid]) {
     q.first_at.push_back(static_cast<int32_t>(q.rows.size()));
@@ -544,6 +657,7 @@ void queue_created(Ctx* ctx, int32_t pool, int32_t row, int32_t kind,
   q.pools.push_back(pool);
   q.rows.push_back(row);
   q.sids.push_back(sid);
+  return row;
 }
 
 bool route_metric(Ctx* ctx, std::string_view name, MetricKind kind,
@@ -568,7 +682,16 @@ struct Parsed {
   uint32_t digest = 0;  // worker-routing digest (fnv1a32 of identity)
 };
 
-bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined);
+// The directory key of a parsed line: it spans identity + scope class
+// (the same MetricKey can legally live in two scope maps); hashed from
+// parts, no key build, no ctx access.
+inline uint64_t series_key_hash(const Parsed& p, std::string_view joined) {
+  return dir_key_hash(p.digest, p.name, kind_type_string(p.kind), joined,
+                      classify(p.kind, p.scope));
+}
+
+bool commit_metric(Ctx* ctx, const Parsed& p, std::string_view joined,
+                   uint64_t key_hash);
 
 // Delimiter finders: one tokenizer body (parse_line_impl), two ways to
 // locate delimiters. MaskFinder covers lines ≤64 bytes (the production
@@ -756,14 +879,6 @@ bool parse_line(Scratch* sc, std::string_view line, Parsed* out) {
   return parse_line_impl(ScalarFinder{line}, sc, line, out);
 }
 
-// Parse one metric line and commit it into ctx (single-shard path).
-bool handle_line(Ctx* ctx, std::string_view line) {
-  thread_local Scratch sc;
-  Parsed p;
-  if (!parse_line(&sc, line, &p)) return false;
-  return commit_metric(ctx, p, sc.joined);
-}
-
 // Route one parsed/converted sample into the pools. Expects ctx->joined to
 // hold the sorted, magic-stripped tag string. Used by the SSF span
 // extraction below (which builds ctx->joined itself); the DogStatsD text
@@ -782,11 +897,59 @@ bool route_metric(Ctx* ctx, std::string_view name, MetricKind kind,
   digest = fnv1a32(kind_type_string(kind), digest);
   digest = fnv1a32(ctx->joined, digest);
   p.digest = digest;
-  return commit_metric(ctx, p, ctx->joined);
+  return commit_metric(ctx, p, ctx->joined, series_key_hash(p, ctx->joined));
 }
 
-// Commit one parsed metric into a shard's directory + SoA buffers.
-// Caller holds ctx->mu (or owns the ctx exclusively).
+constexpr int32_t kMinStageRows = 4096;
+
+// The closing interval's plane needed this many rows (the pow2 its
+// growth by doubling ends on): the next plane starts there.
+void note_stage_rows(Ctx* ctx) {
+  int32_t nr = kMinStageRows;
+  while (nr < ctx->next_histo_row) nr *= 2;
+  std::lock_guard<std::mutex> g(ctx->shelf->mu);
+  ctx->shelf->want_rows = nr;
+}
+
+// A plane nobody reads any more: wiped and kept as its context's spare
+// if it has the size the context wants next, else freed. Takes no
+// context lock.
+void shelve_plane(Ctx::StagePlane* sp) {
+  std::shared_ptr<Ctx::PlaneShelf> shelf = sp->shelf;
+  {
+    std::lock_guard<std::mutex> g(shelf->mu);
+    if (!shelf->open || shelf->spare != nullptr ||
+        sp->rows != shelf->want_rows) {
+      delete sp;
+      return;
+    }
+  }
+  sp->wipe();  // (the plane is nobody's here: no lock needed)
+  std::lock_guard<std::mutex> g(shelf->mu);
+  if (shelf->open && shelf->spare == nullptr) {
+    shelf->spare = sp;
+  } else {
+    delete sp;
+  }
+}
+
+// The plane of a new interval: the spare if it fits, else a new one (its
+// arrays come with its first row).
+Ctx::StagePlane* take_plane(Ctx* ctx) {
+  Ctx::PlaneShelf& shelf = *ctx->shelf;
+  {
+    std::lock_guard<std::mutex> g(shelf.mu);
+    Ctx::StagePlane* sp = shelf.spare;
+    shelf.spare = nullptr;
+    if (sp != nullptr && sp->depth == ctx->stage_depth) return sp;
+    delete sp;
+  }
+  Ctx::StagePlane* sp = new Ctx::StagePlane();
+  sp->depth = ctx->stage_depth;
+  sp->shelf = ctx->shelf;
+  return sp;
+}
+
 // Store one histo/timer sample into the staging plane. Returns false if
 // staging is disabled or the row's slots are full (caller spills to the
 // SoA batch). Caller holds the ctx mutex.
@@ -794,19 +957,16 @@ bool stage_histo_sample(Ctx* ctx, int32_t row, double value,
                         double sample_rate) {
   if (ctx->stage_depth <= 0) return false;
   Ctx::StagePlane* sp = ctx->stage;
-  if (sp == nullptr) {
-    sp = ctx->stage = new Ctx::StagePlane();
-    sp->depth = ctx->stage_depth;
-  }
+  if (sp == nullptr) sp = ctx->stage = take_plane(ctx);
   if (row >= sp->rows) {
-    int32_t nr = sp->rows > 0 ? sp->rows : 4096;
+    int32_t nr = sp->rows;
+    if (nr == 0) {
+      std::lock_guard<std::mutex> g(ctx->shelf->mu);
+      nr = std::max(kMinStageRows, ctx->shelf->want_rows);
+    }
     while (nr <= row) nr *= 2;
-    // resize appends zeroed slots; row-major [rows, depth] layout means
-    // existing rows keep their offsets
-    sp->vals.resize(static_cast<size_t>(nr) * sp->depth, 0.0f);
-    sp->wts.resize(static_cast<size_t>(nr) * sp->depth, 0.0f);
-    sp->count.resize(nr, 0);
-    sp->rows = nr;
+    if (sp->rows > 0) ++ctx->plane_grows;
+    sp->grow_to(nr);
   }
   int32_t& c = sp->count[row];
   if (c >= sp->depth) return false;
@@ -820,7 +980,11 @@ bool stage_histo_sample(Ctx* ctx, int32_t row, double value,
   return true;
 }
 
-bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
+// Commit one parsed metric into a shard's directory + SoA buffers;
+// key_hash is series_key_hash(p, joined). Caller holds ctx->mu (or owns
+// the ctx exclusively).
+bool commit_metric(Ctx* ctx, const Parsed& p, std::string_view joined,
+                   uint64_t key_hash) {
   std::string_view name = p.name;
   MetricKind kind = p.kind;
   double value = p.value;
@@ -828,15 +992,6 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
   double sample_rate = p.sample_rate;
   const char* type_str = kind_type_string(kind);
   ScopeClass cls = classify(kind, p.scope);
-
-  // directory key spans identity + scope class (the same MetricKey can
-  // legally live in two scope maps); hashed from parts, no key build
-  const char cls_char = static_cast<char>('0' + cls);
-  uint64_t key_hash = dir_key_hash(p.digest, name, type_str, joined, cls);
-
-  bool created = false;
-  int32_t row = 0;
-  int32_t pool = 0;
   // Overload shedding: the pending SoA batches are normally drained
   // every ~100ms (Server's native pump / strided ingest checks), but a
   // host whose aggregate throughput is below the offered load can't
@@ -852,10 +1007,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
   switch (kind) {
     case KIND_HISTOGRAM:
     case KIND_TIMER: {
-      pool = 0;
-      row = ctx->dir.upsert_parts(key_hash, name, type_str, joined,
-                                  cls_char, ctx->next_histo_row, &created);
-      if (created) ++ctx->next_histo_row;
+      int32_t row = series_row(ctx, 0, &ctx->next_histo_row, kind, cls,
+                               key_hash, name, type_str, joined);
       if (!stage_histo_sample(ctx, row, value, sample_rate)) {
         // staging disabled, or this row's plane slots are full: spill
         // into the SoA batch for the direct per-batch device fold
@@ -870,11 +1023,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
       break;
     }
     case KIND_SET: {
-      pool = 1;
-      row = ctx->dir.upsert_parts(key_hash, name, type_str, joined,
-                                  cls_char, ctx->next_set_row,
-                                  &created);
-      if (created) ++ctx->next_set_row;
+      int32_t row = series_row(ctx, 1, &ctx->next_set_row, kind, cls,
+                               key_hash, name, type_str, joined);
       uint64_t h = ctx->set_hash_metro ? metro_hash64(set_value, 1337)
                                        : fmix64(fnv1a64(set_value));
       int p = ctx->hll_precision;
@@ -892,10 +1042,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
       break;
     }
     case KIND_COUNTER: {
-      pool = 2;
-      row = ctx->dir.upsert_parts(key_hash, name, type_str, joined,
-                                  cls_char, ctx->next_counter_row, &created);
-      if (created) ++ctx->next_counter_row;
+      int32_t row = series_row(ctx, 2, &ctx->next_counter_row, kind, cls,
+                               key_hash, name, type_str, joined);
       if (ctx->c_rows.size() < kSpillCap) {
         // Go semantics: int64(sample) * int64(1/rate)
         ctx->c_rows.push_back(row);
@@ -908,10 +1056,8 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
       break;
     }
     case KIND_GAUGE: {
-      pool = 3;
-      row = ctx->dir.upsert_parts(key_hash, name, type_str, joined,
-                                  cls_char, ctx->next_gauge_row, &created);
-      if (created) ++ctx->next_gauge_row;
+      int32_t row = series_row(ctx, 3, &ctx->next_gauge_row, kind, cls,
+                               key_hash, name, type_str, joined);
       if (ctx->g_rows.size() < kSpillCap) {
         ctx->g_rows.push_back(row);
         ctx->g_vals.push_back(value);
@@ -931,9 +1077,43 @@ bool commit_metric(Ctx* ctx, const Parsed& p, const std::string& joined) {
       break;
     }
   }
-  if (created)
-    queue_created(ctx, pool, row, kind, cls, key_hash, name, type_str, joined);
   return true;
+}
+
+// The Python-side upsert (vn_upsert, vn_upsert_many): the series' row of
+// this interval by the same probe a parsed line takes, no sample.
+// Caller holds ctx->mu.
+int32_t upsert_series(Ctx* ctx, std::string_view name, int32_t kind,
+                      std::string_view joined, int32_t scope_class) {
+  MetricKind k = static_cast<MetricKind>(kind);
+  const char* type_str = kind_type_string(k);
+  uint32_t digest = fnv1a32(name);
+  digest = fnv1a32(type_str, digest);
+  digest = fnv1a32(joined, digest);
+  uint64_t key_hash = dir_key_hash(digest, name, type_str, joined, scope_class);
+  int32_t* next = nullptr;
+  int32_t pool = 0;
+  switch (k) {
+    case KIND_HISTOGRAM:
+    case KIND_TIMER:
+      next = &ctx->next_histo_row;
+      pool = 0;
+      break;
+    case KIND_SET:
+      next = &ctx->next_set_row;
+      pool = 1;
+      break;
+    case KIND_COUNTER:
+      next = &ctx->next_counter_row;
+      pool = 2;
+      break;
+    case KIND_GAUGE:
+      next = &ctx->next_gauge_row;
+      pool = 3;
+      break;
+  }
+  return series_row(ctx, pool, next, kind, scope_class, key_hash, name,
+                    type_str, joined);
 }
 
 // ---------------------------------------------------------------------------
@@ -1385,6 +1565,12 @@ void* vn_ctx_new(int hll_precision) {
 
 void vn_ctx_free(void* p) {
   Ctx* ctx = static_cast<Ctx*>(p);
+  {
+    std::lock_guard<std::mutex> g(ctx->shelf->mu);
+    ctx->shelf->open = false;  // planes still out are freed, not shelved
+    delete ctx->shelf->spare;
+    ctx->shelf->spare = nullptr;
+  }
   delete ctx->stage;
   delete ctx;
 }
@@ -1409,9 +1595,10 @@ void* vn_stage_detach(void* p, float** vals, float** wts, int32_t** counts,
   Ctx::StagePlane* sp = ctx->stage;
   if (sp == nullptr || sp->total == 0) return nullptr;
   ctx->stage = nullptr;
-  *vals = sp->vals.data();
-  *wts = sp->wts.data();
-  *counts = sp->count.data();
+  note_stage_rows(ctx);
+  *vals = sp->vals;
+  *wts = sp->wts;
+  *counts = sp->count;
   *rows_out = sp->rows;
   *depth_out = sp->depth;
   return sp;
@@ -1424,7 +1611,7 @@ int vn_stage_unit_wts(void* plane) {
 }
 
 void vn_stage_free(void* plane) {
-  delete static_cast<Ctx::StagePlane*>(plane);
+  shelve_plane(static_cast<Ctx::StagePlane*>(plane));
 }
 
 // Staged-sample count (telemetry / drain-threshold checks).
@@ -1684,13 +1871,21 @@ long long vn_encode_histo_batch(
 void vn_ctx_reset(void* p) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> ctx_guard(ctx->mu);
-  ctx->dir.reset();
+  // every row stamped before now is stale: the directory itself stays
+  if (++ctx->epoch == 0) {  // 2^32 resets: unstamp, start over
+    for (Directory::Slot& slot : ctx->interned.slots) slot.epoch = 0;
+    ctx->epoch = 1;
+  }
+  const size_t live_series =
+      static_cast<size_t>(ctx->next_histo_row) + ctx->next_set_row +
+      ctx->next_counter_row + ctx->next_gauge_row;
+  note_stage_rows(ctx);
   ctx->next_histo_row = ctx->next_set_row = 0;
   ctx->next_counter_row = ctx->next_gauge_row = 0;
-  // drop the staging plane wholesale: rows re-register next epoch and a
-  // fresh plane comes back zeroed (slot validity is gated on wts > 0, so
+  // drop the staging plane wholesale: rows re-register next epoch and
+  // the next plane comes zeroed (slot validity is gated on wts > 0, so
   // stale values must never survive a reset)
-  delete ctx->stage;
+  if (ctx->stage != nullptr) shelve_plane(ctx->stage);
   ctx->stage = nullptr;
   ctx->h_rows.clear();
   ctx->h_vals.clear();
@@ -1708,7 +1903,7 @@ void vn_ctx_reset(void* p) {
   ctx->new_series.clear();
   if (ctx->interned.used >= ctx->intern_cap ||
       ctx->interned.arena.size() >= (size_t{1} << 30)) {
-    ctx->interned.reset();
+    ctx->interned.reset(live_series);
     ctx->sid_handed.clear();
     ++ctx->intern_generation;
   }
@@ -1724,106 +1919,280 @@ void vn_ctx_reset(void* p) {
   ctx->ssf_fallback_bytes = 0;
 }
 
+}  // extern "C"
+
+namespace {
+
+// One parsed line of a buffer, waiting for its commit. The views of `p`
+// point into the caller's buffer, the joined tags lie in Batch::tags.
+struct BatchLine {
+  Parsed p;
+  uint64_t key_hash = 0;
+  uint32_t tags_at = 0;
+  uint32_t tags_len = 0;
+  uint32_t target = 0;   // index of the context that owns the series
+  int32_t staged_row = -1;  // the probe stage's finding, for the next one
+};
+
+// A buffer's lines between their parse (no lock) and their commit (one
+// lock hold a target context); one per calling thread, kept for its
+// capacity.
+struct Batch {
+  std::vector<BatchLine> lines;  // the first n_lines are this buffer's
+  size_t n_lines = 0;
+  std::string tags;
+  std::vector<std::string_view> others;  // events and service checks
+  std::vector<uint32_t> order;  // line indices bucketed by target, in order
+  std::vector<uint32_t> bucket_end;
+  long long errors = 0;
+};
+
+// How many lines ahead of the commit each stage of commit_lines runs. A
+// line's commit walks a chain of dependent misses on a stream shuffled
+// over more series than any cache holds (slot -> key bytes and count[row]
+// -> the row's next vals/wts slot); each stage issues the loads the
+// next one will read, so the misses of a dozen lines overlap instead of
+// queueing. Constants, not options: the chain is the code's, and the
+// batch is however many lines the caller was handed. While the directory
+// is small enough to sit in the cache (32,768 slots are 1 MiB, 24,576
+// series at the most) there are no misses to overlap and the stages
+// would cost a tenth of a line each for nothing, so they wait until it
+// has outgrown that.
+constexpr size_t kSlotAhead = 24;
+constexpr size_t kKeyAhead = 12;
+constexpr size_t kSampleAhead = 6;
+constexpr size_t kCacheResidentSlots = size_t{1} << 15;
+
+inline bool stages_samples(MetricKind k) {
+  return k == KIND_HISTOGRAM || k == KIND_TIMER;
+}
+
+inline void prefetch_slot(const Ctx* ctx, const BatchLine& ln) {
+  const std::vector<Directory::Slot>& slots = ctx->interned.slots;
+  __builtin_prefetch(&slots[ln.key_hash & (slots.size() - 1)]);
+}
+
+// Reads the slot prefetch_slot asked for; asks for the key bytes the
+// commit will compare and, for a timer that has its row already, the
+// row's staged count. Only hints: the commit probes again.
+inline void prefetch_key(const Ctx* ctx, BatchLine* ln) {
+  const Directory& dir = ctx->interned;
+  const size_t mask = dir.slots.size() - 1;
+  size_t i = ln->key_hash & mask;
+  ln->staged_row = -1;
+  for (int probes = 0; probes < 4; ++probes, i = (i + 1) & mask) {
+    const Directory::Slot& slot = dir.slots[i];
+    if (slot.sid < 0) return;
+    if (slot.key_hash != ln->key_hash) continue;
+    const char* key = dir.arena.data() + slot.key_off;
+    __builtin_prefetch(key);
+    __builtin_prefetch(key + slot.key_len - 1);
+    const Ctx::StagePlane* sp = ctx->stage;
+    if (slot.epoch == ctx->epoch && sp != nullptr && slot.row < sp->rows &&
+        stages_samples(ln->p.kind)) {
+      ln->staged_row = slot.row;
+      __builtin_prefetch(&sp->count[slot.row]);
+    }
+    return;
+  }
+}
+
+// Reads the count prefetch_key asked for; asks for the slot the sample
+// will be written to.
+inline void prefetch_sample(const Ctx* ctx, const BatchLine& ln) {
+  const Ctx::StagePlane* sp = ctx->stage;
+  if (ln.staged_row < 0 || sp == nullptr || ln.staged_row >= sp->rows) return;
+  const int32_t c = sp->count[ln.staged_row];
+  if (c >= sp->depth) return;
+  const size_t at = static_cast<size_t>(ln.staged_row) * sp->depth + c;
+  __builtin_prefetch(&sp->vals[at], 1);
+  __builtin_prefetch(&sp->wts[at], 1);
+}
+
+inline void commit_line(Ctx* ctx, const Batch& b, const BatchLine& ln) {
+  commit_metric(ctx, ln.p, std::string_view(b.tags).substr(ln.tags_at,
+                                                           ln.tags_len),
+                ln.key_hash);
+}
+
+// Commit lines order[0..n) of the batch (or lines 0..n when `order` is
+// null) into ctx, in that order. Caller holds ctx->mu.
+void commit_lines(Ctx* ctx, Batch* b, const uint32_t* order, size_t n) {
+  auto line = [b, order](size_t k) -> BatchLine& {
+    return b->lines[order != nullptr ? order[k] : k];
+  };
+  if (ctx->interned.slots.size() <= kCacheResidentSlots) {
+    for (size_t k = 0; k < n; ++k) commit_line(ctx, *b, line(k));
+  } else {
+    // step i: stage one looks at line i, the commit at line i - kSlotAhead
+    // (size_t wraps below zero, so one compare bounds both ends)
+    for (size_t i = 0; i < n + kSlotAhead; ++i) {
+      if (i < n) prefetch_slot(ctx, line(i));
+      if (i - (kSlotAhead - kKeyAhead) < n)
+        prefetch_key(ctx, &line(i - (kSlotAhead - kKeyAhead)));
+      if (i - (kSlotAhead - kSampleAhead) < n)
+        prefetch_sample(ctx, line(i - (kSlotAhead - kSampleAhead)));
+      if (i - kSlotAhead < n) commit_line(ctx, *b, line(i - kSlotAhead));
+    }
+  }
+  ctx->processed += static_cast<long long>(n);
+  ++ctx->commit_batches;
+  ctx->commit_lines += static_cast<long long>(n);
+}
+
+// The instrumented commit (vn_set_lock_stats): one acquisition a line,
+// its wait time (blocked acquire) and hold time, with sample rings for
+// percentiles. A diagnostic of the lock, so it keeps the lock's old
+// grain.
+void commit_line_timed(Ctx* target, const Batch& b, const BatchLine& ln) {
+  int64_t t0 = now_ns();
+  bool contended = !target->mu.try_lock();
+  if (contended) target->mu.lock();
+  int64_t t1 = now_ns();
+  commit_line(target, b, ln);
+  ++target->processed;
+  int64_t t2 = now_ns();
+  ++target->lk_acquisitions;
+  if (contended) ++target->lk_contended;
+  int64_t wait = contended ? (t1 - t0) : 0;
+  target->lk_wait_ns_total += wait;
+  target->lk_hold_ns_total += t2 - t1;
+  int slot = target->lk_ring_n % Ctx::kLockRing;
+  target->lk_wait_ring[slot] = wait;
+  target->lk_hold_ring[slot] = t2 - t1;
+  ++target->lk_ring_n;
+  target->mu.unlock();
+}
+
+// Ingest a buffer of newline-separated lines: every entry point that
+// takes text (vn_ingest, vn_ingest_home, the datagram and stream
+// readers) ends here, with whatever it was handed: one line, a datagram
+// of forty, a 64 KiB chunk of two thousand. All lines are parsed first,
+// with no lock held (thread-local scratch; tag sort/join is the
+// expensive part of a line); then each target context (digest % nctx —
+// the native twin of the reference's contention-free Digest%N worker
+// routing, server.go:1028-1039) is locked once and takes its lines in
+// buffer order, which is what a gauge's last write and first-seen rows
+// need, since a series always maps to one context. Events/service checks
+// and parse errors land on the caller's home shard so one noisy event
+// stream can't serialize every reader behind shard 0. A line longer than
+// max_line is a parse error. Returns the metric lines accepted;
+// *lines_out (if given) gets the non-empty lines within max_line.
+int ingest_buffer(Ctx* const* ctxs, int nctx, std::string_view data, int home,
+                  size_t max_line, long long* lines_out) {
+  thread_local Scratch sc;
+  thread_local Batch batch;
+  Batch& b = batch;
+  b.n_lines = 0;
+  b.tags.clear();
+  b.others.clear();
+  b.errors = 0;
+  long long seen = 0;
+  while (!data.empty()) {
+    size_t nl = data.find('\n');
+    std::string_view line =
+        nl == std::string_view::npos ? data : data.substr(0, nl);
+    data = nl == std::string_view::npos ? std::string_view()
+                                        : data.substr(nl + 1);
+    if (line.empty()) continue;
+    if (line.size() > max_line) {
+      ++b.errors;
+      continue;
+    }
+    ++seen;
+    if (line.substr(0, 3) == "_e{" || line.substr(0, 3) == "_sc") {
+      b.others.push_back(line);
+      continue;
+    }
+    // (a slot is written in full by a parse that succeeds, so the
+    // slots of the last buffer are used again as they are)
+    if (b.n_lines == b.lines.size()) b.lines.emplace_back();
+    BatchLine& ln = b.lines[b.n_lines];
+    if (!parse_line(&sc, line, &ln.p)) {
+      ++b.errors;
+      continue;
+    }
+    ++b.n_lines;
+    ln.key_hash = series_key_hash(ln.p, sc.joined);
+    ln.tags_at = static_cast<uint32_t>(b.tags.size());
+    ln.tags_len = static_cast<uint32_t>(sc.joined.size());
+    b.tags.append(sc.joined);
+    ln.target = nctx > 1 ? ln.p.digest % static_cast<uint32_t>(nctx) : 0;
+  }
+  if (lines_out != nullptr) *lines_out = seen;
+
+  // bucket the lines by target, keeping their order (a counting sort);
+  // with one context the lines are their own order
+  const size_t n = b.n_lines;
+  const uint32_t* order = nullptr;
+  b.bucket_end.assign(static_cast<size_t>(nctx), 0);
+  if (nctx > 1) {
+    for (size_t i = 0; i < n; ++i) ++b.bucket_end[b.lines[i].target];
+    uint32_t at = 0;
+    for (uint32_t& e : b.bucket_end) {
+      uint32_t count = e;
+      e = at;  // the bucket's start, advanced to its end below
+      at += count;
+    }
+    b.order.resize(n);
+    for (uint32_t i = 0; i < n; ++i)
+      b.order[b.bucket_end[b.lines[i].target]++] = i;
+    order = b.order.data();
+  } else {
+    b.bucket_end[0] = static_cast<uint32_t>(n);
+  }
+
+  const bool timed = g_lock_stats.load(std::memory_order_relaxed);
+  const bool home_work = b.errors > 0 || !b.others.empty();
+  uint32_t begin = 0;
+  for (int t = 0; t < nctx; ++t) {
+    const uint32_t end = b.bucket_end[t];
+    const bool is_home = t == home && home_work;
+    Ctx* target = ctxs[t];
+    std::unique_lock<std::recursive_mutex> hold(target->mu, std::defer_lock);
+    if (timed) {
+      for (uint32_t k = begin; k < end; ++k)
+        commit_line_timed(target, b, b.lines[order != nullptr ? order[k] : k]);
+    } else if (end > begin) {
+      hold.lock();
+      commit_lines(target, &b, order != nullptr ? order + begin : nullptr,
+                   end - begin);
+    }
+    if (is_home) {
+      if (!hold.owns_lock()) hold.lock();
+      target->errors += b.errors;
+      for (std::string_view other : b.others) {
+        target->other_lines.append(other);
+        target->other_lines.push_back('\n');
+      }
+    }
+    begin = end;
+  }
+  return static_cast<int>(n);
+}
+
+}  // namespace
+
+extern "C" {
+
 // Ingest a datagram (possibly multiple newline-separated lines).
 // Returns the number of metric lines accepted.
 int vn_ingest(void* p, const char* buf, int len) {
   Ctx* ctx = static_cast<Ctx*>(p);
-  std::lock_guard<std::recursive_mutex> ctx_guard(ctx->mu);
-  std::string_view data(buf, static_cast<size_t>(len));
-  int accepted = 0;
-  while (!data.empty()) {
-    size_t nl = data.find('\n');
-    std::string_view line =
-        nl == std::string_view::npos ? data : data.substr(0, nl);
-    data = nl == std::string_view::npos ? std::string_view()
-                                        : data.substr(nl + 1);
-    if (line.empty()) continue;
-    if (line.substr(0, 3) == "_e{" || line.substr(0, 3) == "_sc") {
-      ctx->other_lines.append(line);
-      ctx->other_lines.push_back('\n');
-      continue;
-    }
-    if (handle_line(ctx, line)) {
-      ++ctx->processed;
-      ++accepted;
-    } else {
-      ++ctx->errors;
-    }
-  }
-  return accepted;
+  return ingest_buffer(&ctx, 1, std::string_view(buf, static_cast<size_t>(len)),
+                       0, std::string_view::npos, nullptr);
 }
 
-// Sharded ingest: parse each line lock-free (thread-local scratch), then
-// commit into shard digest % nctx under only that shard's mutex — the
-// native twin of the reference's contention-free Digest%N worker routing
-// (server.go:1028-1039). Multiple SO_REUSEPORT readers call this
-// concurrently; ctypes drops the GIL, so parsing genuinely parallelizes.
-// Events/service checks and parse errors land on the caller's home shard
-// so one noisy event stream can't serialize every reader behind shard 0.
-// With nctx == 1 and home == 0 this degenerates to the shared-nothing
+// Sharded ingest (ingest_buffer): multiple SO_REUSEPORT readers call
+// this concurrently; ctypes drops the GIL, so parsing genuinely
+// parallelizes. With nctx == 1 and home == 0 this is the shared-nothing
 // per-reader commit path: every line commits into the caller's own ctx
 // under a mutex nobody else touches on the line path.
 int vn_ingest_home(void** ctxps, int nctx, const char* buf, int len,
                    int home) {
-  thread_local Scratch sc;
-  Ctx** ctxs = reinterpret_cast<Ctx**>(ctxps);
-  std::string_view data(buf, static_cast<size_t>(len));
-  int accepted = 0;
-  while (!data.empty()) {
-    size_t nl = data.find('\n');
-    std::string_view line =
-        nl == std::string_view::npos ? data : data.substr(0, nl);
-    data = nl == std::string_view::npos ? std::string_view()
-                                        : data.substr(nl + 1);
-    if (line.empty()) continue;
-    if (line.substr(0, 3) == "_e{" || line.substr(0, 3) == "_sc") {
-      std::lock_guard<std::recursive_mutex> g(ctxs[home]->mu);
-      ctxs[home]->other_lines.append(line);
-      ctxs[home]->other_lines.push_back('\n');
-      continue;
-    }
-    Parsed parsed;
-    if (!parse_line(&sc, line, &parsed)) {
-      std::lock_guard<std::recursive_mutex> g(ctxs[home]->mu);
-      ++ctxs[home]->errors;
-      continue;
-    }
-    Ctx* target = ctxs[parsed.digest % static_cast<uint32_t>(nctx)];
-    if (!g_lock_stats.load(std::memory_order_relaxed)) {
-      std::lock_guard<std::recursive_mutex> g(target->mu);
-      if (commit_metric(target, parsed, sc.joined)) {
-        ++target->processed;
-        ++accepted;
-      } else {
-        ++target->errors;
-      }
-      continue;
-    }
-    // instrumented commit: wait time (blocked acquire) and hold time of
-    // this shard's mutex, with sample rings for percentiles
-    int64_t t0 = now_ns();
-    bool contended = !target->mu.try_lock();
-    if (contended) target->mu.lock();
-    int64_t t1 = now_ns();
-    if (commit_metric(target, parsed, sc.joined)) {
-      ++target->processed;
-      ++accepted;
-    } else {
-      ++target->errors;
-    }
-    int64_t t2 = now_ns();
-    ++target->lk_acquisitions;
-    if (contended) ++target->lk_contended;
-    int64_t wait = contended ? (t1 - t0) : 0;
-    target->lk_wait_ns_total += wait;
-    target->lk_hold_ns_total += t2 - t1;
-    int slot = target->lk_ring_n % Ctx::kLockRing;
-    target->lk_wait_ring[slot] = wait;
-    target->lk_hold_ring[slot] = t2 - t1;
-    ++target->lk_ring_n;
-    target->mu.unlock();
-  }
-  return accepted;
+  return ingest_buffer(reinterpret_cast<Ctx**>(ctxps), nctx,
+                       std::string_view(buf, static_cast<size_t>(len)), home,
+                       std::string_view::npos, nullptr);
 }
 
 int vn_ingest_routed(void** ctxps, int nctx, const char* buf, int len) {
@@ -1874,15 +2243,16 @@ void reader_loop(Reader* r) {
         continue;  // SO_RCVTIMEO tick: poll the stop flag
       break;  // fd closed under us (shutdown)
     }
-    r->packets.fetch_add(1, std::memory_order_relaxed);
     if (n > r->max_len) {
       std::lock_guard<std::recursive_mutex> g(r->ctxs[r->home]->mu);
       ++r->ctxs[r->home]->errors;
-      continue;
+    } else {
+      ingest_buffer(r->ctxs.data(), static_cast<int>(r->ctxs.size()),
+                    std::string_view(buf.data(), static_cast<size_t>(n)),
+                    r->home, std::string_view::npos, nullptr);
     }
-    vn_ingest_home(reinterpret_cast<void**>(r->ctxs.data()),
-                   static_cast<int>(r->ctxs.size()), buf.data(),
-                   static_cast<int>(n), r->home);
+    // counted once it is in: who reads N packets finds N committed
+    r->packets.fetch_add(1, std::memory_order_release);
   }
 }
 
@@ -1973,7 +2343,7 @@ void* vn_reader_start(void** ctxps, int nctx, int fd, int max_len) {
 }
 
 long long vn_reader_packets(void* p) {
-  return static_cast<Reader*>(p)->packets.load(std::memory_order_relaxed);
+  return static_cast<Reader*>(p)->packets.load(std::memory_order_acquire);
 }
 
 // Stop and join the reader, then free it. Does NOT close the fd.
@@ -2011,10 +2381,58 @@ struct StreamReader {
   std::vector<Ctx*> ctxs;
 };
 
-void stream_reader_loop(StreamReader* r) {
-  std::vector<char> chunk(64 << 10);
+// What a stream reader carries from one recv to the next: the partial
+// last line, and whether it is inside an overlong line it dropped.
+struct StreamCarry {
   std::string buf;
   bool skipping = false;  // inside an overlong line, waiting for \n
+};
+
+// One recv's bytes: every complete line goes to ingest_buffer at once,
+// the partial last line is kept for the next recv.
+void stream_feed(StreamReader* r, StreamCarry* st, const char* data,
+                 size_t n) {
+  std::string& buf = st->buf;
+  const bool carried = !buf.empty();
+  if (carried) {  // (else the lines are taken where recv put them)
+    buf.append(data, n);
+    data = buf.data();
+    n = buf.size();
+  }
+  const char* last = static_cast<const char*>(memrchr(data, '\n', n));
+  if (st->skipping && last == nullptr) return;  // still inside that line
+  size_t done = 0;  // bytes up to and including the last newline
+  if (last != nullptr) {
+    done = static_cast<size_t>(last - data) + 1;
+    size_t start = 0;
+    if (st->skipping) {  // the tail of the overlong line dropped before
+      start = static_cast<const char*>(memchr(data, '\n', n)) - data + 1;
+      st->skipping = false;
+    }
+    long long lines = 0;
+    ingest_buffer(r->ctxs.data(), static_cast<int>(r->ctxs.size()),
+                  std::string_view(data + start, done - start), r->home,
+                  static_cast<size_t>(r->max_len), &lines);
+    r->lines.fetch_add(lines, std::memory_order_relaxed);
+  }
+  if (carried) {
+    buf.erase(0, done);
+  } else {
+    buf.assign(data + done, n - done);
+  }
+  if (!st->skipping && buf.size() > static_cast<size_t>(r->max_len)) {
+    // partial line already too long: drop it now (bounded memory;
+    // the Python path buffers unboundedly here)
+    std::lock_guard<std::recursive_mutex> g(r->ctxs[r->home]->mu);
+    ++r->ctxs[r->home]->errors;
+    buf.clear();
+    st->skipping = true;
+  }
+}
+
+void stream_reader_loop(StreamReader* r) {
+  std::vector<char> chunk(64 << 10);
+  StreamCarry carry;
   int64_t t_back = now_ns();
   while (!r->stop.load(std::memory_order_acquire)) {
     ssize_t n = timed_recv(r->ctxs[r->home], &t_back, r->fd, chunk.data(),
@@ -2025,35 +2443,7 @@ void stream_reader_loop(StreamReader* r) {
       break;
     }
     if (n == 0) break;  // peer closed
-    buf.append(chunk.data(), static_cast<size_t>(n));
-    size_t start = 0, nl;
-    while ((nl = buf.find('\n', start)) != std::string::npos) {
-      size_t len = nl - start;
-      if (skipping) {
-        skipping = false;  // tail of the dropped overlong line
-      } else if (len > 0) {
-        if (len > static_cast<size_t>(r->max_len)) {
-          std::lock_guard<std::recursive_mutex> g(r->ctxs[r->home]->mu);
-          ++r->ctxs[r->home]->errors;
-        } else {
-          vn_ingest_home(reinterpret_cast<void**>(r->ctxs.data()),
-                         static_cast<int>(r->ctxs.size()),
-                         buf.data() + start, static_cast<int>(len),
-                         r->home);
-          r->lines.fetch_add(1, std::memory_order_relaxed);
-        }
-      }
-      start = nl + 1;
-    }
-    buf.erase(0, start);
-    if (!skipping && buf.size() > static_cast<size_t>(r->max_len)) {
-      // partial line already too long: drop it now (bounded memory;
-      // the Python path buffers unboundedly here)
-      std::lock_guard<std::recursive_mutex> g(r->ctxs[r->home]->mu);
-      ++r->ctxs[r->home]->errors;
-      buf.clear();
-      skipping = true;
-    }
+    stream_feed(r, &carry, chunk.data(), static_cast<size_t>(n));
   }
   close(r->fd);
   r->finished.store(true, std::memory_order_release);
@@ -2255,6 +2645,20 @@ void vn_reader_ns(void* p, long long* out) {
   out[1] = ctx->rd_busy_ns.load(std::memory_order_relaxed);
 }
 
+// What the commit path met, lifetime (Ctx::dir_hits and the five after
+// it, in that order): out[0..5] = dir_hits, dir_restamped,
+// dir_first_seen, commit_batches, commit_lines, plane_grows.
+void vn_commit_counters(void* p, long long* out) {
+  Ctx* ctx = static_cast<Ctx*>(p);
+  std::lock_guard<std::recursive_mutex> g(ctx->mu);
+  out[0] = ctx->dir_hits;
+  out[1] = ctx->dir_restamped;
+  out[2] = ctx->dir_first_seen;
+  out[3] = ctx->commit_batches;
+  out[4] = ctx->commit_lines;
+  out[5] = ctx->plane_grows;
+}
+
 void vn_set_spill_cap(void* p, long long cap) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> g(ctx->mu);
@@ -2377,48 +2781,10 @@ int vn_upsert(void* p, const char* name, int name_len, int kind,
               const char* joined_tags, int tags_len, int scope_class) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> ctx_guard(ctx->mu);
-  std::string_view name_sv(name, static_cast<size_t>(name_len));
-  std::string_view tags_sv(joined_tags, static_cast<size_t>(tags_len));
-  MetricKind k = static_cast<MetricKind>(kind);
-  const char* type_str = kind_type_string(k);
-
-  uint32_t digest = fnv1a32(name_sv);
-  digest = fnv1a32(type_str, digest);
-  digest = fnv1a32(tags_sv, digest);
-  uint64_t key_hash =
-      dir_key_hash(digest, name_sv, type_str, tags_sv, scope_class);
-
-  int32_t* next = nullptr;
-  int32_t pool = 0;
-  switch (k) {
-    case KIND_HISTOGRAM:
-    case KIND_TIMER:
-      next = &ctx->next_histo_row;
-      pool = 0;
-      break;
-    case KIND_SET:
-      next = &ctx->next_set_row;
-      pool = 1;
-      break;
-    case KIND_COUNTER:
-      next = &ctx->next_counter_row;
-      pool = 2;
-      break;
-    case KIND_GAUGE:
-      next = &ctx->next_gauge_row;
-      pool = 3;
-      break;
-  }
-  bool created = false;
-  int32_t row = ctx->dir.upsert_parts(
-      key_hash, name_sv, type_str, tags_sv,
-      static_cast<char>('0' + scope_class), *next, &created);
-  if (created) {
-    ++*next;
-    queue_created(ctx, pool, row, kind, scope_class, key_hash, name_sv,
-                  type_str, tags_sv);
-  }
-  return row;
+  return upsert_series(
+      ctx, std::string_view(name, static_cast<size_t>(name_len)), kind,
+      std::string_view(joined_tags, static_cast<size_t>(tags_len)),
+      scope_class);
 }
 
 // ---------------------------------------------------------------------------
@@ -2874,46 +3240,7 @@ long long vn_upsert_many(void* p, const char* meta, long long meta_len,
     std::string_view joined =
         nend == std::string_view::npos ? std::string_view()
                                        : rec.substr(nend + 1);
-    MetricKind k = static_cast<MetricKind>(kinds[i]);
-    const char* type_str = kind_type_string(k);
-
-    uint32_t digest = fnv1a32(name);
-    digest = fnv1a32(type_str, digest);
-    digest = fnv1a32(joined, digest);
-    uint64_t key_hash =
-        dir_key_hash(digest, name, type_str, joined, scopes[i]);
-
-    int32_t* next = nullptr;
-    int32_t pool = 0;
-    switch (k) {
-      case KIND_HISTOGRAM:
-      case KIND_TIMER:
-        next = &ctx->next_histo_row;
-        pool = 0;
-        break;
-      case KIND_SET:
-        next = &ctx->next_set_row;
-        pool = 1;
-        break;
-      case KIND_COUNTER:
-        next = &ctx->next_counter_row;
-        pool = 2;
-        break;
-      case KIND_GAUGE:
-        next = &ctx->next_gauge_row;
-        pool = 3;
-        break;
-    }
-    bool created = false;
-    int32_t row = ctx->dir.upsert_parts(
-        key_hash, name, type_str, joined,
-        static_cast<char>('0' + scopes[i]), *next, &created);
-    if (created) {
-      ++*next;
-      queue_created(ctx, pool, row, kinds[i], scopes[i], key_hash, name,
-                    type_str, joined);
-    }
-    out_rows[i] = row;
+    out_rows[i] = upsert_series(ctx, name, kinds[i], joined, scopes[i]);
     ++done;
   }
   return done;

@@ -11,6 +11,13 @@
 // concurrently under -fsanitize=thread. Any data race on the shard
 // mutex discipline aborts the build (TSan exits non-zero).
 //
+// A second round drives the chunk commit (ingest_buffer's one lock hold
+// a context for a whole buffer; the first round's lock instrumentation
+// keeps the commit at one line a hold): readers hand 42-line buffers to
+// vn_ingest_routed while a flush thread closes intervals under vn_lock
+// (tally, plane detach, drains, vn_ctx_reset), so epochs turn over under
+// the readers' feet and every accepted line must still be counted once.
+//
 // Built+run by `make -C native tsan` (tools/ci.sh runs it).
 
 #include <atomic>
@@ -53,7 +60,11 @@ int vn_pending_counter(void* p);
 int vn_pending_gauge(void* p);
 void vn_set_lock_stats(int enabled);
 int vn_lock_stats(void* p, long long out[5], long long* wait_out,
-                  long long* hold_out);
+                  long long* hold_out, int cap);
+void vn_lock(void* p);
+void vn_unlock(void* p);
+void vn_ctx_reset(void* p);
+void vn_commit_counters(void* p, long long* out);
 void vn_set_stage_depth(void* p, int depth);
 void* vn_stage_detach(void* p, float** vals, float** wts, int32_t** counts,
                       int32_t* rows_out, int32_t* depth_out);
@@ -113,34 +124,13 @@ std::string make_ssf_batch(int seed) {
   return out;
 }
 
+int make_line(char* line, size_t cap, int i, int tid);
+
 void reader_thread(std::vector<void*>* ctxs, int tid) {
   char line[128];
   for (int i = 0; i < kPacketsPerReader; ++i) {
-    int n;
     int kind = i % 6;
-    switch (kind) {
-      case 0:
-        n = std::snprintf(line, sizeof line, "soak.timer%d:%d|ms|#t:%d",
-                          i % 64, i % 1000, tid);
-        break;
-      case 1:
-        n = std::snprintf(line, sizeof line, "soak.count:%d|c|@0.5", i % 7);
-        break;
-      case 2:
-        n = std::snprintf(line, sizeof line, "soak.gauge%d:%d|g", tid, i);
-        break;
-      case 3:
-        n = std::snprintf(line, sizeof line, "soak.set:user%d|s", i % 997);
-        break;
-      case 4:  // malformed: exercises the error path under contention
-        n = std::snprintf(line, sizeof line, "soak.bad:%d|q", i);
-        break;
-      default:  // event: races the other_lines append in vn_ingest_routed
-                // against the drain thread's vn_drain_other boundary cut
-        n = std::snprintf(line, sizeof line, "_e{9,2}:soaktitle|hi|#t:%d",
-                          tid);
-        break;
-    }
+    int n = make_line(line, sizeof line, i, tid);
     int rc = vn_ingest_routed(ctxs->data(), kShards, line, n);
     if (kind == 5)
       sent_evt.fetch_add(1, std::memory_order_relaxed);
@@ -148,6 +138,98 @@ void reader_thread(std::vector<void*>* ctxs, int tid) {
       sent_ok.fetch_add(rc, std::memory_order_relaxed);
     else
       sent_bad.fetch_add(1, std::memory_order_relaxed);
+  }
+}
+
+// Line i of reader tid: the six cases in rotation.
+int make_line(char* line, size_t cap, int i, int tid) {
+  int n;
+  switch (i % 6) {
+    case 0:
+      n = std::snprintf(line, cap, "soak.timer%d:%d|ms|#t:%d", i % 64,
+                        i % 1000, tid);
+      break;
+    case 1:
+      n = std::snprintf(line, cap, "soak.count:%d|c|@0.5", i % 7);
+      break;
+    case 2:
+      n = std::snprintf(line, cap, "soak.gauge%d:%d|g", tid, i);
+      break;
+    case 3:
+      n = std::snprintf(line, cap, "soak.set:user%d|s", i % 997);
+      break;
+    case 4:  // malformed: exercises the error path under contention
+      n = std::snprintf(line, cap, "soak.bad:%d|q", i);
+      break;
+    default:  // event: races the other_lines append in vn_ingest_routed
+              // against the drain thread's vn_drain_other boundary cut
+      n = std::snprintf(line, cap, "_e{9,2}:soaktitle|hi|#t:%d", tid);
+      break;
+  }
+  return n;
+}
+
+// Round two: the same lines, 42 to a buffer (a datagram's worth, and
+// whole turns of the 6-case rotation).
+constexpr int kLinesPerChunk = 42;
+
+void chunk_reader_thread(std::vector<void*>* ctxs, int tid) {
+  char line[128];
+  std::string buf;
+  for (int i = 0; i < kPacketsPerReader; i += kLinesPerChunk) {
+    buf.clear();
+    for (int k = i; k < i + kLinesPerChunk && k < kPacketsPerReader; ++k) {
+      buf.append(line, make_line(line, sizeof line, k, tid));
+      buf.push_back('\n');
+    }
+    int rc = vn_ingest_routed(ctxs->data(), kShards, buf.data(),
+                              static_cast<int>(buf.size()));
+    sent_ok.fetch_add(rc, std::memory_order_relaxed);
+  }
+}
+
+// Round two's flush: close every context's interval under its lock, as
+// Server._flush_begin does, and keep the closed intervals' tallies.
+void flush_thread(std::vector<void*>* ctxs, long long* processed,
+                  long long* errors) {
+  constexpr int kCap = 8192;
+  std::vector<int32_t> rows(kCap), idx(kCap);
+  std::vector<float> vals(kCap), wts(kCap);
+  std::vector<double> dvals(kCap);
+  std::vector<int8_t> rank(kCap);
+  std::vector<char> namebuf(kCap * 64);
+  bool last = false;
+  while (!last) {
+    last = done.load(std::memory_order_acquire);  // one pass after the end
+    for (void* c : *ctxs) {
+      vn_lock(c);
+      *processed += vn_processed(c);
+      *errors += vn_errors(c);
+      float *sv, *sw;
+      int32_t* scnt;
+      int32_t srows, sdepth;
+      void* plane = vn_stage_detach(c, &sv, &sw, &scnt, &srows, &sdepth);
+      while (vn_drain_histo(c, rows.data(), vals.data(), wts.data(), kCap)) {}
+      while (vn_drain_set(c, rows.data(), idx.data(), rank.data(), kCap)) {}
+      while (vn_drain_counter(c, rows.data(), dvals.data(), kCap)) {}
+      while (vn_drain_gauge(c, rows.data(), dvals.data(), kCap)) {}
+      const int32_t *np, *nr, *ns, *fa, *fk, *fs;
+      const char* strs;
+      int n_first = 0;
+      long long strs_len = 0;
+      unsigned gen = 0;
+      vn_drain_new_series(c, &np, &nr, &ns, &fa, &fk, &fs, &n_first, &strs,
+                          &strs_len, &gen);
+      vn_drain_other(c, namebuf.data(), static_cast<int>(namebuf.size()));
+      vn_ctx_reset(c);
+      vn_unlock(c);
+      if (plane != nullptr) {
+        // the uploader reads the handed-off plane outside the lock
+        volatile float probe = sv[0] + sw[0] + static_cast<float>(scnt[0]);
+        (void)probe;
+        vn_stage_free(plane);
+      }
+    }
   }
 }
 
@@ -214,7 +296,7 @@ void drain_thread(std::vector<void*>* all_ctxs) {
 
 // Self-telemetry: reads the counters the scopedstatsd reporter polls.
 void stats_thread(std::vector<void*>* all_ctxs) {
-  long long out[5], wait = 0, hold = 0;
+  long long out[6];
   while (!done.load(std::memory_order_acquire)) {
     for (void* c : *all_ctxs) {
       (void)vn_processed(c);
@@ -223,7 +305,8 @@ void stats_thread(std::vector<void*>* all_ctxs) {
       (void)vn_pending_set(c);
       (void)vn_pending_counter(c);
       (void)vn_pending_gauge(c);
-      (void)vn_lock_stats(c, out, &wait, &hold);
+      (void)vn_lock_stats(c, out, nullptr, nullptr, 0);
+      vn_commit_counters(c, out);
     }
   }
 }
@@ -281,6 +364,42 @@ int main() {
               processed, errors, want_ok, want_bad, sent_evt.load());
   bool ok = processed == want_ok && errors == want_bad &&
             want_bad == want_bad_expect;
+
+  // round two: the chunk commit under a flush that turns epochs over
+  vn_set_lock_stats(0);
+  done.store(false, std::memory_order_release);
+  sent_ok.store(0);
+  long long batches_before = 0;
+  long long counters[6];
+  for (void* c : shard_ctxs) {
+    vn_commit_counters(c, counters);
+    batches_before += counters[3];
+  }
+  long long chunk_processed = -processed, chunk_errors = -errors;
+  threads.clear();
+  threads.emplace_back(flush_thread, &shard_ctxs, &chunk_processed,
+                       &chunk_errors);
+  threads.emplace_back(stats_thread, &all_ctxs);
+  threads.emplace_back(upsert_thread, &shard_ctxs);
+  for (int t = 0; t < kReaders; ++t)
+    threads.emplace_back(chunk_reader_thread, &shard_ctxs, t);
+  for (size_t i = 2; i < threads.size(); ++i) threads[i].join();
+  done.store(true, std::memory_order_release);
+  threads[0].join();
+  threads[1].join();
+  long long batches = -batches_before, batch_lines = 0;
+  for (void* c : shard_ctxs) {
+    vn_commit_counters(c, counters);
+    batches += counters[3];
+    batch_lines += counters[4];
+  }
+  std::printf("tsan_soak: chunk round processed=%lld errors=%lld "
+              "sent_ok=%lld batches=%lld\n",
+              chunk_processed, chunk_errors, sent_ok.load(), batches);
+  ok = ok && chunk_processed == sent_ok.load() &&
+       chunk_errors == want_bad_expect && batch_lines == chunk_processed &&
+       batches < chunk_processed / 4;
+
   for (void* c : all_ctxs) vn_ctx_free(c);
   if (!ok) {
     std::fprintf(stderr, "tsan_soak: conservation FAILED\n");

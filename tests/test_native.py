@@ -845,6 +845,268 @@ def test_intern_table_is_dropped_at_a_reset_past_its_bound():
     assert batch.first_names == ["n11", "n0"]
 
 
+# -- the chunk commit (ingest_buffer) against the per-line path ---------------
+
+
+def corpus_lines(seed: int, n: int = 1500) -> list:
+    """A seeded corpus of all five kinds over a few hundred series: tags
+    in any order, magic scope tags, sample rates, hot timers that spill
+    past the staging depth, gauges written many times (last write wins),
+    events, service checks and malformed lines."""
+    rng = np.random.default_rng(seed)
+    tagsets = [[], [b"a:1"], [b"b:2", b"a:1"], [b"z", b"env:prod", b"a:1"],
+               [b"a:1", b"veneurlocalonly"], [b"veneurglobalonly:x", b"k:v"]]
+    bad = [b"foo", b":1|c", b"a|b:1|ms", b"x:nan|g", b"x:1|q", b"x:1|c|@2",
+           b"x:1|c|#a|#b", b"x:1 |c"]
+    out = []
+    for _ in range(n):
+        k = int(rng.integers(0, 100))
+        sid = int(rng.integers(0, 40))
+        tags = list(tagsets[int(rng.integers(0, len(tagsets)))])
+        rng.shuffle(tags)
+        suffix = (b"|#" + b",".join(tags)) if tags else b""
+        rate = [b"", b"|@0.5", b"|@0.25"][int(rng.integers(0, 3))]
+        if k < 30:
+            # few timer series, many samples each: rows fill and spill
+            ln = b"c.t%d:%.2f|ms%s%s" % (sid % 6, rng.integers(0, 4000) / 4,
+                                         rate, suffix)
+        elif k < 40:
+            ln = b"c.h%d:%d|%s%s" % (sid, rng.integers(0, 99),
+                                     [b"h", b"d"][sid % 2], suffix)
+        elif k < 58:
+            ln = b"c.c%d:%d|c%s%s" % (sid, rng.integers(1, 9), rate, suffix)
+        elif k < 76:
+            ln = b"c.g%d:%.2f|g%s" % (sid % 10, rng.integers(0, 999) / 4,
+                                      suffix)
+        elif k < 88:
+            ln = b"c.s%d:u%d|s%s" % (sid % 8, rng.integers(0, 50), suffix)
+        elif k < 91:
+            ln = b"_e{5,2}:title|hi|#t:%d" % sid
+        elif k < 93:
+            ln = b"_sc|check.%d|0|#t:1" % sid
+        else:
+            ln = bad[int(rng.integers(0, len(bad)))]
+        out.append(ln)
+    return out
+
+
+def feed(ingest, lines: list, how: str, rng) -> None:
+    """The lines through `ingest` (one call takes newline-joined bytes):
+    whole, one line a call, or split at random line boundaries."""
+    if how == "whole":
+        cuts = [0, len(lines)]
+    elif how == "by_line":
+        cuts = list(range(len(lines) + 1))
+    else:
+        inner = np.unique(rng.integers(1, len(lines), 40)).tolist()
+        cuts = [0] + inner + [len(lines)]
+    for a, b in zip(cuts, cuts[1:]):
+        ingest(b"\n".join(lines[a:b]))
+
+
+def interval_record(ni) -> dict:
+    """Everything a flush takes out of a context, then the reset."""
+    st = ni.detach_stage()
+    plane = None
+    if st is not None:
+        vals, wts, counts, unit, free = st
+        plane = (vals.copy(), wts.copy(), counts.copy(), unit)
+        free()
+    batch = ni.drain_new_series()
+    rec = {
+        "processed": ni.processed, "errors": ni.errors,
+        "series": (batch.pools.tolist(), batch.rows.tolist(),
+                   batch.sids.tolist(), batch.first_records(),
+                   batch.generation),
+        "plane": plane,
+        "histo": [a.tolist() for a in ni.drain_histo(1 << 16)],
+        "set": [a.tolist() for a in ni.drain_set(1 << 16)],
+        "counter": [a.tolist() for a in ni.drain_counter(1 << 16)],
+        "gauge": [a.tolist() for a in ni.drain_gauge(1 << 16)],
+        "other": ni.drain_other(), "rows": ni.num_rows(),
+    }
+    ni.reset()
+    return rec
+
+
+def assert_same_record(got: dict, want: dict) -> None:
+    for key in want:
+        if key != "plane":
+            assert got[key] == want[key], key
+    assert (got["plane"] is None) == (want["plane"] is None)
+    if want["plane"] is not None:
+        for g, w in zip(got["plane"][:3], want["plane"][:3]):
+            np.testing.assert_array_equal(g, w)
+        assert got["plane"][3] == want["plane"][3]
+
+
+def run_intervals(n_ctx: int, how: str, seed: int, timed: bool = False):
+    """Three intervals of the corpus (the second leaves series out and
+    reverses the order, the third brings them back) through n_ctx
+    contexts fed `how`; one record per context per interval."""
+    ctxs = [native_mod.NativeIngest() for _ in range(n_ctx)]
+    for ni in ctxs:
+        ni.set_stage_depth(8)
+    router = native_mod.NativeRouter(ctxs)
+    ingest = router.ingest if n_ctx > 1 else ctxs[0].ingest
+    lines = corpus_lines(seed)
+    rng = np.random.default_rng(seed + 1)
+    if timed:
+        router.set_lock_stats(True)
+    try:
+        out = []
+        for part in (lines, lines[900:300:-1], lines[200:]):
+            feed(ingest, part, how, rng)
+            out.append([interval_record(ni) for ni in ctxs])
+    finally:
+        if timed:
+            router.set_lock_stats(False)
+    return out, [ni.commit_counters() for ni in ctxs]
+
+
+@pytest.mark.parametrize("how", ["whole", "splits", "by_line_timed"])
+@pytest.mark.parametrize("seed", [11, 12])
+def test_chunk_commit_matches_line_by_line(how, seed):
+    """However a buffer is cut, the context ends where one line a call
+    leaves it: counts, the drained series queue (pools, rows, sids,
+    first-seen strings), the staged plane and the SoA batches, over
+    three intervals with resets. The instrumented path (one lock a
+    line) is held to the same."""
+    want, _ = run_intervals(1, "by_line", seed)
+    got, _ = run_intervals(1, how.replace("_timed", ""), seed,
+                           timed=how.endswith("_timed"))
+    for g, w in zip(got, want):
+        assert_same_record(g[0], w[0])
+    assert want[0][0]["errors"] > 0 and want[0][0]["other"]
+    assert want[0][0]["histo"][0]  # timers spilled past the depth
+
+
+@pytest.mark.parametrize("how", ["whole", "splits", "by_line"])
+def test_commit_counters_add_up(how):
+    """Every accepted metric line is a hit, a restamp or a first sight;
+    the chunk commit took every one of them."""
+    records, (cc,) = run_intervals(1, how, 21)
+    processed = sum(r[0]["processed"] for r in records)
+    queued = sum(len(r[0]["series"][0]) for r in records)
+    firsts = sum(len(r[0]["series"][3]) for r in records)
+    assert cc["dir_hits"] + cc["dir_restamped"] + cc["dir_first_seen"] \
+        == processed == cc["commit_lines"]
+    assert cc["dir_restamped"] + cc["dir_first_seen"] == queued
+    assert cc["dir_first_seen"] == firsts
+    assert cc["dir_restamped"] > 0 and cc["dir_hits"] > 0
+    if how == "whole":
+        assert cc["commit_batches"] == 3
+    elif how == "by_line":
+        assert cc["commit_batches"] == processed
+
+
+def test_stream_reader_matches_whole_buffer():
+    """The TCP reader cuts the stream wherever recv returns: partial
+    lines carried over, an overlong line dropped (one error) with the
+    line after it intact, blank lines ignored."""
+    lines = corpus_lines(31, 600)
+    lines[100:100] = [b"", b"long." + b"x" * 300 + b":1|c", b"after.long:1|c"]
+    want_ni = native_mod.NativeIngest()
+    want_ni.set_stage_depth(8)
+    want_ni.ingest(b"\n".join(ln for ln in lines if len(ln) <= 256))
+    want = interval_record(want_ni)
+    want["errors"] += 1  # the overlong line
+
+    ni = native_mod.NativeIngest()
+    ni.set_stage_depth(8)
+    router = native_mod.NativeRouter([ni])
+    a, b = socket.socketpair()
+    handle = router.start_stream_reader(a.detach(), 256)
+    data = b"\n".join(lines) + b"\n"
+    rng = np.random.default_rng(5)
+    cuts = [0] + np.unique(rng.integers(1, len(data), 60)).tolist() + [
+        len(data)]
+    for lo, hi in zip(cuts, cuts[1:]):
+        b.sendall(data[lo:hi])
+        time.sleep(0.002)  # let recv see the cut
+    b.close()
+    deadline = time.time() + 10
+    while not router.stream_reader_done(handle) and time.time() < deadline:
+        time.sleep(0.01)
+    n_lines = router.stop_stream_reader(handle)
+    assert n_lines == sum(1 for ln in lines if 0 < len(ln) <= 256)
+    assert_same_record(interval_record(ni), want)
+
+
+# -- epochs: one lifetime directory, rows stamped per interval ----------------
+
+
+def test_a_series_not_written_in_an_interval_has_no_row_in_it():
+    """Rows are the interval's own, from 0 in first-seen order; a
+    series left out of an interval is not in its queue, and comes back
+    under its sid."""
+    ni = native_mod.NativeIngest()
+    ni.ingest(_lines([b"a", b"b", b"c"]))
+    first = ni.drain_new_series()
+    ni.reset()
+    ni.ingest(_lines([b"c", b"a"]))
+    second = ni.drain_new_series()
+    assert second.sids.tolist() == [2, 0] and second.rows.tolist() == [0, 1]
+    assert ni.num_rows() == (2, 0, 0, 0)
+    ni.reset()
+    ni.ingest(_lines([b"b", b"b", b"c"]))
+    third = ni.drain_new_series()
+    assert third.sids.tolist() == [1, 2] and third.rows.tolist() == [0, 1]
+    assert third.first_names == [] and first.first_names == ["a", "b", "c"]
+    assert ni.commit_counters()["dir_hits"] == 1
+
+
+@pytest.mark.parametrize("line_first", [True, False])
+def test_an_upsert_and_a_line_of_one_identity_meet_in_one_row(line_first):
+    ni = native_mod.NativeIngest()
+    for interval in range(2):
+        ni.ingest(b"other:1|ms")
+        if line_first:
+            ni.ingest(b"u.t:1|ms|#b:2,a:1")
+            row = ni.upsert("u.t", "timer", "a:1,b:2", 0)
+        else:
+            row = ni.upsert("u.t", "timer", "a:1,b:2", 0)
+            ni.ingest(b"u.t:1|ms|#b:2,a:1")
+        assert row == 1
+        batch = ni.drain_new_series()
+        assert batch.rows.tolist() == [0, 1]
+        assert batch.sids.tolist() == [0, 1]
+        assert len(batch.first_at) == (2 if interval == 0 else 0)
+        # the scope twin is another series, in the same pool
+        assert ni.upsert("u.t", "timer", "a:1,b:2", 1) == 2
+        ni.reset()
+
+
+def test_upsert_many_shares_the_directory():
+    ni = native_mod.NativeIngest()
+    ni.ingest(b"m.a:1|c\nm.b:1|c")
+    meta = b"m.b\x1f\x1em.new\x1ft:1\x1em.a\x1f"
+    kinds = np.zeros(3, np.uint8)  # counters
+    rows = native_mod.upsert_many(ni, meta, kinds, np.zeros(3, np.uint8),
+                                  np.ones(3, np.uint8))
+    assert rows.tolist() == [1, 2, 0]
+    assert ni.drain_new_series().sids.tolist() == [0, 1, 2]
+
+
+def test_the_table_drop_mixes_no_generations():
+    """After the drop at intern_cap every slot is gone with its stamp:
+    rows and sids restart together, and a series of the old generation
+    is first-seen again."""
+    ni = native_mod.NativeIngest()
+    ni.set_intern_cap(4)
+    ni.ingest(_lines([b"g%d" % i for i in range(6)]))
+    assert ni.drain_new_series().generation == 0
+    ni.reset()  # 6 >= 4: dropped here
+    ni.ingest(_lines([b"g5", b"g0", b"g5"]))
+    batch = ni.drain_new_series()
+    assert batch.generation == 1
+    assert batch.rows.tolist() == [0, 1] and batch.sids.tolist() == [0, 1]
+    assert batch.first_names == ["g5", "g0"]
+    cc = ni.commit_counters()
+    assert (cc["dir_first_seen"], cc["dir_restamped"], cc["dir_hits"]) \
+        == (8, 0, 1)
+
+
 # -- raw-sample staging plane (vn_set_stage_depth / vn_stage_detach) --------
 
 
@@ -988,6 +1250,53 @@ def test_native_staging_reset_drops_plane():
     # staging stays enabled across epochs
     ni.ingest(b"rs.x:5|ms")
     assert ni.stage_total == 1
+
+
+@pytest.mark.parametrize("close", ["detach", "reset"])
+def test_a_new_plane_is_sized_for_the_last_interval_and_zero(close):
+    """The plane after a detach or a reset starts at the row count the
+    interval before it needed (no growth in a steady interval), every
+    slot zero whatever the plane before it held (a freed plane is wiped
+    and used again), and still grows past that."""
+    ni = native_mod.NativeIngest()
+    ni.set_stage_depth(4)
+    names = [b"p%d" % i for i in range(5000)]
+    ni.ingest(_lines(names, b"%s:7|ms|@0.5"))
+    assert len(ni.drain_stage_delta(64)[0]) == 64  # a watermark to wipe
+    grows = ni.commit_counters()["plane_grows"]
+    assert grows == 1  # 4,096 -> 8,192
+    first_at = None
+    if close == "detach":
+        vals, wts, counts, unit, free = ni.detach_stage()
+        assert vals.shape == (8192, 4) and counts.sum() == 5000
+        assert not unit and wts[4999, 0] == 2.0
+        first_at = vals.ctypes.data
+        free()
+    ni.reset()
+    ni.ingest(b"p0:3|ms")
+    assert ni.drain_stage_delta(64)[2].tolist() == [3.0]
+    vals, wts, counts, unit, free = ni.detach_stage()
+    try:
+        assert first_at in (None, vals.ctypes.data)  # the same memory
+        assert vals.shape == (8192, 4) and unit
+        assert counts.tolist() == [1] + [0] * 8191
+        assert vals[0, 0] == 3.0 and wts[0, 0] == 1.0
+        vals[0, 0] = wts[0, 0] = 0.0
+        assert not vals.any() and not wts.any()
+    finally:
+        free()
+    assert ni.commit_counters()["plane_grows"] == grows
+    ni.reset()
+    # the interval before this one needed one row: back to the least
+    ni.ingest(_lines(names + [b"q%d" % i for i in range(4000)], b"%s:1|ms"))
+    vals, _wts, counts, _unit, free = ni.detach_stage()
+    try:
+        assert vals.shape == (16384, 4) and counts.sum() == 9000
+        assert vals[:9000, 0].tolist() == [1.0] * 9000
+        assert not vals[9000:].any() and not vals[:, 1:].any()
+    finally:
+        free()
+    assert ni.commit_counters()["plane_grows"] == grows + 2
 
 
 def test_native_ssf_reader_end_to_end():

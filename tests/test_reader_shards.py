@@ -347,6 +347,27 @@ def test_events_and_errors_stay_on_committing_context():
 # -- lock stats -------------------------------------------------------------
 
 
+@pytest.mark.parametrize("how", ["whole", "splits", "by_line_timed"])
+def test_chunk_commit_over_two_contexts_matches_line_by_line(how):
+    """digest % 2 routing: a buffer's lines are bucketed by target and
+    each context is locked once; what each context holds afterwards
+    (counts, series queue, staged plane, SoA batches, events and errors
+    on the home context) is what one line a call leaves, over three
+    intervals with resets. The instrumented path is held to the same."""
+    from tests.test_native import assert_same_record, run_intervals
+    want, _ = run_intervals(2, "by_line", 41)
+    got, counters = run_intervals(2, how.replace("_timed", ""), 41,
+                                  timed=how.endswith("_timed"))
+    for g, w in zip(got, want):
+        for g_ctx, w_ctx in zip(g, w):
+            assert_same_record(g_ctx, w_ctx)
+    assert all(rec["processed"] > 100 for rec in want[0])  # both took lines
+    assert want[0][0]["other"] and not want[0][1]["other"]  # home is 0
+    assert want[0][0]["errors"] > 0 and want[0][1]["errors"] == 0
+    if how == "whole":
+        assert [c["commit_batches"] for c in counters] == [3, 3]
+
+
 def test_owned_context_lock_uncontended():
     """The shared-nothing proof at unit scale: a single owner committing
     into its private context records zero contended acquisitions."""

@@ -40,14 +40,14 @@ def build() -> None:
 
 
 def run_one(lines: int) -> dict:
-    out = subprocess.run([BENCH, str(lines)], check=True,
+    out = subprocess.run([BENCH, str(lines), "--cell", "0"], check=True,
                          capture_output=True, text=True).stdout
     return json.loads(out.strip().splitlines()[-1])
 
 
 def run_parallel(lines: int, procs: int) -> dict:
     t0 = time.time()
-    children = [subprocess.Popen([BENCH, str(lines)],
+    children = [subprocess.Popen([BENCH, str(lines), "--cell", "0"],
                                  stdout=subprocess.PIPE, text=True)
                 for _ in range(procs)]
     results = []

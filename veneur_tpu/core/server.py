@@ -2088,6 +2088,10 @@ class Server:
             # record takes the difference between two flushes
             cur = self.rec.current()
             cur.attrs["reader_recv_ns"], cur.attrs["reader_busy_ns"] = rd
+            # beside them, what the commit path met (lifetime too)
+            for w in self.workers:
+                for k, v in (w.commit_counters() or {}).items():
+                    cur.attrs[k] = cur.attrs.get(k, 0) + v
         return qs, swapped, span_counts
 
     def _reader_ns(self):

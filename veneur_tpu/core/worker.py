@@ -1226,6 +1226,17 @@ class DeviceWorker:
         except AttributeError:
             return None
 
+    def commit_counters(self) -> Optional[dict]:
+        """NativeIngest.commit_counters summed over this worker's native
+        contexts; None without native ingest or on a stale .so."""
+        if self._native is None:
+            return None
+        try:
+            per_ctx = [ctx.commit_counters() for ctx in self._all_ctxs()]
+        except AttributeError:
+            return None
+        return {k: sum(c[k] for c in per_ctx) for k in per_ctx[0]}
+
     def reader_stats(self, lock_stats: bool = False) -> dict:
         """Per-context ingest attribution for Server.ingress_stats /
         flush telemetry: context order is [home] + reader shards.

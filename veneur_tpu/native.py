@@ -228,6 +228,9 @@ def load_library() -> Optional[ctypes.CDLL]:
             lib.vn_reader_ns.restype = None
             lib.vn_reader_ns.argtypes = [
                 c.c_void_p, c.POINTER(c.c_longlong)]
+            lib.vn_commit_counters.restype = None
+            lib.vn_commit_counters.argtypes = [
+                c.c_void_p, c.POINTER(c.c_longlong)]
         except AttributeError:
             pass
         lib.vn_drain_histo.restype = c.c_int
@@ -522,6 +525,21 @@ class NativeIngest:
         out = (ctypes.c_longlong * 2)()
         self._lib.vn_reader_ns(self._ctx, out)
         return int(out[0]), int(out[1])
+
+    COMMIT_COUNTERS = ("dir_hits", "dir_restamped", "dir_first_seen",
+                       "commit_batches", "commit_lines", "plane_grows")
+
+    def commit_counters(self) -> dict:
+        """What this context's commit path met, lifetime totals: a
+        committed sample or an upsert found its series with a row of
+        this interval (dir_hits), known but not yet written this
+        interval (dir_restamped) or never seen (dir_first_seen);
+        commit_batches lock holds of the chunk commit took commit_lines
+        lines; plane_grows reallocations of the staging plane. Raises
+        AttributeError on a stale .so (callers degrade)."""
+        out = (ctypes.c_longlong * len(self.COMMIT_COUNTERS))()
+        self._lib.vn_commit_counters(self._ctx, out)
+        return dict(zip(self.COMMIT_COUNTERS, map(int, out)))
 
     def set_spill_cap(self, cap: int) -> None:
         """Entries per pending SoA batch before samples shed (tests /
