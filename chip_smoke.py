@@ -677,8 +677,6 @@ def compare_window(batch, traffic: Traffic) -> dict:
 # --------------------------------------------------------------------------
 
 def device_path_faults(server, collector) -> list[str]:
-    from veneur_tpu.core.worker import DeviceWorker
-
     bad = []
     guard = {}
     for i, w in enumerate(server.workers):
@@ -702,7 +700,6 @@ def device_path_faults(server, collector) -> list[str]:
         bad.append(f"{matched} flushes matched a window, {WINDOWS} sent")
     emit("counters", guard=guard,
          host_fallbacks=sum(w.host_fallback_flushes for w in server.workers),
-         pallas_kernel=bool(DeviceWorker._pallas_ok),
          overload_dropped=st["overload_dropped"],
          parse_errors=st["parse_errors"],
          samples_processed=st["samples_processed"],

@@ -71,41 +71,3 @@ def test_server_places_the_cache_through_the_resolver(monkeypatch, tmp_path):
         assert srv.compilation_cache_dir == str(tmp_path)
     finally:
         srv.shutdown()
-
-
-@pytest.mark.parametrize("env,on_tpu,want", [
-    (None, True, False),     # off by default, on a TPU too
-    ("1", True, True),       # the one switch
-    ("1", False, False),     # never off the TPU
-    ("0", True, False),
-])
-def test_pallas_extract_runs_only_where_asked_for(
-        monkeypatch, env, on_tpu, want):
-    from veneur_tpu.ops import pallas_kernels as pk
-
-    if env is None:
-        monkeypatch.delenv("VENEUR_PALLAS", raising=False)
-    else:
-        monkeypatch.setenv("VENEUR_PALLAS", env)
-    monkeypatch.setattr(backend, "is_tpu_backend", lambda: on_tpu)
-    assert pk.supported() is want
-
-
-def test_pallas_failure_raises_instead_of_demoting(monkeypatch):
-    """Where the kernel was asked for, its failure is the flush's
-    failure: no quiet return to the XLA program."""
-    import numpy as np
-
-    from veneur_tpu.core.worker import DeviceWorker, HistoDeviceState
-    from veneur_tpu.ops import pallas_kernels as pk
-
-    def boom(*a, **k):
-        raise RuntimeError("mosaic says no")
-
-    monkeypatch.setattr(pk, "flush_extract", boom)
-    monkeypatch.setattr(DeviceWorker, "_pallas_ok", True)
-    w = DeviceWorker(initial_histo_rows=8)
-    fields = HistoDeviceState.create(8, w.capacity).fields()
-    with pytest.raises(RuntimeError, match="mosaic says no"):
-        w._extract(fields, np.asarray([0.5], np.float32))
-    assert DeviceWorker._pallas_ok is True

@@ -399,3 +399,90 @@ def test_grow_oom_valve_degrades_not_faults():
     assert w.guard.counters().get("device.valve.grow_oom", 0) >= 1
     _assert_snapshots_identical(clean, got, "grow-valve")
     assert got.degraded
+
+
+# -- one served flush against the failover engine, class by class -----------
+
+T0 = 1_700_000_000
+CLASSES = {"counter": "sf.count", "gauge": "sf.gauge", "timer": "sf.timer",
+           "histogram": "sf.histo", "set": "sf.set"}
+
+
+def _interval_lines(i: int) -> list[bytes]:
+    """One interval of traffic in every metric class, tagged and bare,
+    varied per interval so the streams are distinguishable."""
+    lines = [
+        b"sf.count:%d|c" % (i + 1),
+        b"sf.count:%d|c|#env:prod,team:obs" % (2 * i + 3),
+        b"sf.gauge:%.2f|g" % (1.5 * (i + 1)),
+        b"sf.gauge:%d|g|#env:prod" % (10 * i),
+    ]
+    for v in range(1, 21):
+        lines.append(b"sf.timer:%d|ms" % (v * (i + 1)))
+        lines.append(b"sf.histo:%d|h|#env:prod" % (v + i))
+    for j in range(12 + i):
+        lines.append(b"sf.set:user%d|s" % j)
+        lines.append(b"sf.set:user%d|s|#env:prod" % (j * 7))
+    return lines
+
+
+def _served_streams(faulted: bool) -> list[list]:
+    """Three intervals through a whole Server (two workers, the flush
+    the ticker would run, a channel sink): on the device, or with every
+    flush-path dispatch faulting, so that the second and third flushes
+    run start to finish on ops/host_engine.py."""
+    from veneur_tpu.core.config import Config
+    from veneur_tpu.core.server import Server
+    from veneur_tpu.sinks.channel import ChannelMetricSink
+
+    sink = ChannelMetricSink()
+    srv = Server(Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                        num_workers=2, num_readers=1, interval="10s",
+                        percentiles=[0.5, 0.99], device_fault_streak=1,
+                        tpu_native_ingest=False),
+                 metric_sinks=[sink])
+    plan = fl.DeviceFaultPlan(
+        seed=5, op_windows={op: ALWAYS for op in FLUSH_OPS} if faulted
+        else {})
+    out = []
+    try:
+        with fl.DeviceFaultInjector(plan) as inj:
+            for i in range(3):
+                for line in _interval_lines(i):
+                    srv.handle_metric_packet(line)
+                srv.flush(now=T0 + 10 * i)
+                out.append(sink.queue.get(timeout=30))
+        if faulted:
+            assert sum(inj.injected[k] for k in dg.FAULT_KINDS) > 0
+            assert all(w.guard.quarantined for w in srv.workers)
+            assert sum(w.host_fallback_flushes for w in srv.workers) >= 3
+        else:
+            assert not any(w.host_fallback_flushes for w in srv.workers)
+    finally:
+        srv.shutdown()
+    return out
+
+
+@pytest.fixture(scope="module")
+def served_and_failover():
+    return _served_streams(False), _served_streams(True)
+
+
+@pytest.mark.parametrize("cls", sorted(CLASSES))
+def test_a_served_flush_is_the_failover_engines_answer(
+        served_and_failover, cls):
+    """test_fault_failover_bitwise holds a worker's snapshot to the host
+    engine for timers, counters, gauges and sets; this holds what a
+    sink is handed, through generation and across two workers, and adds
+    the |h class and bare-beside-tagged series."""
+    served, failover = served_and_failover
+
+    def of_class(metrics):
+        return sorted(
+            (m.name, m.timestamp, repr(m.value), tuple(m.tags), m.type,
+             m.hostname)
+            for m in metrics if m.name.startswith(CLASSES[cls]))
+
+    for i, (a, b) in enumerate(zip(served, failover)):
+        assert of_class(a), (cls, i)
+        assert of_class(a) == of_class(b), (cls, i)

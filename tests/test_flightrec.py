@@ -241,26 +241,6 @@ def test_a_flush_that_raises_closes_its_spans(monkeypatch):
         srv.shutdown()
 
 
-def test_a_pipelined_flush_publishes_its_phases_from_the_emit_stage():
-    srv, sink, port = _served(tpu_native_ingest=False, flush_pipeline=True,
-                              interval="1s")
-    try:
-        srv.process_metric_packet(b"fr.p:1|ms")
-        assert _wait_for(lambda: "spans" in srv.last_flush_phases
-                         and srv.last_flush_phases.get("sink_flush_s"), 15)
-        phases = srv.last_flush_phases
-        by = _by_name(phases["spans"])
-        # no root: each phase is the root of its own stage thread
-        assert "flush" not in by
-        for key, name in PHASE_SPANS.items():
-            (sp,) = by[name]
-            assert sp[4] is None or name == "emit.sinks"
-            assert phases[key] == pytest.approx(sp[3] - sp[2], abs=1e-9)
-        assert "late_s" in by["flush.begin"][0][6]
-    finally:
-        srv.shutdown()
-
-
 # -- names on the device ---------------------------------------------------
 
 def _fold_args(rows=256, depth=8):

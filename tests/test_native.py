@@ -1,6 +1,7 @@
 """Native C++ ingest pipeline tests: parity with the Python parser and
 native-mode server end-to-end."""
 
+import os
 import socket
 import time
 
@@ -20,6 +21,9 @@ from veneur_tpu.core.server import Server
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.protocol.dogstatsd import ParseError, parse_metric
 from veneur_tpu.utils.hashing import hll_hash
+
+# the served native/ directory, whatever a test points the loader at
+NATIVE_DIR = native_mod._NATIVE_DIR
 
 
 def test_lock_stats_instrumentation():
@@ -60,6 +64,143 @@ def test_library_matches_source():
     for fn in ("dogstatsd.cpp", "emit.cpp", "forward_codec.cpp"):
         h.update(open(os.path.join(ndir, fn), "rb").read())
     assert native_mod.source_hash() == h.hexdigest()[:16]
+
+
+# -- the contract with the library: this tree's build, or none --------------
+
+_REFUSED = "is not this tree's build"
+
+
+def _c_library(path, stamp=None, extra=""):
+    """A library that is not the served one: `stamp` gives it a
+    vn_source_hash, `extra` is more C."""
+    import subprocess
+
+    src = path.with_suffix(".cpp")
+    body = extra
+    if stamp is not None:
+        body += (f'extern "C" const char* vn_source_hash() '
+                 f'{{ return "{stamp}"; }}\n')
+    src.write_text(body or "int vn_unused;\n")
+    subprocess.run(["g++", "-shared", "-fPIC", "-o", str(path), str(src)],
+                   check=True, capture_output=True)
+
+
+def _foreign_native_dir(tmp_path, case):
+    """A native/ directory as a checkout could come to hold it, with the
+    three sources and (but for one case) a library that is not their
+    build. `make` there does nothing: the library that stands is what
+    the loader meets."""
+    import shutil
+
+    ndir = tmp_path / "native"
+    ndir.mkdir()
+    for name in native_mod._LIB_SOURCES:
+        shutil.copy(os.path.join(NATIVE_DIR, name), ndir / name)
+    lib = ndir / "libveneur_native.so"
+    if case == "build failed and no library":
+        return ndir  # no Makefile either: make fails
+    (ndir / "Makefile").write_text("all:\n")
+    if case == "stamp differs":
+        # the served library beside sources edited since it was built
+        shutil.copy(native_mod._LIB_PATH, lib)
+        with open(ndir / "emit.cpp", "a") as f:
+            f.write("// edited after the build\n")
+    elif case == "no stamp":
+        _c_library(lib, extra='extern "C" int vn_ingest() { return 0; }\n')
+    elif case == "a symbol is missing":
+        _c_library(lib, stamp=native_mod._sources_stamp(
+            native_mod._LIB_SOURCES))
+    return ndir
+
+
+def _python_path_output(w):
+    from veneur_tpu.core.flusher import (
+        device_quantiles, generate_inter_metrics)
+    from veneur_tpu.core.metrics import HistogramAggregates
+
+    aggs = HistogramAggregates.from_names(["min", "max", "count"])
+    for i in range(40):
+        for line in (b"nl.t:%d|ms|#a:b" % i, b"nl.c:2|c", b"nl.g:%d|g" % i,
+                     b"nl.s:u%d|s" % (i % 7)):
+            w.process_metric(parse_metric(line))
+    snap = w.flush(device_quantiles([0.5, 0.99], aggs))
+    return sorted(
+        (m.name, m.type, tuple(m.tags), repr(m.value))
+        for m in generate_inter_metrics(snap, True, [0.5, 0.99], aggs,
+                                        now=1000))
+
+
+@pytest.mark.parametrize("case", [
+    "stamp differs", "no stamp", "a symbol is missing",
+    "build failed and no library"])
+def test_a_library_that_is_not_this_trees_is_refused_whole(
+        case, tmp_path, monkeypatch, caplog):
+    from veneur_tpu.core.worker import DeviceWorker
+
+    never_had_one = _python_path_output(DeviceWorker(stage_depth=16))
+    served_stamp = native_mod.source_hash()
+    ndir = _foreign_native_dir(tmp_path, case)
+    monkeypatch.setattr(native_mod, "_NATIVE_DIR", str(ndir))
+    monkeypatch.setattr(native_mod, "_LIB_PATH",
+                        str(ndir / "libveneur_native.so"))
+    monkeypatch.setattr(native_mod, "_loaded", {})
+    with caplog.at_level("WARNING", logger="veneur_tpu.native"):
+        assert native_mod.load_library() is None
+        # decided once: asking again neither builds nor warns again
+        assert native_mod.load_library() is None
+        assert not native_mod.available()
+        assert not native_mod.emit_available()
+        assert native_mod.source_hash() == ""
+    (warning,) = [r.getMessage() for r in caplog.records]
+    if case == "build failed and no library":
+        assert "native build failed" in warning
+    else:
+        assert _REFUSED in warning
+        want = native_mod._sources_stamp(native_mod._LIB_SOURCES)
+        assert f"the sources' is {want}" in warning
+        found = {"stamp differs": served_stamp, "no stamp": "none",
+                 "a symbol is missing": want}[case]
+        assert (found != want) == (case != "a symbol is missing")
+        assert f"stamp {found}," in warning
+    w = DeviceWorker(stage_depth=16)
+    assert w.attach_native() is False
+    assert w._native is None
+    assert _python_path_output(w) == never_had_one
+
+
+def _exported(source):
+    """The names a source defines inside its extern "C" blocks."""
+    import re
+
+    names, inside = [], False
+    with open(os.path.join(NATIVE_DIR, source)) as f:
+        for line in f:
+            if line.startswith('extern "C" {'):
+                inside = True
+            elif line.startswith('}  // extern "C"'):
+                inside = False
+            elif inside:
+                m = re.match(r"[A-Za-z_][\w \*]*?\b(vn_\w+)\s*\(", line)
+                if m:
+                    names.append(m.group(1))
+    return set(names)
+
+
+# exported for a tool alone, and so bound by no loader: none today
+TOOL_ONLY: set = set()
+
+
+@pytest.mark.parametrize("source", [
+    "dogstatsd.cpp", "emit.cpp", "forward_codec.cpp", "loadgen.cpp"])
+def test_every_exported_name_is_bound_at_load(source):
+    """What the loader does not bind it cannot miss: a name left out of
+    _bind_* would come back as a call-time AttributeError."""
+    lib = (native_mod.load_loadgen_library() if source == "loadgen.cpp"
+           else native_mod.load_library())
+    names = _exported(source)
+    assert len(names) >= 6, names
+    assert names - set(vars(lib)) - TOOL_ONLY == set()
 
 
 def test_parser_parity_property():

@@ -66,8 +66,8 @@ def _mk_worker(sharded: bool, *, micro: bool = False,
         w.tenancy = TenantLedger(default_budget=budget, budgets={})
     if not w.attach_native():
         pytest.skip("native ingest library unavailable")
-    if sharded and not w.attach_reader_shards(R):
-        pytest.skip("reader-shard API unavailable (stale .so)")
+    if sharded:
+        assert w.attach_reader_shards(R)
     return w
 
 
@@ -373,8 +373,6 @@ def test_owned_context_lock_uncontended():
     into its private context records zero contended acquisitions."""
     w = _mk_worker(True)
     lib = w._native._lib
-    if not hasattr(lib, "vn_set_lock_stats"):
-        pytest.skip("lock-stats API unavailable (stale .so)")
     lib.vn_set_lock_stats(1)
     try:
         for ctx in w._reader_ctxs:

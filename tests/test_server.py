@@ -284,6 +284,63 @@ def test_load_config_strict_rejects_unknown(tmp_path):
         load_config(str(p), strict=True)
 
 
+@pytest.mark.parametrize("strict", [False, True], ids=["loose", "strict"])
+@pytest.mark.parametrize("key", ["flush_pipeline", "flush_pipeline_backlog"])
+def test_the_removed_flush_executor_keys_are_unknown_keys(
+        tmp_path, caplog, key, strict):
+    """The stage-parallel flush went with PR 31: a config that still
+    names it gets what any unknown key gets, and no alias."""
+    p = tmp_path / "cfg.yaml"
+    p.write_text(f"interval: 5s\n{key}: 1\n")
+    if strict:
+        with pytest.raises(ValueError, match=key):
+            load_config(str(p), strict=True)
+        return
+    with caplog.at_level("WARNING"):
+        cfg = load_config(str(p))
+    assert not hasattr(cfg, key)
+    assert any("unknown config keys" in r.getMessage()
+               and key in r.getMessage() for r in caplog.records)
+
+
+def test_the_ticker_has_one_branch_and_outlives_a_flush_that_raises(
+        monkeypatch, caplog):
+    """_flush_loop: flush, then adapt the spill caps to its duration. A
+    flush that raises is logged, owes the caps nothing, leaves no
+    _tick_due behind, and the next tick still fires."""
+    # not started: this test's thread is the only ticker
+    srv = Server(Config(statsd_listen_addresses=["udp://127.0.0.1:0"],
+                        interval="50ms", tpu_native_ingest=False),
+                 metric_sinks=[ChannelMetricSink()])
+    calls, adapted, due_seen = [], [], []
+
+    def flush():
+        due_seen.append(srv._tick_due)
+        calls.append(len(calls))
+        if len(calls) == 1:
+            raise RuntimeError("first flush fails")
+        if len(calls) == 3:
+            srv._shutdown.set()
+
+    monkeypatch.setattr(srv, "flush", flush)
+    monkeypatch.setattr(srv, "_adapt_spill_caps", adapted.append)
+    try:
+        with caplog.at_level("ERROR"):
+            t = threading.Thread(target=srv._flush_loop, daemon=True)
+            t.start()
+            t.join(timeout=10)
+        assert not t.is_alive()
+        assert len(calls) == 3
+        assert len(adapted) == 2 and all(0 <= d < 1 for d in adapted)
+        assert all(d is not None for d in due_seen)
+        assert srv._tick_due is None
+        (err,) = [r for r in caplog.records if r.levelname == "ERROR"]
+        assert err.getMessage() == "flush failed"
+        assert "first flush fails" in str(err.exc_info[1])
+    finally:
+        srv.shutdown()
+
+
 def test_calculate_tick_delay():
     assert calculate_tick_delay(10.0, 103.0) == pytest.approx(7.0)
     assert calculate_tick_delay(10.0, 100.0) == pytest.approx(10.0)

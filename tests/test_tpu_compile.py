@@ -141,14 +141,3 @@ def test_dense_hll_insert_and_estimate(one_chip):
     _fits("hll.insert_batch", ins)
     est = hll.estimate.lower(regs, precision=14).compile()
     _fits("hll.estimate", est)
-
-
-def test_pallas_flush_extract(one_chip):
-    from veneur_tpu.ops import pallas_kernels as pk
-
-    compiled = pk.flush_extract.lower(
-        _shape(one_chip, (S, C)), _shape(one_chip, (S, C)),
-        _shape(one_chip, (S,)), _shape(one_chip, (S,)),
-        _shape(one_chip, (P,)), block_rows=256).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits("pallas flush_extract", compiled)

@@ -843,3 +843,62 @@ def test_the_intern_bound_clears_both_sides():
     # and again every interval (each learns 160 anew)
     assert generations == [(0, 160), (1, 160), (2, 160), (3, 160)]
     assert w.interned_series == 160
+
+
+# -- a fault inside the native object is the caller's to see ----------------
+
+def _boom(*a, **k):
+    raise AttributeError("'NativeIngest' object has no attribute 'misspelt'")
+
+
+def _native_worker(**kw):
+    w = DeviceWorker(compression=100, stage_depth=16, batch_size=8,
+                     micro_fold_rows=1, micro_fold_max_age_s=1e9, **kw)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    w.ingest_datagram(b"ae.t:1|ms\nae.t:2|ms\nae.c:1|c")
+    return w
+
+
+@pytest.mark.parametrize("site,attr,micro", [
+    ("micro_fold_pending", "stage_pending", True),
+    ("micro_fold_once", "stage_pending", True),
+    ("micro_fold_once", "drain_stage_delta", True),
+    ("swap_residual_drain", "drain_stage_delta", True),
+    ("swap_detach_stage", "detach_stage", False),
+    ("swap_ssf_fallback", "drain_ssf_fallback", False),
+    ("attach_native_stage_depth", "set_stage_depth", False),
+    ("attach_native_spill_cap", "set_spill_cap", False),
+    ("attach_reader_shards", "set_spill_cap", False),
+    ("commit_counters", "commit_counters", False),
+    ("reader_ns", "reader_ns", False),
+])
+def test_an_attribute_error_inside_native_ingest_reaches_the_caller(
+        monkeypatch, site, attr, micro):
+    """Each of these sites used to catch AttributeError as "the library
+    predates this call", and went on without micro-folds, the staging
+    plane or the cap, in silence: a misspelt name inside NativeIngest
+    read as an old library. The loader now refuses such a library
+    whole, so here the error can only be a fault, and it raises."""
+    from veneur_tpu.native import NativeIngest
+
+    w = _native_worker(micro_fold=micro)
+    patched = (property(_boom) if attr == "stage_pending" else _boom)
+    monkeypatch.setattr(NativeIngest, attr, patched)
+    qs = device_quantiles(PCTS, AGGS)
+    call = {
+        "micro_fold_pending": w.micro_fold_pending,
+        "micro_fold_once": w.micro_fold_once,
+        "swap_residual_drain": lambda: w.swap(qs),
+        "swap_detach_stage": lambda: w.swap(qs),
+        "swap_ssf_fallback": lambda: w.swap(qs),
+        "attach_native_stage_depth":
+            DeviceWorker(stage_depth=16).attach_native,
+        "attach_native_spill_cap":
+            DeviceWorker(stage_depth=0, spill_cap=1 << 16).attach_native,
+        "attach_reader_shards": lambda: w.attach_reader_shards(2),
+        "commit_counters": w.commit_counters,
+        "reader_ns": w.reader_ns,
+    }[site]
+    with pytest.raises(AttributeError, match="misspelt"):
+        call()

@@ -64,24 +64,19 @@ def main() -> None:
     ap.add_argument("--replay", metavar="PATH",
                     help="drive a previously saved ring blob bit-exactly"
                          " instead of synthesizing")
-    ap.add_argument("--flush-pipeline", action="store_true",
-                    help="run the server with the stage-parallel flush "
-                         "executor (core/pipeline.py) instead of the "
-                         "serial flush")
     ap.add_argument("--ab", action="store_true",
                     help="search mode only: run the full rate search "
                          "twice — one per side of --ab-axis — on the "
                          "same ring, and write one artifact with both "
                          "modes plus the speedup")
-    ap.add_argument("--ab-axis", default="pipeline",
-                    choices=["pipeline", "emit-native", "micro-fold",
+    ap.add_argument("--ab-axis",
+                    choices=["emit-native", "micro-fold",
                              "reader-shards", "archive", "device-guard"],
-                    help="what --ab compares: serial vs pipelined "
-                         "flush (default), Python vs native emit "
-                         "serializers (forces --sink serialize; both "
-                         "sides use --flush-pipeline as given), "
+                    help="what --ab compares (required with --ab): "
+                         "Python vs native emit "
+                         "serializers (forces --sink serialize), "
                          "once-per-interval vs always-hot micro-fold "
-                         "staging (both sides use --flush-pipeline and "
+                         "staging (both sides use "
                          "--sink as given), legacy digest-routed vs "
                          "shared-nothing reader-sharded ingest (both "
                          "sides run --readers reader threads; only the "
@@ -130,6 +125,8 @@ def main() -> None:
     ap.add_argument("--out", default="SUSTAINED_PIPELINE.json",
                     help="artifact name (repo root; search mode only)")
     args = ap.parse_args()
+    if args.ab and args.ab_axis is None:
+        ap.error("--ab needs --ab-axis")
     if args.workload == "ssf" and args.out == "SUSTAINED_PIPELINE.json":
         args.out = "SPAN_SUSTAINED.json"
     if (args.ab and args.ab_axis == "archive"
@@ -170,7 +167,6 @@ def main() -> None:
         # a serious rcvbuf: kernel drops are measured as loss, not
         # hidden by a tiny default buffer
         read_buffer_size_bytes=8 * 1048576,
-        flush_pipeline=args.flush_pipeline,
         flush_emit_native=(args.emit_native == "on"),
         **({"loadgen_ring_lines": args.ring_lines}
            if args.ring_lines else {}),
@@ -226,7 +222,7 @@ def main() -> None:
             # staging; the interesting numbers are the steady-state
             # tick_block/ingest_stall decomposition (the flush's
             # deadline-time device work is what micro-folds amortize
-            # away), so both sides run whatever sink/pipeline flags the
+            # away), so both sides run whatever sink flags the
             # caller chose and differ ONLY in cfg.micro_fold
             sink_mode = args.sink
             mode_list = [("micro_off", {"micro_fold": False}),
@@ -254,22 +250,18 @@ def main() -> None:
             archive_dir = _tempfile.mkdtemp(prefix="bench-archive-")
             mode_list = [("archive_off", {}),
                          ("archive_on", {"archive_dir": archive_dir})]
-        elif args.ab_axis == "device-guard":
+        else:
             # guarded device execution off vs on (ops/device_guard.py).
             # Like the archive axis this measures a COST bar, not a
             # win: the guard adds one dispatch frame and a breaker-
             # state read per device call, so the honest expectation is
             # speedup ~= 1.0 — the artifact pins the healthy-path
             # overhead under 1% at sustained load. Both sides run
-            # whatever sink/pipeline flags the caller chose and differ
+            # whatever sink flags the caller chose and differ
             # ONLY in cfg.device_guard.
             sink_mode = args.sink
             mode_list = [("guard_off", {"device_guard": False}),
                          ("guard_on", {"device_guard": True})]
-        else:
-            sink_mode = args.sink
-            mode_list = [("serial", {"flush_pipeline": False}),
-                         ("pipelined", {"flush_pipeline": True})]
 
         ab_ring = ring if ring is not None else spec.build_ring()
         t0 = time.time()
@@ -425,7 +417,7 @@ def main() -> None:
             summary["archive_off_lines_per_s"] = base_rate
             summary["speedup_vs_archive_off"] = speedup
             summary["archive_conserved"] = out["archive_ab"]["conserved"]
-        elif args.ab_axis == "device-guard":
+        else:
             out["speedup_vs_guard_off"] = speedup
             # rate-search granularity bounds what a wall-clock A/B can
             # resolve, so the sub-1% claim is "the guarded side sustains
@@ -442,10 +434,6 @@ def main() -> None:
             summary["speedup_vs_guard_off"] = speedup
             summary["guard_overhead_within_1pct"] = (
                 out["device_guard_ab"]["within_1pct"])
-        else:
-            out["speedup_vs_serial"] = speedup
-            summary["serial_lines_per_s"] = base_rate
-            summary["speedup_vs_serial"] = speedup
         out["wall_s"] = round(time.time() - t0, 1)
         write_artifact(args.out, out)
         print(json.dumps(summary))

@@ -55,15 +55,6 @@ JAX_PLATFORMS=cpu \
 JAX_PLATFORMS=cpu VENEUR_EMIT_NATIVE=0 \
   python -m pytest tests/test_emit_parity.py -q -m 'not slow'
 
-# Pipelined-flush equality lane: the stage-parallel executor
-# (core/pipeline.py) must emit bit-identical InterMetric streams to the
-# serial flush, shed (not queue) under a stalled sink, and drain the
-# final interval on shutdown. Runs as its own lane so a pipeline
-# divergence is named here, not buried in the full suite.
-echo "== pipelined-flush equality lane (serial == pipelined) =="
-JAX_PLATFORMS=cpu \
-  python -m pytest tests/test_pipeline.py -q -m 'not slow'
-
 # Micro-fold parity lane: the always-hot flush path (ops/microfold.py)
 # must be BIT-identical to the once-per-interval batch fold for every
 # metric class, cost identical H2D bytes, and hold the epoch-swap fence.
@@ -127,7 +118,7 @@ assert a["sustained_ab"]["ratio"] > 0.5, \
 print("query bench artifact OK")
 PYGATE
 
-# Delivery chaos lane: a pipelined server flushing into HTTP sinks whose
+# Delivery chaos lane: a server flushing into HTTP sinks whose
 # openers inject seeded faults (utils/faults.py) — refusals, 5xx, slow
 # responses, mid-body resets, payload rejections, and a deterministic
 # outage window. Gates the delivery layer's three contracts
@@ -445,25 +436,21 @@ PYGATE
 
 # Sustained-rate floor: the loadgen harness drives a live server's UDP
 # socket at a fixed offered rate for 5 flush intervals and fails on
-# loss or broken flush cadence. 50k lines/s with the pipelined flush
-# is deliberately well under half the 1-core dev rig's measured A/B
-# rates (serial 110k / pipelined 122.8k confirmed,
-# SUSTAINED_PIPELINE.json) so host noise doesn't flake the lane, while
-# a real pipeline regression (parse slowdown, flush stall, shed storm)
-# still trips it; min-cadence 0.7 tolerates one straggler flush in 5
-# (XLA-CPU occasionally recompiles mid-run on this rig), two fail.
-# --flush-pipeline exercises the stage-parallel executor end to end in
-# CI at a rate the old serial floor (30k) never could — the lane now
-# gates BOTH the packet path and the pipelined tick staying cheap.
+# loss or broken flush cadence. 50k lines/s is deliberately well under
+# half the 1-core dev rig's measured rate (110k confirmed,
+# SUSTAINED_PIPELINE.json, platform cpu) so host noise doesn't flake
+# the lane, while a real regression (parse slowdown, flush stall, shed
+# storm) still trips it; min-cadence 0.7 tolerates one straggler flush
+# in 5 (XLA-CPU occasionally recompiles mid-run on this rig), two fail.
 # --keys 2000 (~10k series) keeps per-flush XLA work well inside the
 # 2s interval on one core — the default 10k-key workload's ~50k series
 # cost 2-4s per flush here, which gates the rig's flush latency, not
 # the packet path this lane is for. Bounded: warmup + 5×2s intervals
 # under a hard cap.
-echo "== sustained-rate smoke (loadgen floor gate, pipelined) =="
+echo "== sustained-rate smoke (loadgen floor gate) =="
 timeout -k 10 300 env JAX_PLATFORMS=cpu \
   python tools/bench_sustained.py --smoke --rate 50000 --intervals 5 \
-    --interval 2s --min-cadence 0.7 --keys 2000 --flush-pipeline
+    --interval 2s --min-cadence 0.7 --keys 2000
 
 # Span-parity lane: the columnar SSF pipeline (veneur_tpu/spans/) must
 # derive metrics BIT-identical to the per-span Python reference for
@@ -504,7 +491,7 @@ JAX_PLATFORMS=cpu VENEUR_READER_SHARDS=0 \
 # span conservation (received == derived + dropped + pending) at a
 # rate well under the rig's measured headroom. The cadence floor is
 # deliberately loose here: span-derived series perturb XLA shapes for
-# the first few intervals on the 1-core rig, so tick-deferral noise is
+# the first few intervals on the 1-core rig, so late-tick noise is
 # expected — the statsd lane above owns the strict cadence gate.
 # Artifact stays in /tmp — the committed SPAN_SUSTAINED.json is the
 # full search run.
@@ -512,7 +499,7 @@ echo "== SSF sustained-rate smoke (span workload + conservation gate) =="
 timeout -k 10 300 env JAX_PLATFORMS=cpu \
   python tools/bench_sustained.py --smoke --workload ssf --rate 20000 \
     --intervals 4 --interval 2s --min-cadence 0.25 --keys 1000 \
-    --flush-pipeline --out "${TMPDIR:-/tmp}/SPAN_SUSTAINED_SMOKE.json"
+    --out "${TMPDIR:-/tmp}/SPAN_SUSTAINED_SMOKE.json"
 
 # Archive round-trip lane: the flush archive (veneur_tpu/archive/) must
 # capture a real factory-wired server's flush bit-identically (raw

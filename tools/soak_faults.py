@@ -1,6 +1,6 @@
 """Seeded fault-injection chaos soak for the sink delivery layer.
 
-A pipelined native-reader server under steady within-capacity load,
+A native-reader server under steady within-capacity load,
 flushing into three real HTTP sinks whose openers are wrapped in
 seeded FaultyOpeners (utils/faults.py): datadog rides a deterministic
 outage window (down_ranges) that forces a full breaker
@@ -131,8 +131,7 @@ def main() -> int:
                  aggregates=["min", "max", "count"],
                  statsd_listen_addresses=[f"udp://127.0.0.1:{PORT}"],
                  tpu_native_ingest=True, tpu_native_readers=True,
-                 num_workers=2, num_readers=2,
-                 flush_pipeline=True)
+                 num_workers=2, num_readers=2)
     sinks, openers = build_faulty_sinks(args.seed)
     srv = Server(cfg, metric_sinks=sinks)
     srv.start()

@@ -92,20 +92,6 @@ class Config:
     # default, right for TPU) keeps the single-program extraction and
     # the reference's unconditional watchdog behavior.
     flush_chunk_target_ms: int = 0
-    # stage-parallel flush executor (core/pipeline.py): the flush tick
-    # stays a cheap snapshot swap, but device extract for interval N,
-    # InterMetric generation for N-1, and sink emission for N-2 run
-    # concurrently on dedicated single-worker stages, so flush cadence
-    # decouples from flush latency (JAX async dispatch covers the
-    # device work while the host stages drain earlier intervals).
-    # Output is bit-identical to the serial flush per interval
-    # (tests/test_pipeline.py). Off by default: serial flush remains
-    # the reference-shaped path.
-    flush_pipeline: bool = False
-    # intervals a stage queue may hold beyond the in-progress one
-    # before the tick sheds instead of enqueueing (health/policy.py
-    # MAX_STAGE_BACKLOG documents why the default is one).
-    flush_pipeline_backlog: int = 1
     # native emit tier (native/emit.cpp): sinks that can hand their wire
     # serialization (JSON bodies, exposition text, statsd lines, deflate)
     # to the C++ serializers do so with the GIL released; per-sink
@@ -1097,9 +1083,6 @@ def validate_config(cfg: Config) -> None:
             and cfg.flush_chunk_target_ms >= cfg.interval_seconds() * 1000):
         raise ValueError("flush_chunk_target_ms must be below the flush"
                          " interval (a chunk IS a sub-interval unit)")
-    if cfg.flush_pipeline_backlog < 1:
-        raise ValueError("flush_pipeline_backlog must be >= 1 (a stage"
-                         " needs at least the in-progress interval)")
     if cfg.flush_timeout_s <= 0:
         raise ValueError("flush_timeout_s must be positive (it is the"
                          " per-attempt network timeout)")

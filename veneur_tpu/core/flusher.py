@@ -110,9 +110,8 @@ def generate_inter_metrics(
         for row, meta in enumerate(hrows):
             if governor is not None and row and row % 200_000 == 0:
                 # the entry beat above covers small flushes; at 1M rows
-                # this loop is seconds of host work, and under the stage
-                # pipeline it overlaps the NEXT interval's extract — the
-                # watchdog must keep seeing progress, not entry-silence
+                # this loop is seconds of host work — the watchdog
+                # must keep seeing progress, not entry-silence
                 governor.beat()
             if hrej and not meta.admitted:
                 # tenant-budget-rejected series (native path marks the

@@ -21,7 +21,8 @@ import socket
 import ssl
 import threading
 import time
-from typing import Callable, Optional
+from dataclasses import dataclass, field
+from typing import Any, Callable, Optional
 
 from veneur_tpu import __version__
 from veneur_tpu.core import crash, flightrec
@@ -57,6 +58,26 @@ _SSF_ERR_PROCESSING = ["ssf_format:framed", "packet_type:unknown",
                        "reason:processing"]
 _SSF_ERR_FRAMING = ["ssf_format:framed", "packet_type:unknown",
                     "reason:framing"]
+
+
+@dataclass
+class FlushJob:
+    """One flush's state, handed from phase to phase. `ts` is frozen
+    when the flush begins, so generation stamps every InterMetric of
+    the interval with one clock."""
+
+    # Server.flush_count of this flush: what its spans are filed under
+    ordinal: int = 0
+    ts: int = 0
+    flush_start: float = 0.0
+    qs: Any = None
+    swapped: list = field(default_factory=list)
+    span_counts: dict = field(default_factory=dict)
+    phases: dict = field(default_factory=dict)
+    snaps: list = field(default_factory=list)
+    batch: Any = None
+    final: list = field(default_factory=list)
+    n_flushed: int = 0
 
 
 class EventWorker:
@@ -321,8 +342,7 @@ class Server:
         # real join outcome instead of the stale initial True
         self._shutdown_complete = threading.Event()
         self.last_flush_unix = time.time()
-        # when the most recent flush finished sink emission (== the tick
-        # time on the serial path; trails it under the stage pipeline)
+        # when the most recent flush finished sink emission
         self.last_emit_unix = 0.0
         # the last COMPLETED flush: its phase seconds (sums of the span
         # record's spans) and, under "spans", the spans themselves
@@ -333,17 +353,6 @@ class Server:
         self.last_flush_transfers: dict[str, int] = {}
         self.last_flush_chunks: dict = {}
         self.flush_count = 0
-        # stage-parallel flush executor (core/pipeline.py): extract,
-        # generate and emit for successive intervals overlap on
-        # dedicated stage threads while the tick stays a cheap swap.
-        # None = serial flush (the reference-shaped default).
-        if cfg.flush_pipeline:
-            from veneur_tpu.core.pipeline import FlushPipeline
-
-            self.flush_pipeline = FlushPipeline(
-                self, max_backlog=cfg.flush_pipeline_backlog)
-        else:
-            self.flush_pipeline = None
         # native emit tier (native/emit.cpp): sinks serialize their wire
         # payloads GIL-free straight from the flush arrays; off = always
         # use the Python columnar formatters
@@ -364,11 +373,8 @@ class Server:
         self._ctr_local = threading.local()
         self._errors_reported = 0
         self._span_sink_reported: dict[tuple[str, str], int] = {}
-        # delivery.* interval-delta bookkeeping + the consecutive
-        # behind-interval count gating the downstream-behind signal
-        # (health/policy.py delivery_should_signal_behind)
+        # delivery.* interval-delta bookkeeping
         self._delivery_reported: dict[tuple[str, str], int] = {}
-        self._delivery_behind_consec = 0
         # plugins.* interval-delta bookkeeping (plugin flush failures
         # ride the self-telemetry stream, not just the logs)
         self._plugin_reported: dict[tuple[str, str], int] = {}
@@ -542,9 +548,7 @@ class Server:
             native = getattr(w, "_native", None)
             if native is None:
                 continue
-            fn = getattr(native._lib, "vn_set_lock_stats", None)
-            if fn is not None:
-                fn(1 if enabled else 0)
+            native._lib.vn_set_lock_stats(1 if enabled else 0)
             for ctx in [native] + list(getattr(w, "_reader_ctxs", ())):
                 ctx.reset_lock_stats()
 
@@ -587,8 +591,7 @@ class Server:
             "last_flush_phases": {k: v for k, v in
                                   self.last_flush_phases.items()
                                   if k != "spans"},
-            # how long the last flush tick held the ticker thread (the
-            # whole serial flush; pipelined, the swap + enqueue): the
+            # how long the last flush held the ticker thread: the
             # ingest-stall component of the cadence decomposition the
             # loadgen controller reports per interval
             "last_tick_s": self._last_tick_s(),
@@ -608,8 +611,6 @@ class Server:
             out["reader_shards"] = w0.reader_stats(
                 lock_stats=self._lock_stats_enabled)
         out["spans"] = self._span_stats()
-        if self.flush_pipeline is not None:
-            out["pipeline"] = self.flush_pipeline.stats()
         delivery = {rname: man.stats()
                     for rname, man in self._delivery_managers()}
         if delivery:
@@ -622,8 +623,7 @@ class Server:
         return out
 
     def _last_tick_s(self) -> float:
-        sp = self.rec.last(
-            "flush" if self.flush_pipeline is None else "flush.begin")
+        sp = self.rec.last("flush")
         return sp.seconds if sp is not None else 0.0
 
     def _span_stats(self) -> dict:
@@ -1566,9 +1566,6 @@ class Server:
         if self.config.tpu_warmup_compile:
             self._spawn(self._warmup_compile, "warmup-compile",
                         compute=True)
-        if self.flush_pipeline is not None:
-            # stage threads must exist before the first tick enqueues
-            self.flush_pipeline.start()
         self._spawn(self._flush_loop, "flush-ticker", compute=True)
         if self.config.micro_fold:
             # always-hot flush scheduler (worker.micro_fold_once): the
@@ -1662,8 +1659,7 @@ class Server:
                        "final_flush": False, "drained_payloads": 0,
                        "drain_passes": 0}
         # 1) final-epoch swap + flush of whatever the last interval
-        #    accumulated (the pipelined path drains in shutdown();
-        #    serial flushes run inline here)
+        #    accumulated
         if deadline_s > 0:
             try:
                 self.flush()
@@ -1839,39 +1835,14 @@ class Server:
             try:
                 self._tick_due = next_tick
                 _t0 = time.perf_counter()
-                if self.flush_pipeline is not None:
-                    outcome = self.flush_pipeline.tick()
-                    tick_s = time.perf_counter() - _t0
-                    if outcome == "ok":
-                        # growth only: under overlap a stage may run
-                        # most of the interval and still keep pace, so
-                        # stage DURATION is not an overload signal —
-                        # BACKLOG is, and persistent backlog sheds via
-                        # _pipeline_overrun on the deferred/shed paths.
-                        # Duration-driven halving here was measured
-                        # shedding 94k lines of a 2.7M-line confirm run
-                        # that the pipeline was absorbing fine.
-                        self._adapt_spill_caps(
-                            max(tick_s, self.flush_pipeline.last_cycle_s),
-                            allow_shrink=False)
-                else:
-                    self.flush()
-                    self._adapt_spill_caps(time.perf_counter() - _t0)
+                self.flush()
+                self._adapt_spill_caps(time.perf_counter() - _t0)
             except Exception:
                 log.exception("flush failed")
             finally:
                 self._tick_due = None
 
-    def _pipeline_overrun(self) -> None:
-        """A flush-pipeline stage fell a full interval behind (deferred
-        tick or shed interval): treat it exactly like a flush that
-        consumed the whole interval, so the standing shedding loop
-        halves the spill caps instead of letting queues grow
-        (health/policy.py MAX_STAGE_BACKLOG documents the contract)."""
-        self._adapt_spill_caps(self.interval)
-
-    def _adapt_spill_caps(self, flush_dur: float,
-                          allow_shrink: bool = True) -> None:
+    def _adapt_spill_caps(self, flush_dur: float) -> None:
         """Closed-loop overload shedding: bound the backlog one flush can
         inherit so the flush fits the interval. The C++ spill caps bound
         the direct-fold work a swap hands to extraction; when a flush
@@ -1885,7 +1856,7 @@ class Server:
         ceiling = self.config.tpu_spill_cap
         floor = min(1 << 16, ceiling)
         cur = self._spill_cap_now
-        if allow_shrink and flush_dur > 0.9 * self.interval:
+        if flush_dur > 0.9 * self.interval:
             new = max(floor, cur >> 1)
         elif flush_dur < 0.3 * self.interval:
             new = min(ceiling, cur << 1)
@@ -1903,12 +1874,9 @@ class Server:
             with self._worker_locks[i]:
                 w.spill_cap = new
                 if w._native is not None:
-                    try:
-                        w._native.set_spill_cap(new)
-                        for ctx in getattr(w, "_reader_ctxs", ()):
-                            ctx.set_spill_cap(new)
-                    except AttributeError:  # stale .so without the cap API
-                        pass
+                    w._native.set_spill_cap(new)
+                    for ctx in getattr(w, "_reader_ctxs", ()):
+                        ctx.set_spill_cap(new)
 
     def flush(self, now: float | None = None):
         """One flush pass (reference Server.Flush, flusher.go:28-134).
@@ -1917,8 +1885,8 @@ class Server:
         ColumnarMetrics batch (len() works; call .materialize() for
         objects) when every sink consumed columns.
 
-        `now` pins the interval's timestamp (tests compare serial and
-        pipelined output bit-for-bit by flushing both at one clock).
+        `now` pins the interval's timestamp (tests compare two servers'
+        output bit-for-bit by flushing both at one clock).
 
         Self-traced: every flush is a span (reference
         tracer.StartSpan("flush"), flusher.go:29) that rejoins this
@@ -1947,12 +1915,7 @@ class Server:
             self.flush_governor.end_flush()
 
     def _flush_inner(self, now: float | None = None):
-        # serial composition of the four flush phases; the stage-parallel
-        # executor (core/pipeline.py) runs the SAME methods on dedicated
-        # stage threads with up to an interval of overlap between them,
-        # which is what keeps pipelined output bit-identical to this path
         job = self._flush_begin(now=now)
-        job.rooted = True
         self._flush_extract(job)
         self._flush_generate(job)
         self._flush_emit(job)
@@ -1961,13 +1924,8 @@ class Server:
     def _flush_begin(self, now: float | None = None):
         """Tick-side flush phase: epoch close + device dispatches under
         the per-worker ingest locks (the map-swap analog of
-        worker.go:498-517) — no device readback, so a pipelined tick
-        stays a fraction of the interval. Freezes the interval's
-        timestamp in job.ts: generation stamps InterMetrics from it on
-        both serial and pipelined paths, so output stays bit-identical
-        even when generation runs a full interval later."""
-        from veneur_tpu.core.pipeline import FlushJob
-
+        worker.go:498-517), no device readback. Freezes the interval's
+        timestamp in job.ts: generation stamps InterMetrics from it."""
         flush_start = time.time() if now is None else float(now)
         self.last_flush_unix = flush_start
         self.flush_count += 1
@@ -2096,7 +2054,7 @@ class Server:
 
     def _reader_ns(self):
         """(ns in recv, ns outside it) summed over every native context,
-        lifetime; None without native ingest or on a stale .so."""
+        lifetime; None without native ingest."""
         per_ctx = [ns for w in self.workers
                    for ns in (w.reader_ns() or ())]
         if not per_ctx:
@@ -2309,12 +2267,9 @@ class Server:
 
     def _flush_emit(self, job) -> None:
         """Sink-emission flush phase plus the flush's self-telemetry
-        tail. A flush with no root span around it (a pipelined one)
-        publishes its phases here; a serial one does in flush()."""
+        tail."""
         with self.rec.span("flush.emit", flush=job.ordinal):
             self._flush_emit_sinks(job)
-        if not job.rooted:
-            self._flush_publish(job)
 
     def _flush_publish(self, job) -> None:
         """Rebind last_flush_phases, so observers always read the phases
@@ -2534,13 +2489,7 @@ class Server:
                     self.stats.count(metric, delta, tags=ptags)
         # delivery-reliability telemetry (sinks/delivery.py): every
         # manager's cumulative counters as interval deltas, breaker and
-        # spill occupancy as gauges. A sink behind — breaker not closed
-        # or fresh spill deferrals — for DELIVERY_BEHIND_INTERVALS
-        # consecutive flushes feeds the pipeline's downstream-behind
-        # shed signal; serial servers skip the signal (their emit stage
-        # already backpressures the tick, and shedding ingest for a
-        # dead backend would drop data the other sinks still take).
-        behind = False
+        # spill occupancy as gauges.
         for rname, man in self._delivery_managers():
             if (self.tenant_ledger is not None
                     and man.abusive_tenants is None):
@@ -2557,25 +2506,12 @@ class Server:
                 self._delivery_reported[rkey] = total
                 if delta:
                     self.stats.count(f"delivery.{key}", delta, tags=tags)
-                    if key == "deferred_payloads":
-                        behind = True
             self.stats.gauge("delivery.circuit_state",
                              float(dstats["circuit_state_code"]), tags=tags)
             self.stats.gauge("delivery.spilled_payloads",
                              float(dstats["spilled_payloads"]), tags=tags)
             self.stats.gauge("delivery.spilled_bytes",
                              float(dstats["spilled_bytes"]), tags=tags)
-            if dstats["circuit_state"] != "closed":
-                behind = True
-        self._delivery_behind_consec = (
-            self._delivery_behind_consec + 1 if behind else 0)
-        from veneur_tpu.health.policy import delivery_should_signal_behind
-
-        if (self.flush_pipeline is not None
-                and delivery_should_signal_behind(
-                    self._delivery_behind_consec)):
-            self.stats.count("flush.delivery_behind_total", 1)
-            self.flush_pipeline.note_downstream_behind()
         # forward-path self-telemetry: the local forwarder's cumulative
         # counters as interval deltas, per proxy destination (tagged
         # proxy:<addr>) plus the spread-level respread/pick counters.
@@ -2656,9 +2592,7 @@ class Server:
         rss = _current_rss_bytes()
         if rss is not None:
             self.stats.gauge("mem.rss_bytes", float(rss))
-        # total duration from the tick-time clock: under the pipeline
-        # this includes inter-stage queue wait, which is the honest
-        # end-to-end latency of the interval's flush
+        # total duration from the tick-time clock
         self.stats.time_in_nanoseconds(
             "flush.total_duration_ns",
             (time.time() - job.flush_start) * 1e9)
@@ -2878,15 +2812,6 @@ class Server:
     def _shutdown_teardown(self) -> bool:
         """The winning shutdown() caller's teardown body."""
         self._stop_native_readers()
-        if self.flush_pipeline is not None:
-            # drain in-flight stages BEFORE sinks stop: the final
-            # admitted interval's metrics must reach the sinks (the
-            # shutdown contract tests/test_pipeline.py pins). Bounded —
-            # a wedged sink forfeits the drain rather than the shutdown.
-            if not self.flush_pipeline.stop(
-                    drain=True, timeout=max(10.0, 2.0 * self.interval)):
-                log.warning("flush pipeline did not drain within the "
-                            "shutdown budget; in-flight flush data lost")
         # join the compute threads (bounded): a daemon thread still
         # inside XLA/C++ when the interpreter finalizes is force-unwound
         # mid-frame — glibc's "FATAL: exception not rethrown" abort

@@ -17,7 +17,7 @@ production scale that is the failure mode that kills an aggregator
 * ``TenantTallies`` — the per-epoch sample accounting (accepted / kept /
   rejected / dropped per tenant) that the worker accumulates into
   lifetime totals pre-swap, exactly like ``Worker.processed_total``, so a
-  tenant's drops in a swapped-out epoch survive a late pipelined extract.
+  tenant's drops in a swapped-out epoch survive the epoch's reset.
   Conservation is exact per tenant: accepted == kept + rejected + dropped
   (the isolation soak's core assertion).
 

@@ -859,10 +859,7 @@ class DeviceWorker:
         if self._native is not None:
             # staging would divert samples from the mesh pool: mesh rows
             # route through add_samples_bulk, not the staged fold
-            try:
-                self._native.set_stage_depth(0)
-            except AttributeError:
-                pass
+            self._native.set_stage_depth(0)
 
     @property
     def processed(self) -> int:
@@ -902,15 +899,9 @@ class DeviceWorker:
         except (RuntimeError, OSError):
             return False
         if self._mesh_pool is None and self.stage_depth > 0:
-            try:
-                self._native.set_stage_depth(self.stage_depth)
-            except AttributeError:  # stale .so without the staging API
-                pass
+            self._native.set_stage_depth(self.stage_depth)
         if self.spill_cap:
-            try:
-                self._native.set_spill_cap(self.spill_cap)
-            except AttributeError:  # stale .so without the cap API
-                pass
+            self._native.set_spill_cap(self.spill_cap)
         return True
 
     def attach_reader_shards(self, n: int) -> bool:
@@ -926,15 +917,13 @@ class DeviceWorker:
         local→canonical map); the flush folds all staging planes
         on-device as ONE stacked batch (ops/reader_stack.py), so every
         downstream consumer sees the same output as the legacy
-        digest-routed path. Requires an attached home context, no mesh
-        pool, and a reader-shard-capable .so. Returns False (legacy
-        path keeps working) when any precondition fails."""
+        digest-routed path. Requires an attached home context and no
+        mesh pool. Returns False (legacy path keeps working) when either
+        precondition fails."""
         if n < 1 or self._reader_ctxs:
             return bool(self._reader_ctxs)
         if self._native is None or self._mesh_pool is not None:
             return False
-        if not hasattr(self._native._lib, "vn_ingest_home"):
-            return False  # stale .so: no home-aware commit entry point
         from veneur_tpu.native import NativeIngest
 
         ctxs = []
@@ -947,7 +936,7 @@ class DeviceWorker:
                 if self.spill_cap:
                     ctx.set_spill_cap(self.spill_cap)
                 ctxs.append(ctx)
-        except (RuntimeError, OSError, AttributeError):
+        except (RuntimeError, OSError):
             for ctx in ctxs:
                 ctx.close()
             return False
@@ -1217,24 +1206,18 @@ class DeviceWorker:
     def reader_ns(self) -> Optional[list]:
         """Per native context, [home] + reader shards: the lifetime
         (ns inside recv, ns outside it: parse + commit + lock wait) of
-        the C++ reader threads homed on it. None without native ingest
-        or on a stale .so without the counter."""
+        the C++ reader threads homed on it. None without native
+        ingest."""
         if self._native is None:
             return None
-        try:
-            return [ctx.reader_ns() for ctx in self._all_ctxs()]
-        except AttributeError:
-            return None
+        return [ctx.reader_ns() for ctx in self._all_ctxs()]
 
     def commit_counters(self) -> Optional[dict]:
         """NativeIngest.commit_counters summed over this worker's native
-        contexts; None without native ingest or on a stale .so."""
+        contexts; None without native ingest."""
         if self._native is None:
             return None
-        try:
-            per_ctx = [ctx.commit_counters() for ctx in self._all_ctxs()]
-        except AttributeError:
-            return None
+        per_ctx = [ctx.commit_counters() for ctx in self._all_ctxs()]
         return {k: sum(c[k] for c in per_ctx) for k in per_ctx[0]}
 
     def reader_stats(self, lock_stats: bool = False) -> dict:
@@ -1337,20 +1320,14 @@ class DeviceWorker:
         others: list = []
         ssf_fb: list = []
         if detach_stage:
-            try:
-                st = ctx.detach_stage()
-            except AttributeError:  # stale .so without the staging API
-                st = None
+            st = ctx.detach_stage()
             # epoch close: pull buffered event/service-check lines and
             # Python-fallback SSF payloads in the SAME critical section —
             # the reset right after this drain clears both buffers, and
             # anything landing between a separate drain and the reset
             # would be destroyed
             others = ctx.drain_other()
-            try:
-                ssf_fb = ctx.drain_ssf_fallback()
-            except AttributeError:  # stale .so without the SSF reader API
-                pass
+            ssf_fb = ctx.drain_ssf_fallback()
         if sync:
             # the epoch close adopts under the context lock: "swap.adopt"
             self._sync_native_series(
@@ -1444,10 +1421,7 @@ class DeviceWorker:
         if not self._micro_active():
             return 0
         if self._native is not None:
-            try:
-                return int(self._native.stage_pending)
-            except AttributeError:  # stale .so without the delta API
-                return 0
+            return int(self._native.stage_pending)
         if self._stage_count is None:
             return 0
         total = int(self._stage_count.sum())
@@ -1506,10 +1480,7 @@ class DeviceWorker:
             self.micro_folds_epoch += 1
             gov = self.governor
             if gov is not None:
-                try:
-                    gov.note_micro_fold(fed)
-                except AttributeError:
-                    pass
+                gov.note_micro_fold(fed)
         return fed
 
     def _micro_drain_native(self) -> int:
@@ -1517,10 +1488,7 @@ class DeviceWorker:
         mirror. drain_stage_delta advances the plane's per-row watermark
         WITHOUT touching counts, so the per-epoch depth cap (and the
         spill partitioning) is identical to a run with no micro-folds."""
-        try:
-            if self._native.stage_pending <= 0:
-                return 0
-        except AttributeError:  # stale .so without the delta API
+        if self._native.stage_pending <= 0:
             return 0
         micro = self._ensure_micro()
         fed = 0
@@ -2551,40 +2519,13 @@ class DeviceWorker:
                 log.warning("device path re-admitted after probe; host "
                             "state re-uploaded")
 
-    _pallas_ok: Optional[bool] = None
-
     def _extract(self, fields: tuple, qs):
-        """Flush extraction: the XLA program, or the fused Pallas kernel
-        where it was asked for on a TPU (ops/pallas_kernels.supported).
-        `fields` is the 14-tuple of (possibly row-sliced, possibly
-        staged-folded) digest arrays in HistoDeviceState order. A kernel
-        that was asked for and fails raises: there is no quiet return to
-        the XLA path. Classified device faults go to the guard's
-        failover as on every other path."""
-        (means, weights, dmin, dmax, drecip, drecip_c,
-         lmin, lmax, lsum, lsum_c, lweight, lweight_c,
-         lrecip, lrecip_c) = fields
-        if DeviceWorker._pallas_ok is None:
-            from veneur_tpu.ops import pallas_kernels as pk
-
-            DeviceWorker._pallas_ok = pk.supported()
-        if DeviceWorker._pallas_ok:
-            from veneur_tpu.ops import pallas_kernels as pk
-
-            quant, dsum, dcount = self.guard.call(
-                "extract", pk.flush_extract,
-                means, weights, dmin, dmax, qs, retryable=True)
-            return (quant, dmin, dmax, dsum, dcount,
-                    drecip + drecip_c,
-                    lmin, lmax,
-                    lsum + lsum_c,
-                    lweight + lweight_c,
-                    lrecip + lrecip_c)
+        """Flush extraction. `fields` is the 14-tuple of (possibly
+        row-sliced, possibly staged-folded) digest arrays in
+        HistoDeviceState order. Classified device faults go to the
+        guard's failover as on every other path."""
         return self.guard.call(
-            "extract", _histo_flush_extract,
-            means, weights, dmin, dmax, drecip, drecip_c, lmin, lmax,
-            lsum, lsum_c, lweight, lweight_c, lrecip, lrecip_c, qs,
-            retryable=True)
+            "extract", _histo_flush_extract, *fields, qs, retryable=True)
 
     # -- flush --------------------------------------------------------------
 
@@ -2639,10 +2580,7 @@ class DeviceWorker:
         for t, c in zip(t_list.tolist(), t_counts.tolist()):
             tt.dropped[t] = tt.dropped.get(t, 0) + int(c)
             if gov is not None:
-                try:
-                    gov.note_tenant_shed(t, int(c))
-                except AttributeError:
-                    pass
+                gov.note_tenant_shed(t, int(c))
         return tuple(a[keep] for a in spill_histo)
 
     def swap(self, quantiles: np.ndarray) -> "SwappedEpoch":
@@ -2772,20 +2710,16 @@ class DeviceWorker:
                         # device feeds run after unlock so reader commits
                         # aren't stalled.
                         with rec.span("swap.drain.residual"):
-                            try:
-                                cap = 1 << 18
-                                while True:
-                                    coo = self._native.drain_stage_delta(
-                                        cap)
-                                    if not len(coo[0]):
-                                        break
-                                    micro_coo.append(coo)
-                                    if len(coo[0]) < cap:
-                                        break
-                                native_mirrored = (
-                                    self._native.stage_pending == 0)
-                            except AttributeError:  # stale .so: plane path
-                                native_mirrored = False
+                            cap = 1 << 18
+                            while True:
+                                coo = self._native.drain_stage_delta(cap)
+                                if not len(coo[0]):
+                                    break
+                                micro_coo.append(coo)
+                                if len(coo[0]) < cap:
+                                    break
+                            native_mirrored = (
+                                self._native.stage_pending == 0)
                     raw = self._drain_native_raw(detach_stage=True)
                     native_stage = raw[4]
                     # event/service-check lines + fallback SSF payloads
@@ -2957,7 +2891,7 @@ class DeviceWorker:
         # per-tenant lifetime fold, still under the caller's ingest lock
         # and BEFORE the epoch reset zeroes the per-epoch dicts — the
         # processed_total pattern above, per tenant per kind, so a
-        # tenant's drops in this epoch survive a late pipelined extract
+        # tenant's drops in this epoch survive the epoch's reset
         with rec.span("swap.reset"):
             self.tenant_tallies.accumulate_into(self.tenant_tallies_total)
             self.processed = 0
@@ -3573,11 +3507,8 @@ class DeviceWorker:
         # one extraction == one transfer window. The reset lives HERE,
         # not in swap(): every ledger-counted transfer (staged-plane
         # uploads, quantile upload, packed readback) happens inside this
-        # method, and under the stage pipeline the NEXT tick's swap runs
-        # on the ticker thread while this extraction is still counting —
-        # a swap-time reset would clobber the window mid-read. Extractions
-        # never overlap each other (single extract stage), so resetting
-        # on this thread keeps the windows tiling exactly.
+        # method, and extractions never overlap each other, so resetting
+        # here keeps the windows tiling exactly.
         self.ledger.begin_flush()
         directory = swapped.directory
         scalars = swapped.scalars

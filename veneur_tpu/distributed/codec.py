@@ -129,8 +129,7 @@ def _histo_wire_native(snap: FlushSnapshot, compression: float
     separators (falls back to the Python encoder)."""
     from veneur_tpu import native as native_mod
 
-    if not native_mod.available() or not hasattr(
-            native_mod.load_library(), "vn_encode_histo_batch"):
+    if not native_mod.available():
         return None  # before the O(rows) meta build, not after
     hrows = snap.directory.histo.rows
     nrows = len(hrows)

@@ -768,10 +768,10 @@ class ProxyServer:
         d = native_mod.decode_metric_batch(blob)
         if d is None:
             # native decoder rejected (malformed per protobuf spec since
-            # the round-4 strictness fixes, or stale .so): the Python
-            # parser gets a say, but ITS rejection must surface in the
-            # proxy's own telemetry, not as a bare worker traceback with
-            # the drop uncounted
+            # the round-4 strictness fixes) or there is no native library:
+            # the Python parser gets a say, but ITS rejection must surface
+            # in the proxy's own telemetry, not as a bare worker traceback
+            # with the drop uncounted
             try:
                 batch = pb.MetricBatch.FromString(blob)
             except Exception as e:

@@ -128,9 +128,8 @@ class FlushDeadlineGovernor:
         # with the floor-size chunk)
         self._rate_ewma: float | None = None
         # progress signal, read by the watchdog thread. A COUNT, not a
-        # bool: the stage-parallel flush pipeline (core/pipeline.py)
-        # overlaps intervals, so several flushes are legitimately in
-        # flight at once; the watchdog only cares whether ANY is.
+        # bool: the ticker's flush and a graceful drain's final flush
+        # may overlap; the watchdog only cares whether ANY is in flight.
         self._in_flight = 0
         self._last_beat_unix = 0.0
         self._chunks_done = 0
@@ -170,29 +169,12 @@ class FlushDeadlineGovernor:
     # -- flush lifecycle (called by the server) ---------------------------
 
     def begin_flush(self) -> None:
-        """Serial-flush entry: marks a flush in flight AND resets the
-        per-flush chunk report (the serial path's contract — "the next
-        flush resets the report", pinned by test_health_governor)."""
+        """Marks a flush in flight and resets the per-flush chunk
+        report ("the next flush resets the report", pinned by
+        test_health_governor)."""
         with self._lock:
             self._in_flight += 1
             self._last_beat_unix = time.time()
-            self._chunks_done = 0
-            self._chunk_times = []
-            self._chunk_rows = []
-
-    def begin_stage_flush(self) -> None:
-        """Pipelined-flush entry: marks a flush in flight WITHOUT
-        touching the chunk report. Under stage overlap the tick that
-        admits interval N must not clobber the report interval N-1's
-        extract stage is still filling; the extract stage calls
-        begin_report() itself when it actually starts chunking."""
-        with self._lock:
-            self._in_flight += 1
-            self._last_beat_unix = time.time()
-
-    def begin_report(self) -> None:
-        """Reset the per-flush chunk report (pipelined extract stage)."""
-        with self._lock:
             self._chunks_done = 0
             self._chunk_times = []
             self._chunk_rows = []

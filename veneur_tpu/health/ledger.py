@@ -24,8 +24,7 @@ expose byte counts, and the flush path's transfers are few and known.
 Thread-safety: one ledger per worker. `begin_flush` runs at the start
 of extract_snapshot — the same stage that performs every counted
 transfer — so window reset, counting, and the server's end-of-extract
-reads are all serialized on the extract thread even under the stage
-pipeline (where the next tick's swap overlaps a running extraction).
+reads are all serialized on the flush thread.
 Telemetry reads from other threads may still race a count, so mutation
 goes through a lock. Overhead is a dict update per transfer —
 nanoseconds against a millisecond-scale device round-trip.
@@ -54,8 +53,7 @@ class TransferLedger:
         # window that will report them opens. They accumulate here;
         # roll_epoch() (called at swap) queues the closed epoch's tally,
         # and begin_flush() folds the oldest queued epoch into the new
-        # window — correct under the stage pipeline, where swaps and
-        # extractions interleave but stay 1:1 (only generate/emit shed).
+        # window (swaps and extractions are 1:1).
         self._epoch_h2d: dict[str, int] = {}
         self._pending_epochs: list[dict[str, int]] = []
         # per-SHARD byte breakdown for the current flush (series-sharded

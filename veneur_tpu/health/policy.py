@@ -41,44 +41,26 @@ from __future__ import annotations
 # can't legitimately take 4x its prediction plus an interval's slack.
 STALL_MULTIPLIER = 4
 
-# Stage-parallel flush backpressure (core/pipeline.py): each stage
-# queue holds at most this many intervals beyond the one the stage is
-# working on. The bound is deliberately one, not a tunable depth — the
-# pipeline's whole point is overlap, not buffering. A stage more than
-# one interval behind means the host cannot keep cadence at this
-# cardinality, and the correct response is the shedding layer
-# (_adapt_spill_caps halving the C++ spill caps / the governor's chunk
-# ladder), not a growing queue that converts overload into unbounded
-# memory and staleness.
-MAX_STAGE_BACKLOG = 1
-
-
-# Delivery-behind gating (core/server.py delivery reporting): a sink
-# whose circuit breaker is not closed, or that deferred payloads to its
-# spill, for this many CONSECUTIVE flush intervals counts the backend
-# as behind and feeds the pipeline's downstream-behind shed signal. One
-# interval is deliberately not enough — a single transient 503 ends as
-# a successful retry, and shedding ingest for it would trade data the
-# backend will take for data it never sees (the same ≥2-consecutive
-# gating the pipeline applies to deferred ticks).
+# Behind gating (distributed/proxy.py RoutingPool.behind): a stage that
+# shed for this many CONSECUTIVE rounds counts its downstream as behind.
+# One round is deliberately not enough — a single transient 503 ends as
+# a successful retry, and shedding for it would trade data the backend
+# will take for data it never sees.
 DELIVERY_BEHIND_INTERVALS = 2
 
 
 def delivery_should_signal_behind(
         consecutive_behind: int,
         threshold: int = DELIVERY_BEHIND_INTERVALS) -> bool:
-    """True once a sink's delivery has been behind (open/half-open
-    breaker or fresh spill deferrals) for `threshold` consecutive flush
-    intervals — the gate between per-sink delivery stats and the
-    pipeline's downstream-behind overload response."""
+    """True once delivery has been behind (sustained shedding) for
+    `threshold` consecutive rounds."""
     return consecutive_behind >= max(1, int(threshold))
 
 
 # Proxy routing-executor backpressure (distributed/proxy.py
-# RoutingPool): unlike the flush pipeline's one-interval bound, the
-# proxy queue holds whole forwarded batches from MANY upstream locals,
-# so the bound is a count of batches, not intervals. Past it the proxy
-# sheds the incoming batch with honest per-metric drop counters — the
+# RoutingPool): the proxy queue holds whole forwarded batches from
+# MANY upstream locals, so the bound is a count of batches. Past it the
+# proxy sheds the incoming batch with honest per-metric drop counters — the
 # alternative (the pre-PR-7 behaviour) was an unbounded daemon thread
 # per batch, which converts a slow global tier into proxy memory growth
 # and thread exhaustion instead of a visible, bounded drop signal.
@@ -88,19 +70,8 @@ ROUTING_QUEUE_MAX = 128
 def routing_should_shed(queue_depth: int,
                         queue_max: int = ROUTING_QUEUE_MAX) -> bool:
     """The proxy routing executor's shed rule: refuse a batch once the
-    bounded routing queue is full. Centralised beside the pipeline shed
-    gate so both backpressure policies read as one contract."""
+    bounded routing queue is full."""
     return queue_depth >= max(1, int(queue_max))
-
-
-def pipeline_should_shed(queue_depth: int,
-                         max_backlog: int = MAX_STAGE_BACKLOG) -> bool:
-    """The backpressure contract for the stage-parallel flush executor:
-    shed (drop the oldest pending interval and signal overload) instead
-    of enqueueing once a stage already has `max_backlog` intervals
-    waiting. Centralised here so the watchdog-vs-shedding contract
-    above and the pipeline's shed rule are documented as one policy."""
-    return queue_depth >= max(1, int(max_backlog))
 
 
 # Tenant-aware shed ordering (per-tenant QoS, core/tenancy.py): when the

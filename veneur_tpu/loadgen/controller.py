@@ -322,13 +322,10 @@ class LoadHarness:
                        - prev["samples_processed"])
                 shed = (snap["overload_dropped"]
                         - prev["overload_dropped"])
-                # cadence decomposition: how long the tick held the
-                # ticker thread (the whole serial flush; just the
-                # swap+enqueue when pipelined), how long ingest was
-                # stalled under the worker locks (the swap phase), and
-                # the total flush work of the last COMPLETED flush —
-                # on a 1-core rig the gap between tick_block_ms and
-                # flush_ms is exactly what the stage pipeline buys
+                # cadence decomposition: how long the flush held the
+                # ticker thread, how long ingest was stalled under the
+                # worker locks (the swap phase), and the total flush
+                # work of the last COMPLETED flush
                 flush_phases = snap.get("last_flush_phases") or {}
                 intervals.append({
                     "duration_s": round(dt, 4),
@@ -426,7 +423,6 @@ class LoadHarness:
         total_dt = sum(i["duration_s"] for i in intervals)
         n_ok = sum(1 for i in intervals if i["cadence_ok"])
         n_iv = max(1, len(intervals))
-        pipeline_stats = self.server.ingress_stats().get("pipeline")
         # warmup vs steady state: a first-interval cadence miss from a
         # first-encounter XLA compile is a trial-boundary artifact, not
         # a pipeline failure. The judged cadence_frac excludes warmup
@@ -483,7 +479,6 @@ class LoadHarness:
                     i.get("archive_bytes", 0) for i in intervals) / n_iv)}
                if self.archive_sink is not None else {}),
             **steady,
-            **({"pipeline": pipeline_stats} if pipeline_stats else {}),
             "offered_lines_per_s": rate,
             "intervals": intervals,
             "total_sent": total_sent,
@@ -669,9 +664,7 @@ def result_artifact(spec: WorkloadSpec, harness: LoadHarness,
         "flushed_series": harness.flushed_series,
         # cadence decomposition of the confirmation run: how long the
         # tick held the ticker thread vs how long ingest stalled under
-        # the worker locks vs the full flush work — on a 1-core rig
-        # tick_block ≈ flush is the cadence-bound serial signature,
-        # tick_block ≈ ingest_stall « flush is the pipelined one
+        # the worker locks vs the full flush work
         "tick_block_ms_mean": confirm.get("tick_block_ms_mean"),
         "ingest_stall_ms_mean": confirm.get("ingest_stall_ms_mean"),
         "flush_ms_mean": confirm.get("flush_ms_mean"),
@@ -686,8 +679,6 @@ def result_artifact(spec: WorkloadSpec, harness: LoadHarness,
         "flush_ms_steady": confirm.get("flush_ms_steady"),
         "drain_ms_mean": confirm.get("drain_ms_mean"),
         "micro_folds_total": confirm.get("micro_folds_total"),
-        **({"pipeline": confirm["pipeline"]}
-           if confirm.get("pipeline") else {}),
         "search_trials": [
             {k: t.get(k) for k in ("offered_lines_per_s",
                                    "accepted_lines_per_s", "loss_frac",

@@ -7,6 +7,7 @@ callers fall back to the pure-Python parser when it isn't.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,333 +23,331 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libveneur_native.so")
 
-_lib = None
-_lib_lock = threading.Lock()
-
 _LOADGEN_PATH = os.path.join(_NATIVE_DIR, "libveneur_loadgen.so")
-_lg_lib = None
-_lg_lock = threading.Lock()
+
+# what the Makefile stamps each library with (SRC_HASH, LG_SRC_HASH):
+# the first 16 hex digits of the sha256 of these sources, concatenated
+_LIB_SOURCES = ("dogstatsd.cpp", "emit.cpp", "forward_codec.cpp")
+_LOADGEN_SOURCES = ("loadgen.cpp",)
+
+# path -> the library as this process loaded it, or None where there is
+# none or it was refused: decided once, so a refusal warns once
+_loaded: dict = {}
+_load_lock = threading.Lock()
 
 
-def _build() -> bool:
+def _build() -> None:
     try:
         subprocess.run(["make", "-C", _NATIVE_DIR],
                        capture_output=True, check=True, timeout=120)
-        return True
     except subprocess.CalledProcessError as e:
         # the libraries are not committed (-march=native): a failed build
         # on a fresh checkout means the Python parser, and must be seen
         log.warning("native build failed (%s): %s", e,
                     e.stderr.decode("utf-8", "replace")[-2000:])
-        return False
     except (OSError, subprocess.TimeoutExpired) as e:
         log.warning("native build unavailable: %s", e)
-        return False
+
+
+def _sources_stamp(sources: tuple) -> Optional[str]:
+    """The stamp a build of this tree carries; None where the sources
+    are not on disk beside the library."""
+    h = hashlib.sha256()
+    try:
+        for name in sources:
+            with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+    except OSError:
+        return None
+    return h.hexdigest()[:16]
+
+
+def _load(path: str, stamp_symbol: str, sources: tuple,
+          bind) -> Optional[ctypes.CDLL]:
+    """The library at `path` with every symbol bound, if it is this
+    tree's build: it exports everything `bind` names and its stamp is
+    the hash of `sources`. Any other library is refused whole, with one
+    warning, and the caller runs as if there were none: a build that
+    failed beside an older library must not change what runs, symbol
+    by symbol, in silence."""
+    with _load_lock:
+        if path in _loaded:
+            return _loaded[path]
+        # make is dependency-checked, so this is a no-op when the .so is
+        # current and a rebuild when a source changed underneath it
+        _build()
+        lib = None
+        if os.path.exists(path):
+            want = _sources_stamp(sources)
+            found, missing = "none", ""
+            try:
+                lib = ctypes.CDLL(path)
+                stamp = getattr(lib, stamp_symbol)
+                stamp.restype = ctypes.c_char_p
+                stamp.argtypes = []
+                found = stamp().decode()
+                bind(lib)
+            except (OSError, AttributeError) as e:
+                missing = f" ({e})"
+            if missing or (want is not None and found != want):
+                log.warning(
+                    "%s is not this tree's build: stamp %s, the sources' "
+                    "is %s%s; running without it", path, found, want,
+                    missing)
+                lib = None
+        _loaded[path] = lib
+        return lib
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    global _lib
-    with _lib_lock:
-        if _lib is not None:
-            return _lib
-        # make is dependency-checked, so this is a no-op when the .so is
-        # current and a rebuild when dogstatsd.cpp changed underneath it
-        if not _build() and not os.path.exists(_LIB_PATH):
-            return None
-        lib = ctypes.CDLL(_LIB_PATH)
-        c = ctypes
-        # optional symbols (absent from a pre-round-3 library): their
-        # absence degrades the feature, never the load
-        try:
-            lib.vn_source_hash.restype = c.c_char_p
-            lib.vn_source_hash.argtypes = []
-        except AttributeError:  # pre-stamp library
-            pass
-        try:
-            lib.vn_encode_histo_batch.restype = c.c_longlong
-            lib.vn_encode_histo_batch.argtypes = [
-                c.c_char_p, c.c_longlong,
-                c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
-                c.c_void_p, c.c_int, c.c_int,
-                c.c_void_p, c.c_void_p, c.c_void_p, c.c_double,
-                c.POINTER(c.c_char_p)]
-        except AttributeError:  # pre-encoder library
-            pass
-        try:
-            P = c.POINTER
-            lib.vn_decode_metric_batch.restype = c.c_longlong
-            lib.vn_decode_metric_batch.argtypes = [
-                c.c_char_p, c.c_longlong,
-                P(c.c_char_p), P(c.c_longlong),          # meta
-                P(c.c_void_p), P(c.c_void_p),            # kinds, scopes
-                P(c.c_void_p), P(c.c_void_p),            # value_kind, digests
-                P(c.c_void_p),                           # scalars
-                P(c.c_void_p), P(c.c_void_p), P(c.c_void_p),  # dmin/max/rec
-                P(c.c_void_p),                           # compression
-                P(c.c_void_p), P(c.c_void_p), P(c.c_void_p),  # centroids
-                P(c.c_void_p), P(c.c_char_p), P(c.c_void_p),  # hll
-                P(c.c_void_p), P(c.c_void_p),  # record byte ranges
-                P(c.c_void_p)]  # ring hashes
-            lib.vn_upsert_many.restype = c.c_longlong
-            lib.vn_upsert_many.argtypes = [
-                c.c_void_p, c.c_char_p, c.c_longlong,
-                c.c_void_p, c.c_void_p, c.c_void_p, c.c_longlong,
-                c.c_void_p]
-        except AttributeError:  # pre-import-decoder library
-            pass
-        try:
-            lib.vn_encode_datadog_series.restype = c.c_longlong
-            lib.vn_encode_datadog_series.argtypes = [
-                c.c_char_p, c.c_longlong, c.c_longlong,       # meta
-                c.c_char_p, c.c_longlong,                     # suffixes
-                c.c_void_p, c.c_int,                          # types, nfam
-                c.c_void_p, c.c_void_p,                       # values, masks
-                c.c_longlong, c.c_double,                     # ts, interval
-                c.c_char_p, c.c_longlong,                     # hostname
-                c.c_char_p, c.c_longlong,                     # common tags
-                c.c_char_p, c.c_longlong,                     # excl keys
-                c.c_char_p, c.c_longlong,                     # excl prefixes
-                c.c_char_p, c.c_longlong,                     # drop prefixes
-                c.c_longlong,                                 # max_per_body
-                c.POINTER(c.c_void_p), c.POINTER(c.c_char_p),
-                c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-            lib.vn_encode_signalfx_body.restype = c.c_longlong
-            lib.vn_encode_signalfx_body.argtypes = [
-                c.c_char_p, c.c_longlong, c.c_longlong,
-                c.c_char_p, c.c_longlong,
-                c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
-                c.c_longlong,
-                c.c_char_p, c.c_longlong, c.c_char_p, c.c_longlong,
-                c.c_char_p, c.c_longlong, c.c_char_p, c.c_longlong,
-                c.c_char_p, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-            lib.vn_encode_prometheus_lines.restype = c.c_longlong
-            lib.vn_encode_prometheus_lines.argtypes = [
-                c.c_char_p, c.c_longlong, c.c_longlong,
-                c.c_char_p, c.c_longlong,
-                c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
-                c.c_char_p, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-        except AttributeError:  # pre-datadog-emitter library
-            pass
-        try:
-            # emit tier (native/emit.cpp): forward lines, exposition
-            # text, and the GIL-free deflate pass
-            lib.vn_encode_forward_lines.restype = c.c_longlong
-            lib.vn_encode_forward_lines.argtypes = (
-                lib.vn_encode_prometheus_lines.argtypes)
-            lib.vn_encode_prometheus_exposition.restype = c.c_longlong
-            lib.vn_encode_prometheus_exposition.argtypes = (
-                lib.vn_encode_prometheus_lines.argtypes)
-            lib.vn_deflate.restype = c.c_longlong
-            lib.vn_deflate.argtypes = [
-                c.c_char_p, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-            lib.vn_deflate_chunks.restype = c.c_longlong
-            lib.vn_deflate_chunks.argtypes = [
-                c.c_char_p, c.c_void_p, c.c_longlong,
-                c.POINTER(c.c_void_p), c.POINTER(c.c_char_p),
-                c.POINTER(c.c_longlong)]
-        except AttributeError:  # pre-emit-tier library
-            pass
-        try:
-            # archive tier (native/emit.cpp): VMB1 columnar sections
-            lib.vn_encode_archive_section.restype = c.c_longlong
-            lib.vn_encode_archive_section.argtypes = [
-                c.c_char_p, c.c_longlong, c.c_longlong,
-                c.c_char_p, c.c_longlong,
-                c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-        except AttributeError:  # pre-archive library
-            pass
-        try:
-            # forward frame codec (native/forward_codec.cpp): VSF1
-            # stream frames/acks + the VDE1 dedup envelope header
-            lib.vn_stream_frame_encode.restype = c.c_longlong
-            lib.vn_stream_frame_encode.argtypes = [
-                c.c_ulonglong, c.c_char_p, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-            lib.vn_stream_frame_decode.restype = c.c_longlong
-            lib.vn_stream_frame_decode.argtypes = [
-                c.c_char_p, c.c_longlong, c.POINTER(c.c_ulonglong)]
-            lib.vn_stream_ack_encode.restype = c.c_longlong
-            lib.vn_stream_ack_encode.argtypes = [
-                c.c_ulonglong, c.c_int, c.c_char_p]
-            lib.vn_stream_ack_decode.restype = c.c_longlong
-            lib.vn_stream_ack_decode.argtypes = [
-                c.c_char_p, c.c_longlong, c.POINTER(c.c_ulonglong)]
-            lib.vn_dedup_header_encode.restype = c.c_longlong
-            lib.vn_dedup_header_encode.argtypes = [
-                c.c_char_p, c.c_longlong, c.c_longlong, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
-            lib.vn_dedup_header_parse.restype = c.c_longlong
-            lib.vn_dedup_header_parse.argtypes = [
-                c.c_char_p, c.c_longlong,
-                c.POINTER(c.c_char_p), c.POINTER(c.c_longlong),
-                c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-        except AttributeError:  # pre-forward-codec library
-            pass
-        try:
-            lib.vn_set_lock_stats.argtypes = [c.c_int]
-            lib.vn_lock_stats.restype = c.c_int
-            lib.vn_lock_stats.argtypes = [
-                c.c_void_p, c.POINTER(c.c_longlong),
-                c.POINTER(c.c_longlong), c.POINTER(c.c_longlong), c.c_int]
-            lib.vn_lock_stats_reset.argtypes = [c.c_void_p]
-        except AttributeError:  # pre-instrumentation library
-            pass
-        lib.vn_ctx_new.restype = c.c_void_p
-        lib.vn_ctx_new.argtypes = [c.c_int]
-        lib.vn_ctx_free.argtypes = [c.c_void_p]
-        lib.vn_ctx_reset.argtypes = [c.c_void_p]
-        lib.vn_ingest.restype = c.c_int
-        lib.vn_ingest.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
-        for name in ("vn_pending_histo", "vn_pending_set",
-                     "vn_pending_counter", "vn_pending_gauge",
-                     "vn_num_histo_rows", "vn_num_set_rows",
-                     "vn_num_counter_rows", "vn_num_gauge_rows"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_int
-            fn.argtypes = [c.c_void_p]
-        for name in ("vn_processed", "vn_errors"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_longlong
-            fn.argtypes = [c.c_void_p]
-        # round-4 overload-shedding API: absent in a stale prebuilt .so
-        # (load_library supports .so-without-toolchain hosts) — absence
-        # degrades the feature, never the load
-        try:
-            lib.vn_overload_dropped.restype = c.c_longlong
-            lib.vn_overload_dropped.argtypes = [c.c_void_p]
-            lib.vn_set_spill_cap.restype = None
-            lib.vn_set_spill_cap.argtypes = [c.c_void_p, c.c_longlong]
-        except AttributeError:
-            pass
-        try:
-            lib.vn_reader_ns.restype = None
-            lib.vn_reader_ns.argtypes = [
-                c.c_void_p, c.POINTER(c.c_longlong)]
-            lib.vn_commit_counters.restype = None
-            lib.vn_commit_counters.argtypes = [
-                c.c_void_p, c.POINTER(c.c_longlong)]
-        except AttributeError:
-            pass
-        lib.vn_drain_histo.restype = c.c_int
-        lib.vn_drain_histo.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
-        lib.vn_drain_set.restype = c.c_int
-        lib.vn_drain_set.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
-        lib.vn_drain_counter.restype = c.c_int
-        lib.vn_drain_counter.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
-        lib.vn_drain_gauge.restype = c.c_int
-        lib.vn_drain_gauge.argtypes = [
-            c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
-        lib.vn_drain_new_series.restype = c.c_int
-        lib.vn_drain_new_series.argtypes = (
-            [c.c_void_p] + [c.POINTER(c.c_void_p)] * 6
-            + [c.POINTER(c.c_int), c.POINTER(c.c_void_p),
-               c.POINTER(c.c_longlong), c.POINTER(c.c_uint)])
-        lib.vn_set_intern_cap.restype = None
-        lib.vn_set_intern_cap.argtypes = [c.c_void_p, c.c_longlong]
-        lib.vn_pending_new_series.restype = c.c_int
-        lib.vn_pending_new_series.argtypes = [c.c_void_p]
-        lib.vn_drain_other.restype = c.c_int
-        lib.vn_drain_other.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
-        lib.vn_upsert.restype = c.c_int
-        lib.vn_upsert.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_int, c.c_int, c.c_char_p, c.c_int,
-            c.c_int]
-        lib.vn_ingest_ssf.restype = c.c_int
-        lib.vn_ingest_ssf.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_int, c.c_char_p, c.c_int,
-            c.c_char_p, c.c_int, c.c_double]
-        lib.vn_ssf_spans.restype = c.c_longlong
-        lib.vn_ssf_spans.argtypes = [c.c_void_p]
-        lib.vn_ssf_invalid.restype = c.c_longlong
-        lib.vn_ssf_invalid.argtypes = [c.c_void_p]
-        lib.vn_drain_ssf_services.restype = c.c_int
-        lib.vn_drain_ssf_services.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
-        lib.vn_ctx_set_metro.argtypes = [c.c_void_p, c.c_int]
-        lib.vn_metro_hash64.restype = c.c_uint64
-        lib.vn_metro_hash64.argtypes = [c.c_char_p, c.c_int, c.c_uint64]
-        lib.vn_ingest_routed.restype = c.c_int
-        lib.vn_ingest_routed.argtypes = [
-            c.POINTER(c.c_void_p), c.c_int, c.c_char_p, c.c_int]
-        lib.vn_lock.argtypes = [c.c_void_p]
-        lib.vn_unlock.argtypes = [c.c_void_p]
-        lib.vn_ingest_ssf_many.restype = c.c_int
-        lib.vn_ingest_ssf_many.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_longlong, c.c_char_p, c.c_int,
-            c.c_char_p, c.c_int, c.c_double, c.POINTER(c.c_int),
-            c.c_void_p, c.c_void_p, c.c_int, c.POINTER(c.c_int)]
-        try:
-            # optional: a stale prebuilt .so may predate the staging API;
-            # callers degrade to the SoA drain path (worker guards the
-            # AttributeError raised at call time)
-            lib.vn_set_stage_depth.argtypes = [c.c_void_p, c.c_int]
-            lib.vn_stage_detach.restype = c.c_void_p
-            lib.vn_stage_detach.argtypes = [
-                c.c_void_p, c.POINTER(c.POINTER(c.c_float)),
-                c.POINTER(c.POINTER(c.c_float)),
-                c.POINTER(c.POINTER(c.c_int32)),
-                c.POINTER(c.c_int32), c.POINTER(c.c_int32)]
-            lib.vn_stage_free.argtypes = [c.c_void_p]
-            lib.vn_stage_total.restype = c.c_longlong
-            lib.vn_stage_total.argtypes = [c.c_void_p]
-            lib.vn_stage_pending.restype = c.c_longlong
-            lib.vn_stage_pending.argtypes = [c.c_void_p]
-            lib.vn_stage_drain_delta.restype = c.c_int64
-            lib.vn_stage_drain_delta.argtypes = [
-                c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
-                c.c_void_p, c.c_int64]
-            lib.vn_stage_unit_wts.restype = c.c_int
-            lib.vn_stage_unit_wts.argtypes = [c.c_void_p]
-            lib.vn_reader_start.restype = c.c_void_p
-            lib.vn_reader_start.argtypes = [
-                c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int]
-            lib.vn_reader_packets.restype = c.c_longlong
-            lib.vn_reader_packets.argtypes = [c.c_void_p]
-            lib.vn_reader_stop.restype = c.c_longlong
-            lib.vn_reader_stop.argtypes = [c.c_void_p]
-            lib.vn_stream_reader_start.restype = c.c_void_p
-            lib.vn_stream_reader_start.argtypes = [
-                c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int]
-            lib.vn_stream_reader_stop.restype = c.c_longlong
-            lib.vn_stream_reader_stop.argtypes = [c.c_void_p]
-            lib.vn_stream_reader_done.restype = c.c_int
-            lib.vn_stream_reader_done.argtypes = [c.c_void_p]
-            lib.vn_ssf_reader_start.restype = c.c_void_p
-            lib.vn_ssf_reader_start.argtypes = [
-                c.c_void_p, c.c_int, c.c_int, c.c_char_p, c.c_int,
-                c.c_char_p, c.c_int, c.c_double]
-            lib.vn_ssf_reader_stop.restype = c.c_longlong
-            lib.vn_ssf_reader_stop.argtypes = [c.c_void_p]
-            lib.vn_drain_ssf_fallback.restype = c.c_int
-            lib.vn_drain_ssf_fallback.argtypes = [
-                c.c_void_p, c.c_char_p, c.c_int]
-        except AttributeError:
-            pass
-        try:
-            # reader-shard API: home-aware routed ingest (events/errors
-            # land on the caller's own shard) and reader constructors
-            # that take a home shard. Absent on a stale .so — callers
-            # fall back to the shard-0 funnel behaviour.
-            lib.vn_ingest_home.restype = c.c_int
-            lib.vn_ingest_home.argtypes = [
-                c.POINTER(c.c_void_p), c.c_int, c.c_char_p, c.c_int,
-                c.c_int]
-            lib.vn_reader_start2.restype = c.c_void_p
-            lib.vn_reader_start2.argtypes = [
-                c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int, c.c_int]
-            lib.vn_stream_reader_start2.restype = c.c_void_p
-            lib.vn_stream_reader_start2.argtypes = [
-                c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int, c.c_int]
-        except AttributeError:  # pre-reader-shard library
-            pass
-        _lib = lib
-        return _lib
+    return _load(_LIB_PATH, "vn_source_hash", _LIB_SOURCES, _bind_library)
+
+
+def _bind_library(lib) -> None:
+    c = ctypes
+    lib.vn_encode_histo_batch.restype = c.c_longlong
+    lib.vn_encode_histo_batch.argtypes = [
+        c.c_char_p, c.c_longlong,
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
+        c.c_void_p, c.c_int, c.c_int,
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_double,
+        c.POINTER(c.c_char_p)]
+    P = c.POINTER
+    lib.vn_decode_metric_batch.restype = c.c_longlong
+    lib.vn_decode_metric_batch.argtypes = [
+        c.c_char_p, c.c_longlong,
+        P(c.c_char_p), P(c.c_longlong),          # meta
+        P(c.c_void_p), P(c.c_void_p),            # kinds, scopes
+        P(c.c_void_p), P(c.c_void_p),            # value_kind, digests
+        P(c.c_void_p),                           # scalars
+        P(c.c_void_p), P(c.c_void_p), P(c.c_void_p),  # dmin/max/rec
+        P(c.c_void_p),                           # compression
+        P(c.c_void_p), P(c.c_void_p), P(c.c_void_p),  # centroids
+        P(c.c_void_p), P(c.c_char_p), P(c.c_void_p),  # hll
+        P(c.c_void_p), P(c.c_void_p),  # record byte ranges
+        P(c.c_void_p)]  # ring hashes
+    lib.vn_upsert_many.restype = c.c_longlong
+    lib.vn_upsert_many.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_longlong,
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_longlong,
+        c.c_void_p]
+    lib.vn_encode_datadog_series.restype = c.c_longlong
+    lib.vn_encode_datadog_series.argtypes = [
+        c.c_char_p, c.c_longlong, c.c_longlong,       # meta
+        c.c_char_p, c.c_longlong,                     # suffixes
+        c.c_void_p, c.c_int,                          # types, nfam
+        c.c_void_p, c.c_void_p,                       # values, masks
+        c.c_longlong, c.c_double,                     # ts, interval
+        c.c_char_p, c.c_longlong,                     # hostname
+        c.c_char_p, c.c_longlong,                     # common tags
+        c.c_char_p, c.c_longlong,                     # excl keys
+        c.c_char_p, c.c_longlong,                     # excl prefixes
+        c.c_char_p, c.c_longlong,                     # drop prefixes
+        c.c_longlong,                                 # max_per_body
+        c.POINTER(c.c_void_p), c.POINTER(c.c_char_p),
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
+    lib.vn_encode_signalfx_body.restype = c.c_longlong
+    lib.vn_encode_signalfx_body.argtypes = [
+        c.c_char_p, c.c_longlong, c.c_longlong,
+        c.c_char_p, c.c_longlong,
+        c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
+        c.c_longlong,
+        c.c_char_p, c.c_longlong, c.c_char_p, c.c_longlong,
+        c.c_char_p, c.c_longlong, c.c_char_p, c.c_longlong,
+        c.c_char_p, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    lib.vn_encode_prometheus_lines.restype = c.c_longlong
+    lib.vn_encode_prometheus_lines.argtypes = [
+        c.c_char_p, c.c_longlong, c.c_longlong,
+        c.c_char_p, c.c_longlong,
+        c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
+        c.c_char_p, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    # emit tier (native/emit.cpp): forward lines, exposition
+    # text, and the GIL-free deflate pass
+    lib.vn_encode_forward_lines.restype = c.c_longlong
+    lib.vn_encode_forward_lines.argtypes = (
+        lib.vn_encode_prometheus_lines.argtypes)
+    lib.vn_encode_prometheus_exposition.restype = c.c_longlong
+    lib.vn_encode_prometheus_exposition.argtypes = (
+        lib.vn_encode_prometheus_lines.argtypes)
+    lib.vn_deflate.restype = c.c_longlong
+    lib.vn_deflate.argtypes = [
+        c.c_char_p, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    lib.vn_deflate_chunks.restype = c.c_longlong
+    lib.vn_deflate_chunks.argtypes = [
+        c.c_char_p, c.c_void_p, c.c_longlong,
+        c.POINTER(c.c_void_p), c.POINTER(c.c_char_p),
+        c.POINTER(c.c_longlong)]
+    # archive tier (native/emit.cpp): VMB1 columnar sections
+    lib.vn_encode_archive_section.restype = c.c_longlong
+    lib.vn_encode_archive_section.argtypes = [
+        c.c_char_p, c.c_longlong, c.c_longlong,
+        c.c_char_p, c.c_longlong,
+        c.c_void_p, c.c_int, c.c_void_p, c.c_void_p,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    # forward frame codec (native/forward_codec.cpp): VSF1
+    # stream frames/acks + the VDE1 dedup envelope header
+    lib.vn_stream_frame_encode.restype = c.c_longlong
+    lib.vn_stream_frame_encode.argtypes = [
+        c.c_ulonglong, c.c_char_p, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    lib.vn_stream_frame_decode.restype = c.c_longlong
+    lib.vn_stream_frame_decode.argtypes = [
+        c.c_char_p, c.c_longlong, c.POINTER(c.c_ulonglong)]
+    lib.vn_stream_ack_encode.restype = c.c_longlong
+    lib.vn_stream_ack_encode.argtypes = [
+        c.c_ulonglong, c.c_int, c.c_char_p]
+    lib.vn_stream_ack_decode.restype = c.c_longlong
+    lib.vn_stream_ack_decode.argtypes = [
+        c.c_char_p, c.c_longlong, c.POINTER(c.c_ulonglong)]
+    lib.vn_dedup_header_encode.restype = c.c_longlong
+    lib.vn_dedup_header_encode.argtypes = [
+        c.c_char_p, c.c_longlong, c.c_longlong, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong)]
+    lib.vn_dedup_header_parse.restype = c.c_longlong
+    lib.vn_dedup_header_parse.argtypes = [
+        c.c_char_p, c.c_longlong,
+        c.POINTER(c.c_char_p), c.POINTER(c.c_longlong),
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
+    lib.vn_set_lock_stats.argtypes = [c.c_int]
+    lib.vn_lock_stats.restype = c.c_int
+    lib.vn_lock_stats.argtypes = [
+        c.c_void_p, c.POINTER(c.c_longlong),
+        c.POINTER(c.c_longlong), c.POINTER(c.c_longlong), c.c_int]
+    lib.vn_lock_stats_reset.argtypes = [c.c_void_p]
+    lib.vn_ctx_new.restype = c.c_void_p
+    lib.vn_ctx_new.argtypes = [c.c_int]
+    lib.vn_ctx_free.argtypes = [c.c_void_p]
+    lib.vn_ctx_reset.argtypes = [c.c_void_p]
+    lib.vn_ingest.restype = c.c_int
+    lib.vn_ingest.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
+    for name in ("vn_pending_histo", "vn_pending_set",
+                 "vn_pending_counter", "vn_pending_gauge",
+                 "vn_num_histo_rows", "vn_num_set_rows",
+                 "vn_num_counter_rows", "vn_num_gauge_rows"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_int
+        fn.argtypes = [c.c_void_p]
+    for name in ("vn_processed", "vn_errors"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_longlong
+        fn.argtypes = [c.c_void_p]
+    lib.vn_overload_dropped.restype = c.c_longlong
+    lib.vn_overload_dropped.argtypes = [c.c_void_p]
+    lib.vn_set_spill_cap.restype = None
+    lib.vn_set_spill_cap.argtypes = [c.c_void_p, c.c_longlong]
+    lib.vn_reader_ns.restype = None
+    lib.vn_reader_ns.argtypes = [
+        c.c_void_p, c.POINTER(c.c_longlong)]
+    lib.vn_commit_counters.restype = None
+    lib.vn_commit_counters.argtypes = [
+        c.c_void_p, c.POINTER(c.c_longlong)]
+    lib.vn_drain_histo.restype = c.c_int
+    lib.vn_drain_histo.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
+    lib.vn_drain_set.restype = c.c_int
+    lib.vn_drain_set.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
+    lib.vn_drain_counter.restype = c.c_int
+    lib.vn_drain_counter.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
+    lib.vn_drain_gauge.restype = c.c_int
+    lib.vn_drain_gauge.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_int]
+    lib.vn_drain_new_series.restype = c.c_int
+    lib.vn_drain_new_series.argtypes = (
+        [c.c_void_p] + [c.POINTER(c.c_void_p)] * 6
+        + [c.POINTER(c.c_int), c.POINTER(c.c_void_p),
+           c.POINTER(c.c_longlong), c.POINTER(c.c_uint)])
+    lib.vn_set_intern_cap.restype = None
+    lib.vn_set_intern_cap.argtypes = [c.c_void_p, c.c_longlong]
+    lib.vn_pending_new_series.restype = c.c_int
+    lib.vn_pending_new_series.argtypes = [c.c_void_p]
+    lib.vn_drain_other.restype = c.c_int
+    lib.vn_drain_other.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
+    lib.vn_upsert.restype = c.c_int
+    lib.vn_upsert.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_int, c.c_int, c.c_char_p, c.c_int,
+        c.c_int]
+    lib.vn_ingest_ssf.restype = c.c_int
+    lib.vn_ingest_ssf.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_int, c.c_char_p, c.c_int,
+        c.c_char_p, c.c_int, c.c_double]
+    lib.vn_ssf_spans.restype = c.c_longlong
+    lib.vn_ssf_spans.argtypes = [c.c_void_p]
+    lib.vn_ssf_invalid.restype = c.c_longlong
+    lib.vn_ssf_invalid.argtypes = [c.c_void_p]
+    lib.vn_drain_ssf_services.restype = c.c_int
+    lib.vn_drain_ssf_services.argtypes = [c.c_void_p, c.c_char_p, c.c_int]
+    lib.vn_ctx_set_metro.argtypes = [c.c_void_p, c.c_int]
+    lib.vn_metro_hash64.restype = c.c_uint64
+    lib.vn_metro_hash64.argtypes = [c.c_char_p, c.c_int, c.c_uint64]
+    lib.vn_ingest_routed.restype = c.c_int
+    lib.vn_ingest_routed.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_char_p, c.c_int]
+    lib.vn_lock.argtypes = [c.c_void_p]
+    lib.vn_unlock.argtypes = [c.c_void_p]
+    lib.vn_ingest_ssf_many.restype = c.c_int
+    lib.vn_ingest_ssf_many.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_longlong, c.c_char_p, c.c_int,
+        c.c_char_p, c.c_int, c.c_double, c.POINTER(c.c_int),
+        c.c_void_p, c.c_void_p, c.c_int, c.POINTER(c.c_int)]
+    lib.vn_set_stage_depth.argtypes = [c.c_void_p, c.c_int]
+    lib.vn_stage_detach.restype = c.c_void_p
+    lib.vn_stage_detach.argtypes = [
+        c.c_void_p, c.POINTER(c.POINTER(c.c_float)),
+        c.POINTER(c.POINTER(c.c_float)),
+        c.POINTER(c.POINTER(c.c_int32)),
+        c.POINTER(c.c_int32), c.POINTER(c.c_int32)]
+    lib.vn_stage_free.argtypes = [c.c_void_p]
+    lib.vn_stage_total.restype = c.c_longlong
+    lib.vn_stage_total.argtypes = [c.c_void_p]
+    lib.vn_stage_pending.restype = c.c_longlong
+    lib.vn_stage_pending.argtypes = [c.c_void_p]
+    lib.vn_stage_drain_delta.restype = c.c_int64
+    lib.vn_stage_drain_delta.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
+        c.c_void_p, c.c_int64]
+    lib.vn_stage_unit_wts.restype = c.c_int
+    lib.vn_stage_unit_wts.argtypes = [c.c_void_p]
+    lib.vn_reader_start.restype = c.c_void_p
+    lib.vn_reader_start.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int]
+    lib.vn_reader_packets.restype = c.c_longlong
+    lib.vn_reader_packets.argtypes = [c.c_void_p]
+    lib.vn_reader_stop.restype = c.c_longlong
+    lib.vn_reader_stop.argtypes = [c.c_void_p]
+    lib.vn_stream_reader_start.restype = c.c_void_p
+    lib.vn_stream_reader_start.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int]
+    lib.vn_stream_reader_stop.restype = c.c_longlong
+    lib.vn_stream_reader_stop.argtypes = [c.c_void_p]
+    lib.vn_stream_reader_done.restype = c.c_int
+    lib.vn_stream_reader_done.argtypes = [c.c_void_p]
+    lib.vn_ssf_reader_start.restype = c.c_void_p
+    lib.vn_ssf_reader_start.argtypes = [
+        c.c_void_p, c.c_int, c.c_int, c.c_char_p, c.c_int,
+        c.c_char_p, c.c_int, c.c_double]
+    lib.vn_ssf_reader_stop.restype = c.c_longlong
+    lib.vn_ssf_reader_stop.argtypes = [c.c_void_p]
+    lib.vn_drain_ssf_fallback.restype = c.c_int
+    lib.vn_drain_ssf_fallback.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_int]
+    # reader-shard API: home-aware routed ingest (events/errors
+    # land on the caller's own shard) and reader constructors
+    # that take a home shard
+    lib.vn_ingest_home.restype = c.c_int
+    lib.vn_ingest_home.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_char_p, c.c_int,
+        c.c_int]
+    lib.vn_reader_start2.restype = c.c_void_p
+    lib.vn_reader_start2.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int, c.c_int]
+    lib.vn_stream_reader_start2.restype = c.c_void_p
+    lib.vn_stream_reader_start2.argtypes = [
+        c.POINTER(c.c_void_p), c.c_int, c.c_int, c.c_int, c.c_int]
 
 
 def _ptr(arr: np.ndarray):
@@ -392,6 +391,21 @@ class NewSeriesBatch:
                         self.first_kinds.tolist(),
                         self.first_scopes.tolist(),
                         self.first_names, self.first_tags))
+
+
+def _lock_stats(lib, ctx) -> dict:
+    totals = (ctypes.c_longlong * 5)()
+    wait = (ctypes.c_longlong * 4096)()
+    hold = (ctypes.c_longlong * 4096)()
+    n = lib.vn_lock_stats(ctx, totals, wait, hold, 4096)
+    return {
+        "acquisitions": int(totals[0]),
+        "contended": int(totals[1]),
+        "wait_ns_total": int(totals[2]),
+        "hold_ns_total": int(totals[3]),
+        "wait_ns_samples": [int(wait[i]) for i in range(n)],
+        "hold_ns_samples": [int(hold[i]) for i in range(n)],
+    }
 
 
 class NativeIngest:
@@ -445,16 +459,14 @@ class NativeIngest:
         """Shared-nothing ingest: parse lock-free, commit every line into
         THIS context under its own (uncontended on the reader-shard path)
         mutex — the in-process twin of an owned C++ reader thread.
-        Events/service checks and parse errors stay on this context too.
-        Raises AttributeError on a stale .so."""
+        Events/service checks and parse errors stay on this context too."""
         return self._lib.vn_ingest_home(
             self._self_arr(), 1, datagram, len(datagram), 0)
 
     def start_owned_reader(self, fd: int, max_len: int):
         """Spawn a C++ reader thread committing exclusively into this
         context (the shared-nothing per-reader shape; same fd/stop
-        contract as NativeRouter.start_reader). Raises AttributeError on
-        a stale .so."""
+        contract as NativeRouter.start_reader)."""
         h = self._lib.vn_reader_start2(self._self_arr(), 1, fd, max_len, 0)
         if not h:
             raise RuntimeError("vn_reader_start2 failed")
@@ -462,29 +474,11 @@ class NativeIngest:
 
     def lock_stats(self) -> dict:
         """This context's commit-mutex contention record (same shape as
-        NativeRouter.lock_stats); zeros on a stale .so."""
-        fn = getattr(self._lib, "vn_lock_stats", None)
-        if fn is None:
-            return {"acquisitions": 0, "contended": 0, "wait_ns_total": 0,
-                    "hold_ns_total": 0, "wait_ns_samples": [],
-                    "hold_ns_samples": []}
-        totals = (ctypes.c_longlong * 5)()
-        wait = (ctypes.c_longlong * 4096)()
-        hold = (ctypes.c_longlong * 4096)()
-        n = fn(self._ctx, totals, wait, hold, 4096)
-        return {
-            "acquisitions": int(totals[0]),
-            "contended": int(totals[1]),
-            "wait_ns_total": int(totals[2]),
-            "hold_ns_total": int(totals[3]),
-            "wait_ns_samples": [int(wait[i]) for i in range(n)],
-            "hold_ns_samples": [int(hold[i]) for i in range(n)],
-        }
+        NativeRouter.lock_stats)."""
+        return _lock_stats(self._lib, self._ctx)
 
     def reset_lock_stats(self) -> None:
-        fn = getattr(self._lib, "vn_lock_stats_reset", None)
-        if fn is not None:
-            fn(self._ctx)
+        self._lib.vn_lock_stats_reset(self._ctx)
 
     # pending counts ---------------------------------------------------------
 
@@ -515,13 +509,11 @@ class NativeIngest:
     @property
     def overload_dropped(self) -> int:
         """Samples shed at the pending-batch spill caps (overload)."""
-        fn = getattr(self._lib, "vn_overload_dropped", None)
-        return int(fn(self._ctx)) if fn is not None else 0
+        return int(self._lib.vn_overload_dropped(self._ctx))
 
     def reader_ns(self) -> tuple:
         """(ns inside recv, ns outside it) of the C++ reader threads
-        homed on this context: lifetime totals, never reset. Raises
-        AttributeError on a stale .so (callers degrade)."""
+        homed on this context: lifetime totals, never reset."""
         out = (ctypes.c_longlong * 2)()
         self._lib.vn_reader_ns(self._ctx, out)
         return int(out[0]), int(out[1])
@@ -535,16 +527,14 @@ class NativeIngest:
         this interval (dir_hits), known but not yet written this
         interval (dir_restamped) or never seen (dir_first_seen);
         commit_batches lock holds of the chunk commit took commit_lines
-        lines; plane_grows reallocations of the staging plane. Raises
-        AttributeError on a stale .so (callers degrade)."""
+        lines; plane_grows reallocations of the staging plane."""
         out = (ctypes.c_longlong * len(self.COMMIT_COUNTERS))()
         self._lib.vn_commit_counters(self._ctx, out)
         return dict(zip(self.COMMIT_COUNTERS, map(int, out)))
 
     def set_spill_cap(self, cap: int) -> None:
         """Entries per pending SoA batch before samples shed (tests /
-        memory-constrained deployments; default 2^22). Raises
-        AttributeError on a stale .so (callers degrade)."""
+        memory-constrained deployments; default 2^22)."""
         self._lib.vn_set_spill_cap(self._ctx, int(cap))
 
     def num_rows(self) -> tuple[int, int, int, int]:
@@ -570,17 +560,15 @@ class NativeIngest:
     @property
     def stage_pending(self) -> int:
         """Staged samples not yet copied out by drain_stage_delta
-        (micro-fold due checks). 0 on a stale .so without the API."""
-        fn = getattr(self._lib, "vn_stage_pending", None)
-        return int(fn(self._ctx)) if fn is not None else 0
+        (micro-fold due checks)."""
+        return int(self._lib.vn_stage_pending(self._ctx))
 
     def drain_stage_delta(self, cap: int):
         """Copy up to `cap` not-yet-drained staged samples out as COO
         (rows, slots, vals, wts) with ABSOLUTE slot positions, advancing
         the plane's per-row drained watermark. The plane's counts are
         untouched, so the per-epoch depth cap (and the spill
-        partitioning) is identical to a run with no micro-folds. Raises
-        AttributeError on a stale .so (callers gate on stage_pending)."""
+        partitioning) is identical to a run with no micro-folds."""
         rows = np.empty(cap, np.int32)
         slots = np.empty(cap, np.int32)
         vals = np.empty(cap, np.float32)
@@ -613,10 +601,7 @@ class NativeIngest:
         vals = np.ctypeslib.as_array(pv, shape=(r, d))
         wts = np.ctypeslib.as_array(pw, shape=(r, d))
         counts = np.ctypeslib.as_array(pc, shape=(r,))
-        try:
-            unit = bool(self._lib.vn_stage_unit_wts(handle))
-        except AttributeError:
-            unit = False
+        unit = bool(self._lib.vn_stage_unit_wts(handle))
         lib = self._lib
 
         def free(_h=handle, _lib=lib):
@@ -864,8 +849,7 @@ def emit_available() -> bool:
     if os.environ.get("VENEUR_EMIT_NATIVE", "").lower() in (
             "0", "false", "off", "no"):
         return False
-    lib = load_library()
-    return lib is not None and hasattr(lib, "vn_deflate")
+    return load_library() is not None
 
 
 def codec_available() -> bool:
@@ -877,8 +861,7 @@ def codec_available() -> bool:
     if os.environ.get("VENEUR_CODEC_NATIVE", "").lower() in (
             "0", "false", "off", "no"):
         return False
-    lib = load_library()
-    return lib is not None and hasattr(lib, "vn_stream_frame_encode")
+    return load_library() is not None
 
 
 def _blob_arg(blob) -> tuple:
@@ -903,9 +886,9 @@ def encode_histo_batch(meta_blob: bytes, kinds: np.ndarray,
                        compression: float) -> Optional[bytes]:
     """Histogram rows -> veneurtpu.MetricBatch wire bytes at C++ speed
     (see native/dogstatsd.cpp vn_encode_histo_batch). Returns None when
-    the native library (or the symbol) is unavailable."""
+    the native library is unavailable."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_encode_histo_batch"):
+    if lib is None:
         return None
     rows, cap = means.shape
     means = np.ascontiguousarray(means, np.float32)
@@ -950,10 +933,10 @@ def _copy_arr(ptr: "ctypes.c_void_p", count: int, dtype) -> np.ndarray:
 def decode_metric_batch(blob: bytes) -> Optional[DecodedBatch]:
     """Parse serialized veneurtpu.MetricBatch wire bytes into SoA arrays
     via the C++ decoder (native/dogstatsd.cpp vn_decode_metric_batch).
-    Returns None when the library lacks the symbol or the input is
+    Returns None when there is no library or the input is
     malformed (callers fall back to the Python protobuf path)."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_decode_metric_batch"):
+    if lib is None:
         return None
     c = ctypes
     meta = c.c_char_p()
@@ -1028,14 +1011,12 @@ def encode_datadog_series(meta_blob: bytes, nrows: int,
                           ) -> "Optional[tuple[list[bytes], int]]":
     """Chunked Datadog {"series": [...]} bodies straight from columnar
     arrays (native/emit.cpp vn_encode_datadog_series). Returns
-    (bodies, emitted_count), or None when the library lacks the
-    symbol. compress=True deflates every chunk natively before it is
+    (bodies, emitted_count), or None when there is no library.
+    compress=True deflates every chunk natively before it is
     copied out (vn_deflate_chunks; byte-identical to zlib.compress),
     so only compressed bytes cross back into Python."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_encode_datadog_series"):
-        return None
-    if compress and not hasattr(lib, "vn_deflate_chunks"):
+    if lib is None:
         return None
     c = ctypes
     values = np.ascontiguousarray(values, np.float64)
@@ -1090,7 +1071,7 @@ def encode_signalfx_body(meta_blob: bytes, nrows: int,
     """One SignalFx {"counter":[...],"gauge":[...]} body from columnar
     arrays; (body, emitted_count), or None when unavailable."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_encode_signalfx_body"):
+    if lib is None:
         return None
     c = ctypes
     values = np.ascontiguousarray(values, np.float64)
@@ -1122,9 +1103,9 @@ def _encode_lines(symbol: str, meta_blob, nrows: int,
                   ) -> "Optional[tuple[bytes, int]]":
     """Shared wrapper for the line-oriented emitters (statsd lines,
     forward lines, exposition text): one newline-joined buffer plus the
-    emitted count; None when the library lacks the symbol."""
+    emitted count; None when there is no library."""
     lib = load_library()
-    if lib is None or not hasattr(lib, symbol):
+    if lib is None:
         return None
     c = ctypes
     values = np.ascontiguousarray(values, np.float64)
@@ -1151,9 +1132,9 @@ def encode_archive_section(meta_blob, nrows: int,
                            ) -> "Optional[bytes]":
     """One VMB1 columnar section body (archive/wire.py) straight from an
     EmitGroupPlan's buffers, GIL-free; byte-identical to the Python
-    encoder. None when the library lacks the symbol."""
+    encoder. None when there is no library."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_encode_archive_section"):
+    if lib is None:
         return None
     c = ctypes
     values = np.ascontiguousarray(values, np.float64)
@@ -1179,7 +1160,7 @@ def encode_prometheus_lines(meta_blob, nrows: int,
                             excluded_keys: list[str]
                             ) -> "Optional[tuple[bytes, int]]":
     """statsd repeater lines from columnar arrays (one newline-joined
-    buffer + line count); None when the library lacks the symbol."""
+    buffer + line count); None when there is no library."""
     return _encode_lines("vn_encode_prometheus_lines", meta_blob, nrows,
                          suffixes, family_types, values, masks,
                          excluded_keys)
@@ -1212,9 +1193,9 @@ def encode_prometheus_exposition(meta_blob, nrows: int,
 def deflate(data: bytes) -> Optional[bytes]:
     """zlib deflate with the GIL released (native/emit.cpp vn_deflate);
     byte-identical to zlib.compress(data) — both drive the system zlib
-    at default level. None when the library lacks the symbol."""
+    at default level. None when there is no library."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_deflate"):
+    if lib is None:
         return None
     c = ctypes
     out = c.c_char_p()
@@ -1231,7 +1212,7 @@ def stream_frame_encode(seq: int, body: bytes) -> Optional[bytes]:
     falls back to the Python reference (library or symbol missing,
     seq outside u64)."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_stream_frame_encode"):
+    if lib is None:
         return None
     if not 0 <= seq < 1 << 64:
         return None  # Python raises OverflowError; keep that path
@@ -1249,7 +1230,7 @@ def stream_frame_decode(blob: bytes) -> "Optional[tuple[int, bytes]]":
     raises the pinned ValueError) or a missing library — callers
     distinguish the two with codec_available()."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_stream_frame_decode"):
+    if lib is None:
         return None
     c = ctypes
     seq = c.c_ulonglong()
@@ -1262,7 +1243,7 @@ def stream_frame_decode(blob: bytes) -> "Optional[tuple[int, bytes]]":
 def stream_ack_encode(seq: int, status: int) -> Optional[bytes]:
     """9 ack bytes (u64 LE seq + u8 status); None -> Python fallback."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_stream_ack_encode"):
+    if lib is None:
         return None
     if not 0 <= seq < 1 << 64 or not 0 <= status <= 0xFF:
         return None  # Python raises Overflow/ValueError; keep that path
@@ -1275,7 +1256,7 @@ def stream_ack_decode(blob: bytes) -> "Optional[tuple[int, int]]":
     """(seq, status) for a 9-byte ack; None on a non-ack blob or a
     missing library."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_stream_ack_decode"):
+    if lib is None:
         return None
     c = ctypes
     seq = c.c_ulonglong()
@@ -1292,7 +1273,7 @@ def dedup_header_encode(sender: bytes, dedup_id: int,
     Python fallback (ints outside i64, malformed UTF-8); ValueError
     for the pinned too-large header."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_dedup_header_encode"):
+    if lib is None:
         return None
     if not (-(1 << 63) <= dedup_id < 1 << 63
             and -(1 << 63) <= count < 1 << 63):
@@ -1315,7 +1296,7 @@ def dedup_header_parse(hdr: bytes) -> "Optional[tuple[str, int, int]]":
     the header isn't canonical (caller falls back to json.loads for
     the exact Python semantics) or the library is missing."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "vn_dedup_header_parse"):
+    if lib is None:
         return None
     c = ctypes
     sender = c.c_char_p()
@@ -1333,15 +1314,10 @@ def dedup_header_parse(hdr: bytes) -> "Optional[tuple[str, int, int]]":
 
 def source_hash() -> str:
     """Build stamp of the loaded library (sha256 prefix of
-    dogstatsd.cpp + emit.cpp concatenated at build time); '' when no
-    library is loadable, 'unstamped' for a pre-stamp build."""
+    dogstatsd.cpp + emit.cpp + forward_codec.cpp concatenated at build
+    time); '' when no library is loadable."""
     lib = load_library()
-    if lib is None:
-        return ""
-    try:
-        return lib.vn_source_hash().decode()
-    except AttributeError:
-        return "unstamped"
+    return lib.vn_source_hash().decode() if lib is not None else ""
 
 
 class NativeRouter:
@@ -1372,12 +1348,9 @@ class NativeRouter:
         alive); stop_reader() joins without closing it, preserving
         fd-handoff semantics. `home` picks the shard that absorbs this
         reader's events/service checks and parse errors (spreading the
-        funnel across workers); 0 on a stale .so without the API."""
-        start2 = getattr(self._lib, "vn_reader_start2", None)
-        if home and start2 is not None:
-            h = start2(self._arr, self._n, fd, max_len, home % self._n)
-        else:
-            h = self._lib.vn_reader_start(self._arr, self._n, fd, max_len)
+        funnel across workers)."""
+        h = self._lib.vn_reader_start2(
+            self._arr, self._n, fd, max_len, home % self._n)
         if not h:
             raise RuntimeError("vn_reader_start failed")
         return h
@@ -1397,12 +1370,8 @@ class NativeRouter:
         finished readers with stream_reader_done + stop_stream_reader.
         `home` routes this connection's events/errors like
         start_reader's."""
-        start2 = getattr(self._lib, "vn_stream_reader_start2", None)
-        if home and start2 is not None:
-            h = start2(self._arr, self._n, fd, max_len, home % self._n)
-        else:
-            h = self._lib.vn_stream_reader_start(self._arr, self._n, fd,
-                                                 max_len)
+        h = self._lib.vn_stream_reader_start2(
+            self._arr, self._n, fd, max_len, home % self._n)
         if not h:
             raise RuntimeError("vn_stream_reader_start failed")
         return h
@@ -1436,19 +1405,7 @@ class NativeRouter:
     def lock_stats(self, shard: int) -> dict:
         """Contention record for one shard's mutex: totals plus the most
         recent (up to 4096) wait/hold samples in ns."""
-        totals = (ctypes.c_longlong * 5)()
-        wait = (ctypes.c_longlong * 4096)()
-        hold = (ctypes.c_longlong * 4096)()
-        n = self._lib.vn_lock_stats(
-            self._contexts[shard]._ctx, totals, wait, hold, 4096)
-        return {
-            "acquisitions": int(totals[0]),
-            "contended": int(totals[1]),
-            "wait_ns_total": int(totals[2]),
-            "hold_ns_total": int(totals[3]),
-            "wait_ns_samples": [int(wait[i]) for i in range(n)],
-            "hold_ns_samples": [int(hold[i]) for i in range(n)],
-        }
+        return _lock_stats(self._lib, self._contexts[shard]._ctx)
 
     def reset_lock_stats(self) -> None:
         for c in self._contexts:
@@ -1462,70 +1419,63 @@ class NativeRouter:
 
 
 def load_loadgen_library() -> Optional[ctypes.CDLL]:
-    global _lg_lib
-    with _lg_lock:
-        if _lg_lib is not None:
-            return _lg_lib
-        if not _build() and not os.path.exists(_LOADGEN_PATH):
-            return None
-        if not os.path.exists(_LOADGEN_PATH):
-            return None
-        lib = ctypes.CDLL(_LOADGEN_PATH)
-        c = ctypes
-        lib.vn_lg_source_hash.restype = c.c_char_p
-        lib.vn_lg_ring_new.restype = c.c_void_p
-        lib.vn_lg_ring_free.argtypes = [c.c_void_p]
-        lib.vn_lg_ring_count.restype = c.c_longlong
-        lib.vn_lg_ring_count.argtypes = [c.c_void_p]
-        lib.vn_lg_ring_total_lines.restype = c.c_longlong
-        lib.vn_lg_ring_total_lines.argtypes = [c.c_void_p]
-        lib.vn_lg_ring_total_bytes.restype = c.c_longlong
-        lib.vn_lg_ring_total_bytes.argtypes = [c.c_void_p]
-        lib.vn_lg_ring_hash.restype = c.c_uint64
-        lib.vn_lg_ring_hash.argtypes = [c.c_void_p]
-        lib.vn_lg_ring_datagram.restype = c.c_longlong
-        lib.vn_lg_ring_datagram.argtypes = [
-            c.c_void_p, c.c_longlong, c.POINTER(c.c_char_p)]
-        lib.vn_lg_ring_append.restype = c.c_longlong
-        lib.vn_lg_ring_append.argtypes = [
-            c.c_void_p, c.c_char_p, c.c_longlong, c.c_int]
-        lib.vn_lg_ring_synth.restype = c.c_longlong
-        lib.vn_lg_ring_synth.argtypes = [
-            c.c_void_p, c.c_uint64, c.c_longlong, c.c_double,
-            c.POINTER(c.c_double), c.c_int, c.c_longlong,
-            c.c_char_p, c.c_int, c.c_int, c.c_longlong,
-            c.c_longlong, c.c_double, c.c_double, c.c_longlong]
-        lib.vn_lg_ring_serialize.restype = c.c_longlong
-        lib.vn_lg_ring_serialize.argtypes = [
-            c.c_void_p, c.POINTER(c.c_char_p)]
-        lib.vn_lg_ring_load.restype = c.c_longlong
-        lib.vn_lg_ring_load.argtypes = [c.c_void_p, c.c_char_p,
-                                        c.c_longlong]
-        lib.vn_lg_send_start.restype = c.c_void_p
-        lib.vn_lg_send_start.argtypes = [
-            c.c_void_p, c.c_int, c.c_double, c.c_longlong, c.c_int]
-        for name in ("vn_lg_send_lines", "vn_lg_send_packets",
-                     "vn_lg_send_errors", "vn_lg_send_resyncs",
-                     "vn_lg_send_stop"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_longlong
-            fn.argtypes = [c.c_void_p]
-        lib.vn_lg_send_done.restype = c.c_int
-        lib.vn_lg_send_done.argtypes = [c.c_void_p]
-        lib.vn_lg_send_free.restype = None
-        lib.vn_lg_send_free.argtypes = [c.c_void_p]
-        lib.vn_lg_capture_start.restype = c.c_void_p
-        lib.vn_lg_capture_start.argtypes = [c.c_int, c.c_int, c.c_longlong]
-        for name in ("vn_lg_capture_packets", "vn_lg_capture_truncated",
-                     "vn_lg_capture_stop"):
-            fn = getattr(lib, name)
-            fn.restype = c.c_longlong
-            fn.argtypes = [c.c_void_p]
-        lib.vn_lg_capture_detach_ring.restype = c.c_void_p
-        lib.vn_lg_capture_detach_ring.argtypes = [c.c_void_p]
-        lib.vn_lg_capture_free.argtypes = [c.c_void_p]
-        _lg_lib = lib
-        return _lg_lib
+    return _load(_LOADGEN_PATH, "vn_lg_source_hash", _LOADGEN_SOURCES,
+                 _bind_loadgen)
+
+
+def _bind_loadgen(lib) -> None:
+    c = ctypes
+    lib.vn_lg_ring_new.restype = c.c_void_p
+    lib.vn_lg_ring_free.argtypes = [c.c_void_p]
+    lib.vn_lg_ring_count.restype = c.c_longlong
+    lib.vn_lg_ring_count.argtypes = [c.c_void_p]
+    lib.vn_lg_ring_total_lines.restype = c.c_longlong
+    lib.vn_lg_ring_total_lines.argtypes = [c.c_void_p]
+    lib.vn_lg_ring_total_bytes.restype = c.c_longlong
+    lib.vn_lg_ring_total_bytes.argtypes = [c.c_void_p]
+    lib.vn_lg_ring_hash.restype = c.c_uint64
+    lib.vn_lg_ring_hash.argtypes = [c.c_void_p]
+    lib.vn_lg_ring_datagram.restype = c.c_longlong
+    lib.vn_lg_ring_datagram.argtypes = [
+        c.c_void_p, c.c_longlong, c.POINTER(c.c_char_p)]
+    lib.vn_lg_ring_append.restype = c.c_longlong
+    lib.vn_lg_ring_append.argtypes = [
+        c.c_void_p, c.c_char_p, c.c_longlong, c.c_int]
+    lib.vn_lg_ring_synth.restype = c.c_longlong
+    lib.vn_lg_ring_synth.argtypes = [
+        c.c_void_p, c.c_uint64, c.c_longlong, c.c_double,
+        c.POINTER(c.c_double), c.c_int, c.c_longlong,
+        c.c_char_p, c.c_int, c.c_int, c.c_longlong,
+        c.c_longlong, c.c_double, c.c_double, c.c_longlong]
+    lib.vn_lg_ring_serialize.restype = c.c_longlong
+    lib.vn_lg_ring_serialize.argtypes = [
+        c.c_void_p, c.POINTER(c.c_char_p)]
+    lib.vn_lg_ring_load.restype = c.c_longlong
+    lib.vn_lg_ring_load.argtypes = [c.c_void_p, c.c_char_p,
+                                    c.c_longlong]
+    lib.vn_lg_send_start.restype = c.c_void_p
+    lib.vn_lg_send_start.argtypes = [
+        c.c_void_p, c.c_int, c.c_double, c.c_longlong, c.c_int]
+    for name in ("vn_lg_send_lines", "vn_lg_send_packets",
+                 "vn_lg_send_errors", "vn_lg_send_resyncs",
+                 "vn_lg_send_stop"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_longlong
+        fn.argtypes = [c.c_void_p]
+    lib.vn_lg_send_done.restype = c.c_int
+    lib.vn_lg_send_done.argtypes = [c.c_void_p]
+    lib.vn_lg_send_free.restype = None
+    lib.vn_lg_send_free.argtypes = [c.c_void_p]
+    lib.vn_lg_capture_start.restype = c.c_void_p
+    lib.vn_lg_capture_start.argtypes = [c.c_int, c.c_int, c.c_longlong]
+    for name in ("vn_lg_capture_packets", "vn_lg_capture_truncated",
+                 "vn_lg_capture_stop"):
+        fn = getattr(lib, name)
+        fn.restype = c.c_longlong
+        fn.argtypes = [c.c_void_p]
+    lib.vn_lg_capture_detach_ring.restype = c.c_void_p
+    lib.vn_lg_capture_detach_ring.argtypes = [c.c_void_p]
+    lib.vn_lg_capture_free.argtypes = [c.c_void_p]
 
 
 def loadgen_available() -> bool:

@@ -57,15 +57,13 @@ def _prefix_scans_xla(srows, svals, sw, n):
     product rounded via exn.block before the adds) so the host fallback
     engine's NumPy twin reproduces them bitwise.
 
-    RESOLVED (round 4): a fused two-pass Pallas kernel for these five
-    scans (ops/pallas_scan.py, gated behind VENEUR_FUSED_SCANS) was
-    deleted rather than enabled. The staged-ingest redesign
-    (core/worker._histo_fold_staged) moved add_batch off the hot ingest
-    path — samples stage host-side and the per-interval fold never runs
-    these scans — so the kernel's only remaining callers are the hot-row
-    spill and import merge paths, whose batches are too small for a
-    custom kernel to pay for itself. The Pallas kernel that remains on a
-    hot path is flush_extract (ops/pallas_kernels.py)."""
+    Takes the batch sorted by (row, value): rows, values, weights.
+    Returns the running sums of weight, value*weight and weight/value
+    over the whole batch (each [N+1], leading zero), and each sample's
+    running weight within its own row, counted from the row's first
+    sample (seg_cum) and from its last (suffix). The staged fold
+    (core/worker._histo_fold_staged) does not run these scans: add_batch
+    serves the hot-row spill and the import merge."""
     with jax.named_scope("tdigest.prefix_scans"):
         zero1 = jnp.zeros((1,), sw.dtype)
         pre_w = jnp.concatenate([zero1, exn.cumsum(sw)])  # [N+1]
