@@ -282,6 +282,30 @@ struct NewSeriesQueue {
     first_scopes.clear();
     strs.clear();
   }
+
+  // Reorder the records so that each pool's are together (pool 0 first),
+  // in the order they were queued: rows are handed out per pool, so a
+  // pool's records then carry consecutive rows. first_at follows.
+  void group_by_pool() {
+    const size_t n = size();
+    size_t start[5] = {0, 0, 0, 0, 0};
+    for (int32_t pool : pools) ++start[pool + 1];
+    for (int p = 0; p < 4; ++p)
+      if (start[p + 1] == n) return;  // one pool: grouped as it stands
+    for (int p = 0; p < 4; ++p) start[p + 1] += start[p];
+    std::vector<int32_t> at(n), by_pool(n), by_row(n), by_sid(n);
+    for (size_t i = 0; i < n; ++i) {
+      const size_t to = start[pools[i]]++;
+      at[i] = static_cast<int32_t>(to);
+      by_pool[to] = pools[i];
+      by_row[to] = rows[i];
+      by_sid[to] = sids[i];
+    }
+    pools.swap(by_pool);
+    rows.swap(by_row);
+    sids.swap(by_sid);
+    for (int32_t& first : first_at) first = at[first];
+  }
 };
 
 // Open-addressing directory, one per context for the context's lifetime:
@@ -2731,15 +2755,18 @@ int vn_pending_new_series(void* p) {
 }
 
 // Drain every pending new-series record in one call. The queue is
-// swapped out whole (nothing is copied or shifted under the lock) and
-// the caller is handed pointers into it: pools/rows/sids for all n
-// records, and for the *n_first of them whose strings Python has not
-// been given yet, their positions in those arrays, kinds, scope classes
-// and packed "name\x1fjoined_tags\x1e" records. The pointers stay valid
-// until the next drain of this context; one thread drains a context at
-// a time (NativeIngest.drain_new_series holds the context lock across
-// its copy). *generation changes when the intern table was dropped:
-// every sid the caller holds is then void.
+// swapped out whole and the caller is handed pointers into it:
+// pools/rows/sids for all n records, and for the *n_first of them whose
+// strings Python has not been given yet, their positions in those
+// arrays, kinds, scope classes and packed "name\x1fjoined_tags\x1e"
+// records. The records come grouped by pool (0, 1, 2, 3), each pool's in
+// the order its rows were handed out, so a pool's series are one slice
+// with consecutive rows and the caller sorts nothing.
+// The pointers stay valid until the next drain of this context; one
+// thread drains a context at a time (NativeIngest.drain_new_series
+// sees to that; this lock is held for the swap and the grouping only).
+// *generation changes when the intern table was dropped: every sid the
+// caller holds is then void.
 int vn_drain_new_series(void* p, const int32_t** pools, const int32_t** rows,
                         const int32_t** sids, const int32_t** first_at,
                         const int32_t** first_kinds,
@@ -2752,6 +2779,7 @@ int vn_drain_new_series(void* p, const int32_t** pools, const int32_t** rows,
   q.clear();
   std::swap(q, ctx->new_series);
   for (int32_t at : q.first_at) ctx->sid_handed[q.sids[at]] = 1;
+  q.group_by_pool();
   *pools = q.pools.data();
   *rows = q.rows.data();
   *sids = q.sids.data();

@@ -76,7 +76,7 @@ def make_batch(rows, fams_spec, ts=1700000000, extras=()):
         meta_at=lambda i: (rows[i][0], rows[i][1], None),
         families=fams,
         frag_at=lambda i: build_frag(*rows[i]),
-        meta_blob=arena if clean else None,
+        blob_of=lambda: arena if clean else None,
     )
     return ColumnarMetrics(timestamp=ts, groups=[g], extras=list(extras))
 

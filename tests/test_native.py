@@ -894,6 +894,7 @@ def test_series_keeps_its_sid_across_reset_and_rows_restart():
     assert first.rows.tolist() == [0, 1, 2, 0]
     assert first.pools.tolist() == [0, 0, 0, 2]
     assert first.sids.tolist() == [0, 1, 2, 3]
+    assert first.pool_slices() == [(0, 0, 3), (2, 3, 4)]
     sid = dict(zip([r[2:] for r in first.first_records()],
                    first.sids.tolist()))
     ni.reset()
@@ -901,14 +902,131 @@ def test_series_keeps_its_sid_across_reset_and_rows_restart():
     ni.ingest(b"a:1|c\n" + _lines([b"c", b"new", b"a"])
               + b"\nc:1|ms|#a:1,veneurlocalonly")
     second = ni.drain_new_series()
-    assert second.pools.tolist() == [2, 0, 0, 0, 0]
-    assert second.rows.tolist() == [0, 0, 1, 2, 3]
+    # grouped by pool, a pool's records in the order their rows went out
+    assert second.pools.tolist() == [0, 0, 0, 0, 2]
+    assert second.rows.tolist() == [0, 1, 2, 3, 0]
     assert second.sids.tolist() == [
-        sid[(0, 0, "a", "")], sid[(3, 0, "c", "a:1")], 4,
-        sid[(3, 0, "a", "a:1")], 5]
+        sid[(3, 0, "c", "a:1")], 4, sid[(3, 0, "a", "a:1")], 5,
+        sid[(0, 0, "a", "")]]
+    assert second.pool_slices() == [(0, 0, 4), (2, 4, 5)]
     assert second.first_records() == [
         (0, 1, 3, 0, "new", "a:1"), (0, 3, 3, 1, "c", "a:1")]
     assert second.generation == first.generation
+
+
+@pytest.mark.parametrize("known", [False, True],
+                         ids=["first-seen", "known"])
+def test_a_drain_comes_grouped_by_pool_with_its_strings_in_step(known):
+    """Lines of the four kinds interleaved: the drain hands each pool's
+    records as one slice with consecutive rows, in the order the pool
+    gave the rows out, and a first-seen record's kind, scope and strings
+    still find it there."""
+    ni = native_mod.NativeIngest()
+    lines = []
+    for i in range(40):
+        lines += [b"g%d:1|g" % i, b"t%d:1|ms|#k:%d" % (i, i),
+                  b"s%d:m|s" % i, b"c%d:1|c" % i, b"h%d:1|h" % i]
+    ni.ingest(b"\n".join(lines))
+    batch = ni.drain_new_series()
+    sid_of = dict(zip(batch.first_names, batch.sids[batch.first_at].tolist()))
+    if known:
+        ni.reset()
+        ni.ingest(b"\n".join(reversed(lines)))
+        batch = ni.drain_new_series()
+        assert len(batch.first_at) == 0
+    assert batch.pool_slices() == [(0, 0, 80), (1, 80, 120), (2, 120, 160),
+                                   (3, 160, 200)]
+    for pool, start, stop in batch.pool_slices():
+        assert set(batch.pools[start:stop].tolist()) == {pool}
+        assert batch.rows[start:stop].tolist() == list(range(stop - start))
+    order = range(39, -1, -1) if known else range(40)
+    want = ([n % i for i in order for n in (("h%d", "t%d") if known
+                                            else ("t%d", "h%d"))]
+            + ["s%d" % i for i in order] + ["c%d" % i for i in order]
+            + ["g%d" % i for i in order])
+    assert batch.sids.tolist() == [sid_of[name] for name in want]
+    if not known:
+        records = batch.first_records()
+        assert len(records) == 200
+        pool_of = {"t": 0, "h": 0, "s": 1, "c": 2, "g": 3}
+        for pool, row, kind, _scope, name, joined in records:
+            assert pool == pool_of[name[0]] and row < 80
+            assert kind == {"t": 3, "h": 2, "s": 4, "c": 0, "g": 1}[name[0]]
+            assert joined == ("k:" + name[1:] if name[0] == "t" else "")
+
+
+def test_two_drainers_of_one_context_never_overlap():
+    """The drain's pointers are good until the context's next drain and
+    its out-parameters are shared, so a second drainer waits for the
+    first one's copy (a Python lock; the context's mutex is held inside
+    the call only). Four threads drain while a feeder registers series,
+    and each is held up between the call and its copy, as a thread that
+    waits for the interpreter is: every series is handed over exactly
+    once, and every batch is whole (grouped, a pool's rows consecutive,
+    its strings its own)."""
+    import sys
+    import threading
+
+    class SlowToCopy:
+        def __init__(self, lib):
+            self._lib = lib
+
+        def __getattr__(self, name):
+            return getattr(self._lib, name)
+
+        def vn_drain_new_series(self, *args):
+            n = self._lib.vn_drain_new_series(*args)
+            time.sleep(0.0005)
+            return n
+
+    ni = native_mod.NativeIngest()
+    ni._lib = SlowToCopy(ni._lib)
+    total, batches, done = 6000, [], threading.Event()
+
+    def feed():
+        for at in range(0, total, 10):
+            ni.ingest(b"\n".join(
+                b"d%d:1|%s" % (i, (b"ms", b"c", b"g")[i % 3])
+                for i in range(at, at + 10)))
+        done.set()
+
+    def drain(out):
+        while True:
+            last = done.is_set()
+            batch = ni.drain_new_series()
+            if len(batch):
+                out.append(batch)
+            if last:
+                return
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        outs = [[] for _ in range(4)]
+        threads = [threading.Thread(target=drain, args=(out,))
+                   for out in outs] + [threading.Thread(target=feed)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    batches = [b for out in outs for b in out]
+    names, sids = [], []
+    for batch in batches:
+        assert len(batch.first_at) == len(batch) == len(batch.first_names)
+        for pool, start, stop in batch.pool_slices():
+            assert set(batch.pools[start:stop].tolist()) == {pool}
+            rows = batch.rows[start:stop]
+            assert (rows[1:] - rows[:-1] == 1).all()
+        for pool, _row, kind, _scope, name, joined in batch.first_records():
+            assert (pool, kind) == {0: (0, 3), 1: (2, 0), 2: (3, 1)}[
+                int(name[1:]) % 3] and joined == ""
+        names += batch.first_names
+        sids += batch.sids.tolist()
+    assert sorted(names) == sorted("d%d" % i for i in range(total))
+    assert sorted(sids) == list(range(total))
 
 
 def test_known_series_cross_the_drain_as_integers():

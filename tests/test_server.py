@@ -614,6 +614,66 @@ def test_adoption_spans_and_gauge_tell_known_from_first_seen():
         srv.shutdown()
 
 
+@pytest.mark.parametrize("disturbed", [False, True],
+                         ids=["all-by-id", "index-and-blob-asked"])
+def test_the_span_record_says_how_the_row_books_were_kept(disturbed):
+    """`adopt` / `swap.adopt` carry `by_id`, the series that took their
+    row as an integer; `flush.begin` carries, for the epoch it closed,
+    `books_materialised` and `frag_blob_builds`. Through one native
+    context into sinks that read the columns: by_id = series, 0 and 0.
+    Ask a live book for its index and let a sink ask for the frag blobs:
+    one book materialised (its arena joined then) and one join for each
+    of the other pools that held rows."""
+    from veneur_tpu.sinks import MetricSink
+
+    class BlobSink(MetricSink):
+        def name(self):
+            return "blob"
+
+        def flush(self, metrics):
+            pass
+
+        def flush_columnar(self, batch, excluded_tags=None):
+            self.blobs = [g.meta_blob for g in batch.groups]
+
+    srv, sink, ports = _server(num_workers=1, interval="600s")
+    try:
+        if not srv.native_mode:
+            pytest.skip("native ingest library unavailable")
+        blob_sink = BlobSink()
+        if disturbed:
+            srv.metric_sinks.append(blob_sink)
+        port = next(iter(ports.values()))
+        lines = [b"bk.t%d:1|ms" % i for i in range(30)]
+        lines += [b"bk.c%d:1|c" % i for i in range(10)]
+        lines += [b"bk.g%d:1|g" % i for i in range(5)]
+        w = srv.workers[0]
+        for interval in range(2):
+            _send_udp(port, b"\n".join(lines))
+            assert _wait_for(lambda: sum(c.processed for c in w._all_ctxs())
+                             >= len(lines))
+            if disturbed:
+                with srv._worker_locks[0]:
+                    w.sync_native_series()
+                    assert len(w.directory.histo.index) == 30
+            srv.flush()
+            ordinal = w.flight_epoch - 1
+            adopts = [s for s in srv.rec.closed() if s.flush == ordinal
+                      and s.name in ("adopt", "swap.adopt")]
+            assert sum(s.attrs["series"] for s in adopts) == 45
+            assert sum(s.attrs["by_id"] for s in adopts) == 45
+            begin = [s for s in srv.last_flush_phases["spans"]
+                     if s[1] == "flush.begin"]
+            assert len(begin) == 1 and begin[0][5] == ordinal
+            attrs = begin[0][6]
+            assert attrs["books_materialised"] == (1 if disturbed else 0)
+            assert attrs["frag_blob_builds"] == (3 if disturbed else 0)
+        if disturbed:
+            assert [b is not None for b in blob_sink.blobs] == [True] * 3
+    finally:
+        srv.shutdown()
+
+
 def test_the_heap_is_frozen_once_the_series_table_has_grown(monkeypatch):
     """What adoption keeps lives as long as the series: once a flush
     ends with the table a quarter (and 4,096 series) past what was last

@@ -24,6 +24,7 @@ pinned by tests/test_columnar.py against the object path.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -87,11 +88,18 @@ class ColumnGroup:
     # accessor for native emitters; None entry = row needs the Python
     # path (separators in the data)
     frag_at: Optional[Callable[[int], Optional[bytes]]] = None
-    # the pool's incremental \x1e-joined frag arena covering rows
-    # [0, nrows) — handed to the native emit tier zero-copy (ctypes
-    # views the bytearray's buffer directly); None = some row needs
-    # the Python formatter
-    meta_blob: Optional[bytearray] = None
+    # where to ask for the pool's \x1e-joined frag blob covering rows
+    # [0, nrows) (directory.RowBook.frag_blob): a pool whose book holds
+    # ids joins it on the first request, so a flush whose sinks all
+    # take the columns pays for none
+    blob_of: Optional[Callable[[], Optional[bytearray]]] = None
+
+    @functools.cached_property
+    def meta_blob(self) -> Optional[bytearray]:
+        """The blob, handed to the native emit tier zero-copy (ctypes
+        views the bytearray's buffer directly); None = some row needs
+        the Python formatter."""
+        return self.blob_of() if self.blob_of is not None else None
 
     def count(self) -> int:
         return sum(f.count(self.nrows) for f in self.families)

@@ -8,15 +8,22 @@ histogram/timer series, HLL rows for set series), and the scope split
 becomes a per-row class label consulted only at flush/forward time — the
 device programs are scope-oblivious and operate on whole pools.
 
-Like the reference, all aggregation state lives exactly one flush interval:
-the directory (and its pools) is swapped wholesale at flush (the map-swap of
-worker.go:498-517 becomes a directory+buffer swap).
+Like the reference, aggregation state lives one flush interval: the
+directory (and its pools) is swapped wholesale at flush (the map-swap of
+worker.go:498-517 becomes a directory+buffer swap), and a series not written
+in an interval has no row in it. What outlives the interval is what a series
+*is*, not what it holds: on the native path a context names a series by a
+lifetime id and the worker keeps its strings and objects per id
+(``LifetimeSeries``), so an interval's book of rows (``RowBook``) is an array
+of those ids until something needs it to be more.
 """
 
 from __future__ import annotations
 
 import enum
+import threading
 from array import array
+from collections.abc import Sequence
 from dataclasses import dataclass
 from typing import Optional
 
@@ -102,18 +109,66 @@ class RowMeta:
         return frag
 
 
+class RowView(Sequence):
+    """Rows [0, len(ids)) of a book that holds lifetime ids: row r is
+    ``table[ids[r]]``. What ``entries`` / ``rows`` / ``meta`` read as
+    while nothing was copied; it follows the book as rows are added. A
+    loop over many rows iterates it (C speed); indexing it is a Python
+    call a row, which is why the flush's per-row accessors do not come
+    through here (``RowBook.accessors``)."""
+
+    __slots__ = ("_table", "_ids")
+
+    def __init__(self, table: list, ids: array) -> None:
+        self._table = table
+        self._ids = ids
+
+    def __len__(self) -> int:
+        return len(self._ids)
+
+    def __getitem__(self, at):
+        if isinstance(at, slice):
+            return list(map(self._table.__getitem__, self._ids[at]))
+        return self._table[self._ids[at]]
+
+    def __iter__(self):
+        return map(self._table.__getitem__, self._ids)
+
+
 class RowBook:
     """What a pool keeps per row beside its values: the row's entry
     (``RowMeta`` in the device pools, a ``(key, tags, scope_class,
-    sinks)`` tuple in the worker's scalar pools), packed scope and
-    admission codes, the routed/rejected counts, the native emitters'
-    frag arena, and the (key, scope class) -> row index. Rows are
-    append-only within an interval, one at a time (the Python upsert
-    path) or a batch at a time (native adoption, worker._adopt_pending).
+    sinks)`` tuple in the worker's scalar pools), scope and admission
+    codes, the routed/rejected counts, the native emitters' frag blob,
+    and the (key, scope class) -> row index. Rows are append-only within
+    an interval.
+
+    One of two representations at a time, chosen by what arrives:
+
+    * **by id** — every row so far came from one native context's drain
+      (worker._adopt_pending, single context): the book is a packed
+      array of that context's lifetime series ids and a reference to the
+      ``LifetimeSeries`` they index. Adopting a batch is one append of
+      its ids (and three sums over the batch once the table holds a
+      rejected, routed or frag-less series); entries, codes and the
+      frag blob are derived from the ids when a reader asks.
+    * **materialised** — a row came one at a time (the Python upsert
+      path), a batch came through ``upsert_batch`` (reader shards), a
+      second table showed up, or somebody asked for ``index``: the ids
+      are resolved once into per-interval containers (an entry list,
+      packed code arrays, the frag arena) and every later row is
+      appended to those.
     """
 
     def __init__(self) -> None:
-        self.entries: list = []
+        # by id: the table (None = materialised) and the rows' ids in it
+        self._known: Optional["LifetimeSeries"] = None
+        self._sids = array("i")
+        # [2, n] scope and admission codes taken for the ids, kept while
+        # the row count stands (a flush reads them several times)
+        self._taken: Optional[np.ndarray] = None
+        # materialised: one slot per row
+        self._entries: list = []
         # the index is filled when somebody reads it: a batch appends
         # rows without touching it, and on the native single-context
         # path (no tenancy) nobody ever asks
@@ -123,20 +178,25 @@ class RowBook:
         # view for the columnar flush — no O(rows) attribute walk at
         # flush time), plus a count of rows carrying veneursinkonly
         # routing so the common no-routing case skips per-row checks
-        self.scope_codes = array("b")
+        self._scope_codes = array("b")
         self.routed_rows = 0
         # per-row admission codes (1 admitted / 0 rejected), same
         # packed-byte idiom; rejected_rows counts them so the common
         # all-admitted case skips per-row checks entirely
-        self.admit_codes = array("b")
+        self._admit_codes = array("b")
         self.rejected_rows = 0
-        # \x1e-joined wire_frag arena over rows [0, len(entries)),
-        # maintained at adopt so the flush hands the native emit tier
-        # one contiguous buffer with zero per-row work; poisoned
-        # (frag_clean False, arena abandoned) the moment any row's frag
-        # is None
-        self.frag_arena = bytearray()
+        # \x1e-joined wire_frag arena: kept current row by row once
+        # materialised; by id joined when frag_blob() is asked (over the
+        # _arena_rows rows there were then); poisoned (frag_clean False,
+        # arena abandoned) the moment any row's frag is None
+        self._arena = bytearray()
+        self._arena_rows = 0
+        self._blob_lock = threading.Lock()
         self.frag_clean = True
+        # what the flush reports (flush.begin attrs): whether ids were
+        # resolved into containers, and the joins frag_blob() ran
+        self.materialised = False
+        self.frag_blob_builds = 0
 
     @staticmethod
     def _ikey(entry) -> tuple:
@@ -144,33 +204,131 @@ class RowBook:
         raise NotImplementedError
 
     @property
+    def entries(self):
+        """Row r's entry at [r]: the list, or a view over the ids."""
+        if self._known is not None:
+            return RowView(self._known.entries, self._sids)
+        return self._entries
+
+    def accessors(self) -> tuple:
+        """(meta_at, frag_at): row i's ``(name, tags, sinks)`` and its
+        wire frag (None = separators in the data), for the columnar
+        flush, whose sinks call them once a row (394k-1.18M times a
+        flush). By id each is two built-in subscripts into what the
+        table keeps per series, ``metas[ids[i]]``: no attribute walk, no
+        tuple built, and never a sequence written in Python. The tuple
+        and the tags list are the series' own: callers do not mutate
+        them."""
+        known = self._known
+        if known is None:
+            return self._entry_accessors(self._entries)
+
+        def meta_at(i, _metas=known.metas, _ids=self._sids):
+            return _metas[_ids[i]]
+
+        def frag_at(i, _frags=known.frags, _ids=self._sids):
+            return _frags[_ids[i]]
+
+        return meta_at, frag_at
+
+    @staticmethod
+    def _entry_accessors(entries: list) -> tuple:
+        """``accessors`` over a materialised book's entry list."""
+        raise NotImplementedError
+
+    def _codes(self) -> np.ndarray:
+        n = len(self._sids)
+        if self._taken is None or self._taken.shape[1] != n:
+            sids = np.frombuffer(self._sids, np.int32)
+            self._taken = self._known.codes[:2].take(sids, axis=1)
+            # derived: a write would be lost with the next batch
+            self._taken.flags.writeable = False
+        return self._taken
+
+    @property
+    def scope_codes(self):
+        """int8 per row, as a buffer (np.frombuffer reads either)."""
+        if self._known is not None:
+            return self._codes()[LifetimeSeries.SCOPE]
+        return self._scope_codes
+
+    @property
+    def admit_codes(self):
+        if self._known is not None:
+            return self._codes()[LifetimeSeries.ADMITTED]
+        return self._admit_codes
+
+    @property
     def index(self) -> dict:
         """(MetricKey, ScopeClass) -> row over every row adopted so far.
         Its readers: the Python upsert path, the reader-shard reconcile
         (upsert_batch) and the worker's tenancy gate."""
-        n = len(self.entries)
+        self._materialise()
+        n = len(self._entries)
         if self._indexed < n:
             at = self._indexed
             self._index.update(
-                zip(map(self._ikey, self.entries[at:]), range(at, n)))
+                zip(map(self._ikey, self._entries[at:]), range(at, n)))
             self._indexed = n
         return self._index
 
     def frag_blob(self) -> Optional[bytearray]:
         """The native emitters' metadata buffer for this pool, or None
-        when some row needs the Python path."""
-        return self.frag_arena if self.frag_clean else None
+        when some row needs the Python path. By id it is joined here, on
+        the first request, and anew only if rows came since."""
+        if not self.frag_clean:
+            return None
+        if self._known is not None:
+            # the sinks' threads may all ask at once: one joins
+            with self._blob_lock:
+                n = len(self._sids)
+                if self._arena_rows != n:
+                    self._arena = bytearray(b"\x1e".join(map(
+                        self._known.frags.__getitem__, self._sids)))
+                    self._arena_rows = n
+                    self.frag_blob_builds += 1
+        return self._arena
+
+    def _materialise(self) -> None:
+        """Resolve the ids into per-interval containers, once; from here
+        on the book is what it was before it could hold ids."""
+        known = self._known
+        if known is None:
+            return
+        self.frag_blob()  # the arena, current, while the ids still say it
+        self._known = None
+        self._entries, codes, _ = known.take(
+            np.frombuffer(self._sids, np.int32))
+        self._keep_codes(codes)
+        self._sids = array("i")
+        self._taken = None
+        self.materialised = True
+
+    def _keep_codes(self, codes) -> None:
+        """A batch's scope and admission codes onto the packed arrays."""
+        self._scope_codes.frombytes(codes[LifetimeSeries.SCOPE].tobytes())
+        self._admit_codes.frombytes(
+            codes[LifetimeSeries.ADMITTED].tobytes())
+
+    def _tally(self, admitted, routed, no_frag) -> None:
+        """Count a batch's rejected and routed rows and poison the arena
+        if a frag is None, from its rows of LifetimeSeries.codes."""
+        self.rejected_rows += len(admitted) - np.count_nonzero(admitted)
+        self.routed_rows += np.count_nonzero(routed)
+        if no_frag.any():
+            self.frag_clean = False
 
     def _append(self, row: int, entry, scope_class, sinks, admitted: bool,
                 frag) -> None:
         """One row, assigned by the caller in append order."""
-        assert row == len(self.entries), "rows must be adopted in order"
+        self._materialise()
+        assert row == len(self._entries), "rows must be adopted in order"
         if self._indexed == row:  # keep a current index current
             self._index[self._ikey(entry)] = row
             self._indexed = row + 1
-        self.entries.append(entry)
-        self.scope_codes.append(int(scope_class))
-        self.admit_codes.append(1 if admitted else 0)
+        self._entries.append(entry)
+        self._scope_codes.append(int(scope_class))
+        self._admit_codes.append(1 if admitted else 0)
         if not admitted:
             self.rejected_rows += 1
         if sinks is not None:
@@ -180,37 +338,51 @@ class RowBook:
                 self.frag_clean = False
             else:
                 if row:
-                    self.frag_arena += b"\x1e"
-                self.frag_arena += frag
+                    self._arena += b"\x1e"
+                self._arena += frag
 
-    def adopt_batch(self, first_row: int, entries: list, codes,
-                    frags: list) -> None:
-        """Rows [first_row, first_row + len(entries)) at once. The first
-        four rows of ``codes`` (int8, n columns: LifetimeSeries.codes) are
-        scope class, admitted, routed (has sinks) and whether the row's
-        frag is None; ``frags`` the rows' wire frags."""
+    def _extend(self, entries: list, codes, frags: list) -> None:
+        """A batch onto a materialised book (``LifetimeSeries.take``'s
+        triple)."""
+        first_row = len(self._entries)
+        self._entries.extend(entries)
+        self._keep_codes(codes)
+        self._tally(*codes[LifetimeSeries.ADMITTED:
+                           LifetimeSeries.NO_FRAG + 1])
+        if self.frag_clean:
+            if first_row:
+                self._arena += b"\x1e"
+            self._arena += b"\x1e".join(frags)
+
+    def adopt_batch(self, first_row: int, known: "LifetimeSeries",
+                    sids: np.ndarray) -> int:
+        """Rows [first_row, first_row + len(sids)) at once: the series
+        ``sids`` (int32) of ``known``, in row order. A book that holds
+        nothing yet, or ids of the same table, appends the ids and does
+        nothing per series; returns how many rows it took that way."""
         assert first_row == len(self.entries), \
             "rows must be adopted in order"
-        n = len(entries)
-        self.entries.extend(entries)
-        self.scope_codes.frombytes(codes[0].tobytes())
-        self.admit_codes.frombytes(codes[1].tobytes())
-        _, admitted, routed, no_frag = codes[:4].sum(axis=1, dtype=np.int64)
-        self.rejected_rows += n - int(admitted)
-        self.routed_rows += int(routed)
-        if self.frag_clean:
-            if no_frag:
-                self.frag_clean = False
-            else:
-                if first_row:
-                    self.frag_arena += b"\x1e"
-                self.frag_arena += b"\x1e".join(frags)
+        if self._known is None and not self._entries:
+            self._known = known
+        if self._known is not known:
+            self._materialise()
+            self._extend(*known.take(sids))
+            return 0
+        self._sids.frombytes(sids.astype(np.int32, copy=False).tobytes())
+        if not known.plain:
+            # (a take a row: ten times faster than codes[1:4, sids])
+            self._tally(*(known.codes[row].take(sids) for row in (
+                LifetimeSeries.ADMITTED, LifetimeSeries.ROUTED,
+                LifetimeSeries.NO_FRAG)))
+        return len(sids)
 
-    def upsert_batch(self, entries: list, codes, frags: list) -> list:
+    def upsert_batch(self, known: "LifetimeSeries",
+                     sids: np.ndarray) -> list:
         """The reader-shard reconcile: N per-reader row spaces fold into
         this canonical pool, so a series that arrived through another
         reader (or the Python path) keeps the row it has and only the
-        others are adopted. Returns every entry's canonical row."""
+        others are adopted. Returns every series' canonical row."""
+        entries, codes, frags = known.take(sids)
         index = self.index
         keys = list(map(self._ikey, entries))
         found = list(map(index.get, keys))
@@ -222,16 +394,16 @@ class RowBook:
                              reversed(fresh)))
             if len(first) != len(fresh):
                 fresh = sorted(first.values())
-            base = len(self.entries)
+            base = len(self._entries)
             if len(fresh) == len(entries):
-                self.adopt_batch(base, entries, codes, frags)
+                self._extend(entries, codes, frags)
             else:
-                self.adopt_batch(
-                    base, [entries[i] for i in fresh], codes[:, fresh],
+                self._extend(
+                    [entries[i] for i in fresh], codes[:, fresh],
                     [frags[i] for i in fresh])
             index.update(zip(map(keys.__getitem__, fresh),
                              range(base, base + len(fresh))))
-            self._indexed = len(self.entries)
+            self._indexed = len(self._entries)
             found = list(map(index.__getitem__, keys))
         return found
 
@@ -239,20 +411,31 @@ class RowBook:
 class _Pool(RowBook):
     """A device pool's rows: ``rows[r]`` is row r's RowMeta."""
 
-    def __init__(self) -> None:
-        super().__init__()
-        self.rows: list[RowMeta] = self.entries
+    @property
+    def rows(self):
+        return self.entries
 
     @staticmethod
     def _ikey(meta: RowMeta) -> tuple:
         return (meta.key, meta.scope_class)
+
+    @staticmethod
+    def _entry_accessors(rows: list) -> tuple:
+        def meta_at(i, _rows=rows):
+            m = _rows[i]
+            return m.key.name, m.tags, m.sinks
+
+        def frag_at(i, _rows=rows):
+            return _rows[i].wire_frag()
+
+        return meta_at, frag_at
 
     def upsert(self, key: MetricKey, scope_class: ScopeClass, tags: list[str],
                tenant: str = "") -> tuple[int, bool]:
         row = self.index.get((key, scope_class))
         if row is not None:
             return row, False
-        row = len(self.rows)
+        row = len(self._entries)
         self.adopt(row, key, scope_class, tags, tenant=tenant)
         return row, True
 
@@ -273,29 +456,34 @@ class _Pool(RowBook):
 class LifetimeSeries:
     """What the worker keeps per lifetime series id (``sid``) of one
     native context, so that a series' strings and objects are built once
-    and an interval's re-registration is a take by integer: the pool
-    entry (``RowMeta`` for the device pools, the scalar pools' tuple),
-    its wire frag, and packed per-sid codes. A context hands a sid's
-    strings over once (NativeIngest.drain_new_series); when it drops its
-    table it says so by a new generation and everything here goes too."""
+    and an interval's re-registration is its integer: the pool entry
+    (``RowMeta`` for the device pools, the scalar pools' tuple), its
+    wire frag, and packed per-sid codes. A context hands a sid's strings
+    over once (NativeIngest.drain_new_series). A table only grows: the
+    books of an interval hold ids into it (RowBook, by id), snapshots
+    keep those books past the interval, so when the context drops its
+    table (a new ``generation``) the worker starts a new one and leaves
+    this one to whoever still reads it."""
 
-    # rows of ``codes``: what RowBook.adopt_batch reads, then whether the
+    # rows of ``codes``: scope class, then what RowBook._tally sums
+    # (admitted, routed = has sinks, the frag is None), then whether the
     # series counts toward the unique-timeseries tally
     SCOPE, ADMITTED, ROUTED, NO_FRAG, COUNTED = range(5)
 
-    def __init__(self) -> None:
-        self.generation = 0
+    def __init__(self, generation: int = 0) -> None:
+        self.generation = generation
         self.entries: list = []  # None: a sid not learnt (yet)
         self.frags: list = []
+        # (name, tags, sinks): what RowBook.accessors' meta_at returns
+        self.metas: list = []
         self.codes = np.zeros((5, 1024), np.int8)
         self.ts_hash = np.zeros(1024, np.uint64)
+        # no series so far is rejected, routed or without a frag: a
+        # book that takes ids of such a table has nothing to count
+        self.plain = True
 
     def __len__(self) -> int:
         return len(self.entries)
-
-    def clear(self, generation: int) -> None:
-        self.__init__()
-        self.generation = generation
 
     def reserve(self, n: int) -> None:
         """Room for sids [0, n)."""
@@ -303,6 +491,7 @@ class LifetimeSeries:
         if short > 0:
             self.entries.extend([None] * short)
             self.frags.extend([None] * short)
+            self.metas.extend([None] * short)
         cap = self.codes.shape[1]
         if n > cap:
             while cap < n:
@@ -313,16 +502,21 @@ class LifetimeSeries:
             self.ts_hash = np.concatenate(
                 [self.ts_hash, np.zeros(cap - len(self.ts_hash), np.uint64)])
 
-    def put(self, sid: int, entry, frag, scope_class: int, admitted: bool,
-            routed: bool, ts_hash: Optional[int]) -> None:
+    def put(self, sid: int, entry, meta: tuple, frag, scope_class: int,
+            admitted: bool, ts_hash: Optional[int]) -> None:
         self.entries[sid] = entry
+        self.metas[sid] = meta
         self.frags[sid] = frag
-        self.codes[:, sid] = (scope_class, admitted, routed, frag is None,
+        routed, no_frag = meta[2] is not None, frag is None
+        self.codes[:, sid] = (scope_class, admitted, routed, no_frag,
                               ts_hash is not None)
+        if routed or no_frag or not admitted:
+            self.plain = False
         self.ts_hash[sid] = ts_hash or 0
 
     def take(self, sids: np.ndarray) -> tuple:
-        """(entries, codes [5, n], frags) of a batch of sids."""
+        """(entries, codes [5, n], frags) of a batch of sids, copied by
+        reference into new containers: what a materialised book keeps."""
         at = sids.tolist()
         return (list(map(self.entries.__getitem__, at)),
                 self.codes[:, sids],

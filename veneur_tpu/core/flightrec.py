@@ -39,7 +39,9 @@ RING = 8192
 
 class Span:
     """An open or closed span; the context manager ``Recorder.span``
-    returns. ``attrs`` may be added to until the span closes."""
+    returns. ``attrs`` may be added to until the span closes, and by
+    whoever kept the span until its flush's record is handed on
+    (``of_flush`` copies them then: Server._flush_publish)."""
 
     __slots__ = ("rec", "id", "name", "t0", "t1", "parent", "flush",
                  "attrs", "_ann")

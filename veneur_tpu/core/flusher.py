@@ -20,6 +20,11 @@ from typing import Optional
 
 import numpy as np
 
+from veneur_tpu.core.columnar import (
+    ColumnarMetrics,
+    ColumnGroup,
+    MetricFamily,
+)
 from veneur_tpu.core.directory import ScopeClass
 from veneur_tpu.core.metrics import (
     Aggregate,
@@ -316,10 +321,6 @@ def generate_columnar(
     per-row Python loop. Emits the identical metric multiset (pinned by
     tests/test_columnar.py); costs O(R) numpy, not O(R·families) Python.
     """
-    from veneur_tpu.core.columnar import (
-        ColumnarMetrics, ColumnGroup, MetricFamily,
-    )
-
     if governor is not None:
         # liveness beat for the flush watchdog's deferral rule (see
         # generate_inter_metrics)
@@ -424,17 +425,8 @@ def generate_columnar(
                             snap.quantile_values[:, q_index[float(p)]],
                             np.float64),
                         pmask))
-        pool = snap.directory.histo
-
-        def histo_meta(i, _rows=hrows):
-            m = _rows[i]
-            return m.key.name, m.tags, m.sinks
-
-        batch.groups.append(ColumnGroup(
-            nrows=len(hrows), meta_at=histo_meta, families=fams,
-            has_routing=pool.routed_rows > 0,
-            frag_at=lambda i, _rows=hrows: _rows[i].wire_frag(),
-            meta_blob=pool.frag_blob()))
+        batch.groups.append(_group(
+            snap.directory.histo, len(hrows), fams))
 
     # -- set rows ----------------------------------------------------------
     srows = snap.directory.sets.rows
@@ -447,18 +439,11 @@ def generate_columnar(
                                  dtype=np.int8)[: len(srows)] != 0
             smask = sadm if smask is None else (smask & sadm)
 
-        def set_meta(i, _rows=srows):
-            m = _rows[i]
-            return m.key.name, m.tags, m.sinks
-
-        batch.groups.append(ColumnGroup(
-            nrows=len(srows), meta_at=set_meta,
-            families=[MetricFamily(
+        batch.groups.append(_group(
+            snap.directory.sets, len(srows),
+            [MetricFamily(
                 "", GAUGE, np.asarray(snap.set_estimates, np.float64),
-                smask)],
-            has_routing=snap.directory.sets.routed_rows > 0,
-            frag_at=lambda i, _rows=srows: _rows[i].wire_frag(),
-            meta_blob=snap.directory.sets.frag_blob()))
+                smask)]))
 
     # -- counters / gauges -------------------------------------------------
     for pool, mtype in ((snap.scalars.counters, MetricType.COUNTER),
@@ -472,27 +457,11 @@ def generate_columnar(
             cadm = np.frombuffer(pool.admit_codes, dtype=np.int8)[:n] != 0
             cmask = cadm if cmask is None else (cmask & cadm)
 
-        def scalar_meta(i, _meta=pool.meta):
-            key, tags, _cls, sinks = _meta[i]
-            return key.name, tags, sinks
-
-        def scalar_frag(i, _meta=pool.meta):
-            key, tags, _cls, _sinks = _meta[i]
-            rec = (key.name + "\x1f" + "\x1f".join(tags)
-                   if tags else key.name)
-            if "\x1e" in rec or "\x1f" in key.name or any(
-                    "\x1f" in t or "\x1e" in t for t in tags):
-                return None
-            return rec.encode("utf-8")
-
-        batch.groups.append(ColumnGroup(
-            nrows=n, meta_at=scalar_meta,
-            families=[MetricFamily(
+        batch.groups.append(_group(
+            pool, n,
+            [MetricFamily(
                 "", mtype, np.asarray(pool.values[:n], np.float64),
-                cmask)],
-            has_routing=pool.routed_rows > 0,
-            frag_at=scalar_frag,
-            meta_blob=pool.frag_blob()))
+                cmask)]))
 
     # -- status checks (rare; objects) -------------------------------------
     for (key, tags, _cls, sinks), sv in zip(
@@ -507,6 +476,17 @@ def generate_columnar(
             )
         )
     return batch
+
+
+def _group(pool, nrows: int, families: list) -> ColumnGroup:
+    """A pool's ColumnGroup: the row accessors and the frag blob are the
+    pool's book's to give (directory.RowBook), in whatever way it holds
+    its rows."""
+    meta_at, frag_at = pool.accessors()
+    return ColumnGroup(
+        nrows=nrows, meta_at=meta_at, families=families,
+        has_routing=pool.routed_rows > 0, frag_at=frag_at,
+        blob_of=pool.frag_blob)
 
 
 # ---------------------------------------------------------------------------

@@ -72,6 +72,9 @@ class FlushJob:
     flush_start: float = 0.0
     qs: Any = None
     swapped: list = field(default_factory=list)
+    # the flush.begin span: closed by then, it still takes the attrs
+    # that only the end of the flush knows (_flush_publish)
+    begin: Any = None
     span_counts: dict = field(default_factory=dict)
     phases: dict = field(default_factory=dict)
     snaps: list = field(default_factory=list)
@@ -1760,12 +1763,15 @@ class Server:
 
     def _series_sync_loop(self) -> None:
         """Adopt new-series registrations from the C++ contexts as they
-        arrive instead of all at once inside flush's swap phase — at 1M
-        fresh series per interval the adoption is ~7s of Python work
-        that would otherwise sit under the ingest lock (profiled:
-        _sync_native_series was 0.88s of a 0.99s swap at 131k series).
-        Cadence is a fraction of the interval so the swap-time tail is
-        small; the sweep early-returns when nothing is pending."""
+        arrive instead of all at once inside flush's swap phase, so that
+        the interval in which a million series hand over their strings
+        for the first time (seconds of Python, once in their lifetime:
+        worker._learn_series) does not spend them under the ingest lock
+        at the tick. A series the context has handed over before costs
+        an integer appended to its pool's book (RowBook, by id), here or
+        in the micro-fold that gets to it first. Cadence is a fraction
+        of the interval so the swap-time tail is small; the sweep
+        early-returns when nothing is pending."""
         cadence = max(0.1, min(1.0, self.interval / 8.0))
         while not self._shutdown.wait(cadence):
             try:
@@ -1964,7 +1970,7 @@ class Server:
         self.flush_governor.beat()  # swap complete: flush is live
         return FlushJob(ordinal=ordinal, ts=int(flush_start),
                         flush_start=flush_start, qs=qs, swapped=swapped,
-                        span_counts=span_counts, phases=phases)
+                        begin=begin, span_counts=span_counts, phases=phases)
 
     def _flush_begin_swap(self, ordinal: int):
         """The body of flush.begin: drain what is buffered beside the
@@ -2275,6 +2281,18 @@ class Server:
         """Rebind last_flush_phases, so observers always read the phases
         of the most recently COMPLETED flush, and beside them the spans
         of this flush and of the ingest side of its epoch."""
+        # what became of the flushed epoch's row books (directory.RowBook):
+        # how many had their ids resolved into per-interval containers,
+        # and how many frag blobs were joined, the sinks' requests during
+        # this flush included. 0 and 0 where every series came by id and
+        # every sink took the columns
+        books = [book for sw in job.swapped
+                 for book in (sw.directory.histo, sw.directory.sets,
+                              sw.scalars.counters, sw.scalars.gauges)]
+        job.begin.attrs["books_materialised"] = sum(
+            book.materialised for book in books)
+        job.begin.attrs["frag_blob_builds"] = sum(
+            book.frag_blob_builds for book in books)
         job.phases["spans"] = self.rec.of_flush(job.ordinal)
         self.last_flush_phases = job.phases
         self.last_emit_unix = time.time()

@@ -391,14 +391,30 @@ class ScalarPool(RowBook):
 
     def __init__(self, initial: int = 256) -> None:
         super().__init__()
-        self.meta: list = self.entries  # (key, tags, scope_class, sinks)
         self.values = np.zeros(initial, np.float64)
         self.present = np.zeros(initial, bool)
         self.used = 0
 
+    @property
+    def meta(self):
+        """Row r's (key, tags, scope_class, sinks) at [r]."""
+        return self.entries
+
     @staticmethod
     def _ikey(entry) -> tuple:
         return (entry[0], entry[2])
+
+    @staticmethod
+    def _entry_accessors(meta: list) -> tuple:
+        def meta_at(i, _meta=meta):
+            key, tags, _cls, sinks = _meta[i]
+            return key.name, tags, sinks
+
+        def frag_at(i, _meta=meta):
+            key, tags, _cls, _sinks = _meta[i]
+            return build_frag(key.name, tags)
+
+        return meta_at, frag_at
 
     def ensure(self, rows: int) -> None:
         if rows > len(self.values):
@@ -425,18 +441,25 @@ class ScalarPool(RowBook):
             frag = build_frag(getattr(key, "name", key), list(tags))
         self._append(row, (key, tags, scope_class, sinks), scope_class,
                      sinks, True, frag)
+        self._cover(row + 1)
+
+    def _cover(self, rows: int) -> None:
+        """Values for rows [0, rows)."""
         # grow BEFORE bumping used: ensure() copies/zeroes relative to
         # self.used, and with used already including the new row it
         # copies one element past the old arrays (crash at a capacity
         # boundary) and leaves np.resize's recycled junk in the new row
-        self.ensure(row + 1)
-        self.used = row + 1
+        self.ensure(rows)
+        self.used = rows
 
-    def adopt_batch(self, first_row: int, entries: list, codes,
-                    frags: list) -> None:
-        super().adopt_batch(first_row, entries, codes, frags)
-        self.ensure(len(self.entries))  # before used moves: see adopt_row
-        self.used = len(self.entries)
+    def adopt_batch(self, first_row: int, known, sids) -> int:
+        by_id = super().adopt_batch(first_row, known, sids)
+        self._cover(first_row + len(sids))
+        return by_id
+
+    def _extend(self, entries: list, codes, frags: list) -> None:
+        super()._extend(entries, codes, frags)
+        self._cover(len(self._entries))
 
 
 @dataclass
@@ -989,27 +1012,34 @@ class DeviceWorker:
         if not ctx.pending_new_series:
             return
         with self.rec.span(span, flush=self.flight_epoch) as sp:
-            n, first_seen = self._adopt_pending(ctx, ctx_i)
+            n, first_seen, by_id = self._adopt_pending(ctx, ctx_i)
             sp.attrs["series"] = n
             sp.attrs["known"] = n - first_seen
             sp.attrs["first_seen"] = first_seen
+            sp.attrs["by_id"] = by_id
 
-    def _adopt_pending(self, ctx, ctx_i: int) -> tuple[int, int]:
+    def _adopt_pending(self, ctx, ctx_i: int) -> tuple[int, int, int]:
         """Give every series the context created since the last drain its
         row in the pools, a batch per pool; returns (series adopted, how
-        many of them the context handed over for the first time).
+        many of them the context handed over for the first time, how
+        many took their row without a per-series step).
 
         Every flush resets the native directory and the same series
         re-register next interval, so the drain names a series by its
         lifetime id: what a row needs (entry, codes, frag) was built when
-        the series' strings first arrived (_learn_series) and is taken
-        from the context's LifetimeSeries by integer here."""
+        the series' strings first arrived (_learn_series) and stays in
+        the context's LifetimeSeries; a pool's book takes the ids
+        (RowBook.adopt_batch)."""
         batch = ctx.drain_new_series()
         if not len(batch):
-            return 0, 0
+            return 0, 0, 0
         known = self._adopt_cache[ctx_i]
         if batch.generation != known.generation:
-            known.clear(batch.generation)
+            # the context dropped its table and hands out sids anew: a
+            # new table here too, never the old one wiped, because the
+            # books of snapshots still in flight hold ids into it
+            known = self._adopt_cache[ctx_i] = LifetimeSeries(
+                batch.generation)
         if len(batch.first_at):
             self._learn_series(known, batch)
         # reader-shard mode: context rows are LOCAL — reconcile each into
@@ -1021,35 +1051,34 @@ class DeviceWorker:
         shard_maps = self._ctx_maps[ctx_i] if self._reader_ctxs else None
         pools = (self.directory.histo, self.directory.sets,
                  self.scalars.counters, self.scalars.gauges)
-        per_pool = np.bincount(batch.pools, minlength=4)
-        for pool_i, pool in enumerate(pools):
-            if not per_pool[pool_i]:
-                continue
-            rows, sids = batch.rows, batch.sids
-            if per_pool[pool_i] < len(batch):
-                at = np.flatnonzero(batch.pools == pool_i)
-                rows, sids = rows[at], sids[at]
+        by_id = 0
+        # the drain comes grouped by pool, a pool's rows consecutive:
+        # nothing here sorts, compares or gathers over the batch (every
+        # such call gives the interpreter up, under the ingest lock,
+        # and waits a switch interval for it while another thread is busy)
+        for pool_i, start, stop in batch.pool_slices():
+            pool = pools[pool_i]
+            sids = batch.sids[start:stop]
             first_row = (len(pool.entries) if shard_maps is None
                          else len(shard_maps[pool_i]))
-            assert (rows[0] == first_row
-                    and (rows[1:] - rows[:-1] == 1).all()), \
+            assert (batch.rows[start] == first_row
+                    and batch.rows[stop - 1] - first_row
+                    == stop - start - 1), \
                 "native series must drain in row order"
-            entries, codes, frags = known.take(sids)
             if shard_maps is None:
-                pool.adopt_batch(first_row, entries, codes, frags)
+                by_id += pool.adopt_batch(first_row, known, sids)
             else:
-                shard_maps[pool_i].extend(
-                    pool.upsert_batch(entries, codes, frags))
+                shard_maps[pool_i].extend(pool.upsert_batch(known, sids))
             if self._umts is not None:
                 # feed the unique-timeseries HLL once per new series; the
                 # HLL insert is idempotent so per-sample feeding (the
                 # Python path, worker.go:300-341) and per-series feeding
                 # agree
-                counted = codes[LifetimeSeries.COUNTED] != 0
+                counted = known.codes[LifetimeSeries.COUNTED, sids] != 0
                 idx, rank = hll_ops.split_hashes(
                     known.ts_hash[sids[counted]], self.hll_precision)
                 np.maximum.at(self._umts, idx, rank)
-        return len(batch), len(batch.first_at)
+        return len(batch), len(batch.first_at), by_id
 
     def _learn_series(self, known: LifetimeSeries, batch) -> None:
         """The once-in-a-lifetime part of adoption: build what the pools
@@ -1091,7 +1120,7 @@ class DeviceWorker:
             if (self._umts is not None
                     and self._should_count_timeseries(mtype, scope_class)):
                 ts_hash = fmix64(metric_digest(name, mtype, joined))
-            known.put(sid, entry, frag, scope, admitted, sinks is not None,
+            known.put(sid, entry, (name, tags, sinks), frag, scope, admitted,
                       ts_hash)
 
     @property

@@ -6,6 +6,7 @@ callers fall back to the pure-Python parser when it isn't.
 
 from __future__ import annotations
 
+import bisect
 import ctypes
 import hashlib
 import logging
@@ -363,11 +364,14 @@ class NewSeriesBatch:
     (NativeIngest.drain_new_series). Record i is the series that took
     row ``rows[i]`` of pool ``pools[i]`` (0 histo, 1 set, 2 counter, 3
     gauge) this interval; ``sids[i]`` names it for the context's
-    lifetime. The records at positions ``first_at`` are the ones whose
-    strings this context hands over for the first time: their
-    MetricKind ints, scope classes, names and joined tags ride along,
-    in the same order. ``generation`` changes when the context dropped
-    its table: every sid learnt under another generation is void."""
+    lifetime. The records come grouped by pool, pool 0 first, and a
+    pool's rows are consecutive: ``pool_slices()`` says where each
+    pool's records lie. The records at positions ``first_at`` are the
+    ones whose strings this context hands over for the first time:
+    their MetricKind ints, scope classes, names and joined tags ride
+    along, in the same order. ``generation`` changes when the context
+    dropped its table: every sid learnt under another generation is
+    void."""
 
     generation: int
     pools: np.ndarray
@@ -381,6 +385,18 @@ class NewSeriesBatch:
 
     def __len__(self) -> int:
         return len(self.rows)
+
+    def pool_slices(self) -> list:
+        """[(pool, start, stop)] of the pools that have records, by
+        bisection of ``pools`` (sorted): a few scalar reads, so that an
+        adoption under the ingest lock sorts and compares nothing."""
+        out, start, n = [], 0, len(self.pools)
+        while start < n:
+            pool = int(self.pools[start])
+            stop = bisect.bisect_right(self.pools, pool, start, n)
+            out.append((pool, start, stop))
+            start = stop
+        return out
 
     def first_records(self) -> list:
         """The first-seen records as (pool, row, kind, scope_class,
@@ -427,6 +443,7 @@ class NativeIngest:
         outs = (c.c_int(0), c.c_void_p(), c.c_longlong(0), c.c_uint(0))
         self._ns_out = (ptrs, *outs,
                         [c.byref(o) for o in (*ptrs, *outs)])
+        self._ns_lock = threading.Lock()
 
     def __del__(self):
         if getattr(self, "_ctx", None):
@@ -648,16 +665,19 @@ class NativeIngest:
 
     def drain_new_series(self) -> "NewSeriesBatch":
         """Every series created since the last drain, in one call: the
-        whole pending queue as int32 arrays, and strings only for the
-        series this context has not handed over before (first-seen
-        `sid`s; a series keeps its sid across reset())."""
+        whole pending queue as int32 arrays, grouped by pool, and
+        strings only for the series this context has not handed over
+        before (first-seen `sid`s; a series keeps its sid across
+        reset())."""
         c = ctypes
         ptrs, n_first, strs, strs_len, generation, args = self._ns_out
-        # the pointers are good until this context's next drain: hold
-        # its lock across the copy so a second drainer cannot get there
-        # (the lock also makes the shared out-parameters safe)
-        self.lock()
-        try:
+        # the pointers are good until this context's next drain, and the
+        # out-parameters are shared: one drainer at a time, kept out by a
+        # lock of Python's. The context's own lock is held inside the
+        # call only, so a reader never waits for a thread that holds it
+        # while waiting for the interpreter, and the drain gives the
+        # interpreter up once, not three times
+        with self._ns_lock:
             n = self._lib.vn_drain_new_series(self._ctx, *args)
             nf = n_first.value
             # (string_at + frombuffer: a read-only copy, at a tenth of
@@ -668,8 +688,6 @@ class NativeIngest:
                 for ptr, count in zip(ptrs, (n, n, n, nf, nf, nf)))
             packed = c.string_at(strs, strs_len.value) if nf else b""
             gen = generation.value
-        finally:
-            self.unlock()
         names: list[str] = []
         tags: list[str] = []
         if nf:
