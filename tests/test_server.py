@@ -913,6 +913,9 @@ def test_flush_ingest_stress_matrix(num_workers, num_readers, n_blasters):
                    for i in range(n_blasters)]
         for t in threads:
             t.start()
+        # three flushes of empty epochs can be over before a blaster
+        # thread has run once: race the epochs only once all send
+        assert _wait_for(lambda: all(sent), timeout=15.0)
         flushes = 0
         deadline = time.time() + 30.0
         while flushes < 3 and time.time() < deadline:

@@ -249,7 +249,7 @@ def test_staged_fold_quantile_accuracy():
         for r in range(S):
             all_vals[r].extend(sv[r])
         fields = list(_histo_fold_staged(
-            *fields, jnp.asarray(sv), jnp.asarray(sw)))
+            *fields, jnp.asarray(sv), jnp.asarray(sw))[:14])
 
     qs = jnp.asarray(np.array([0.25, 0.5, 0.9, 0.99], np.float32))
     quant = np.asarray(td.quantile(fields[0], fields[1], fields[2],

@@ -255,6 +255,58 @@ def _expand_flat_planes(flat_v, flat_w, counts, depth: int, unit: bool):
     return sv, sw
 
 
+#: The staged fold's full-width pass takes K = S // FOLD_WIDE_SHARE rows a
+#: trip (fold_wide_slots): its wide rows compacted K at a time while they
+#: are at most FOLD_GATHER_TRIPS * K, past that the pool itself in
+#: contiguous chunks of K (fold_takes_all).
+FOLD_WIDE_SHARE = 16
+FOLD_GATHER_TRIPS = 8
+
+
+def fold_wide_slots(rows: int, depth: int, capacity: int) -> int:
+    """K, the rows a trip of `_histo_fold_staged`'s full-width pass takes
+    when it splits the rows by width, or 0 where it never splits: the
+    staging depth is no wider than the narrow width, or that width does
+    not divide both (td._compress_narrow asks for it), or there are
+    fewer than FOLD_WIDE_SHARE rows."""
+    w = td.NARROW_WIDTH
+    if depth <= w or depth % w or capacity % w:
+        return 0
+    return rows // FOLD_WIDE_SHARE
+
+
+def fold_takes_all(n_wide, k: int):
+    """Whether a fold that found `n_wide` rows wide, k = fold_wide_slots
+    > 0, takes every row at the full width. Up to FOLD_GATHER_TRIPS * K
+    wide rows are gathered, compressed and scattered back K at a time
+    (3.2 ms a trip on the chip at K = 16,384); more, and the trips walk
+    the whole pool in slices of K, which need no gather (1.9 ms each,
+    S / K = 16 of them: what nine gathered trips would cost; PERF.md
+    section 6, PR 38). One rule for the program (`n_wide` traced) and
+    for the host that reads its count back."""
+    return n_wide > FOLD_GATHER_TRIPS * k
+
+
+def _staged_rows_wide(weights, svals, swts):
+    """bool[S]: the rows the staged fold compresses at the full C + B.
+
+    A row is *narrow*, and is compressed over its first td.NARROW_WIDTH
+    staging slots alone, when its digest is empty (its first centroid
+    weighs 0: a digest row is sorted live-first), it staged nothing past
+    those slots, every staged weight is a whole number of at most 2**19
+    (so every partial sum of a row is exact, in any order), and no
+    staged value is infinite or NaN (an infinite sample would sort among
+    the digest's empty slots). Everything else is wide."""
+    w = td.NARROW_WIDTH
+    live = swts > 0
+    exact = (swts >= 0) & (swts == jnp.floor(swts)) & (swts <= 2.0 ** 19)
+    narrow = ((weights[:, 0] == 0)
+              & ~jnp.any(live[:, w:], axis=-1)
+              & jnp.all(exact & (~live | (jnp.abs(svals) < jnp.inf)),
+                        axis=-1))
+    return ~narrow
+
+
 @functools.partial(jax.jit, static_argnames=("compression",),
                    donate_argnums=tuple(range(14)))
 def _histo_fold_staged(
@@ -277,8 +329,31 @@ def _histo_fold_staged(
     detection, or prefix-sum gathers are needed: per-row scalar stats
     are masked [S, B] reductions and the merge is one compress over
     [S, C+B]. Empty slots carry weight 0 (value ignored).
+
+    **A row is compressed at the width of what it holds.** Rows are
+    handed out per interval, so a timer that saw a handful of samples
+    meets this fold with an empty digest: of its C + B slots all but a
+    few are +inf / weight-0 padding that sorts last and adds zeros. Such
+    a *narrow* row (`_staged_rows_wide` says which) goes through
+    td._compress_narrow over its first td.NARROW_WIDTH staging slots.
+    The *wide* rows go through td._compress_rows at C + B as ever, K =
+    fold_wide_slots rows a trip of one loop whose trip count the
+    program reads from its input (fold_takes_all): the wide rows
+    compacted, K to a trip (none: no trip), or, where there are too
+    many to be worth compacting, the pool itself in contiguous chunks
+    of K. So the program specialises on (S, B) alone and holds the
+    compress twice, at [S, NARROW_WIDTH] and at [K, C + B], whatever
+    the rows hold. The result is bit for bit the full-width one, which
+    is ops/host_engine.np_fold_staged's (td._compress_narrow says why;
+    tests/test_fold_width.py holds both to it, and the program to its
+    size).
+
+    Returns the 14 fields and, fifteenth, the i32 count of the rows it
+    found wide (`DeviceWorker._note_fold_widths` reads it once the
+    flush's readback has blocked on the device anyway).
     """
     c = means.shape[1]
+    s, b = svals.shape
     with jax.named_scope("fold_staged.row_stats"):
         live = swts > 0
         # Order-pinned tree sums (ops/exactnum.py): the host fallback engine
@@ -289,10 +364,62 @@ def _histo_fold_staged(
         s_min = jnp.min(jnp.where(live, svals, jnp.inf), axis=-1)
         s_max = jnp.max(jnp.where(live, svals, -jnp.inf), axis=-1)
 
-    with jax.named_scope("fold_staged.merge"):
-        cat_means = jnp.concatenate([means, svals], axis=-1)
-        cat_w = jnp.concatenate([weights, swts], axis=-1)
-    means, weights = td._compress_rows(cat_means, cat_w, compression, c)
+    def full_width(means, weights, svals, swts):
+        with jax.named_scope("fold_staged.merge"):
+            cat_means = jnp.concatenate([means, svals], axis=-1)
+            cat_w = jnp.concatenate([weights, swts], axis=-1)
+        return td._compress_rows(cat_means, cat_w, compression, c)
+
+    k = fold_wide_slots(s, b, c)
+    if k == 0:
+        n_wide = jnp.int32(s)
+        means, weights = full_width(means, weights, svals, swts)
+    else:
+        with jax.named_scope("fold_staged.widths"):
+            wide = _staged_rows_wide(weights, svals, swts)
+            n_wide = jnp.sum(wide, dtype=jnp.int32)
+            full = fold_takes_all(n_wide, k)
+            trips = -(-jnp.where(full, s, n_wide) // k)
+            # the wide rows' numbers, ascending; the slots past n_wide
+            # point past the pool, each at a row of its own: read
+            # clipped, written nowhere. The keys differ, so an unstable
+            # sort has one answer, and the chip's compiler takes 2 s
+            # over it where the stable sort of 262,144 keys takes 15
+            # (jnp.nonzero: 9 s, and 3.0 ms on the chip against 0.7).
+            row = jnp.arange(s, dtype=jnp.int32)
+            order = jax.lax.sort(jnp.where(wide, row, s + row),
+                                 is_stable=False)
+        w = td.NARROW_WIDTH
+        planes = (means, weights, svals, swts)
+
+        def trip(i, out):
+            at = i * k  # clamped to S - K by the slices: rows done twice
+            with jax.named_scope("fold_staged.wide_rows"):
+                rows = jax.lax.dynamic_slice_in_dim(order, at, k)
+                t_means, t_w, t_svals, t_swts = jax.lax.cond(
+                    full,
+                    lambda: tuple(jax.lax.dynamic_slice_in_dim(a, at, k)
+                                  for a in planes),
+                    lambda: tuple(a.at[rows].get(mode="clip",
+                                                 indices_are_sorted=True)
+                                  for a in planes))
+            done = full_width(t_means, t_w, t_svals, t_swts)
+            with jax.named_scope("fold_staged.wide_rows"):
+                return jax.lax.cond(
+                    full,
+                    lambda out: tuple(
+                        jax.lax.dynamic_update_slice_in_dim(a, u, at, 0)
+                        for a, u in zip(out, done)),
+                    lambda out: tuple(
+                        a.at[rows].set(u, mode="drop",
+                                       indices_are_sorted=True,
+                                       unique_indices=True)
+                        for a, u in zip(out, done)),
+                    out)
+
+        means, weights = jax.lax.fori_loop(
+            0, trips, trip,
+            td._compress_narrow(svals[:, :w], swts[:, :w], compression, c))
 
     with jax.named_scope("fold_staged.scalars"):
         dmin = jnp.minimum(dmin, s_min)
@@ -304,7 +431,8 @@ def _histo_fold_staged(
         lweight, lweight_c = _comp_add(lweight, lweight_c, s_w)
         lrecip, lrecip_c = _comp_add(lrecip, lrecip_c, s_recip)
     return (means, weights, dmin, dmax, drecip, drecip_c,
-            lmin, lmax, lsum, lsum_c, lweight, lweight_c, lrecip, lrecip_c)
+            lmin, lmax, lsum, lsum_c, lweight, lweight_c, lrecip, lrecip_c,
+            n_wide)
 
 
 @functools.partial(jax.jit, static_argnames=("compression",), donate_argnums=(0, 1, 2, 3, 4, 5))
@@ -845,6 +973,8 @@ class DeviceWorker:
         # after swap(), so ingest-side spans name their flush exactly
         self.rec = self.guard.rec = flightrec.Recorder()
         self.flight_epoch = 1
+        # (rows, wide slots, wide rows) of the flush's staged folds so far
+        self._fold_widths: list = []
         # live pools are host-side (HostHistoState / np registers)
         self._host_live = False
         # a device fault voided this epoch's micro-fold mirror: the
@@ -3072,10 +3202,38 @@ class DeviceWorker:
                 "staged", self._shard.fold_staged, *fields, svj, swj)
         else:
             fields = self.guard.call(
-                "staged", _histo_fold_staged, *fields, svj, swj,
-                compression=self.compression)
+                "staged", self._fold_staged, *fields, svj, swj)
         pending.pop(0)
         return fields
+
+    def _fold_staged(self, *args):
+        """`_histo_fold_staged` over (the 14 fields, svals, swts): the 14
+        fields; its count of wide rows is kept, on the device, for
+        `_note_fold_widths`."""
+        weights, svals = args[1], args[14]
+        out = _histo_fold_staged(*args, compression=self.compression)
+        self._fold_widths.append(
+            (svals.shape[0],
+             fold_wide_slots(*svals.shape, weights.shape[1]), out[14]))
+        return out[:14]
+
+    def _note_fold_widths(self, span) -> None:
+        """Onto the flush's extract span: how many rows this flush's
+        staged folds found wide and narrow, and whether every fold
+        compacted its wide rows (`split`) or some fold took all its rows
+        at the full width (`full`: too many wide rows to compact, or a
+        depth it never splits)."""
+        if span is None or not self._fold_widths:
+            return
+        wide = total = 0
+        split = True
+        for rows, k, n in self._fold_widths:
+            n = int(n)
+            wide += n
+            total += rows
+            split = split and k > 0 and not fold_takes_all(n, k)
+        span.attrs.update(wide_rows=wide, narrow_rows=total - wide,
+                          fold_path="split" if split else "full")
 
     def _shard_flat_upload(self, flat_v, flat_w, counts_np, s_eff: int):
         """Split one compacted staged plane (flat samples in LOGICAL row
@@ -3140,6 +3298,8 @@ class DeviceWorker:
         for the query-view publish."""
         directory = swapped.directory
         rec = self.rec
+        extract_span = rec.current()
+        self._fold_widths = []
         if spill is not None:
             # hot-row spill backlog deferred by swap(): chunked fold
             # off the ingest lock (plain numpy from drain_histo — no
@@ -3239,10 +3399,7 @@ class DeviceWorker:
             # what pins micro-folded == batch-folded
             dense = (mf.mirror_dense if sh is None
                      else sh.mirror_dense)
-            folder = (sh.fold_staged if sh is not None
-                      else functools.partial(
-                          _histo_fold_staged,
-                          compression=self.compression))
+            folder = sh.fold_staged if sh is not None else self._fold_staged
 
             def _mirror_fold(fl):
                 dv = dense(dstage.vals, s_eff)
@@ -3344,6 +3501,7 @@ class DeviceWorker:
                 run.note(c, time.perf_counter() - t0)
             packed = (parts[0] if len(parts) == 1
                       else np.concatenate(parts, axis=0))
+        self._note_fold_widths(extract_span)
         with rec.span("extract.unpack"):
             qv, (dmin, dmax, dsum, dcount, drecip, lmin, lmax, lsum,
                  lweight, lrecip) = columnar.unpack_extract_columns(

@@ -503,17 +503,17 @@ def build_sharded_staged_fold(mesh: Mesh, compression: float = 100.0):
     sharded."""
     from veneur_tpu.core.worker import _histo_fold_staged
 
-    rows = P(("hosts", "series"))
-    spec2 = NamedSharding(mesh, P(("hosts", "series"), None))
-    spec1 = NamedSharding(mesh, rows)
+    spec2 = P(("hosts", "series"), None)
+    fields = tuple([spec2] * 2 + [P(("hosts", "series"))] * 12)
 
-    def _fold(*args):
+    def _local(*args):
+        # per shard, its wide rows told from its narrow ones there
         return _histo_fold_staged.__wrapped__(
-            *args, compression=compression)
+            *args, compression=compression)[:14]
 
-    in_sh = tuple([spec2, spec2] + [spec1] * 12 + [spec2, spec2])
-    out_sh = tuple([spec2, spec2] + [spec1] * 12)
-    return jax.jit(_fold, in_shardings=in_sh, out_shardings=out_sh)
+    return jax.jit(shard_map(_local, mesh=mesh,
+                             in_specs=fields + (spec2, spec2),
+                             out_specs=fields, check_vma=False))
 
 
 def build_counter_merge(mesh: Mesh):

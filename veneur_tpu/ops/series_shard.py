@@ -193,19 +193,23 @@ class SeriesSharding:
 
     @functools.cached_property
     def fold_staged(self):
-        """Sharded `_histo_fold_staged`: per-row independent, so plain
-        GSPMD jit with explicit shardings is enough — no shard_map."""
+        """Sharded `_histo_fold_staged`: per-row independent, each shard
+        folding its own rows (and telling its own wide rows from its
+        narrow ones: under plain GSPMD the fold's compaction of the wide
+        rows would run across the shards)."""
         from veneur_tpu.core.worker import _histo_fold_staged
 
         comp = self.compression
 
-        def _fold(*args):
-            return _histo_fold_staged.__wrapped__(*args, compression=comp)
+        def _local(*args):
+            return _histo_fold_staged.__wrapped__(
+                *args, compression=comp)[:14]
 
-        in_sh = tuple([self.sh2] * 2 + [self.sh1] * 12 + [self.sh2] * 2)
-        out_sh = tuple([self.sh2] * 2 + [self.sh1] * 12)
-        return jax.jit(_fold, in_shardings=in_sh, out_shardings=out_sh,
-                       donate_argnums=tuple(range(14)))
+        fields = tuple([P("series", None)] * 2 + [P("series")] * 12)
+        sm = shard_map(_local, mesh=self.mesh,
+                       in_specs=fields + (P("series", None),) * 2,
+                       out_specs=fields, check_vma=False)
+        return jax.jit(sm, donate_argnums=tuple(range(14)))
 
     @functools.cached_property
     def flush_extract(self):

@@ -146,6 +146,12 @@ def _compress_rows(
 
     Empty candidate slots must have weight 0 (mean value is then ignored).
     Output rows are sorted by mean with +inf padding.
+
+    Every row pays for all M slots, live or not: two sorts and three
+    log2(M)-step scans over [S, M]. A row that holds a few raw samples
+    and nothing else has the same result at `NARROW_WIDTH`
+    (`_compress_narrow`, which the staged fold takes for such rows); the
+    import and merge paths stay here, their rows are dense.
     """
     s, m = means.shape
     # 1. Sort each row by mean, carrying weights. Zero-weight slots are
@@ -209,6 +215,50 @@ def compress_rows(
     capacity: int = DEFAULT_CAPACITY,
 ) -> tuple[jax.Array, jax.Array]:
     return _compress_rows(means, weights, compression, capacity)
+
+
+#: The width a row of few raw samples is compressed at (`_compress_narrow`):
+#: a power of two, so that a prefix sum over the first NARROW_WIDTH slots
+#: of a wider row is the same tree of adds.
+NARROW_WIDTH = 16
+
+
+def _compress_narrow(
+    vals: jax.Array, wts: jax.Array, compression: float, capacity: int
+) -> tuple[jax.Array, jax.Array]:
+    """`_compress_rows` for rows whose only live slots are raw samples in
+    [S, W], W = NARROW_WIDTH: [S, W] → [S, capacity], bit for bit what
+    `_compress_rows` gives for the same rows at any width M that W
+    divides, empty slots (weight 0) filling the rest.
+
+    Why the same: a stable sort puts the live slots first in both, in the
+    same order. exn.cumsum is a doubling scan that shifts zeros in, so
+    its prefix at slot i < W is the same tree of adds whatever the row's
+    width, and the row's total, slot M-1 of the wide scan, is slot W-1
+    here where W divides M (its window splits into aligned blocks of
+    zeros and this one). So q_left, the bucket, the run ends and the run
+    sums of the live slots are the same, and the slots past them hold
+    weight 0 in both. Two things differ. The wide scan goes on adding
+    the zeros shifted in, which turns a prefix of -0.0 into +0.0: a
+    sample of -0.0 enters here as +0.0, which it equals in the sort and
+    in every sum it meets a non-zero in. And a wide row's padding can
+    round: its prefix sums are other trees over the same addends, and
+    where they fall a bit short of the total the padding splits into
+    runs of its own. The caller therefore sends here only rows whose
+    weights are whole numbers small enough that every partial sum is
+    exact (core/worker._staged_rows_wide).
+
+    On the chip the compiler lays an [S, 16] array out with S on the
+    lanes: 3.1 ms for 262,144 rows, pad included, where [S, 192] takes
+    62 (tools/fold_width_bench.py; PERF §6, PR 38)."""
+    w = vals.shape[1]
+    means, weights = _compress_rows(
+        jnp.where(vals == 0, jnp.zeros_like(vals), vals), wts,
+        compression, capacity)
+    with jax.named_scope("tdigest.narrow.pad"):
+        pad = ((0, 0), (0, capacity - w))
+        return (jnp.pad(means, pad, constant_values=_INF),
+                jnp.pad(weights, pad))
 
 
 class BatchStats(NamedTuple):
