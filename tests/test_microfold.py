@@ -416,3 +416,48 @@ def test_dispatched_chunk_buffers_are_not_refilled():
     st = m.finish()
     np.testing.assert_array_equal(np.asarray(st.vals)[:chunk, 0], first)
     np.testing.assert_array_equal(np.asarray(st.vals)[:chunk - 1, 1], second)
+
+
+@pytest.mark.parametrize("use_native", [False, True],
+                         ids=["python-plane", "native-plane"])
+def test_feed_spans_say_what_the_mirror_holds_and_what_they_dispatched(
+        use_native):
+    """`micro_fold.feed` carries `mirror_rows` (the rows the mirror has
+    allocated: what a scatter writes into) and `chunks` (the scatters
+    this feed dispatched) beside `samples` and `rows`, and
+    `swap.mirror_handoff` carries what the epoch's feeds came to
+    (PERF.md section 3): a scatter's device time can then be laid
+    against the entries it wrote and the array it wrote them into."""
+    from veneur_tpu.ops import microfold as mf
+
+    w = DeviceWorker(compression=100, stage_depth=64, batch_size=6,
+                     micro_fold=True, micro_fold_rows=1,
+                     micro_fold_max_age_s=1e9, initial_histo_rows=32)
+    if use_native and not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    w._micro = mf.MicroFoldMirror(
+        w.stage_depth, ledger=w.ledger, initial_rows=w._initial_histo_rows,
+        chunk=8, guard=w.guard)
+    fed = 0
+    for batch in range(5):
+        lines = [f"h{i}:{batch + i}.5|ms" for i in range(6)]
+        if use_native:
+            w.ingest_datagram("\n".join(lines).encode())
+        else:
+            for ln in lines:
+                w.process_metric(parse_metric(ln.encode()))
+        fed += w.micro_fold_once()
+    assert fed == 30
+    w.flush(QS)
+    spans = w.rec.closed()
+    feeds = [s.attrs for s in spans if s.name == "micro_fold.feed"]
+    assert [a["samples"] for a in feeds] == [6] * 5
+    assert all(a["rows"] == 6 for a in feeds)
+    # 30 entries in chunks of 8: the feeds that reach 8, 16 and 24
+    # entries scatter, the padded fourth chunk is the flush's
+    assert [a["chunks"] for a in feeds] == [0, 1, 1, 1, 0]
+    # nothing is allocated before the first scatter
+    assert [a["mirror_rows"] for a in feeds] == [0, 32, 32, 32, 32]
+    (handoff,) = [s.attrs for s in spans if s.name == "swap.mirror_handoff"]
+    assert handoff == {"samples": 30, "rows": 6, "mirror_rows": 32,
+                       "chunks": 3}

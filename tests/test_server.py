@@ -847,6 +847,9 @@ def test_flush_ingest_soak_columnar_no_loss():
                    for i in range(2)]
         for t in threads:
             t.start()
+        # three flushes of an idle server can be over before a loaded
+        # runner has let either blaster send: race them against traffic
+        assert _wait_for(lambda: all(sent), timeout=10.0)
         flushes = 0
         deadline = time.time() + 30.0
         while flushes < 3 and time.time() < deadline:

@@ -6,7 +6,9 @@ TestLocalServerMixedMetrics (:299).
 """
 
 import numpy as np
+import pytest
 
+import veneur_tpu.core.worker as W
 from veneur_tpu.core.directory import ScopeClass
 from veneur_tpu.core.flusher import (
     device_quantiles,
@@ -321,11 +323,39 @@ def test_swap_then_extract_two_phase_flush():
     assert len(snap_new.scalars.counter_meta) == 1
 
 
+class _SpillSteps:
+    """While open, every dispatch of the spill fold's ingest step, as
+    (op, pool rows, row bucket, samples, summed weight); `check` sees
+    each step's batch weights and output fields."""
+
+    def __init__(self, check=None):
+        self.steps, self.check = [], check
+
+    def __enter__(self):
+        self._orig = orig = W.DeviceWorker._spill_step
+        rec = self
+
+        def recording(self, op, fields, pool_rows, active, lids, v, wts):
+            out = orig(self, op, fields, pool_rows, active, lids, v, wts)
+            if rec.check is not None:
+                rec.check(wts, out)
+            rec.steps.append((op, pool_rows, len(active), len(v),
+                              float(wts.sum())))
+            return out
+
+        W.DeviceWorker._spill_step = recording
+        return self.steps
+
+    def __exit__(self, *exc):
+        W.DeviceWorker._spill_step = self._orig
+
+
 def test_chunked_drain_fold_conserves_samples():
     """A drain after a stall can hold far more spilled samples than one
     fold batch should carry (each fold's padded arrays are O(batch));
-    _apply_native_raw folds in bounded chunks. Weight conservation
-    across the chunk boundary proves no sample is lost or doubled."""
+    _fold_batch_direct folds in slices of _FOLD_CHUNK, each padded to
+    that one length. Weight conservation across the slice boundary
+    proves no sample is lost or doubled."""
     w = DeviceWorker(stage_depth=2)
     if not w.attach_native():
         pytest.skip("native library unavailable")
@@ -336,9 +366,8 @@ def test_chunked_drain_fold_conserves_samples():
             b"\n".join(b"chunk.r%d:%d|ms" % (r, (i + r) % 97)
                        for r in range(4)))
     # shrink the chunk so this test crosses several boundaries
-    import veneur_tpu.core.worker as W
     orig_chunk = W._FOLD_CHUNK
-    orig_fold = W.DeviceWorker._fold_batch_direct
+    orig_fold = W.DeviceWorker._fold_slice_direct
     calls = []
 
     def counting(self, rows, vals, wts):
@@ -346,14 +375,18 @@ def test_chunked_drain_fold_conserves_samples():
         return orig_fold(self, rows, vals, wts)
 
     W._FOLD_CHUNK = 512
-    W.DeviceWorker._fold_batch_direct = counting
+    W.DeviceWorker._fold_slice_direct = counting
     try:
-        w.drain_native()
+        with _SpillSteps() as steps:
+            w.drain_native()
     finally:
         W._FOLD_CHUNK = orig_chunk
-        W.DeviceWorker._fold_batch_direct = orig_fold
+        W.DeviceWorker._fold_slice_direct = orig_fold
     assert len(calls) > 1  # the drain really folded in chunks
     assert all(c <= 512 for c in calls)
+    # and the one-shape pad takes the shrunk chunk: every dispatch, the
+    # short last slice too, is 512 samples over the least row bucket
+    assert [st[2:4] for st in steps] == [(256, 512)] * len(calls)
     qs = device_quantiles(PCTS, AGGS)
     snap = w.flush(qs)
     # staged (2/row) + spilled samples all land: lweight == total
@@ -598,6 +631,215 @@ def test_staged_matches_direct_fold():
     assert abs(sa["p50"] - da["p50"]) <= 0.05 * max(1.0, abs(da["p50"]))
 
 
+def _spill_worker(n_rows: int, **kw) -> DeviceWorker:
+    """A worker with `n_rows` timer rows of one sample (value 1) each and
+    a device pool to spill into."""
+    w = DeviceWorker(stage_depth=512, batch_size=1 << 20, **kw)
+    for r in range(n_rows):
+        w.process_metric(parse_metric(b"spill.r%d:1|ms" % r))
+    w._ensure_histo(w.directory.num_histo_rows)
+    return w
+
+
+def _pool_bytes(fields) -> list:
+    """The pool's rows below the scratch row, byte for byte. (The
+    scratch row takes every fold's padding: it stays at weight 0 and
+    its centroid means go from a fresh pool's 0 to the empty digest's
+    inf with the first fold of anything.)"""
+    assert not np.asarray(fields[1])[-1].any()
+    return [np.asarray(a)[:-1].tobytes() for a in fields]
+
+
+@pytest.mark.parametrize("n", [1, 2, 255, 256, 257, 4095, 4097, 8191,
+                               16383, 16384])
+def test_spill_pad_has_one_sample_length(n):
+    """_pad_spill_batch pads every batch to _FOLD_CHUNK samples, so the
+    ingest step's programs differ by row bucket alone: powers of four
+    from 256, padding at weight 0 on the scratch row's bucket slot."""
+    assert W._FOLD_CHUNK == 16384 and W._SHED_FLOOR == 262144
+    rng = np.random.default_rng(n)
+    n_uniq = min(n, int(rng.integers(1, 5000)))
+    rows = rng.choice(100000, size=n_uniq, replace=False)[
+        rng.integers(0, n_uniq, size=n)].astype(np.int32)
+    vals = rng.integers(1, 1000, size=n).astype(np.float32)
+    active, lids, v, w = DeviceWorker._pad_spill_batch(
+        rows, vals, np.ones(n, np.float32), scratch=131071)
+    assert len(lids) == len(v) == len(w) == W._FOLD_CHUNK
+    k = len(active)
+    uniq = len(np.unique(rows))
+    assert k in (256, 1024, 4096, 16384) and uniq <= k
+    assert k == 256 or uniq > k // 4
+    np.testing.assert_array_equal(active[lids[:n]], rows)
+    np.testing.assert_array_equal(v[:n], vals)
+    assert (active[uniq:] == 131071).all()
+    assert (lids[n:] == k - 1).all() and not w[n:].any() and w[:n].all()
+
+
+def test_a_long_spill_batch_folds_in_slices_to_what_the_reference_says():
+    """40,000 samples over 300 rows fold in three dispatches of
+    _FOLD_CHUNK samples; count, min and max of every row are the
+    float64 reference's, the sum is to float32 rounding, and each
+    quantile's rank error is inside the benchmark's limit (PERF.md
+    section 2; bench/reference.py)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import reference
+
+    n_rows, extra = 300, 40000
+    w = _spill_worker(n_rows)
+    rng = np.random.default_rng(11)
+    rows = rng.integers(0, n_rows, size=extra).astype(np.int32)
+    vals = rng.integers(2, 5000, size=extra).astype(np.float32)
+    with _SpillSteps() as steps:
+        w._fold_batch_direct(rows, vals, np.ones(extra, np.float32))
+    chunk = W._FOLD_CHUNK
+    # 300 rows: bucket 1024, after bucket 256 once on nothing
+    assert [st[2:] for st in steps] == [
+        (256, chunk, 0.0), (1024, chunk, float(chunk)),
+        (1024, chunk, float(chunk)), (1024, chunk, float(extra - 2 * chunk))]
+    snap = w.flush(device_quantiles(PCTS, AGGS))
+    worst = 0.0
+    for r in range(n_rows):
+        seg = np.sort(np.append(vals[rows == r].astype(np.float64), 1.0))
+        n = len(seg)
+        assert float(snap.lweight[r]) == n
+        # float32 segment sums inside a 16,384-sample step: to rounding
+        assert abs(float(snap.lsum[r]) - seg.sum()) <= 1e-4 * seg.sum()
+        assert float(snap.lmin[r]) == seg[0] == 1.0
+        assert float(snap.lmax[r]) == seg[-1]
+        assert n > reference.unmerged_n(w.compression)
+        for j, q in enumerate(snap.quantile_qs):
+            if not 0.0 < q < 1.0:
+                continue
+            got = float(snap.quantile_values[r, j])
+            err = max(0.0, np.searchsorted(seg, got, "left") / n - q,
+                      q - np.searchsorted(seg, got, "right") / n)
+            worst = max(worst, err / reference.rank_bound(q, n, w.compression))
+    assert 0.0 < worst <= reference.LIMITS["quantile_rank_over_bound"]
+
+
+@pytest.mark.parametrize("shards", [0, 2], ids=["one-device", "sharded"])
+def test_a_row_buckets_first_spill_fold_warms_the_smaller_buckets(shards):
+    """Before the first fold of row bucket 4,096 at a pool size, the
+    buckets 256 and 1,024 are dispatched once each on nothing, inside
+    `spill.warm` spans, and leave every series' row bitwise as it was;
+    later folds warm nothing; a second pool size warms anew; and a swapped
+    epoch's deferred spill (_fold_spill_chunk) shares the book."""
+    import jax
+
+    if shards and len(jax.devices()) < shards:
+        pytest.skip("needs %d devices" % shards)
+    n_rows = 1100
+    w = _spill_worker(n_rows, **({"series_shards": shards} if shards else {}))
+    pool_rows = w._histo.num_rows
+    before = _pool_bytes(w._histo.fields())
+
+    def nothing_changes_nothing(wts, out):
+        if not wts.any():
+            assert _pool_bytes(out) == before
+
+    def warm_spans():
+        return [(s.attrs["pool_rows"], s.attrs["bucket"])
+                for s in w.rec.closed() if s.name == "spill.warm"]
+
+    rows = np.arange(n_rows, dtype=np.int32)
+    ones = np.ones(n_rows, np.float32)
+    chunk = W._FOLD_CHUNK
+    with _SpillSteps(nothing_changes_nothing) as steps:
+        w._fold_batch_direct(rows, 2 * ones, ones)
+        assert steps == [("fold", pool_rows, 256, chunk, 0.0),
+                         ("fold", pool_rows, 1024, chunk, 0.0),
+                         ("fold", pool_rows, 4096, chunk, float(n_rows))]
+        assert warm_spans() == [(pool_rows, 256), (pool_rows, 1024)]
+        # every bucket up to 4,096 is there now: nothing warms again,
+        # on the live pool or on a swapped epoch's
+        w._fold_batch_direct(rows[:300], 3 * ones[:300], ones[:300])
+        w._fold_batch_direct(rows[:7], 3 * ones[:7], ones[:7])
+        h = w._histo
+        (h.means, h.weights, h.dmin, h.dmax, h.drecip, h.drecip_c, h.lmin,
+         h.lmax, h.lsum, h.lsum_c, h.lweight, h.lweight_c, h.lrecip,
+         h.lrecip_c) = w._fold_spill_chunk(
+             h.fields(), rows[:9], 3 * ones[:9], ones[:9], pool_rows)
+        assert [(st[0], st[2], st[4]) for st in steps[3:]] == [
+            ("fold", 1024, 300.0), ("fold", 256, 7.0), ("spill", 256, 9.0)]
+        assert len(warm_spans()) == 2
+        snap = w.flush(device_quantiles(PCTS, AGGS))
+        assert float(np.sum(snap.lweight[:n_rows])) == 2 * n_rows + 316
+        # a second pool size: its programs are others, so it warms anew,
+        # here from a swapped epoch's deferred spill
+        del steps[:]
+        w.process_metric(parse_metric(b"spill.r0:1|ms"))
+        w._ensure_histo(4 * pool_rows)
+        grown = w._histo.num_rows
+        assert grown > pool_rows
+        before = _pool_bytes(w._histo.fields())
+        w._fold_spill_chunk(w._histo.fields(), rows[:300], ones[:300],
+                            ones[:300], grown)
+        assert steps == [("spill", grown, 256, chunk, 0.0),
+                         ("spill", grown, 1024, chunk, 300.0)]
+        assert warm_spans()[2:] == [(grown, 256)]
+
+
+def test_a_thin_flush_folds_at_a_row_bucket_it_has_programs_for():
+    """A flush that needs fewer rows than an earlier one at this pool
+    size folds and extracts at the earlier one's row count (`fold_rows`
+    on the extract span, beside `rows_used`) and compiles nothing; one
+    that needs more than any before takes its own bucket; all of them
+    hold to the samples."""
+    w = DeviceWorker(stage_depth=8, initial_histo_rows=8192)
+    qs = device_quantiles([0.5], AGGS)
+    rng = np.random.default_rng(5)
+
+    def flush_of(n_series):
+        per = rng.integers(1, 6, size=n_series)
+        vals = [np.sort(rng.integers(1, 4000, size=k)).astype(np.float64)
+                for k in per]
+        for i, vs in enumerate(vals):
+            for v in rng.permutation(vs):
+                w.process_metric(parse_metric(b"thin.s%d:%d|ms" % (i, v)))
+        with w.rec.span("flush.extract") as sp:
+            snap = w.flush(qs)
+        assert snap.directory.num_histo_rows == n_series
+        ops = {s.attrs.get("op") for s in w.rec.closed()[-40:]
+               if s.name == "dispatch"}
+        assert {"staged", "extract"} <= ops
+        (j,) = [j for j, q in enumerate(snap.quantile_qs) if q == 0.5]
+        for i, vs in enumerate(vals):
+            k = len(vs)
+            assert float(snap.lweight[i]) == k
+            assert float(snap.lmin[i]) == vs[0]
+            assert float(snap.lmax[i]) == vs[-1]
+            # few samples: the median lies between the order statistics
+            # on either side of rank k/2 (bench/reference.py)
+            lo = vs[max(int(np.floor(0.5 * k)) - 1, 0)]
+            hi = vs[min(int(np.ceil(0.5 * k)), k - 1)]
+            assert lo <= float(snap.quantile_values[i, j]) <= hi
+        return sp.attrs["rows_used"], sp.attrs["fold_rows"]
+
+    def programs():
+        return (W._histo_fold_staged._cache_size(),
+                W._histo_flush_extract._cache_size())
+
+    assert flush_of(3000) == (3000, 4096)
+    had = programs()
+    assert flush_of(1000) == (1000, 4096)
+    assert programs() == had
+    assert flush_of(5000) == (5000, 8192)
+    assert flush_of(1000) == (1000, 4096)
+    assert flush_of(4000) == (4000, 4096)
+    assert flush_of(4097) == (4097, 8192)
+    assert w._flush_rows_had == {8192: {4096, 8192}}
+    # a bucket is a pool size's own: another pool size starts anew
+    assert w._flush_rows(16384, 1000) == 1024
+    assert w._flush_rows(16384, 9000) == 16384
+    assert w._flush_rows(16384, 600) == 1024
+    assert w._flush_rows(16384, 1025) == 16384
+
+
 def test_scalar_pool_growth_at_capacity_boundary():
     """Regression: adopting the row that crosses the pool's capacity
     (row == initial capacity) crashed in ensure() because `used` was
@@ -628,8 +870,6 @@ def test_scalar_pool_growth_at_capacity_boundary():
 # series id. The plain reference below is the per-series loop the program
 # had before (one RowMeta lookup, one adopt call per series), kept here so
 # that the batch path is held to its answers field by field.
-
-import pytest
 
 from veneur_tpu.core.directory import RowMeta, SeriesDirectory
 from veneur_tpu.core.metrics import MetricKey, route_info, tenant_of

@@ -66,20 +66,27 @@ from veneur_tpu.utils.hashing import hll_hash, fmix64, metric_digest
 log = logging.getLogger("veneur_tpu.core.worker")
 
 
-# max spilled samples per direct-fold dispatch (see _apply_native_raw);
-# bounds drain memory to O(chunk) x the in-flight window, not O(backlog)
-_FOLD_CHUNK = 1 << 18
+# spilled samples per spill-fold dispatch: the one sample shape the spill
+# fold has (see _pad_spill_batch). A longer batch goes in slices, which
+# also bounds drain memory to O(chunk) x the in-flight window.
+_FOLD_CHUNK = 1 << 14
+
+# the least backlog a flush's spill-fold budget keeps (_shed_spill_budget)
+_SHED_FLOOR = 1 << 18
 
 # The spill fold's shape ladder (see _pad_spill_batch): active rows in
-# powers of four from 256, samples in powers of two from 16,384. Every
-# (rows, samples) pair is a program of its own — 37-66 s to compile on a
-# v5e, 0.15-0.5 s to load, under the ingest lock — and a drain's spill
-# batch is whatever arrived since the last micro-fold, so a fine ladder
-# keeps meeting new shapes for many intervals (PERF.md section 6, PR 26).
-# Padding is cheap since the k-bucket is a count: the step takes 13 ms on
-# the chip at 4096 x 32,768, against 74 with the search.
+# powers of four from 256, samples always _FOLD_CHUNK. Every (rows,
+# samples) pair is a program of its own — 10-66 s to compile on a v5e,
+# 0.15-0.5 s to load, under the ingest lock — and a drain's spill batch
+# is whatever arrived since the last micro-fold: with samples in powers
+# of two as well, a seventh shape was still first met thirty intervals
+# into a run (PERF.md section 6, PR 39). A row bucket's first fold also
+# runs the smaller buckets once on nothing (_warm_spill_rows), so what a
+# traffic needs is compiled when its first interval spills and not when
+# a thin batch happens by. Padding is cheap since the k-bucket is a
+# count: the step takes 8 ms on the chip at 4096 x 16,384, against 74
+# with the search.
 _SPILL_MIN_ROWS = 256
-_SPILL_MIN_SAMPLES = 1 << 14
 
 # HBM valve threshold (see _ensure_histo): pool growths whose estimated
 # device footprint stays under this skip the allocation pre-flight — a
@@ -881,6 +888,12 @@ class DeviceWorker:
         self.overload_dropped = 0
         self.overload_dropped_total = 0
         self._inflight_folds = 0
+        # pool rows -> the largest spill row bucket folded at that pool
+        # size (_warm_spill_rows)
+        self._spill_rows_warm: dict[int, int] = {}
+        # pool rows -> the row counts flushes have folded and extracted
+        # at, at that pool size (_flush_rows)
+        self._flush_rows_had: dict[int, set[int]] = {}
         # per-flush spill-fold budget: seconds of fold work one flush may
         # inherit (the server sets this to a fraction of its interval)
         # and the measured fold throughput that converts it to samples.
@@ -1515,21 +1528,15 @@ class DeviceWorker:
                     # with native staging on, the SoA batch holds only
                     # hot-row spill: fold it directly (K is small there;
                     # re-staging it in the Python plane would just add a
-                    # second fold). Chunked: a drain after a stall can
-                    # hold millions of spilled samples, and one fold's
-                    # padded [N] arrays at that size are ~100MB — eight
-                    # in flight was most of the RSS in the overload
-                    # soak. Bounded chunks × the in-flight window keeps
-                    # drain memory O(chunk), not O(backlog).
+                    # second fold). The fold goes in slices of
+                    # _FOLD_CHUNK: a drain after a stall can hold
+                    # millions of spilled samples, and one fold's padded
+                    # [N] arrays at that size are ~100MB — eight in
+                    # flight was most of the RSS in the overload soak.
                     if defer_histo_spill:
                         deferred = h
                     else:
-                        rows, vals, wts = h
-                        chunk = _FOLD_CHUNK
-                        for i in range(0, len(rows), chunk):
-                            self._fold_batch_direct(
-                                rows[i:i + chunk], vals[i:i + chunk],
-                                wts[i:i + chunk])
+                        self._fold_batch_direct(*h)
                 else:
                     self._device_histo_step(*h)
         if s is not None and len(s[0]):
@@ -1616,13 +1623,9 @@ class DeviceWorker:
                 with self.rec.span("micro_fold.drain",
                                    flush=self.flight_epoch):
                     self.drain_native()
-                with self.rec.span("micro_fold.feed",
-                                   flush=self.flight_epoch):
-                    fed = self._micro_drain_native()
+                fed = self._micro_feed(self._micro_drain_native)
             else:
-                with self.rec.span("micro_fold.feed",
-                                   flush=self.flight_epoch):
-                    fed = self._micro_drain_python()
+                fed = self._micro_feed(self._micro_drain_python)
         except dg.DeviceFaultError as exc:
             # the mirror is a CACHE of the staging plane — the plane
             # retains every sample (watermarks advanced, counts did
@@ -1640,6 +1643,22 @@ class DeviceWorker:
             gov = self.governor
             if gov is not None:
                 gov.note_micro_fold(fed)
+        return fed
+
+    def _micro_feed(self, drain) -> int:
+        """One drain into the mirror as a ``micro_fold.feed`` span that
+        says what it fed and into what: ``samples``, ``rows`` (1 + the
+        highest row mirrored), ``mirror_rows`` (the rows the mirror has
+        allocated) and ``chunks`` (scatters this feed dispatched)."""
+        before = self._micro.chunks if self._micro is not None else 0
+        with self.rec.span("micro_fold.feed",
+                           flush=self.flight_epoch) as sp:
+            fed = drain()
+            micro = self._micro
+            if micro is not None:
+                sp.attrs.update(samples=fed, rows=micro.rows_hi,
+                                mirror_rows=micro.mirror_rows,
+                                chunks=micro.chunks - before)
         return fed
 
     def _micro_drain_native(self) -> int:
@@ -2135,16 +2154,17 @@ class DeviceWorker:
     @staticmethod
     def _pad_spill_batch(rows: np.ndarray, vals: np.ndarray,
                          wts: np.ndarray, scratch: int):
-        """Pad one spill batch to the ingest step's shape ladder
-        (_SPILL_MIN_ROWS): padding sample slots point at `scratch` with
-        weight 0, which the step treats as absent. Shared by the
-        live-pool and swapped-epoch folds so their jit shapes (and
-        semantics) cannot drift."""
+        """Pad one spill batch (at most _FOLD_CHUNK samples) to the
+        ingest step's shape ladder (_SPILL_MIN_ROWS): padding sample
+        slots point at `scratch` with weight 0, which the step treats as
+        absent. Shared by the live-pool and swapped-epoch folds so their
+        jit shapes (and semantics) cannot drift."""
         uniq, inverse = np.unique(rows, return_inverse=True)
         k = _SPILL_MIN_ROWS
         while k < len(uniq):
             k *= 4
-        n = _next_pow2(len(vals), _SPILL_MIN_SAMPLES)
+        n = _FOLD_CHUNK
+        assert len(vals) <= n
         active = np.full(k, scratch, dtype=np.int32)
         active[: len(uniq)] = uniq
         lids = np.full(n, k - 1, dtype=np.int32)
@@ -2158,42 +2178,38 @@ class DeviceWorker:
     def _fold_batch_direct(self, rows: np.ndarray, vals: np.ndarray,
                            wts: np.ndarray) -> None:
         """Gather→add_batch→scatter device fold of one sample batch — the
-        spill path for rows whose staging plane is full."""
+        spill path for rows whose staging plane is full — in slices of
+        _FOLD_CHUNK samples."""
+        for i in range(0, len(vals), _FOLD_CHUNK):
+            self._fold_slice_direct(rows[i:i + _FOLD_CHUNK],
+                                    vals[i:i + _FOLD_CHUNK],
+                                    wts[i:i + _FOLD_CHUNK])
+
+    def _fold_slice_direct(self, rows: np.ndarray, vals: np.ndarray,
+                           wts: np.ndarray) -> None:
+        """One slice (at most _FOLD_CHUNK samples) into the live pool."""
         h = self._histo
         assert h is not None
         active, lids, v, w = self._pad_spill_batch(
             rows, vals, wts, h.num_rows - 1)
 
-        if isinstance(h, he.HostHistoState):
-            # quarantined: the host engine's bit-identical ingest twin
-            out = he.np_ingest_step(*h.fields(), active, lids, v, w,
-                                    compression=self.compression)
+        def put(out):
             (h.means, h.weights, h.dmin, h.dmax, h.drecip, h.drecip_c,
              h.lmin, h.lmax, h.lsum, h.lsum_c, h.lweight, h.lweight_c,
              h.lrecip, h.lrecip_c) = out
+
+        if isinstance(h, he.HostHistoState):
+            # quarantined: the host engine's bit-identical ingest twin
+            put(he.np_ingest_step(*h.fields(), active, lids, v, w,
+                                  compression=self.compression))
             return
 
-        sh = self._shard
+        def step(*batch):
+            put(self._spill_step("fold", h.fields(), h.num_rows, *batch))
+
         try:
-            if sh is not None:
-                # replicated COO, physical `active`: every shard folds the
-                # bit-identical batch and keeps only the writes it owns
-                # (ops/series_shard.ingest_step — the OOB-foreign remap)
-                out = self.guard.call(
-                    "fold", sh.ingest_step,
-                    *h.fields(),
-                    sh.replicate(sh.phys_rows(active, h.num_rows)),
-                    sh.replicate(lids), sh.replicate(v), sh.replicate(w),
-                )
-            else:
-                out = self.guard.call(
-                    "fold", _histo_ingest_step,
-                    h.means, h.weights, h.dmin, h.dmax, h.drecip,
-                    h.drecip_c, h.lmin, h.lmax, h.lsum, h.lsum_c,
-                    h.lweight, h.lweight_c, h.lrecip, h.lrecip_c,
-                    jnp.asarray(active), jnp.asarray(lids), jnp.asarray(v),
-                    jnp.asarray(w), compression=self.compression,
-                )
+            self._warm_spill_rows(h.num_rows, len(active), step)
+            step(active, lids, v, w)
         except dg.DeviceFaultError:
             # the fold donates the pool, so no in-place retry. The host
             # inputs are still ours: if the breaker tripped, quarantine
@@ -2203,15 +2219,12 @@ class DeviceWorker:
             # and a still-sick device walks the streak to the breaker.
             if self.guard.quarantined:
                 self._quarantine_live()
-                self._fold_batch_direct(rows, vals, wts)
+                self._fold_slice_direct(rows, vals, wts)
             else:
                 self._ph_rows.extend(rows.tolist())
                 self._ph_vals.extend(vals.tolist())
                 self._ph_wts.extend(wts.tolist())
             return
-        (h.means, h.weights, h.dmin, h.dmax, h.drecip, h.drecip_c,
-         h.lmin, h.lmax, h.lsum, h.lsum_c, h.lweight, h.lweight_c,
-         h.lrecip, h.lrecip_c) = out
         # bound the async dispatch queue: an un-executed fold holds its
         # input buffers, and a backend slower than the offered load
         # would otherwise queue folds without limit (observed: 2.7GB RSS
@@ -2226,38 +2239,78 @@ class DeviceWorker:
                 h.means.block_until_ready()
             self._inflight_folds = 0
 
+    def _spill_step(self, op: str, fields: tuple, pool_rows: int,
+                    active: np.ndarray, lids: np.ndarray, v: np.ndarray,
+                    w: np.ndarray) -> tuple:
+        """One padded spill batch through the ingest step: the whole
+        pool `fields` (donated) -> the 14 new fields. `op` is the guard's
+        name for the dispatch: "fold" into the live pool, "spill" into a
+        swapped epoch's, whose uploads the flush's ledger books."""
+        led = self.ledger if op == "spill" else None
+        sh = self._shard
+        if sh is not None:
+            # replicated COO, physical `active`: every shard folds the
+            # bit-identical batch and keeps only the writes it owns
+            # (ops/series_shard.ingest_step — the OOB-foreign remap).
+            # Replication is a real per-device transfer: booked once per
+            # shard (the transfer-diet pin stays honest)
+            ups = []
+            for a in (sh.phys_rows(active, pool_rows), lids, v, w):
+                if led is not None:
+                    led.count_h2d_shards([a.nbytes] * sh.shards, "spill")
+                ups.append(sh.replicate(a))
+            return self.guard.call(op, sh.ingest_step, *fields, *ups)
+        up = (jnp.asarray if led is None
+              else functools.partial(led.h2d, kind="spill"))
+        return self.guard.call(
+            op, _histo_ingest_step, *fields,
+            up(active), up(lids), up(v), up(w),
+            compression=self.compression)
+
+    def _warm_spill_rows(self, pool_rows: int, k: int, step) -> None:
+        """Before the first fold of row bucket `k` at this pool size,
+        fold nothing through each smaller bucket not folded there yet
+        (`step` takes one padded batch; all padding here, weight 0 on
+        the scratch row, which the ingest step treats as absent: every
+        series' row comes back bitwise equal). An interval's spill
+        climbs through the buckets as its hot rows fill, and a thin
+        batch between two micro-folds may skip one: left to chance, that
+        bucket's program compiles the first time one does not, any
+        number of intervals later, under the ingest lock (PERF.md
+        section 6, PR 39)."""
+        top = self._spill_rows_warm.get(pool_rows, 0)
+        if k <= top:
+            return
+        b = max(top * 4, _SPILL_MIN_ROWS)
+        zeros = np.zeros(_FOLD_CHUNK, dtype=np.float32)
+        while b < k:
+            with self.rec.span("spill.warm", pool_rows=pool_rows, bucket=b):
+                step(np.full(b, pool_rows - 1, dtype=np.int32),
+                     np.full(_FOLD_CHUNK, b - 1, dtype=np.int32),
+                     zeros, zeros)
+            b *= 4
+        self._spill_rows_warm[pool_rows] = k
+
     def _fold_spill_chunk(self, fields: tuple, rows: np.ndarray,
                           vals: np.ndarray, wts: np.ndarray,
                           pool_rows: int) -> tuple:
-        """_fold_batch_direct's twin for a SWAPPED epoch: folds one spill
+        """_fold_slice_direct's twin for a SWAPPED epoch: folds one spill
         chunk into the detached full-pool `fields` tuple instead of the
         live self._histo — same shapes, same jit specialization, so the
         compile _fold_batch_direct paid mid-interval is reused here.
         Runs in extract_snapshot, off the ingest lock. Padding entries
         carry weight 0, which the ingest step treats as absent (same
-        invariant _fold_batch_direct relies on for its scratch row)."""
+        invariant _fold_slice_direct relies on for its scratch row)."""
         active, lids, v, w = self._pad_spill_batch(
             rows, vals, wts, pool_rows - 1)
-        led = self.ledger
-        sh = self._shard
-        if sh is not None:
-            # replication is a real per-device transfer: book the batch
-            # once per shard (the transfer-diet pin stays honest), then
-            # fold it everywhere with the OOB-foreign remap
-            d = sh.shards
-            act = sh.phys_rows(active, pool_rows)
-            ups = []
-            for a in (act, lids, v, w):
-                led.count_h2d_shards([a.nbytes] * d, "spill")
-                ups.append(sh.replicate(a))
-            return self.guard.call("spill", sh.ingest_step, *fields, *ups)
-        return self.guard.call(
-            "spill", _histo_ingest_step,
-            *fields,
-            led.h2d(active, "spill"), led.h2d(lids, "spill"),
-            led.h2d(v, "spill"), led.h2d(w, "spill"),
-            compression=self.compression,
-        )
+
+        def step(*batch):
+            nonlocal fields
+            fields = self._spill_step("spill", fields, pool_rows, *batch)
+
+        self._warm_spill_rows(pool_rows, len(active), step)
+        step(active, lids, v, w)
+        return fields
 
     def _flush_pending_sets(self) -> None:
         if not self._ps_rows:
@@ -2698,7 +2751,7 @@ class DeviceWorker:
         (health/policy.py): over-budget tenants shed first."""
         if spill_histo is None:
             return None
-        budget = max(_FOLD_CHUNK,
+        budget = max(_SHED_FLOOR,
                      int(self._fold_rate_ewma * self.fold_budget_s))
         total = len(spill_histo[0])
         if total <= budget:
@@ -2957,12 +3010,18 @@ class DeviceWorker:
         device_stage = None
         micro_residual = None
         if self._micro_active():
-            with rec.span("swap.mirror_handoff"):
+            with rec.span("swap.mirror_handoff") as sp:
                 if self._native is None:
                     coo = self._python_stage_delta()
                     if coo is not None:
                         micro_coo.append(coo)
                 mirror, self._micro = self._micro, None
+                if mirror is not None:
+                    # the epoch's whole mirror: what its feeds came to
+                    sp.attrs.update(samples=mirror.samples,
+                                    rows=mirror.rows_hi,
+                                    mirror_rows=mirror.mirror_rows,
+                                    chunks=mirror.chunks)
                 residual_n = sum(len(c[0]) for c in micro_coo)
                 if (mirror is not None and mirror.samples > 0) or residual_n:
                     if mirror is None:
@@ -3685,6 +3744,24 @@ class DeviceWorker:
             snap.digest_weights = np.array(fields[1][:n])
         return fields, s_eff
 
+    def _flush_rows(self, pool_rows: int, n: int) -> int:
+        """The row count a flush of `n` used rows folds and extracts at.
+        Over the USED rows only: the pool is up to 2x oversized from
+        power-of-two growth, and both programs' cost is linear in rows;
+        pow2 bucketing bounds compile variants. And at a bucket this
+        worker already has programs for, where one holds the rows: a
+        flush that needs fewer rows than an earlier one (traffic thinned:
+        a deploy, a partition, a sender's stop) would otherwise compile
+        fold, extract, pack and slices at its own bucket inside the
+        flush, 7-25 s on a v5e against the 0.1-0.4 s the larger bucket
+        costs (PERF.md section 6, PR 40). The rows between `n` and the
+        bucket are unused pool rows either way."""
+        want = min(pool_rows, _next_pow2(n, 1024))
+        had = self._flush_rows_had.setdefault(pool_rows, set())
+        s_eff = min((b for b in had if b >= want), default=want)
+        had.add(s_eff)
+        return s_eff
+
     def extract_snapshot(self, swapped: "SwappedEpoch",
                          quantiles: np.ndarray,
                          interval_s: float = 10.0) -> FlushSnapshot:
@@ -3736,10 +3813,9 @@ class DeviceWorker:
         view_s_eff = 0
         if histo is not None and directory.num_histo_rows:
             n = directory.num_histo_rows
-            # fold + extract over the USED rows only: the pool is up to 2x
-            # oversized from power-of-two growth, and both programs' cost
-            # is linear in rows. Pow2 bucketing bounds compile variants.
-            s_eff = min(histo.num_rows, _next_pow2(n, 1024))
+            s_eff = self._flush_rows(histo.num_rows, n)
+            self.rec.add("fold_rows", s_eff)
+            self.rec.add("rows_used", n)
             full = (histo.means, histo.weights, histo.dmin,
                     histo.dmax, histo.drecip, histo.drecip_c,
                     histo.lmin, histo.lmax, histo.lsum, histo.lsum_c,

@@ -156,6 +156,12 @@ class MicroFoldMirror:
         # drains so upload totals are partition-invariant
         self._new_carry()
 
+    @property
+    def mirror_rows(self) -> int:
+        """Rows the device mirror has allocated (0 before the first
+        dispatch): what a scatter writes into, whatever it writes."""
+        return self._m
+
     def _new_carry(self) -> None:
         """Fresh host buffers for the next chunk. An upload returns
         before the device has the bytes (and the CPU backend aliases an
