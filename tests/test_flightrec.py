@@ -272,7 +272,10 @@ def _lowered_text(name):
             jnp.zeros((rows, 2), jnp.float32),
             *[jnp.zeros((rows,), jnp.float32)] * 10),
         "scatter_chunk": lambda: mf._scatter_chunk.lower(
-            plane, plane, i32, i32, f32v, f32v),
+            plane.reshape(-1), plane.reshape(-1), i32, i32, f32v, f32v,
+            depth=depth),
+        "mirror_dense": lambda: mf.mirror_dense.lower(
+            plane.reshape(-1), rows // 2, depth),
         "hll_estimate": lambda: hll.estimate.lower(regs, 14),
         "hll_insert": lambda: hll.insert_batch.lower(
             regs, i32, i32, jnp.zeros((64,), jnp.int8)),
@@ -296,6 +299,7 @@ def _lowered_text(name):
     ("flush_extract", ["tdigest.quantile", "flush_extract.sums"]),
     ("pack_extract", ["pack_extract"]),
     ("scatter_chunk", ["microfold.scatter"]),
+    ("mirror_dense", ["microfold.dense"]),
     ("hll_estimate", ["hll.estimate"]),
     ("hll_insert", ["hll.insert.sort", "hll.insert.scatter_max"]),
 ])

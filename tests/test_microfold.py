@@ -414,8 +414,9 @@ def test_dispatched_chunk_buffers_are_not_refilled():
     for before, now in zip(sent, uploaded):
         np.testing.assert_array_equal(before, now)
     st = m.finish()
-    np.testing.assert_array_equal(np.asarray(st.vals)[:chunk, 0], first)
-    np.testing.assert_array_equal(np.asarray(st.vals)[:chunk - 1, 1], second)
+    dense = np.asarray(mf.mirror_dense(st.vals, chunk, 4))
+    np.testing.assert_array_equal(dense[:, 0], first)
+    np.testing.assert_array_equal(dense[:chunk - 1, 1], second)
 
 
 @pytest.mark.parametrize("use_native", [False, True],
@@ -461,3 +462,145 @@ def test_feed_spans_say_what_the_mirror_holds_and_what_they_dispatched(
     (handoff,) = [s.attrs for s in spans if s.name == "swap.mirror_handoff"]
     assert handoff == {"samples": 30, "rows": 6, "mirror_rows": 32,
                        "chunks": 3}
+
+
+# -- the flat mirror (PR 41) -----------------------------------------------
+
+def _host_plane(s_eff, depth, rows, slots, data):
+    plane = np.zeros((s_eff, depth), np.float32)
+    plane[rows, slots] = data
+    return plane
+
+
+@pytest.mark.parametrize("tail", [0, 3], ids=["full-chunks", "padded-chunk"])
+@pytest.mark.parametrize("grow", [False, True],
+                         ids=["preset", "grown-mid-epoch"])
+@pytest.mark.parametrize("s_eff", [24, 32, 64],
+                         ids=["mirror-larger", "mirror-equal",
+                              "mirror-smaller"])
+def test_flat_mirror_through_mirror_dense_is_the_host_plane(
+        s_eff, grow, tail):
+    """The mirror keeps flat [M x B] arrays, entry row x B + slot;
+    `mirror_dense` yields bitwise the dense [s_eff, B] plane the batch
+    path would have uploaded: a prefix when the mirror is the larger,
+    the whole, or zero-padded when the directory outgrew the mirror;
+    whether the mirror was allocated at its size or grew in mid-epoch
+    (`_grow_mirror`: a prefix copy), and whether or not the last chunk
+    went out padded with DROP_ROW."""
+    from veneur_tpu.ops import microfold as mf
+
+    depth, chunk, m_rows = 4, 8, 32
+    rng = np.random.default_rng(s_eff + 2 * grow + tail)
+    # distinct (row, slot) pairs over rows 0..19: the first feed stays
+    # under 8 rows, the second reaches row 19 and forces 8 -> 32
+    n = 5 * chunk + tail
+    low = rng.permutation(8 * depth)[:2 * chunk]
+    high = 8 * depth + rng.permutation(12 * depth)[:n - 2 * chunk]
+    high[0] = 19 * depth + 1
+    at = np.concatenate([low, high])
+    rows = (at // depth).astype(np.int32)
+    slots = (at % depth).astype(np.int32)
+    vals = rng.normal(size=n).astype(np.float32)
+    wts = rng.integers(1, 9, n).astype(np.float32)
+    m = mf.MicroFoldMirror(depth=depth, chunk=chunk,
+                           initial_rows=8 if grow else m_rows)
+    cut = 2 * chunk
+    m.feed(rows[:cut], slots[:cut], vals[:cut], wts[:cut])
+    assert m.mirror_rows == (8 if grow else m_rows)
+    m.feed(rows[cut:], slots[cut:], vals[cut:], wts[cut:])
+    st = m.finish()
+    assert st.chunks == 5 + (tail > 0) and st.samples == n
+    assert st.rows_hi == 20
+    assert st.vals.shape == st.wts.shape == (m_rows * depth,)
+    for got, data in ((st.vals, vals), (st.wts, wts)):
+        dense = np.asarray(mf.mirror_dense(got, s_eff, depth))
+        want = _host_plane(s_eff, depth, rows, slots, data)
+        assert dense.dtype == want.dtype and dense.shape == want.shape
+        assert dense.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("row,slot", [
+    (int(np.iinfo(np.int32).max), 0),   # DROP_ROW: x 64 wraps to -64
+    (16, 0),                            # the first row past the mirror
+    (1 << 26, 3),                       # x 64 wraps to 0: row 0's slots
+    ((1 << 26) + 5, 3),                 # x 64 wraps into row 5
+    (-1, 0),                            # would count back from the end
+    (3, 64),                            # a slot past the depth: row 4's
+    (3, -1),                            # row 2's last slot
+], ids=["drop-row", "row-eq-mirror-rows", "wraps-to-zero",
+        "wraps-into-live-row", "negative-row", "slot-eq-depth",
+        "negative-slot"])
+def test_scatter_entries_outside_the_mirror_land_nowhere(row, slot):
+    """`rows x depth + slots` is int32: DROP_ROW x 64 wraps, and so does
+    any row from 2^25 up. An entry whose row is outside [0, mirror_rows)
+    or whose slot is outside [0, depth) changes no element of either
+    array; the live entry beside it in the chunk still lands."""
+    import jax.numpy as jnp
+
+    from veneur_tpu.ops import microfold as mf
+
+    depth, m_rows = 64, 16
+    before = np.arange(1, m_rows * depth + 1, dtype=np.float32)
+    rows = np.asarray([row, 7], np.int32)
+    slots = np.asarray([slot, 2], np.int32)
+    dv, dw = mf._scatter_chunk(
+        jnp.asarray(before), jnp.asarray(-before), jnp.asarray(rows),
+        jnp.asarray(slots), jnp.asarray([111.0, 222.0], jnp.float32),
+        jnp.asarray([333.0, 444.0], jnp.float32), depth=depth)
+    want_v, want_w = before.copy(), -before
+    want_v[7 * depth + 2], want_w[7 * depth + 2] = 222.0, 444.0
+    np.testing.assert_array_equal(np.asarray(dv), want_v)
+    np.testing.assert_array_equal(np.asarray(dw), want_w)
+
+
+@pytest.mark.parametrize("allocated", [False, True],
+                         ids=["first-allocation", "growth"])
+def test_mirror_refuses_a_flat_index_past_int32(allocated):
+    """2^25 rows x 64 slots = 2^31 entries: the flat index would not fit
+    int32. `_ensure_rows` refuses before it allocates or grows."""
+    from veneur_tpu.ops import microfold as mf
+
+    m = mf.MicroFoldMirror(depth=64,
+                           initial_rows=16 if allocated else 1 << 25)
+    if allocated:
+        m._ensure_rows(1)
+        assert m.mirror_rows == 16
+    with pytest.raises(ValueError, match="int32"):
+        m._ensure_rows(1 << 25)
+    assert m.mirror_rows == (16 if allocated else 0)
+
+
+@pytest.mark.parametrize("use_native", [False, True],
+                         ids=["python-plane", "native-plane"])
+def test_mirror_dense_span_says_what_the_conversion_met(use_native):
+    """`extract.mirror_dense` wraps the dispatch of the flush's one
+    change of layout (flat mirror -> the fold's [rows, depth] planes)
+    inside `extract.mirror_fold`, with `mirror_rows` (what the mirror had
+    allocated) and `rows` (what the fold takes) as attrs; it waits for
+    nothing, so `extract_wait_s` keeps its meaning (PERF.md section 3)."""
+    from veneur_tpu.ops import microfold as mf
+
+    w = DeviceWorker(compression=100, stage_depth=64, batch_size=6,
+                     micro_fold=True, micro_fold_rows=1,
+                     micro_fold_max_age_s=1e9, initial_histo_rows=64)
+    if use_native and not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    w._micro = mf.MicroFoldMirror(
+        w.stage_depth, ledger=w.ledger, initial_rows=16, chunk=8,
+        guard=w.guard)
+    lines = [f"h{i}:{i}.5|ms" for i in range(6)]
+    if use_native:
+        w.ingest_datagram("\n".join(lines).encode())
+    else:
+        for ln in lines:
+            w.process_metric(parse_metric(ln.encode()))
+    assert w.micro_fold_once() == 6
+    w.flush(QS)
+    spans = {s.id: s for s in w.rec.closed()}
+    (dense,) = [s for s in spans.values() if s.name == "extract.mirror_dense"]
+    assert dense.attrs == {"mirror_rows": 16, "rows": 64}
+    up = spans[dense.parent]
+    assert (up.name, up.attrs["op"]) == ("dispatch", "staged")
+    assert spans[up.parent].name == "extract.mirror_fold"
+    # the planes the fold reads, booked on its dispatch span as before
+    assert up.attrs["bytes"] >= 2 * 64 * 64 * 4

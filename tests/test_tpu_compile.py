@@ -121,12 +121,31 @@ def test_expand_flat_planes(one_chip):
 def test_micro_fold_scatter(one_chip):
     from veneur_tpu.ops import microfold as mf
 
-    mirror = _shape(one_chip, (S, DEPTH))
+    # the mirror of a 2^20-series pool: 2 x S rows, flat (PR 41)
+    mirror = _shape(one_chip, (2 * S * DEPTH,))
     idx = _shape(one_chip, (mf.MICRO_CHUNK,), jnp.int32)
     val = _shape(one_chip, (mf.MICRO_CHUNK,))
     compiled = mf._scatter_chunk.lower(
-        mirror, mirror, idx, idx, val, val).compile()
+        mirror, mirror, idx, idx, val, val, depth=DEPTH).compile()
     _fits("microfold._scatter_chunk", compiled)
+    # it scatters in place: into [M, 64] planes XLA copied each plane
+    # whole into a linear array and back, 1.5 GiB of temp a chunk
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 2 * 2 * S * DEPTH * 4
+    assert m.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("mirror_rows", [2 * S, S, S // 2],
+                         ids=["slice", "whole", "pad"])
+def test_micro_fold_mirror_dense(one_chip, mirror_rows):
+    """flat -> [s_eff, depth], once per array per flush: one program a
+    (mirror_rows, s_eff) pair, whichever of the two is the larger."""
+    from veneur_tpu.ops import microfold as mf
+
+    compiled = mf.mirror_dense.lower(
+        _shape(one_chip, (mirror_rows * DEPTH,)), S, DEPTH).compile()
+    _fits("microfold.mirror_dense", compiled)
+    assert compiled.memory_analysis().output_size_in_bytes == S * DEPTH * 4
 
 
 def test_dense_hll_insert_and_estimate(one_chip):

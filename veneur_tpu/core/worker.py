@@ -3456,13 +3456,20 @@ class DeviceWorker:
             # Python upload would have built (values and weights at
             # the same absolute slots, zeros elsewhere), which is
             # what pins micro-folded == batch-folded
-            dense = (mf.mirror_dense if sh is None
-                     else sh.mirror_dense)
+            dense = (functools.partial(mf.mirror_dense,
+                                       depth=self.stage_depth)
+                     if sh is None else sh.mirror_dense)
             folder = sh.fold_staged if sh is not None else self._fold_staged
 
             def _mirror_fold(fl):
-                dv = dense(dstage.vals, s_eff)
-                dw = dense(dstage.wts, s_eff)
+                # the mirror's one change of layout, flat -> [s_eff,
+                # depth], once per array per flush: dispatched, not
+                # waited for
+                with rec.span("extract.mirror_dense", rows=s_eff,
+                              mirror_rows=(dstage.vals.size
+                                           // self.stage_depth)):
+                    dv = dense(dstage.vals, s_eff)
+                    dw = dense(dstage.wts, s_eff)
                 # the dispatch span's bytes: the planes the fold reads,
                 # which the guard cannot see among its arguments
                 rec.add("bytes", int(dv.nbytes) + int(dw.nbytes))

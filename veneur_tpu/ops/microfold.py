@@ -6,16 +6,24 @@ in the milliseconds — the device is cold between flushes). This module
 keeps a device-side mirror of the staging plane warm DURING the
 interval: every micro-fold drains the staged samples accumulated since
 the last drain as COO deltas (row, absolute slot, value, weight) and
-scatters them into a persistent [M, B] mirror with donated dispatches,
-so by flush time the staged state is already resident on device and the
+scatters them into a persistent mirror with donated dispatches, so by
+flush time the staged state is already resident on device and the
 tick's fold collapses to a drain.
+
+The mirror is two FLAT float32[M x B] arrays, row-major (entry
+``row x B + slot``), not [M, B] planes: XLA will not scatter into the
+TPU's tiled 2-D layout, it copies the plane whole into a linear array,
+scatters there and converts it back, so a 65,536-entry chunk cost 51.9
+ms at M = 2,097,152 against 13.1 ms into the flat array (PERF.md
+section 6, PR 41). ``mirror_dense`` turns the flat array into the
+[s_eff, B] plane the fold takes, once per array per flush.
 
 Bit-identity by construction: slots are ABSOLUTE positions in the host
 staging plane, so after the final drain the mirror holds exactly the
 dense [S, B] array the batch path would have uploaded (values/weights at
 filled slots, zeros elsewhere — including unit weights, which both paths
 materialize as exact 1.0f). The flush then runs the SAME single
-``_histo_fold_staged`` program over the mirror sliced to ``s_eff`` that
+``_histo_fold_staged`` program over ``mirror_dense(mirror, s_eff)`` that
 the batch path runs over its uploaded plane, so micro-folded ==
 batch-folded is bitwise, not approximate (tests/test_microfold.py pins
 all three metric classes).
@@ -50,48 +58,66 @@ from veneur_tpu.ops.device_guard import wait_span
 
 # COO entries per upload chunk. 65536 x 16B = 1 MB per dispatch: large
 # enough to amortize dispatch overhead (and, on backends that cannot
-# honor the scatter's donation — XLA-CPU copies the whole [M, B] mirror
-# per dispatch — to keep the per-interval dispatch count in the single
+# honor the scatter's donation — XLA-CPU copies the whole flat [M x B]
+# mirror per dispatch — to keep the per-interval dispatch count in the single
 # digits), small enough that the carry buffer and the padded final
 # chunk stay trivial and uploads still interleave with compute.
 MICRO_CHUNK = 65536
 
 # Sentinel row for padding the final partial chunk: out of bounds for
-# any mirror, so the donated scatter's mode="drop" discards it.
+# any mirror, so the donated scatter's mode="drop" discards it. It is
+# int32 max, so ``row x B`` wraps: _scatter_chunk sends it out of range
+# by the row, never by the product.
 DROP_ROW = np.int32(np.iinfo(np.int32).max)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_chunk(dvals, dwts, rows, slots, vals, wts):
-    """Scatter one COO chunk into the mirror (padding rows dropped)."""
+@functools.partial(jax.jit, static_argnames=("depth",),
+                   donate_argnums=(0, 1))
+def _scatter_chunk(dvals, dwts, rows, slots, vals, wts, *, depth: int):
+    """Scatter one COO chunk into the flat mirror at ``row x depth +
+    slot``. A row outside [0, M) (the padding's DROP_ROW, whose product
+    wraps) or a slot outside [0, depth) goes to the out-of-range index
+    ``M x depth`` and is dropped, never into another row."""
+    size = dvals.shape[0]
     with jax.named_scope("microfold.scatter"):
-        dvals = dvals.at[rows, slots].set(vals, mode="drop")
-        dwts = dwts.at[rows, slots].set(wts, mode="drop")
+        live = ((rows >= 0) & (rows < size // depth)
+                & (slots >= 0) & (slots < depth))
+        idx = jnp.where(live, rows * depth + slots, size)
+        dvals = dvals.at[idx].set(vals, mode="drop")
+        dwts = dwts.at[idx].set(wts, mode="drop")
     return dvals, dwts
 
 
-@functools.partial(jax.jit, static_argnames=("new_rows",),
+@functools.partial(jax.jit, static_argnames=("new_size",),
                    donate_argnums=(0,))
-def _grow_mirror(old, new_rows: int):
-    s, b = old.shape
+def _grow_mirror(old, new_size: int):
+    """Row-major, so growth by rows is a prefix copy."""
     with jax.named_scope("microfold.grow"):
-        return jnp.zeros((new_rows, b), old.dtype).at[:s].set(old)
+        return jnp.zeros((new_size,), old.dtype).at[:old.shape[0]].set(old)
 
 
-def mirror_dense(arr, s_eff: int):
-    """The mirror as a dense [s_eff, B] plane: slice when the mirror is
-    larger, zero-pad when the directory outgrew it. Either way the
-    result is bitwise the array the batch path would have built."""
-    m = arr.shape[0]
-    if m == s_eff:
-        return arr
-    if m > s_eff:
-        return arr[:s_eff]
-    return jnp.zeros((s_eff, arr.shape[1]), arr.dtype).at[:m].set(arr)
+@functools.partial(jax.jit, static_argnames=("s_eff", "depth"))
+def mirror_dense(arr, s_eff: int, depth: int):
+    """The flat mirror as the dense [s_eff, depth] plane the fold takes:
+    its prefix when the mirror is larger, zero-padded when the directory
+    outgrew it, reshaped. Either way the result is bitwise the array the
+    batch path would have built. One program per (mirror_rows, s_eff),
+    run once per array per flush: the only change of layout the mirror
+    pays."""
+    n = s_eff * depth
+    with jax.named_scope("microfold.dense"):
+        if arr.shape[0] >= n:
+            flat = arr[:n]
+        else:
+            flat = jnp.zeros((n,), arr.dtype).at[:arr.shape[0]].set(arr)
+        return flat.reshape(s_eff, depth)
 
 
 class MirrorState(NamedTuple):
-    """A finished epoch's mirror, handed to the swapped-epoch extract."""
+    """A finished epoch's mirror, handed to the swapped-epoch extract.
+    ``vals`` / ``wts`` are the flat float32[mirror_rows x depth] arrays
+    (``mirror_dense`` makes the fold's plane of them); the series-sharded
+    mirror's are [mirror_rows, depth], per-shard blocks."""
 
     vals: jax.Array
     wts: jax.Array
@@ -101,7 +127,10 @@ class MirrorState(NamedTuple):
 
 
 class MicroFoldMirror:
-    """Device-side [M, B] mirror of one epoch's staging plane.
+    """Device-side mirror (M rows of B slots) of one epoch's staging
+    plane. Unsharded, allocation, scatter, growth and the dense view are
+    this module's functions over flat [M x B] arrays; a ``shard`` supplies
+    all four of its own over [M/D, B] blocks.
 
     Single-threaded by contract: the worker's ingest lock serializes
     feed() (micro-fold scheduler) against finish() (swap). The ledger
@@ -260,7 +289,8 @@ class MicroFoldMirror:
             with wait_span(self._guard, "micro.fence"):
                 jax.block_until_ready(self._dvals)
             self._unsynced = 1
-        scatter = _scatter_chunk if sh is None else sh.scatter_chunk
+        scatter = (functools.partial(_scatter_chunk, depth=self.depth)
+                   if sh is None else sh.scatter_chunk)
         if self._guard is not None:
             # donated operands — never retryable
             self._dvals, self._dwts = self._guard.call(
@@ -272,28 +302,28 @@ class MicroFoldMirror:
         self.chunks += 1
 
     def _ensure_rows(self, needed: int) -> None:
-        if self._dvals is None:
-            m = self._rows0
-            while m < needed:
-                m *= 2
-            dv = jnp.zeros((m, self.depth), jnp.float32)
-            dw = jnp.zeros((m, self.depth), jnp.float32)
-            if self._shard is not None:
-                dv = self._shard.place(dv)
-                dw = self._shard.place(dw)
-            self._dvals = dv
-            self._dwts = dw
-            self._m = m
-            return
-        if needed <= self._m:
-            return
-        m = self._m
+        m = self._m or self._rows0
         while m < needed:
             m *= 2
-        if self._shard is not None:
-            self._dvals = self._shard.grow_2d(self._dvals, m)
-            self._dwts = self._shard.grow_2d(self._dwts, m)
+        if m == self._m:
+            return
+        sh = self._shard
+        if sh is None and m * self.depth >= 2 ** 31:
+            raise ValueError(
+                f"micro-fold mirror of {m} rows x {self.depth} slots: "
+                "the flat index row x depth + slot must fit int32")
+        if self._dvals is None:
+            if sh is None:
+                self._dvals = jnp.zeros((m * self.depth,), jnp.float32)
+                self._dwts = jnp.zeros((m * self.depth,), jnp.float32)
+            else:
+                shape = (m, self.depth)
+                self._dvals = sh.place(jnp.zeros(shape, jnp.float32))
+                self._dwts = sh.place(jnp.zeros(shape, jnp.float32))
+        elif sh is None:
+            self._dvals = _grow_mirror(self._dvals, m * self.depth)
+            self._dwts = _grow_mirror(self._dwts, m * self.depth)
         else:
-            self._dvals = _grow_mirror(self._dvals, m)
-            self._dwts = _grow_mirror(self._dwts, m)
+            self._dvals = sh.grow_2d(self._dvals, m)
+            self._dwts = sh.grow_2d(self._dwts, m)
         self._m = m

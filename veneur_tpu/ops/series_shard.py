@@ -372,6 +372,9 @@ class SeriesSharding:
         return fn(old)
 
     # -- micro-fold mirror --------------------------------------------------
+    # Its planes stay [M/D, depth] blocks per shard, not the unsharded
+    # mirror's flat arrays (ops/microfold.py): no cell runs them yet, so
+    # a flat interleaved layout could not be measured.
 
     @functools.cached_property
     def scatter_chunk(self):
