@@ -43,10 +43,6 @@
 
 namespace {
 
-// Commit-path lock instrumentation gate (vn_set_lock_stats): off by
-// default so the per-line clock reads never tax production ingest.
-std::atomic<bool> g_lock_stats{false};
-
 inline int64_t now_ns() {
   return std::chrono::duration_cast<std::chrono::nanoseconds>(
              std::chrono::steady_clock::now().time_since_epoch())
@@ -601,16 +597,19 @@ struct Ctx {
   long long commit_lines = 0;
   long long plane_grows = 0;
 
-  // Commit-path lock contention stats (vn_lock_stats; recorded only
-  // while vn_set_lock_stats(1) — the try_lock probe and clock reads cost
-  // ~10-20% of per-line budget, so the hot path skips them by default).
-  // Sample rings keep the most recent waits/holds for true percentiles.
+  // The commit lock as the committers meet it (vn_lock_stats), written
+  // under `mu`, always on: one record a lock hold of ingest_buffer (a
+  // try_lock and three clock reads a chunk or a datagram, nothing a
+  // line), so lk_acquisitions moves with commit_batches. Wait is the
+  // blocked acquire (0 where try_lock took it), hold is commit_lines.
+  // Lifetime totals unless vn_lock_stats_reset is called; the rings
+  // keep the most recent waits/holds for true percentiles.
   long long lk_acquisitions = 0;
   long long lk_contended = 0;
   long long lk_wait_ns_total = 0;
   long long lk_hold_ns_total = 0;
   static constexpr int kLockRing = 4096;
-  int32_t lk_ring_n = 0;  // total samples ever (ring index = n % kLockRing)
+  int64_t lk_ring_n = 0;  // total samples ever (ring index = n % kLockRing)
   int64_t lk_wait_ring[kLockRing] = {0};
   int64_t lk_hold_ring[kLockRing] = {0};
 
@@ -2064,30 +2063,6 @@ void commit_lines(Ctx* ctx, Batch* b, const uint32_t* order, size_t n) {
   ctx->commit_lines += static_cast<long long>(n);
 }
 
-// The instrumented commit (vn_set_lock_stats): one acquisition a line,
-// its wait time (blocked acquire) and hold time, with sample rings for
-// percentiles. A diagnostic of the lock, so it keeps the lock's old
-// grain.
-void commit_line_timed(Ctx* target, const Batch& b, const BatchLine& ln) {
-  int64_t t0 = now_ns();
-  bool contended = !target->mu.try_lock();
-  if (contended) target->mu.lock();
-  int64_t t1 = now_ns();
-  commit_line(target, b, ln);
-  ++target->processed;
-  int64_t t2 = now_ns();
-  ++target->lk_acquisitions;
-  if (contended) ++target->lk_contended;
-  int64_t wait = contended ? (t1 - t0) : 0;
-  target->lk_wait_ns_total += wait;
-  target->lk_hold_ns_total += t2 - t1;
-  int slot = target->lk_ring_n % Ctx::kLockRing;
-  target->lk_wait_ring[slot] = wait;
-  target->lk_hold_ring[slot] = t2 - t1;
-  ++target->lk_ring_n;
-  target->mu.unlock();
-}
-
 // Ingest a buffer of newline-separated lines: every entry point that
 // takes text (vn_ingest, vn_ingest_home, the datagram and stream
 // readers) ends here, with whatever it was handed: one line, a datagram
@@ -2095,9 +2070,10 @@ void commit_line_timed(Ctx* target, const Batch& b, const BatchLine& ln) {
 // with no lock held (thread-local scratch; tag sort/join is the
 // expensive part of a line); then each target context (digest % nctx —
 // the native twin of the reference's contention-free Digest%N worker
-// routing, server.go:1028-1039) is locked once and takes its lines in
-// buffer order, which is what a gauge's last write and first-seen rows
-// need, since a series always maps to one context. Events/service checks
+// routing, server.go:1028-1039) is locked once (the hold is timed:
+// Ctx::lk_acquisitions) and takes its lines in buffer order, which is
+// what a gauge's last write and first-seen rows need, since a series
+// always maps to one context. Events/service checks
 // and parse errors land on the caller's home shard so one noisy event
 // stream can't serialize every reader behind shard 0. A line longer than
 // max_line is a parse error. Returns the metric lines accepted;
@@ -2166,7 +2142,6 @@ int ingest_buffer(Ctx* const* ctxs, int nctx, std::string_view data, int home,
     b.bucket_end[0] = static_cast<uint32_t>(n);
   }
 
-  const bool timed = g_lock_stats.load(std::memory_order_relaxed);
   const bool home_work = b.errors > 0 || !b.others.empty();
   uint32_t begin = 0;
   for (int t = 0; t < nctx; ++t) {
@@ -2174,13 +2149,23 @@ int ingest_buffer(Ctx* const* ctxs, int nctx, std::string_view data, int home,
     const bool is_home = t == home && home_work;
     Ctx* target = ctxs[t];
     std::unique_lock<std::recursive_mutex> hold(target->mu, std::defer_lock);
-    if (timed) {
-      for (uint32_t k = begin; k < end; ++k)
-        commit_line_timed(target, b, b.lines[order != nullptr ? order[k] : k]);
-    } else if (end > begin) {
-      hold.lock();
+    if (end > begin) {
+      const int64_t t0 = now_ns();
+      const bool contended = !hold.try_lock();
+      if (contended) hold.lock();
+      const int64_t t1 = now_ns();
       commit_lines(target, &b, order != nullptr ? order + begin : nullptr,
                    end - begin);
+      const int64_t t2 = now_ns();
+      const int64_t wait = contended ? t1 - t0 : 0;
+      ++target->lk_acquisitions;
+      if (contended) ++target->lk_contended;
+      target->lk_wait_ns_total += wait;
+      target->lk_hold_ns_total += t2 - t1;
+      const int64_t slot = target->lk_ring_n % Ctx::kLockRing;
+      target->lk_wait_ring[slot] = wait;
+      target->lk_hold_ring[slot] = t2 - t1;
+      ++target->lk_ring_n;
     }
     if (is_home) {
       if (!hold.owns_lock()) hold.lock();
@@ -2579,11 +2564,6 @@ int vn_drain_ssf_fallback(void* p, char* buf, int cap) {
                             ctx->ssf_fallback.begin() + taken);
   }
   return written;
-}
-
-// Enable/disable commit-path lock timing (global; affects all contexts).
-void vn_set_lock_stats(int enabled) {
-  g_lock_stats.store(enabled != 0, std::memory_order_relaxed);
 }
 
 // Totals: [acquisitions, contended, wait_ns_total, hold_ns_total,

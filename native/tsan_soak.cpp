@@ -58,7 +58,6 @@ int vn_pending_histo(void* p);
 int vn_pending_set(void* p);
 int vn_pending_counter(void* p);
 int vn_pending_gauge(void* p);
-void vn_set_lock_stats(int enabled);
 int vn_lock_stats(void* p, long long out[5], long long* wait_out,
                   long long* hold_out, int cap);
 void vn_lock(void* p);
@@ -324,7 +323,6 @@ void upsert_thread(std::vector<void*>* ctxs) {
 }  // namespace
 
 int main() {
-  vn_set_lock_stats(1);
   std::vector<void*> shard_ctxs;
   for (int i = 0; i < kShards; ++i) {
     void* c = vn_ctx_new(12);
@@ -366,14 +364,14 @@ int main() {
             want_bad == want_bad_expect;
 
   // round two: the chunk commit under a flush that turns epochs over
-  vn_set_lock_stats(0);
   done.store(false, std::memory_order_release);
   sent_ok.store(0);
-  long long batches_before = 0;
+  long long batches_before = 0, lines_before = 0;
   long long counters[6];
   for (void* c : shard_ctxs) {
     vn_commit_counters(c, counters);
     batches_before += counters[3];
+    lines_before += counters[4];
   }
   long long chunk_processed = -processed, chunk_errors = -errors;
   threads.clear();
@@ -387,18 +385,25 @@ int main() {
   done.store(true, std::memory_order_release);
   threads[0].join();
   threads[1].join();
-  long long batches = -batches_before, batch_lines = 0;
+  // the lock's record is one entry a lock hold of the commit, both
+  // rounds, whoever else took the lock meanwhile
+  long long batches = -batches_before, batch_lines = -lines_before;
+  long long timed_holds = 0;
   for (void* c : shard_ctxs) {
     vn_commit_counters(c, counters);
     batches += counters[3];
     batch_lines += counters[4];
+    timed_holds -= counters[3];
+    long long lock_totals[5];
+    (void)vn_lock_stats(c, lock_totals, nullptr, nullptr, 0);
+    timed_holds += lock_totals[0];
   }
   std::printf("tsan_soak: chunk round processed=%lld errors=%lld "
               "sent_ok=%lld batches=%lld\n",
               chunk_processed, chunk_errors, sent_ok.load(), batches);
   ok = ok && chunk_processed == sent_ok.load() &&
        chunk_errors == want_bad_expect && batch_lines == chunk_processed &&
-       batches < chunk_processed / 4;
+       batches < chunk_processed / 4 && timed_holds == 0;
 
   for (void* c : all_ctxs) vn_ctx_free(c);
   if (!ok) {

@@ -30,6 +30,12 @@ def _wait_for(predicate, timeout=10.0):
     return False
 
 
+def _less_cpu(attrs: dict) -> dict:
+    """A span's attrs without the one every span has."""
+    assert isinstance(attrs["cpu_s"], float) and attrs["cpu_s"] >= 0.0
+    return {k: v for k, v in attrs.items() if k != "cpu_s"}
+
+
 # -- the recorder ----------------------------------------------------------
 
 def test_spans_nest_by_thread_and_inherit_the_flush_ordinal():
@@ -46,7 +52,7 @@ def test_spans_nest_by_thread_and_inherit_the_flush_ordinal():
     # children close before their parents; as_list is JSON types only
     assert [s.name for s in rec.closed()] == [
         "extract.readback", "flush.extract", "flush.emit", "flush"]
-    assert rb.as_list()[6] == {"wait": True}
+    assert _less_cpu(rb.as_list()[6]) == {"wait": True}
     assert root.t0 <= ext.t0 <= rb.t0 <= rb.t1 <= ext.t1 <= root.t1
     json.dumps(rec.of_flush(7))
     assert [s[1] for s in rec.of_flush(8)] == ["flush.emit"]
@@ -81,7 +87,7 @@ def test_the_ring_is_bounded_and_add_sums_into_the_open_span():
             rec.add("bytes", 4)
     kept = rec.closed()
     assert len(kept) == 16 and kept[-1].flush == 99 and kept[0].flush == 84
-    assert kept[0].attrs == {"bytes": 7}
+    assert _less_cpu(kept[0].attrs) == {"bytes": 7}
     assert rec.last("s") is kept[-1] and rec.last("nope") is None
     rec.add("bytes", 1)  # no span open: dropped, not raised
 
@@ -97,6 +103,92 @@ def test_a_span_that_raises_closes_and_says_so():
     assert by["flush"].attrs["error"] == "ValueError"
     assert by["flush"].t1 >= by["flush.extract"].t1 > 0
     assert rec.current() is None
+
+
+# -- what a span's thread did with its time ----------------------------------
+
+def _spin(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("how", ["sleeps", "spins", "spins_then_sleeps"])
+def test_cpu_s_tells_a_span_that_worked_from_one_that_stood_still(how):
+    rec = flightrec.Recorder()
+    with rec.span("s", flush=1) as sp:
+        if how != "sleeps":
+            _spin(0.1)
+        if how != "spins":
+            time.sleep(0.1)
+    cpu = sp.attrs["cpu_s"]
+    if how == "sleeps":
+        assert cpu < 0.1 * sp.seconds
+    elif how == "spins":
+        # a preempted spin is longer on the wall, never on the thread
+        assert 0.1 <= cpu <= sp.seconds + 1e-3 and cpu < 0.12
+    else:
+        assert 0.1 <= cpu < 0.12 and sp.seconds >= 0.2
+    # in as_list's attrs, JSON-clean, and the list keeps its seven fields
+    (row,) = rec.of_flush(1)
+    assert len(row) == 7 and row[6]["cpu_s"] == cpu
+    assert json.loads(json.dumps(row))[6]["cpu_s"] == cpu
+
+
+def test_cpu_s_is_the_span_threads_own_clock():
+    """A span open on a thread that sleeps while another spins reads
+    the sleeper's clock, not the process's."""
+    rec = flightrec.Recorder()
+    other = threading.Thread(target=_spin, args=(0.15,))
+    with rec.span("sleeper") as sp:
+        other.start()
+        other.join(10.0)
+    assert not other.is_alive() and sp.seconds >= 0.1
+    assert sp.attrs["cpu_s"] < 0.5 * sp.seconds
+
+
+@pytest.mark.parametrize("case", ["full", "young_and_long",
+                                  "young_and_short"])
+def test_a_collection_inside_an_open_span_is_a_gc_child_of_it(case,
+                                                              monkeypatch):
+    import gc
+
+    generation = 2 if case == "full" else 0
+    # a pass of the young generation is a span from GC_SPAN_S on
+    monkeypatch.setattr(flightrec, "GC_SPAN_S",
+                        0.0 if case == "young_and_long" else 10.0)
+    rec = flightrec.Recorder()
+    with rec.span("flush.begin", flush=5) as begin:
+        with rec.span("swap.handoff") as handoff:
+            gc.collect(generation)
+        assert rec.current() is begin
+    mine = [s for s in rec.closed() if s.name == "gc"
+            and s.attrs["generation"] == generation]
+    if case == "young_and_short":
+        assert mine == []
+        return
+    assert mine, [s.as_list() for s in rec.closed()]
+    g = mine[-1]
+    assert g.parent == handoff.id and g.flush == 5
+    assert handoff.t0 <= g.t0 <= g.t1 <= handoff.t1
+    assert g.attrs["collected"] >= 0 and 0.0 <= g.attrs["cpu_s"]
+    assert g.id not in (begin.id, handoff.id)
+    json.dumps(rec.of_flush(5))
+    # with no span open on the thread a collection leaves nothing
+    n = len(rec.closed())
+    gc.collect(generation)
+    assert len(rec.closed()) == n
+
+
+def test_a_record_that_goes_takes_its_gc_callback_with_it():
+    import gc
+
+    before = list(gc.callbacks)
+    rec = flightrec.Recorder()
+    (mine,) = [cb for cb in gc.callbacks if cb not in before]
+    del rec
+    gc.collect()
+    assert mine not in gc.callbacks
 
 
 # -- a served flush --------------------------------------------------------
@@ -179,7 +271,7 @@ def test_a_served_flush_leaves_its_spans_and_the_old_phase_keys():
                      "extract.readback", "extract.unpack", "extract.sets",
                      "extract.guard_tick"):
             assert by[name][0][4] == extract, name
-        assert by["extract.readback"][0][6] == {"wait": True}
+        assert _less_cpu(by["extract.readback"][0][6]) == {"wait": True}
         (sinks,) = by["emit.sinks"]
         assert sinks[4] == by["flush.emit"][0][0]
         assert [(s[4], s[6]["sink"]) for s in by["emit.sink"]] == [
@@ -341,6 +433,116 @@ def test_the_reader_counter_rises_with_traffic_and_fits_the_wall_clock():
                     if s[1] == "flush.begin"]
         assert begin[6]["reader_busy_ns"] >= busy1
         assert begin[6]["reader_recv_ns"] >= recv1
+    finally:
+        srv.shutdown()
+
+
+def test_flush_begin_says_what_the_readers_busy_time_was_made_of():
+    srv, sink, port = _served()
+    if not srv.native_mode:
+        srv.shutdown()
+        pytest.skip("native ingest library unavailable")
+    try:
+        payload, n = _lines(200, 16)
+        with socket.create_connection(("127.0.0.1", port)) as s:
+            s.sendall(payload)
+            assert _wait_for(
+                lambda: srv.ingress_stats()["samples_processed"] >= n)
+            busy = srv._reader_ns()[1]
+            s.sendall(b"fr.last:1|c\n")         # ends the recv in flight
+            assert _wait_for(lambda: srv._reader_ns()[1] > busy)
+            time.sleep(0.05)                     # the reader is in recv
+            srv.flush()
+        (begin,) = [s for s in srv.last_flush_phases["spans"]
+                    if s[1] == "flush.begin"]
+        a = begin[6]
+        assert a["reader_parse_ns"] + a["reader_lock_wait_ns"] \
+            + a["reader_commit_ns"] == a["reader_busy_ns"]
+        assert a["reader_commit_ns"] > 0 and a["reader_parse_ns"] > 0
+        assert a["reader_lock_wait_ns"] >= 0
+        # the lock's record is the commit's: one entry a lock hold
+        lock = srv.workers[0]._native.lock_stats(samples=False)
+        assert lock["acquisitions"] == a["commit_batches"]
+        assert lock["hold_ns_total"] == a["reader_commit_ns"]
+    finally:
+        srv.shutdown()
+
+
+# -- the ingest side: who holds which lock -----------------------------------
+
+def test_the_ingest_side_says_who_held_which_lock():
+    """A server that is built and never started, each of its threads'
+    steps called by hand: the pump's threshold drain, a micro-fold, the
+    sync sweep and the flush leave the spans that say which of them
+    held the worker's ingest lock and which the native context's."""
+    cfg = Config(statsd_listen_addresses=["tcp://127.0.0.1:0"],
+                 num_workers=1, num_readers=1, interval="10s",
+                 percentiles=[0.5], tpu_native_ingest=True,
+                 tpu_batch_size=64, tpu_stage_depth=8)
+    srv = Server(cfg, metric_sinks=[ChannelMetricSink()])
+    try:
+        if not srv.native_mode:
+            pytest.skip("native ingest library unavailable")
+        w = srv.workers[0]
+        hot = "\n".join(f"fr.hot:{v}|ms" for v in range(100)).encode()
+        srv._drain_native_thresholds()           # nothing due: no span
+        assert srv.rec.last("pump") is None
+        srv._native_router.ingest(hot)           # 92 past the depth spill
+        srv._native_router.ingest(b"fr.c:1|c\nfr.g:2|g\nfr.s:x|s")
+        srv._drain_native_thresholds()
+        srv._native_router.ingest(_lines(20, 4)[0])
+        srv.sync_native_series_once()
+        srv._native_router.ingest(b"fr.late:1|ms")
+        srv._micro_fold(0, w)
+        srv.flush()
+        spans = srv.last_flush_phases["spans"]
+        json.dumps(spans)
+        by = _by_name(spans)
+        ids = {s[0]: s for s in spans}
+
+        def up(s):
+            return ids[s[4]][1] if s[4] in ids else None
+
+        # each taker of the worker's lock: its span, then the wait
+        for name in ("pump", "sync", "micro_fold"):
+            (sp,) = by[name]
+            (lw,) = by[name + ".lock_wait"]
+            assert sp[4] is None and lw[4] == sp[0]
+            assert sp[6]["worker"] == 0 and sp[5] == srv.flush_count
+        # the pump's drain: the context's lock, the adoption, the apply
+        pump = by["pump"][0]
+        kids = [s for s in spans if s[4] == pump[0]]
+        assert [s[1] for s in sorted(kids, key=lambda s: s[2])] == [
+            "pump.lock_wait", "drain.raw", "adopt", "drain.apply"]
+        raw = next(s for s in by["drain.raw"] if s[4] == pump[0])
+        assert _less_cpu(raw[6]) == {"ctx_lock": True, "ctx": 0, "histo": 92,
+                                     "sets": 1, "counters": 1, "gauges": 1}
+        apply = next(s for s in by["drain.apply"] if s[4] == pump[0])
+        assert apply[6]["spill_samples"] == 92
+        # (a spill fold into the live pool is the dispatch op "fold")
+        assert "fold" in {s[6]["op"] for s in spans
+                          if s[4] == apply[0] and s[1] == "dispatch"}
+        # the sweep adopts and holds no context lock of its own
+        assert [s[1] for s in spans if s[4] == by["sync"][0][0]] == [
+            "sync.lock_wait", "adopt"]
+        # a micro-fold: the drain's three under micro_fold.drain, each
+        # stage delta under micro_fold.feed
+        (mdrain,) = by["micro_fold.drain"]
+        assert {s[1] for s in spans if s[4] == mdrain[0]} >= {
+            "drain.raw", "drain.apply"}
+        deltas = by["feed.stage_delta"]
+        assert all(up(s) == "micro_fold.feed" and s[6]["ctx_lock"] is True
+                   for s in deltas)
+        assert sum(s[6]["samples"] for s in deltas) \
+            == by["micro_fold.feed"][0][6]["samples"] == 8 + 20 * 4 + 1
+        # one copy into the carry a delta that held something
+        assert [up(s) for s in by["feed.carry"]] == ["micro_fold.feed"] * sum(
+            1 for s in deltas if s[6]["samples"])
+        # and the swap's own hold says so too
+        assert all(s[6]["ctx_lock"] is True for s in by["swap.drain"])
+        held = [s for s in spans if s[6].get("ctx_lock")]
+        assert {s[1] for s in held} == {"drain.raw", "feed.stage_delta",
+                                        "swap.drain"}
     finally:
         srv.shutdown()
 

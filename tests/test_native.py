@@ -27,26 +27,73 @@ NATIVE_DIR = native_mod._NATIVE_DIR
 
 
 def test_lock_stats_instrumentation():
-    """Commit-path mutex timing: off by default, accurate when enabled,
-    resettable (tools/bench_lock_contention.py relies on this API)."""
+    """Commit-path mutex timing: always on, one record a lock hold of
+    the chunk commit, resettable (tools/bench_lock_contention.py relies
+    on this API)."""
     ctxs = [native_mod.NativeIngest() for _ in range(2)]
     router = native_mod.NativeRouter(ctxs)
     router.ingest(b"lk.a:1|c\nlk.b:2|ms")
-    st = router.lock_stats(0)
-    assert st["acquisitions"] == 0  # disabled: nothing recorded
-    router.set_lock_stats(True)
-    try:
-        router.ingest(b"lk.a:1|c\nlk.b:2|ms\nlk.c:3|g")
-        total = sum(router.lock_stats(s)["acquisitions"] for s in range(2))
-        assert total == 3
-        st = router.lock_stats(0)
+    router.ingest(b"lk.a:1|c\nlk.b:2|ms\nlk.c:3|g")
+    for s, ctx in enumerate(ctxs):
+        st = router.lock_stats(s)
+        assert st["acquisitions"] == ctx.commit_counters()["commit_batches"]
         assert len(st["hold_ns_samples"]) == st["acquisitions"]
         assert all(h > 0 for h in st["hold_ns_samples"])
+        assert st["hold_ns_total"] == sum(st["hold_ns_samples"])
         assert st["contended"] == 0  # single thread never blocks
-    finally:
-        router.set_lock_stats(False)
+        assert st["wait_ns_total"] == 0
+    total = sum(router.lock_stats(s)["acquisitions"] for s in range(2))
+    assert 2 <= total <= 4  # a buffer locks each context it has lines for
     router.reset_lock_stats()
     assert router.lock_stats(0)["acquisitions"] == 0
+
+
+@pytest.mark.parametrize("n_buffers", [1, 7, 64])
+def test_lock_record_is_one_entry_a_commit_batch(n_buffers):
+    """With no switch anywhere: after N buffers the lock's record holds
+    N acquisitions, as many as commit_batches, none contended."""
+    ni = native_mod.NativeIngest()
+    for k in range(n_buffers):
+        ni.ingest(b"\n".join(b"lk.t%d:%d|ms" % (j, k) for j in range(40)))
+    st = ni.lock_stats()
+    assert st["acquisitions"] == n_buffers \
+        == ni.commit_counters()["commit_batches"]
+    assert st["contended"] == 0 and st["wait_ns_total"] == 0
+    assert st["hold_ns_total"] > 0
+    totals = ni.lock_stats(samples=False)
+    assert "hold_ns_samples" not in totals
+    assert totals["acquisitions"] == n_buffers
+
+
+def test_lock_record_sees_a_python_hold_of_the_context_lock():
+    """The other end: a thread that holds ctx.lock() for 50 ms while
+    another ingests leaves the wait in the committer's record."""
+    import threading
+
+    ni = native_mod.NativeIngest()
+    ni.ingest(b"lk.w:1|c")
+    holding, done = threading.Event(), threading.Event()
+
+    def hold() -> None:
+        ni.lock()
+        try:
+            holding.set()
+            time.sleep(0.05)
+        finally:
+            ni.unlock()
+        done.set()
+
+    th = threading.Thread(target=hold)
+    th.start()
+    assert holding.wait(5.0)
+    ni.ingest(b"lk.w:1|c")  # blocks until the holder lets go
+    th.join(5.0)
+    assert done.is_set() and not th.is_alive()
+    st = ni.lock_stats()
+    assert st["acquisitions"] == 2
+    assert st["contended"] >= 1
+    assert st["wait_ns_total"] >= 40_000_000
+    assert max(st["wait_ns_samples"]) >= 40_000_000
 
 
 def test_library_matches_source():
@@ -1199,10 +1246,11 @@ def assert_same_record(got: dict, want: dict) -> None:
         assert got["plane"][3] == want["plane"][3]
 
 
-def run_intervals(n_ctx: int, how: str, seed: int, timed: bool = False):
+def run_intervals(n_ctx: int, how: str, seed: int):
     """Three intervals of the corpus (the second leaves series out and
     reverses the order, the third brings them back) through n_ctx
-    contexts fed `how`; one record per context per interval."""
+    contexts fed `how`; one record per context per interval, then each
+    context's commit counters with its lock's acquisitions beside them."""
     ctxs = [native_mod.NativeIngest() for _ in range(n_ctx)]
     for ni in ctxs:
         ni.set_stage_depth(8)
@@ -1210,32 +1258,31 @@ def run_intervals(n_ctx: int, how: str, seed: int, timed: bool = False):
     ingest = router.ingest if n_ctx > 1 else ctxs[0].ingest
     lines = corpus_lines(seed)
     rng = np.random.default_rng(seed + 1)
-    if timed:
-        router.set_lock_stats(True)
-    try:
-        out = []
-        for part in (lines, lines[900:300:-1], lines[200:]):
-            feed(ingest, part, how, rng)
-            out.append([interval_record(ni) for ni in ctxs])
-    finally:
-        if timed:
-            router.set_lock_stats(False)
-    return out, [ni.commit_counters() for ni in ctxs]
+    out = []
+    for part in (lines, lines[900:300:-1], lines[200:]):
+        feed(ingest, part, how, rng)
+        out.append([interval_record(ni) for ni in ctxs])
+    return out, [{**ni.commit_counters(), "lock_acquisitions":
+                  ni.lock_stats(samples=False)["acquisitions"]}
+                 for ni in ctxs]
 
 
-@pytest.mark.parametrize("how", ["whole", "splits", "by_line_timed"])
+@pytest.mark.parametrize("how", ["whole", "splits", "by_line"])
 @pytest.mark.parametrize("seed", [11, 12])
 def test_chunk_commit_matches_line_by_line(how, seed):
     """However a buffer is cut, the context ends where one line a call
     leaves it: counts, the drained series queue (pools, rows, sids,
     first-seen strings), the staged plane and the SoA batches, over
-    three intervals with resets. The instrumented path (one lock a
-    line) is held to the same."""
+    three intervals with resets; and the lock's record has one entry a
+    lock hold, so one a line fed where a call is a line."""
     want, _ = run_intervals(1, "by_line", seed)
-    got, _ = run_intervals(1, how.replace("_timed", ""), seed,
-                           timed=how.endswith("_timed"))
+    got, (cc,) = run_intervals(1, how, seed)
     for g, w in zip(got, want):
         assert_same_record(g[0], w[0])
+    assert cc["lock_acquisitions"] == cc["commit_batches"]
+    if how == "by_line":
+        assert cc["lock_acquisitions"] == cc["commit_lines"] \
+            == sum(r[0]["processed"] for r in got)
     assert want[0][0]["errors"] > 0 and want[0][0]["other"]
     assert want[0][0]["histo"][0]  # timers spilled past the depth
 

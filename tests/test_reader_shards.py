@@ -347,48 +347,49 @@ def test_events_and_errors_stay_on_committing_context():
 # -- lock stats -------------------------------------------------------------
 
 
-@pytest.mark.parametrize("how", ["whole", "splits", "by_line_timed"])
+@pytest.mark.parametrize("how", ["whole", "splits", "by_line"])
 def test_chunk_commit_over_two_contexts_matches_line_by_line(how):
     """digest % 2 routing: a buffer's lines are bucketed by target and
     each context is locked once; what each context holds afterwards
     (counts, series queue, staged plane, SoA batches, events and errors
     on the home context) is what one line a call leaves, over three
-    intervals with resets. The instrumented path is held to the same."""
+    intervals with resets. Each context's lock record has one entry a
+    lock hold: one a line it took, fed a line a call."""
     from tests.test_native import assert_same_record, run_intervals
     want, _ = run_intervals(2, "by_line", 41)
-    got, counters = run_intervals(2, how.replace("_timed", ""), 41,
-                                  timed=how.endswith("_timed"))
+    got, counters = run_intervals(2, how, 41)
     for g, w in zip(got, want):
         for g_ctx, w_ctx in zip(g, w):
             assert_same_record(g_ctx, w_ctx)
     assert all(rec["processed"] > 100 for rec in want[0])  # both took lines
     assert want[0][0]["other"] and not want[0][1]["other"]  # home is 0
     assert want[0][0]["errors"] > 0 and want[0][1]["errors"] == 0
+    assert all(c["lock_acquisitions"] == c["commit_batches"]
+               for c in counters)
     if how == "whole":
         assert [c["commit_batches"] for c in counters] == [3, 3]
+    elif how == "by_line":
+        assert [c["lock_acquisitions"] for c in counters] \
+            == [c["commit_lines"] for c in counters]
 
 
 def test_owned_context_lock_uncontended():
     """The shared-nothing proof at unit scale: a single owner committing
     into its private context records zero contended acquisitions."""
     w = _mk_worker(True)
-    lib = w._native._lib
-    lib.vn_set_lock_stats(1)
-    try:
+    for ctx in w._reader_ctxs:
+        ctx.reset_lock_stats()
+    for i in range(200):
         for ctx in w._reader_ctxs:
-            ctx.reset_lock_stats()
-        for i in range(200):
-            for ctx in w._reader_ctxs:
-                ctx.ingest_owned(b"lk.h:1.5|ms\nlk.c:1|c")
-        for ctx in w._reader_ctxs:
-            st = ctx.lock_stats()
-            assert st["acquisitions"] > 0
-            assert st["contended"] == 0, st
-    finally:
-        lib.vn_set_lock_stats(0)
-    rs = w.reader_stats(lock_stats=True)
+            ctx.ingest_owned(b"lk.h:1.5|ms\nlk.c:1|c")
+    for ctx in w._reader_ctxs:
+        st = ctx.lock_stats()
+        assert st["acquisitions"] == 200
+        assert st["contended"] == 0, st
+    rs = w.reader_stats()
     assert rs["shards"] == R
     assert len(rs["lock"]) == R + 1
+    assert [lk["acquisitions"] for lk in rs["lock"][1:]] == [200] * R
 
 
 # -- config resolution ------------------------------------------------------

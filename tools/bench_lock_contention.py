@@ -77,7 +77,6 @@ def run(readers: int, shards: int, seconds: float,
     for d in datagrams:
         router.ingest(d)
     router.reset_lock_stats()
-    router.set_lock_stats(True)
 
     stop = threading.Event()
     counts = [0] * readers
@@ -101,7 +100,6 @@ def run(readers: int, shards: int, seconds: float,
     for t in threads:
         t.join(30)
     wall = time.perf_counter() - t0
-    router.set_lock_stats(False)
 
     per_shard = []
     waits: list[int] = []
@@ -153,10 +151,8 @@ def run_sharded(readers: int, seconds: float,
     for ctx in contexts:
         for d in datagrams:
             ctx.ingest_owned(d)
-    lib = contexts[0]._lib
     for ctx in contexts:
         ctx.reset_lock_stats()
-    lib.vn_set_lock_stats(1)
 
     stop = threading.Event()
     counts = [0] * readers
@@ -180,7 +176,6 @@ def run_sharded(readers: int, seconds: float,
     for t in threads:
         t.join(30)
     wall = time.perf_counter() - t0
-    lib.vn_set_lock_stats(0)
 
     per_reader = []
     waits: list[int] = []

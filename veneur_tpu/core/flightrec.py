@@ -12,7 +12,20 @@ is ``(id, name, t_start, t_end, parent, flush ordinal, attrs)``:
 * the flush ordinal is ``Server.flush_count`` for the spans of a flush
   and, for the ingest side (micro-folds, series adoption), the ordinal
   of the flush that will close their epoch; a child inherits its
-  parent's unless told otherwise.
+  parent's unless told otherwise;
+* ``attrs["cpu_s"]`` is what the span's thread spent on a core between
+  the two times (``time.thread_time()``): the span's length less it, in
+  a span that is not ``wait: true``, is time the thread stood still for
+  the interpreter, a lock or a page.
+
+A collection of the cycle collector that lands on a thread with a span
+open is a ``gc`` span under that span (attrs ``generation``,
+``collected``), from ``gc.callbacks``, if it is a full one or lasts a
+millisecond (``GC_SPAN_S``): the young generation's ordinary passes,
+a few an interval of 0.2-0.5 ms and seven thousand in a flush that
+compiles, would be the ring. One that lands on a thread with no span
+open (a thread the program did not start) leaves none either, and shows
+in the other threads' spans as length their ``cpu_s`` lacks.
 
 Each span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
 a profile shows the same spans beside the device lines; with no trace
@@ -26,15 +39,19 @@ traffic the operator never sent.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import threading
 import time
+import weakref
 from typing import Optional
 
 import jax
 
 #: closed spans kept: some twenty intervals of a busy single-worker server
 RING = 8192
+#: a collection below generation 2 is a span from this length on
+GC_SPAN_S = 0.001
 
 
 class Span:
@@ -44,7 +61,7 @@ class Span:
     (``of_flush`` copies them then: Server._flush_publish)."""
 
     __slots__ = ("rec", "id", "name", "t0", "t1", "parent", "flush",
-                 "attrs", "_ann")
+                 "attrs", "_ann", "_cpu0")
 
     def __init__(self, rec: "Recorder", name: str, parent, flush,
                  attrs: dict) -> None:
@@ -56,6 +73,7 @@ class Span:
         self.flush = flush
         self.attrs = attrs
         self._ann = None
+        self._cpu0 = 0.0
 
     @property
     def seconds(self) -> float:
@@ -75,16 +93,20 @@ class Span:
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
         self.t0 = time.time()
+        self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, etype, exc, tb) -> bool:
+        # a span closes on the thread that opened it: one thread's
+        # clock, read inside the span's two times
+        self.attrs["cpu_s"] = time.thread_time() - self._cpu0
         self.t1 = time.time()
         self._ann.__exit__(etype, exc, tb)
         self._ann = None
         if etype is not None:
             self.attrs["error"] = etype.__name__
         stack = self.rec._stack()
-        # a span closes on the thread that opened it, innermost first
+        # innermost first
         while stack and stack.pop() is not self:
             pass
         self.rec._ring.append(self)  # deque.append is atomic
@@ -102,6 +124,43 @@ class Recorder:
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self._ids = itertools.count(1)
         self._tls = threading.local()
+        # the collector calls every entry of gc.callbacks on the thread
+        # whose allocation set it off; the entry holds this record
+        # weakly and goes when the record does
+        ref = weakref.ref(self)
+
+        def on_gc(phase: str, info: dict) -> None:
+            rec = ref()
+            if rec is not None:
+                rec._gc(phase, info)
+
+        gc.callbacks.append(on_gc)
+        weakref.finalize(self, gc.callbacks.remove, on_gc).atexit = False
+
+    def _gc(self, phase: str, info: dict) -> None:
+        """A collection's start or stop, on the thread it runs on: with
+        a span open there, the pair becomes a closed ``gc`` span under
+        it. Nothing is opened: no annotation, nothing on the stack."""
+        stack = self._stack()
+        if not stack:
+            return
+        if phase == "start":
+            self._tls.gc0 = (time.time(), time.thread_time())
+            return
+        t0 = getattr(self._tls, "gc0", None)
+        if t0 is None:
+            return
+        self._tls.gc0 = None
+        t1 = time.time()
+        if info["generation"] < 2 and t1 - t0[0] < GC_SPAN_S:
+            return
+        top = stack[-1]
+        sp = Span(self, "gc", top.id, top.flush, {
+            "generation": info["generation"], "collected": info["collected"],
+            "cpu_s": time.thread_time() - t0[1]})
+        sp.id = next(self._ids)
+        sp.t0, sp.t1 = t0[0], t1
+        self._ring.append(sp)  # closed: it was never on the stack
 
     def _stack(self) -> list:
         stack = getattr(self._tls, "stack", None)

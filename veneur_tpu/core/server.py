@@ -14,6 +14,7 @@ The reference shards series across N workers by Digest%N (server.go:1028,
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import logging
 import os
@@ -446,7 +447,6 @@ class Server:
         # resolve_reader_shards gates on single-worker native-reader
         # mode and honors the VENEUR_READER_SHARDS=0 legacy hatch.
         self._reader_shards = 0
-        self._lock_stats_enabled = False
         if self.native_mode:
             from veneur_tpu.core.config import resolve_reader_shards
 
@@ -540,21 +540,6 @@ class Server:
                     n += router.reader_packets(h)
         return n
 
-    def set_lock_stats(self, enabled: bool) -> None:
-        """Toggle commit-mutex contention recording on every native
-        context (global C++ flag; ~10-20% per-line overhead while on)
-        and reset the tallies, so a measurement window starts clean.
-        Stats surface in ingress_stats()["reader_shards"]["lock"] and
-        the per-flush reader telemetry."""
-        self._lock_stats_enabled = bool(enabled)
-        for w in self.workers:
-            native = getattr(w, "_native", None)
-            if native is None:
-                continue
-            native._lib.vn_set_lock_stats(1 if enabled else 0)
-            for ctx in [native] + list(getattr(w, "_reader_ctxs", ())):
-                ctx.reset_lock_stats()
-
     def ingress_stats(self) -> dict:
         """Cumulative ingress counters for the loadgen controller
         (veneur_tpu/loadgen): lifetime tallies that survive epoch swaps,
@@ -609,10 +594,9 @@ class Server:
         if getattr(w0, "_reader_ctxs", None):
             # shared-nothing ingest: per-context lifetime attribution
             # (index 0 = home context, 1.. = reader shards) plus the
-            # commit-mutex contention record when recording is on —
-            # contended_fraction ~ 0 is the shared-nothing proof
-            out["reader_shards"] = w0.reader_stats(
-                lock_stats=self._lock_stats_enabled)
+            # commit-mutex contention record — contended_fraction ~ 0 is
+            # the shared-nothing proof
+            out["reader_shards"] = w0.reader_stats()
         out["spans"] = self._span_stats()
         delivery = {rname: man.stats()
                     for rname, man in self._delivery_managers()}
@@ -786,13 +770,31 @@ class Server:
     def _drain_native_thresholds(self) -> None:
         """Drain any worker whose native SoA spill/set/scalar batches
         crossed batch_size (shared by the strided ingest check and the
-        native-reader pump)."""
+        native-reader pump). A drain that is due is a ``pump`` span:
+        how long it waited for the worker's ingest lock, then the
+        drain's own spans (worker.drain_native)."""
         for i, w in enumerate(self.workers):
             ctxs = [w._native] + list(getattr(w, "_reader_ctxs", ()))
             if any(c.pending_histo >= w.batch_size
                    or c.pending_set >= w.batch_size for c in ctxs):
-                with self._worker_locks[i]:
+                with self._locked_span("pump", i, w):
                     w.drain_native()
+
+    @contextlib.contextmanager
+    def _locked_span(self, name: str, i: int, worker):
+        """``name`` > ``name``.lock_wait, then the body under worker i's
+        ingest lock: who stood in line for that lock, and for how long.
+        Both bear the epoch read under the lock (a swap may have closed
+        one while this thread waited), as _micro_fold's do."""
+        lock = self._worker_locks[i]
+        with self.rec.span(name, worker=i) as sp:
+            with self.rec.span(name + ".lock_wait") as lw:
+                lock.acquire()
+            try:
+                sp.flush = lw.flush = worker.flight_epoch
+                yield sp
+            finally:
+                lock.release()
 
     def _drain_native_events(self) -> None:
         """Pull buffered event/service-check lines out of the C++ context
@@ -1758,7 +1760,7 @@ class Server:
         for i, worker in enumerate(self.workers):
             if worker._native is None or not worker.native_series_pending():
                 continue
-            with self._worker_locks[i]:
+            with self._locked_span("sync", i, worker):
                 worker.sync_native_series()
 
     def _series_sync_loop(self) -> None:
@@ -1811,21 +1813,13 @@ class Server:
 
     def _micro_fold(self, i: int, worker) -> None:
         """One micro-fold of one worker, as a span: how long it waited
-        for the ingest lock, and how long it then held it against the
-        readers (micro_fold minus micro_fold.lock_wait)."""
-        lock = self._worker_locks[i]
-        with self.rec.span("micro_fold", worker=i) as sp:
-            with self.rec.span("micro_fold.lock_wait") as lw:
-                lock.acquire()
-            try:
-                # the epoch is known only under the lock: a swap may have
-                # closed one while this thread waited
-                sp.flush = lw.flush = worker.flight_epoch
-                sp.attrs["samples"] = int(worker.micro_fold_once())
-                sp.attrs["rows"] = int(
-                    getattr(worker._micro, "rows_hi", 0))
-            finally:
-                lock.release()
+        for the worker's ingest lock, and how long it then held it
+        against the pump, the sweep and the flush's swap (micro_fold
+        minus micro_fold.lock_wait; the C++ readers wait on the native
+        context's lock, the ``ctx_lock`` spans below)."""
+        with self._locked_span("micro_fold", i, worker) as sp:
+            sp.attrs["samples"] = int(worker.micro_fold_once())
+            sp.attrs["rows"] = int(getattr(worker._micro, "rows_hi", 0))
 
     def _flush_loop(self) -> None:
         """Interval ticker, optionally aligned to the wall clock
@@ -2052,6 +2046,16 @@ class Server:
             # record takes the difference between two flushes
             cur = self.rec.current()
             cur.attrs["reader_recv_ns"], cur.attrs["reader_busy_ns"] = rd
+            # what the busy time is made of: waiting for a context's
+            # lock, holding it to commit, and the rest, the parse. (The
+            # lock's record also counts a Python thread that ingests
+            # through the router; the readers' clock does not.)
+            locks = [w.reader_lock_ns() or (0, 0) for w in self.workers]
+            wait_ns = sum(a for a, _ in locks)
+            commit_ns = sum(b for _, b in locks)
+            cur.attrs["reader_lock_wait_ns"] = wait_ns
+            cur.attrs["reader_commit_ns"] = commit_ns
+            cur.attrs["reader_parse_ns"] = rd[1] - wait_ns - commit_ns
             # beside them, what the commit path met (lifetime too)
             for w in self.workers:
                 for k, v in (w.commit_counters() or {}).items():
@@ -2108,8 +2112,7 @@ class Server:
                 # settled reader_committed) + contention record:
                 # emitted as lifetime-deltas per context, stashed
                 # whole for ingress_stats/bench readers
-                rs = worker.reader_stats(
-                    lock_stats=self._lock_stats_enabled)
+                rs = worker.reader_stats()
                 prev = getattr(self, "_reader_reported", None) or {}
                 for kind, stat in (
                         ("committed", "ingest.reader_committed_total"),

@@ -1306,29 +1306,38 @@ class DeviceWorker:
         routed commits from reader threads can't interleave between
         calls; the new-series adoption runs after the unlock (it only
         has to come after the sample drain, see _drain_native_raw_ctx,
-        and it is seconds of Python at 1M fresh series)."""
+        and it is seconds of Python at 1M fresh series).
+
+        Three spans a context, whoever calls (a micro-fold, the pump, a
+        routed upsert): ``drain.raw`` is the hold of the context's lock,
+        the one the C++ readers commit under (``ctx_lock``); then
+        ``adopt``; then ``drain.apply``, whose spill folds are its
+        ``dispatch`` children."""
         if self._native is None:
             return
-        if self._reader_ctxs:
-            # shard mode: per-context drain → local→canonical row
-            # translation → apply. Each context's lock is held only for
-            # its own drain (shared-nothing extends to the drain path).
-            for i, ctx in enumerate(self._all_ctxs()):
+        # shard mode: per-context drain → local→canonical row
+        # translation → apply. Each context's lock is held only for
+        # its own drain (shared-nothing extends to the drain path).
+        sharded = bool(self._reader_ctxs)
+        for i, ctx in enumerate(self._all_ctxs() if sharded
+                                else [self._native]):
+            with self.rec.span("drain.raw", flush=self.flight_epoch,
+                               ctx_lock=True, ctx=i) as sp:
                 ctx.lock()
                 try:
                     raw = self._drain_native_raw_ctx(ctx, i, sync=False)
                 finally:
                     ctx.unlock()
-                self._sync_native_series(ctx, i)
-                self._apply_native_raw(self._map_raw_rows(i, raw))
-            return
-        self._native.lock()
-        try:
-            raw = self._drain_native_raw(sync=False)
-        finally:
-            self._native.unlock()
-        self._sync_native_series()
-        self._apply_native_raw(raw)
+                h, st, c, g = raw[:4]
+                sp.attrs.update(
+                    histo=0 if h is None else len(h[0]),
+                    sets=0 if st is None else len(st[0]),
+                    counters=len(c[0]), gauges=len(g[0]))
+            self._sync_native_series(ctx, i)
+            with self.rec.span("drain.apply", flush=self.flight_epoch,
+                               ctx=i, spill_samples=sp.attrs["histo"]):
+                self._apply_native_raw(
+                    self._map_raw_rows(i, raw) if sharded else raw)
 
     def _map_raw_rows(self, ctx_i: int, raw):
         """Translate one context's drained SoA batches from its LOCAL
@@ -1392,11 +1401,22 @@ class DeviceWorker:
         per_ctx = [ctx.commit_counters() for ctx in self._all_ctxs()]
         return {k: sum(c[k] for c in per_ctx) for k in per_ctx[0]}
 
-    def reader_stats(self, lock_stats: bool = False) -> dict:
+    def reader_lock_ns(self) -> Optional[tuple]:
+        """(ns the committers waited for a native context's lock, ns
+        they held it committing), lifetime, summed over this worker's
+        contexts: what the C++ readers' busy time is made of besides the
+        parse. None without native ingest."""
+        if self._native is None:
+            return None
+        per_ctx = [ctx.lock_stats(samples=False) for ctx in self._all_ctxs()]
+        return (sum(st["wait_ns_total"] for st in per_ctx),
+                sum(st["hold_ns_total"] for st in per_ctx))
+
+    def reader_stats(self) -> dict:
         """Per-context ingest attribution for Server.ingress_stats /
         flush telemetry: context order is [home] + reader shards.
-        lock_stats=True also reads each context's commit-mutex record
-        (meaningful only while vn_set_lock_stats is on)."""
+        ``lock`` is each context's commit-mutex record (always on: one
+        entry a lock hold of the chunk commit)."""
         out = {
             "shards": len(self._reader_ctxs),
             "committed": list(self.reader_committed),
@@ -1406,7 +1426,7 @@ class DeviceWorker:
         if ns is not None:
             out["recv_ns"] = [r for r, _ in ns]
             out["busy_ns"] = [b for _, b in ns]
-        if lock_stats and self._native is not None:
+        if self._native is not None:
             locks = []
             for ctx in self._all_ctxs():
                 st = ctx.lock_stats()
@@ -1672,11 +1692,18 @@ class DeviceWorker:
         fed = 0
         cap = 1 << 18
         while True:
-            rows, slots, vals, wts = self._native.drain_stage_delta(cap)
-            n = len(rows)
+            # one C call that copies under the context's lock: the
+            # readers' commits wait for it
+            with self.rec.span("feed.stage_delta", flush=self.flight_epoch,
+                               ctx_lock=True) as sp:
+                rows, slots, vals, wts = self._native.drain_stage_delta(cap)
+                n = sp.attrs["samples"] = len(rows)
             if n == 0:
                 break
-            micro.feed(rows, slots, vals, wts)
+            # the copy into the mirror's carry; a full carry's scatter
+            # is its dispatch child (op micro)
+            with self.rec.span("feed.carry", flush=self.flight_epoch):
+                micro.feed(rows, slots, vals, wts)
             fed += n
             if n < cap:
                 break
@@ -2845,7 +2872,7 @@ class DeviceWorker:
             for i, ctx in enumerate(self._all_ctxs()):
                 seen = (self._native_proc_seen if i == 0
                         else self._reader_proc_seen[i - 1])
-                with rec.span("swap.drain", ctx=i):
+                with rec.span("swap.drain", ctx=i, ctx_lock=True):
                     ctx.lock()
                     try:
                         raw = self._drain_native_raw_ctx(
@@ -2909,7 +2936,7 @@ class DeviceWorker:
             # under one lock hold: a routed commit can otherwise land
             # between the last drain and the reset and be destroyed with
             # the old epoch
-            with rec.span("swap.drain"):
+            with rec.span("swap.drain", ctx_lock=True):
                 self._native.lock()
                 try:
                     if self._micro_active():

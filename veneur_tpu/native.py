@@ -212,7 +212,6 @@ def _bind_library(lib) -> None:
         c.c_char_p, c.c_longlong,
         c.POINTER(c.c_char_p), c.POINTER(c.c_longlong),
         c.POINTER(c.c_longlong), c.POINTER(c.c_longlong)]
-    lib.vn_set_lock_stats.argtypes = [c.c_int]
     lib.vn_lock_stats.restype = c.c_int
     lib.vn_lock_stats.argtypes = [
         c.c_void_p, c.POINTER(c.c_longlong),
@@ -409,19 +408,23 @@ class NewSeriesBatch:
                         self.first_names, self.first_tags))
 
 
-def _lock_stats(lib, ctx) -> dict:
+def _lock_stats(lib, ctx, samples: bool = True) -> dict:
+    """A context's commit-lock record (Ctx::lk_*, always on): one entry
+    a lock hold of the chunk commit. ``samples=False`` reads the four
+    totals alone and leaves the rings where they are."""
     totals = (ctypes.c_longlong * 5)()
-    wait = (ctypes.c_longlong * 4096)()
-    hold = (ctypes.c_longlong * 4096)()
-    n = lib.vn_lock_stats(ctx, totals, wait, hold, 4096)
-    return {
-        "acquisitions": int(totals[0]),
-        "contended": int(totals[1]),
-        "wait_ns_total": int(totals[2]),
-        "hold_ns_total": int(totals[3]),
-        "wait_ns_samples": [int(wait[i]) for i in range(n)],
-        "hold_ns_samples": [int(hold[i]) for i in range(n)],
-    }
+    out = {}
+    if samples:
+        wait = (ctypes.c_longlong * 4096)()
+        hold = (ctypes.c_longlong * 4096)()
+        n = lib.vn_lock_stats(ctx, totals, wait, hold, 4096)
+        out["wait_ns_samples"] = wait[:n]
+        out["hold_ns_samples"] = hold[:n]
+    else:
+        lib.vn_lock_stats(ctx, totals, None, None, 0)
+    out.update(acquisitions=int(totals[0]), contended=int(totals[1]),
+               wait_ns_total=int(totals[2]), hold_ns_total=int(totals[3]))
+    return out
 
 
 class NativeIngest:
@@ -489,10 +492,10 @@ class NativeIngest:
             raise RuntimeError("vn_reader_start2 failed")
         return h
 
-    def lock_stats(self) -> dict:
+    def lock_stats(self, samples: bool = True) -> dict:
         """This context's commit-mutex contention record (same shape as
         NativeRouter.lock_stats)."""
-        return _lock_stats(self._lib, self._ctx)
+        return _lock_stats(self._lib, self._ctx, samples)
 
     def reset_lock_stats(self) -> None:
         self._lib.vn_lock_stats_reset(self._ctx)
@@ -1414,11 +1417,6 @@ class NativeRouter:
 
     def stop_ssf_reader(self, handle) -> int:
         return int(self._lib.vn_ssf_reader_stop(handle))
-
-    def set_lock_stats(self, enabled: bool) -> None:
-        """Toggle commit-path mutex wait/hold timing (global; ~10-20%
-        per-line overhead while on — diagnostics, not production)."""
-        self._lib.vn_set_lock_stats(1 if enabled else 0)
 
     def lock_stats(self, shard: int) -> dict:
         """Contention record for one shard's mutex: totals plus the most
