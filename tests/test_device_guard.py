@@ -274,6 +274,225 @@ def test_fault_failover_bitwise(shards, micro):
     assert w.host_fallback_flushes >= 2
 
 
+def _native_micro_interval(w, seed):
+    """One interval through the C++ plane with the micro-fold on: timers
+    (one past the staging depth, so the spill fold runs too), counters,
+    gauges, sets; two micro-folds, then lines the swap's residual drain
+    finds. Every staged sample is mirrored at the tick, so the swap hands
+    the detached plane over as the mirror's replay copy."""
+    rng = np.random.default_rng(seed)
+    for part in range(3):
+        lines = []
+        for i in range(40):
+            k = (part * 40 + i) % 17
+            lines += [f"h{k}:{rng.normal():.6f}|ms|#a:{k % 3}",
+                      f"c{k}:{1 + k % 4}|c", f"g{k}:{rng.normal():.6f}|g",
+                      f"s{k}:v{rng.integers(200)}|s"]
+        lines += [f"hot:{rng.normal():.6f}|ms" for _ in range(20)]
+        w.ingest_datagram("\n".join(lines).encode())
+        if part < 2:
+            assert w.micro_fold_once() > 0
+
+
+# where the fault lands -> does the failover read the replay copy?
+REPLAY_FAULTS = {
+    "micro": True,     # the residual feed: the mirror never completes
+    "staged": True,    # the mirror's fold
+    "extract": False,  # after the fold has landed: the copy is spent
+}
+
+
+@pytest.mark.parametrize("op", sorted(REPLAY_FAULTS))
+def test_native_replay_plane_failover_bitwise(op):
+    """Native ingest + micro-fold: the mirror's replay copy is the
+    detached C++ plane itself, handed over uncompacted (`replay: plane`
+    on `swap.handoff`); a fault before the mirror's fold has landed
+    compacts it once (`extract.replay_compact`) and folds it on the host
+    engine, a fault after it reads nothing; either way the flush is the
+    clean run's bit for bit, and so is the next one."""
+    base = _mk_worker(0, True)
+    if not base.attach_native():
+        pytest.skip("native ingest library unavailable")
+    clean = []
+    for seed in (1, 2):
+        _native_micro_interval(base, seed)
+        clean.append(base.flush(QS))
+
+    w = _mk_worker(0, True, device_fault_streak=100)
+    assert w.attach_native()
+    plan = fl.DeviceFaultPlan(seed=9, op_windows={op: ALWAYS})
+    _native_micro_interval(w, 1)
+    with fl.DeviceFaultInjector(plan) as inj:
+        got = [w.flush(QS)]
+    assert sum(inj.injected[k] for k in dg.FAULT_KINDS) > 0
+    faulted = [s for s in w.rec.closed() if s.name.startswith(
+        ("swap.handoff", "extract.replay_"))]
+    _native_micro_interval(w, 2)
+    got.append(w.flush(QS))
+    for n, (a, b) in enumerate(zip(clean, got)):
+        _assert_snapshots_identical(a, b, f"op={op} interval={n}")
+        assert b.degraded == (n == 0) and not a.degraded
+    handoff = [s for s in faulted if s.name == "swap.handoff"]
+    assert [s.attrs["replay"] for s in handoff] == ["plane"]
+    assert handoff[0].attrs["plane_rows"] >= 18
+    compact = [s for s in faulted if s.name == "extract.replay_compact"]
+    assert len(compact) == REPLAY_FAULTS[op]
+    if compact:
+        # 3 x 40 samples over 17 rows + the hot row's first 32
+        assert compact[0].attrs["samples"] == 120 + 32
+        assert "wait" not in compact[0].attrs
+    # the clean flushes: the plane held, released once, never compacted
+    names = [s.name for s in base.rec.closed()]
+    assert names.count("extract.replay_release") == 2
+    assert names.count("extract.replay_compact") == 0
+    assert {s.attrs["replay"] for s in base.rec.closed()
+            if s.name == "swap.handoff"} == {"plane"}
+
+
+def _count_plane_frees(w) -> dict:
+    """Wrap the native context's detach_stage: every detached plane's
+    address, and how often its free() ran."""
+    real = w._native.detach_stage
+    log = {"addrs": [], "frees": []}
+
+    def detach_stage():
+        st = real()
+        if st is None:
+            return None
+        k = len(log["addrs"])
+        log["addrs"].append(int(st[0].ctypes.data))
+        log["frees"].append(0)
+
+        def free(_free=st[4], _k=k):
+            log["frees"][_k] += 1
+            _free()
+
+        return (*st[:4], free)
+
+    w._native.detach_stage = detach_stage
+    return log
+
+
+def _raise(exc):
+    def boom(*a, **kw):
+        raise exc
+    return boom
+
+
+RELEASE_PATHS = ("clean", "failover_before_mirror_fold",
+                 "failover_after_mirror_fold", "exception_before_mirror_fold",
+                 "exception_after_mirror_fold", "no_histogram_rows",
+                 "dropped_unextracted")
+
+
+@pytest.mark.parametrize("path", RELEASE_PATHS)
+def test_replay_plane_is_released_exactly_once(path, monkeypatch):
+    """However a swapped epoch dies, the detached C++ plane it holds as
+    the mirror's replay copy gets one free(): none would leak a plane an
+    interval, two would hand one plane to the reader twice."""
+    w = _mk_worker(0, True, device_fault_streak=100)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    log = _count_plane_frees(w)
+    _native_micro_interval(w, 1)
+    if path == "clean":
+        w.flush(QS)
+        assert w.rec.last("extract.replay_release").attrs["rows"] == 4096
+    elif path.startswith("failover"):
+        op = "staged" if "before" in path else "extract"
+        with fl.DeviceFaultInjector(fl.DeviceFaultPlan(
+                seed=9, op_windows={op: ALWAYS})):
+            assert w.flush(QS).degraded
+    elif path.startswith("exception"):
+        # not a device fault: the server logs it and goes on
+        name = "_fold_staged" if "before" in path else "_note_fold_widths"
+        monkeypatch.setattr(w, name, _raise(ValueError("not the device's")))
+        with pytest.raises(ValueError):
+            w.flush(QS)
+        monkeypatch.undo()
+    elif path == "no_histogram_rows":
+        sw = w.swap(QS)
+        assert sw.micro_replay.free is not None
+        sw.histo = None
+        w.extract_snapshot(sw, QS)
+    else:
+        sw = w.swap(QS)
+        assert log["frees"] == [0]  # the swap reads and frees nothing
+        sw.release()
+        sw.release()
+    assert log["frees"] == [1]
+    # and the worker goes on: the next interval's plane, once as well
+    _native_micro_interval(w, 2)
+    assert not w.flush(QS).degraded
+    assert log["frees"] == [1, 1]
+
+
+def test_two_planes_take_turns():
+    """The release still wipes and shelves: with the reader's next
+    interval under way while a flush extracts, as in the server, the
+    context allocates no plane after its second; every interval starts
+    on the spare the flush before last gave back."""
+    w = _mk_worker(0, True)
+    if not w.attach_native():
+        pytest.skip("native ingest library unavailable")
+    log = _count_plane_frees(w)
+    for seed in range(5):
+        _native_micro_interval(w, seed)
+        sw = w.swap(QS)
+        w.ingest_datagram(b"h0:1.5|ms")
+        assert log["frees"][-1] == 0
+        w.extract_snapshot(sw, QS)
+    a, b = log["addrs"][:2]
+    assert a != b and log["addrs"] == [a, b, a, b, a]
+    assert log["frees"] == [1] * 5
+    assert [s.attrs["plane_rows"] for s in w.rec.closed()
+            if s.name == "swap.handoff"] == [4096] * 5
+
+
+@pytest.mark.parametrize("dies_in", ["swap", "extract"])
+def test_a_flush_cut_short_gives_its_planes_back(dies_in, monkeypatch):
+    """A shutdown's interrupt between a worker's swap and its extraction
+    (here a KeyboardInterrupt out of the second worker's swap, or out of
+    the extract phase before any worker was reached): the epochs already
+    closed die with the flush, and the server gives their planes back."""
+    from veneur_tpu.core.config import Config
+    from veneur_tpu.core.server import Server
+    from veneur_tpu.sinks.channel import ChannelMetricSink
+
+    srv = Server(Config(statsd_listen_addresses=["tcp://127.0.0.1:0"],
+                        num_workers=2, num_readers=1, interval="10s",
+                        percentiles=[0.5], tpu_native_ingest=True),
+                 metric_sinks=[ChannelMetricSink()])
+    try:
+        if not srv.native_mode:
+            pytest.skip("native ingest library unavailable")
+        logs = [_count_plane_frees(w) for w in srv.workers]
+        for k in range(2):
+            srv._native_router.ingest("\n".join(
+                f"cut.t{i}:{i}.5|ms" for i in range(64)).encode())
+            for i, w in enumerate(srv.workers):
+                srv._micro_fold(i, w)
+            if k:
+                break
+            if dies_in == "swap":
+                monkeypatch.setattr(srv.workers[1], "swap",
+                                    _raise(KeyboardInterrupt()))
+            else:
+                monkeypatch.setattr(srv, "_flush_extract_workers",
+                                    _raise(KeyboardInterrupt()))
+            with pytest.raises(KeyboardInterrupt):
+                srv.flush()
+            monkeypatch.undo()
+            want = [[1], [] if dies_in == "swap" else [1]]
+            assert [lg["frees"] for lg in logs] == want
+        srv.flush()
+        assert all(lg["frees"] == [1] * len(lg["frees"]) for lg in logs)
+        assert sum(len(lg["frees"]) for lg in logs) == (
+            3 if dies_in == "swap" else 4)
+    finally:
+        srv.shutdown()
+
+
 @pytest.mark.parametrize("shards", [0, 2], ids=["unsharded", "sharded"])
 def test_probe_readmits_and_restores_device_path(shards):
     """quarantine → probe → re-admission: the post-readmit flush runs on

@@ -547,6 +547,66 @@ def test_the_ingest_side_says_who_held_which_lock():
         srv.shutdown()
 
 
+# -- the mirror's replay copy ----------------------------------------------
+
+@pytest.mark.parametrize("case", ["native_clean", "native_fault_before_fold",
+                                  "native_fault_after_fold", "python_plane",
+                                  "nothing_staged"])
+def test_the_replay_copy_says_what_it_is_and_where_it_went(case):
+    """`swap.handoff` names the micro-fold mirror's replay copy (`plane`:
+    the detached C++ plane, held as it is; `host`: the Python path's dense
+    pair; `none`) and its rows; a native plane's release is the span
+    `extract.replay_release` under `flush.extract`, after the extract's
+    dispatch and before the readback that waits for it, and is not a
+    wait itself; the compaction only a failover needs is
+    `extract.replay_compact`, there and nowhere else (PERF.md section 3)."""
+    from veneur_tpu.utils import faults as fl
+
+    native = case.startswith("native")
+    cfg = Config(statsd_listen_addresses=["tcp://127.0.0.1:0"],
+                 num_workers=1, num_readers=1, interval="10s",
+                 percentiles=[0.5], tpu_native_ingest=native,
+                 device_fault_streak=100)
+    srv = Server(cfg, metric_sinks=[ChannelMetricSink()])
+    try:
+        if native and not srv.native_mode:
+            pytest.skip("native ingest library unavailable")
+        lines = [b"fr.c:1|c"] if case == "nothing_staged" else [
+            b"fr.t%d:%d.5|ms" % (i % 5, i) for i in range(40)]
+        if native:
+            srv._native_router.ingest(b"\n".join(lines))
+        else:
+            for ln in lines:
+                srv.handle_metric_packet(ln)
+        srv._micro_fold(0, srv.workers[0])
+        op = {"native_fault_before_fold": "staged",
+              "native_fault_after_fold": "extract"}.get(case)
+        plan = fl.DeviceFaultPlan(
+            seed=3, op_windows={op: [(0, 10**6, "oom")]} if op else {})
+        with fl.DeviceFaultInjector(plan):
+            srv.flush()
+        spans = srv.last_flush_phases["spans"]
+        by = _by_name(spans)
+        (handoff,) = by["swap.handoff"]
+        assert handoff[4] == by["swap"][0][0]
+        want = {"native": ("plane", 4096), "python": ("host", 4096),
+                "nothing": ("none", 0)}[case.split("_")[0]]
+        assert (handoff[6]["replay"], handoff[6]["plane_rows"]) == want
+        extract = by["flush.extract"][0][0]
+        release = by.get("extract.replay_release", [])
+        compact = by.get("extract.replay_compact", [])
+        assert len(release) == (case == "native_clean")
+        assert len(compact) == (case == "native_fault_before_fold")
+        for sp in release:
+            assert sp[4] == extract and _less_cpu(sp[6]) == {"rows": 4096}
+            assert (by["extract.quantiles"][0][3] <= sp[2]
+                    and sp[3] <= by["extract.readback"][0][2])
+        for sp in compact:
+            assert sp[4] == extract and _less_cpu(sp[6]) == {"samples": 40}
+    finally:
+        srv.shutdown()
+
+
 # -- the lifetime tally ----------------------------------------------------
 
 def test_a_line_committed_just_before_the_swap_takes_the_lock_is_tallied():

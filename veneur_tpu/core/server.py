@@ -61,6 +61,13 @@ _SSF_ERR_FRAMING = ["ssf_format:framed", "packet_type:unknown",
                     "reason:framing"]
 
 
+def _abandon_swapped(swapped) -> None:
+    """Give back the native staging planes of swapped epochs nobody
+    will extract (SwappedEpoch.release: a second call frees nothing)."""
+    for sw in swapped:
+        sw.release()
+
+
 @dataclass
 class FlushJob:
     """One flush's state, handed from phase to phase. `ts` is frozen
@@ -1916,7 +1923,14 @@ class Server:
 
     def _flush_inner(self, now: float | None = None):
         job = self._flush_begin(now=now)
-        self._flush_extract(job)
+        try:
+            self._flush_extract(job)
+        finally:
+            # an epoch that was swapped and never reached its
+            # extraction (a shutdown or an interrupt between the two)
+            # still holds its detached C++ staging planes; an extracted
+            # one holds none, and this frees nothing
+            _abandon_swapped(job.swapped)
         self._flush_generate(job)
         self._flush_emit(job)
         return job
@@ -2023,6 +2037,10 @@ class Server:
                 # ingest-side spans (micro-folds, adoption) from here on
                 # belong to the epoch the NEXT flush closes
                 worker.flight_epoch = ordinal + 1
+            except BaseException:
+                # the epochs closed so far die with this flush
+                _abandon_swapped(swapped)
+                raise
             finally:
                 lock.release()
         # event lines the swap caught at epoch close (would otherwise be

@@ -187,7 +187,13 @@ class StagedPlane(NamedTuple):
     """One raw-sample staging plane handed to the flush: host arrays
     (vals/wts [S, B], counts [S]) plus the native-memory release hook.
     wts is None when every weight is 1.0 (rebuilt on device from counts);
-    free is None for Python-owned planes."""
+    free is None for Python-owned planes. While free is set the arrays
+    alias a detached C++ plane: whoever holds the StagedPlane owes it
+    exactly one free() (wipe and back on its context's shelf), after the
+    last read. That holds for a plane to fold (staged_histo) and for
+    the micro-fold mirror's replay copy (SwappedEpoch.micro_replay)
+    alike; _staged_plane_to_host is the compaction both take when a
+    host engine has to read them."""
 
     vals: np.ndarray
     wts: Optional[np.ndarray]
@@ -792,12 +798,33 @@ class SwappedEpoch:
     reader_planes: Optional[list] = None
     # conservation insurance for the micro-fold mirror (device fault
     # domain): the staging plane the mirror fully covered, RETAINED
-    # (host-side) instead of freed at swap. If a device fault voids the
-    # mirror before or during extract, the flush folds this plane on
-    # the host engine — no epoch lost. Freed once the mirror's fold
-    # lands. A StagedPlane (native path, flat host copies) or a dense
-    # (vals, wts) pair (python path).
-    micro_replay: Optional[object] = None
+    # instead of freed at swap. If a device fault voids the mirror
+    # before or during extract, the flush folds this plane on the host
+    # engine — no epoch lost. A StagedPlane either way: the detached
+    # C++ plane ITSELF, uncompacted and with its free (native path:
+    # only the failover compacts it, _staged_plane_to_host), or the
+    # dense Python pair with counts and free None (python path).
+    micro_replay: Optional[StagedPlane] = None
+    # the same plane once the mirror's fold has landed: nothing will
+    # read it again (a later fault must not fold it twice), it only
+    # waits for its release, which extract_snapshot puts under the
+    # device's fold + extract
+    spent_replay: Optional[StagedPlane] = None
+
+    def release(self) -> None:
+        """Give back every native staging plane this epoch still
+        holds. Each plane leaves its field before its free() runs, so
+        a second call, or a call after a clean extraction, frees
+        nothing: extract_snapshot ends on it, and so does whoever
+        drops a swapped epoch without extracting it."""
+        planes = list(self.staged_histo or ())
+        planes += [StagedPlane(*st[:3], st[4])
+                   for st, _m in self.reader_planes or ()]
+        planes += [p for p in (self.micro_replay, self.spent_replay)
+                   if p is not None]
+        self.staged_histo = self.reader_planes = None
+        self.micro_replay = self.spent_replay = None
+        _free_staged_planes(planes)
 
 
 class DeviceWorker:
@@ -3064,18 +3091,20 @@ class DeviceWorker:
         # epoch: queue the closed epoch's tally for its begin_flush
         self.ledger.roll_epoch()
 
-        with rec.span("swap.handoff"):
+        with rec.span("swap.handoff") as handoff:
             staged = 0
             staged_histo = []
             # device-fault replay batch (ops/device_guard failover): when a
             # staging plane is handed over as a MIRROR (micro_residual)
             # instead of a host plane, the mirror is the only carrier of
             # those samples — and the mirror is device state. micro_replay
-            # retains the host ground truth (the staging plane's content,
-            # which the mirror duplicates bit-for-bit) until the mirror's
-            # flush fold succeeds; if the mirror faults first, the replay
-            # batch folds through the host engine instead. Freed by
-            # extract_snapshot after a clean mirror fold.
+            # retains the host ground truth, the staging plane itself
+            # (which the mirror duplicates bit-for-bit), until the mirror's
+            # flush fold succeeds; if the mirror faults first, the plane
+            # is compacted and folds through the host engine instead.
+            # Nothing of it is read, copied or freed here, on the tick
+            # path and under the ingest lock: extract_snapshot releases it
+            # once the mirror's fold has landed, under the device's work.
             micro_replay = None
             # a mirrored plane is handed over as micro_residual (mirror +
             # deferred COO) INSTEAD of a host plane — exactly one of the two
@@ -3098,25 +3127,17 @@ class DeviceWorker:
                         self._stage_vals, self._stage_wts, None, None))
             if native_stage is not None:
                 sv, sw, counts, unit, free = native_stage
+                # unit weights (no sampled metrics this epoch): nobody
+                # reads the weights plane; the fold rebuilds it from counts
+                plane = StagedPlane(sv, None if unit else sw, counts, free)
                 if native_mirrored and micro_residual is not None:
                     # plane content fully captured by the mirror + residual
-                    # COO (all copies): compact a host replay copy out of the
-                    # C++ memory, then release it — nothing to upload at
-                    # flush unless the mirror faults
-                    B = sv.shape[1]
-                    counts_np = np.minimum(counts, B).astype(np.int32)
-                    r_mask = (np.arange(B, dtype=np.int32)[None, :]
-                              < counts_np[:, None])
-                    flat_v = sv[r_mask]
-                    flat_w = None if unit else sw[r_mask]
-                    free()
-                    micro_replay = StagedPlane(flat_v, flat_w, counts_np, None)
+                    # COO (all copies): nothing to upload at flush, and
+                    # nothing to read unless the mirror faults
+                    micro_replay = plane
                 else:
                     staged += int(counts.sum())
-                    # unit weights (no sampled metrics this epoch): skip the
-                    # weights plane upload; the fold rebuilds it from counts
-                    staged_histo.append(
-                        StagedPlane(sv, None if unit else sw, counts, free))
+                    staged_histo.append(plane)
             if micro_residual is not None:
                 staged += micro_samples
             if reader_planes:
@@ -3124,6 +3145,13 @@ class DeviceWorker:
             staged_histo = staged_histo or None
             # flush self-telemetry (veneur.worker.samples_staged_total)
             self.staged_samples_swapped = staged
+            # what the mirror's replay copy is: a native plane held
+            # uncompacted, the Python path's dense pair, or none
+            handoff.attrs.update(
+                replay=("none" if micro_replay is None else
+                        "host" if micro_replay.free is None else "plane"),
+                plane_rows=(0 if micro_replay is None
+                            else int(micro_replay.vals.shape[0])))
             swapped = SwappedEpoch(
                 directory=self.directory, scalars=self.scalars,
                 histo=self._histo, sets=self._sets,
@@ -3507,9 +3535,12 @@ class DeviceWorker:
             st["fields"] = fields
             if gov is not None:
                 gov.beat()
-        # the mirror's content is folded (or there was none): the host
-        # replay copy swap() retained is no longer needed
-        swapped.micro_replay = None
+        # the mirror's content is folded (or there was none): the replay
+        # copy swap() retained will not be read again, and a fault from
+        # here on must not fold it a second time. Its release waits
+        # until the extract programs are dispatched (_release_replay)
+        swapped.spent_replay, swapped.micro_replay = (
+            swapped.micro_replay, None)
         qnp = np.asarray(quantiles, dtype=np.float32)
         if sh is None:
             qs = self.ledger.h2d(qnp, "quantiles")
@@ -3530,6 +3561,7 @@ class DeviceWorker:
                     out = self.guard.call("extract", sh.flush_extract,
                                           *fields, qs, retryable=True)
                     pk = _pack_extract_columns(*out)
+                self._release_replay(swapped)
                 with rec.span("extract.readback", wait=True):
                     packed = np.asarray(pk)
                 self.ledger.count_d2h_shards(
@@ -3540,6 +3572,7 @@ class DeviceWorker:
                 with rec.span("extract.quantiles"):
                     out = self._extract(fields, qs)
                     pk = _pack_extract_columns(*out)
+                self._release_replay(swapped)
                 # ONE device→host transfer for the whole extraction:
                 # eleven per-array np.asarray calls are eleven
                 # synchronous D2H round-trips, and on a link with
@@ -3573,6 +3606,7 @@ class DeviceWorker:
                             "extract", sh.flush_extract, *sub, qs,
                             retryable=True)
                         pk = _pack_extract_columns(*out)
+                    self._release_replay(swapped)
                     with rec.span("extract.readback", wait=True):
                         pk = np.asarray(pk)
                     self.ledger.count_d2h_shards(
@@ -3587,6 +3621,7 @@ class DeviceWorker:
                             for a in fields)
                         out = self._extract(sub, qs)
                         pk = _pack_extract_columns(*out)
+                    self._release_replay(swapped)
                     with rec.span("extract.readback", wait=True):
                         parts.append(
                             self.ledger.d2h(pk, "extract_packed"))
@@ -3649,6 +3684,20 @@ class DeviceWorker:
                     snap.digest_weights = self.ledger.d2h(
                         fields[1], "forward_digests")[:n]
         return fields, s_eff
+
+    def _release_replay(self, swapped: "SwappedEpoch") -> None:
+        """Give the spent replay plane back to its context: free() wipes
+        it and shelves it for the reader's interval after next (with the
+        interpreter given up, so ingest goes on). Called once the
+        flush's extract programs are dispatched and before their
+        readback blocks, so that the wipe runs under the device's fold
+        + extract and not ahead of them; a no-op once done, and for the
+        Python path's pair, which the collector takes."""
+        plane, swapped.spent_replay = swapped.spent_replay, None
+        if plane is not None and plane.free is not None:
+            with self.rec.span("extract.replay_release",
+                               rows=int(plane.vals.shape[0])):
+                _free_staged_planes((plane,))
 
     def _fields_to_host(self, fields) -> tuple:
         """d2h the 14 fold-state arrays in LOGICAL row order for the
@@ -3736,6 +3785,13 @@ class DeviceWorker:
         # dropped; its samples fold from the host replay batch swap()
         # retained — the no-epoch-lost contract for streamed samples
         replay = swapped.micro_replay
+        if replay is not None and replay.free is not None:
+            # a native plane, held as it was detached: compacted here, in
+            # a flush that is degraded already, and released by the same
+            # call (the epoch keeps it until then, for its release())
+            with self.rec.span("extract.replay_compact") as sp:
+                replay = _staged_plane_to_host(replay)
+                sp.attrs["samples"] = int(len(replay.vals))
         swapped.micro_replay = None
         swapped.device_stage = None
         swapped.micro_residual = None
@@ -3801,7 +3857,18 @@ class DeviceWorker:
                          interval_s: float = 10.0) -> FlushSnapshot:
         """Device readback for a swapped epoch. Safe to run outside the
         ingest lock — it touches only the swapped objects (plus immutable
-        worker config), never the live epoch."""
+        worker config), never the live epoch. However it ends (a clean
+        flush, a failover, an exception the server logs and walks past),
+        no native staging plane outlives it: per-flush data is
+        expendable, a plane of a million rows an interval is not."""
+        try:
+            return self._extract_swapped(swapped, quantiles, interval_s)
+        finally:
+            swapped.release()
+
+    def _extract_swapped(self, swapped: "SwappedEpoch",
+                         quantiles: np.ndarray,
+                         interval_s: float) -> FlushSnapshot:
         # one extraction == one transfer window. The reset lives HERE,
         # not in swap(): every ledger-counted transfer (staged-plane
         # uploads, quantile upload, packed readback) happens inside this
@@ -3930,28 +3997,13 @@ class DeviceWorker:
             log.warning(
                 "extract: dropped %d deferred spill samples — swapped "
                 "epoch has no histogram pool to fold them into", n_lost)
-        if swapped.staged_histo:
-            # histo block skipped (no rows): planes can hold nothing
-            # meaningful, but C++ memory must still be released
-            _free_staged_planes(swapped.staged_histo)
-            swapped.staged_histo = None
-        if swapped.reader_planes:
-            # same skip case for reader-shard planes: no canonical histo
-            # rows means no staged histo samples synced, but the C++
-            # plane memory must still be released
-            for st, _m in swapped.reader_planes:
-                if st[4] is not None:
-                    try:
-                        st[4]()
-                    except Exception:  # pragma: no cover
-                        log.exception("reader plane free failed")
-            swapped.reader_planes = None
-        # (a mirror with nowhere to fold is just device garbage — drop it,
-        # along with any never-fed residual and its host replay copy: no
-        # rows means nothing to lose)
+        # (histo block skipped, no rows: a mirror with nowhere to fold is
+        # just device garbage — drop it, along with any never-fed
+        # residual. The planes still on the epoch can hold nothing
+        # meaningful either: their C++ memory goes with the release()
+        # extract_snapshot ends on)
         swapped.device_stage = None
         swapped.micro_residual = None
-        swapped.micro_replay = None
         if swapped.mesh_out is not None:
             mout = swapped.mesh_out
             n = directory.num_histo_rows
