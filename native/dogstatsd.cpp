@@ -589,13 +589,18 @@ struct Ctx {
   // stamped and queued as integers), dir_first_seen (inserted, strings
   // queued). commit_batches counts lock holds of ingest_buffer and
   // commit_lines the metric lines committed inside them; plane_grows
-  // the staging plane's reallocations.
+  // the staging plane's reallocations. A histogram or timer sample that
+  // was committed went into the staging plane (histo_staged) or, its
+  // row's slots full, into the SoA batch for the spill fold
+  // (histo_spilled); a shed sample is neither.
   long long dir_hits = 0;
   long long dir_restamped = 0;
   long long dir_first_seen = 0;
   long long commit_batches = 0;
   long long commit_lines = 0;
   long long plane_grows = 0;
+  long long histo_staged = 0;
+  long long histo_spilled = 0;
 
   // The commit lock as the committers meet it (vn_lock_stats), written
   // under `mu`, always on: one record a lock hold of ingest_buffer (a
@@ -1032,16 +1037,17 @@ bool commit_metric(Ctx* ctx, const Parsed& p, std::string_view joined,
     case KIND_TIMER: {
       int32_t row = series_row(ctx, 0, &ctx->next_histo_row, kind, cls,
                                key_hash, name, type_str, joined);
-      if (!stage_histo_sample(ctx, row, value, sample_rate)) {
+      if (stage_histo_sample(ctx, row, value, sample_rate)) {
+        ++ctx->histo_staged;
+      } else if (ctx->h_rows.size() < kSpillCap) {
         // staging disabled, or this row's plane slots are full: spill
         // into the SoA batch for the direct per-batch device fold
-        if (ctx->h_rows.size() < kSpillCap) {
-          ctx->h_rows.push_back(row);
-          ctx->h_vals.push_back(static_cast<float>(value));
-          ctx->h_wts.push_back(static_cast<float>(1.0 / sample_rate));
-        } else {
-          ++ctx->overload_dropped;
-        }
+        ctx->h_rows.push_back(row);
+        ctx->h_vals.push_back(static_cast<float>(value));
+        ctx->h_wts.push_back(static_cast<float>(1.0 / sample_rate));
+        ++ctx->histo_spilled;
+      } else {
+        ++ctx->overload_dropped;
       }
       break;
     }
@@ -2649,9 +2655,10 @@ void vn_reader_ns(void* p, long long* out) {
   out[1] = ctx->rd_busy_ns.load(std::memory_order_relaxed);
 }
 
-// What the commit path met, lifetime (Ctx::dir_hits and the five after
-// it, in that order): out[0..5] = dir_hits, dir_restamped,
-// dir_first_seen, commit_batches, commit_lines, plane_grows.
+// What the commit path met, lifetime (Ctx::dir_hits and the seven after
+// it, in that order): out[0..7] = dir_hits, dir_restamped,
+// dir_first_seen, commit_batches, commit_lines, plane_grows,
+// histo_staged, histo_spilled.
 void vn_commit_counters(void* p, long long* out) {
   Ctx* ctx = static_cast<Ctx*>(p);
   std::lock_guard<std::recursive_mutex> g(ctx->mu);
@@ -2661,6 +2668,8 @@ void vn_commit_counters(void* p, long long* out) {
   out[3] = ctx->commit_batches;
   out[4] = ctx->commit_lines;
   out[5] = ctx->plane_grows;
+  out[6] = ctx->histo_staged;
+  out[7] = ctx->histo_spilled;
 }
 
 void vn_set_spill_cap(void* p, long long cap) {

@@ -131,9 +131,12 @@ def test_a_flush_of_the_served_path_is_what_the_reference_says(served, seed):
     assert {"micro_fold.feed", "extract.mirror_fold"} <= {s[1] for s in spans}
     (extract,) = [{k: v for k, v in s[6].items() if k != "cpu_s"}
                   for s in spans if s[1] == "flush.extract"]
+    # (since PR 44 also the deepest row's samples and the epoch's ingest
+    # steps: one from the micro-fold's drain, one deferred to the tick)
     assert extract == {"wide_rows": 32, "narrow_rows": 8160,
                        "fold_path": "split", "fold_rows": 8192,
-                       "rows_used": 8192}
+                       "rows_used": 8192, "hot_row_samples": 256,
+                       "spill_steps": 2}
     nothing_shed(srv)
 
 

@@ -250,7 +250,11 @@ NEW = ("reader_parse_ns.line", "reader_lock_wait_ns.line",
 def test_a_new_metric_is_appended_with_its_file_and_lists_no_cells(name):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"][-len(NEW):]] == list(NEW)
+    # appended together and in this order, after what PR 41 left and
+    # before whatever a later PR appends (PR 44: three more)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert at == 27 and names[at:at + len(NEW)] == list(NEW)
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     assert "workloads" not in entry
     spec = stream.load_json("layer_metrics", name)

@@ -810,6 +810,10 @@ class SwappedEpoch:
     # waits for its release, which extract_snapshot puts under the
     # device's fold + extract
     spent_replay: Optional[StagedPlane] = None
+    # spill batches the epoch folded into `histo` while it was live
+    # (DeviceWorker.spill_steps_epoch at the swap); the flush's extract
+    # span adds the deferred ones and carries the sum as `spill_steps`
+    spill_steps: int = 0
 
     def release(self) -> None:
         """Give back every native staging plane this epoch still
@@ -970,6 +974,9 @@ class DeviceWorker:
         self.micro_folds_total = 0
         self.micro_folds_epoch = 0
         self.micro_folds_swapped = 0
+        # spill batches (one ingest step each, _fold_slice_direct) folded
+        # into the live pool this epoch
+        self.spill_steps_epoch = 0
         # what is kept per lifetime series id, one table per native
         # context (see _adopt_pending); deliberately NOT in _reset_epoch —
         # surviving the per-flush directory swap is its whole purpose
@@ -1835,6 +1842,7 @@ class DeviceWorker:
         # [mark[r], count[r]) are staged but not yet mirrored
         self._ustage_mark: Optional[np.ndarray] = None
         self.micro_folds_epoch = 0
+        self.spill_steps_epoch = 0
         self._micro_last_drain = time.monotonic()
         # pending SoA buffers (host)
         self._ph_rows: list[int] = []
@@ -2256,6 +2264,7 @@ class DeviceWorker:
             # quarantined: the host engine's bit-identical ingest twin
             put(he.np_ingest_step(*h.fields(), active, lids, v, w,
                                   compression=self.compression))
+            self.spill_steps_epoch += 1
             return
 
         def step(*batch):
@@ -2279,6 +2288,7 @@ class DeviceWorker:
                 self._ph_vals.extend(vals.tolist())
                 self._ph_wts.extend(wts.tolist())
             return
+        self.spill_steps_epoch += 1
         # bound the async dispatch queue: an un-executed fold holds its
         # input buffers, and a backend slower than the offered load
         # would otherwise queue folds without limit (observed: 2.7GB RSS
@@ -3160,6 +3170,7 @@ class DeviceWorker:
                 spill_histo=spill_histo, device_stage=device_stage,
                 micro_residual=micro_residual, reader_planes=reader_planes,
                 micro_replay=micro_replay,
+                spill_steps=self.spill_steps_epoch,
             )
         # per-tenant lifetime fold, still under the caller's ingest lock
         # and BEFORE the epoch reset zeroes the per-epoch dicts — the
@@ -3959,6 +3970,10 @@ class DeviceWorker:
             if merged_plane is not None:
                 pending.append(merged_plane)
             swapped.staged_histo = None
+            # ingest steps of the epoch: the live folds and the deferred
+            # spill's, one a _FOLD_CHUNK of it
+            self.rec.add("spill_steps", swapped.spill_steps + (
+                0 if spill is None else -(-len(spill[0]) // _FOLD_CHUNK)))
             if isinstance(histo, he.HostHistoState):
                 # the epoch quarantined before swap: the fold state is
                 # already host-resident, so the whole flush runs on the
@@ -3987,6 +4002,10 @@ class DeviceWorker:
                     view_fields, view_s_eff = self._host_complete_extract(
                         snap, swapped, host_fields, s_eff, n, spill,
                         st["spill_off"], pending, quantiles, gov)
+            # the deepest row of the flush: the largest digest count
+            # the extract read back
+            self.rec.add("hot_row_samples", int(
+                np.fmax.reduce(snap.dcount[:n], initial=0.0)))
         elif spill is not None and len(spill[0]):
             # deferred spill with nowhere to fold (ADVICE item 2): the
             # samples are lost either way, but lost-and-counted — the
@@ -4020,10 +4039,12 @@ class DeviceWorker:
             snap.lsum = np.zeros(n, np.float64)
             snap.lweight = np.zeros(n, np.float64)
             snap.lrecip = np.zeros(n, np.float64)
-        with self.rec.span("extract.sets"):
+        with self.rec.span("extract.sets") as sets_span:
             if staged_sets is not None and directory.num_set_rows:
                 n = directory.num_set_rows
                 snap.set_estimates = staged_sets.estimates(n)
+                sets_span.attrs.update(
+                    sets=n, sparse_entries=staged_sets.sparse_entries)
                 # register materialization is [n, 2^p] host bytes — only pay
                 # it where forwarding can read it (locals forward mixed sets;
                 # a global is a terminal aggregator for them)

@@ -539,7 +539,8 @@ class NativeIngest:
         return int(out[0]), int(out[1])
 
     COMMIT_COUNTERS = ("dir_hits", "dir_restamped", "dir_first_seen",
-                       "commit_batches", "commit_lines", "plane_grows")
+                       "commit_batches", "commit_lines", "plane_grows",
+                       "histo_staged", "histo_spilled")
 
     def commit_counters(self) -> dict:
         """What this context's commit path met, lifetime totals: a
@@ -547,7 +548,10 @@ class NativeIngest:
         this interval (dir_hits), known but not yet written this
         interval (dir_restamped) or never seen (dir_first_seen);
         commit_batches lock holds of the chunk commit took commit_lines
-        lines; plane_grows reallocations of the staging plane."""
+        lines; plane_grows reallocations of the staging plane; a
+        committed histogram or timer sample went into the staging plane
+        (histo_staged) or past its depth to the spill fold
+        (histo_spilled)."""
         out = (ctypes.c_longlong * len(self.COMMIT_COUNTERS))()
         self._lib.vn_commit_counters(self._ctx, out)
         return dict(zip(self.COMMIT_COUNTERS, map(int, out)))
