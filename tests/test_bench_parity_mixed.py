@@ -176,6 +176,10 @@ def test_two_intervals_of_zipf_traffic_are_what_the_reference_says(served,
         sets = attrs_of(srv, "extract.sets")
         assert sets["sets"] == int((truth.set_distinct > 0).sum())
         assert 0 < sets["sparse_entries"] <= int(truth.set_distinct.sum())
+        assert attrs_of(srv, "extract.sets.compact")["pending"] >= 0
+        estimate = attrs_of(srv, "extract.sets.estimate")
+        assert (estimate["sparse_rows"], estimate["dense_rows"]) == (
+            sets["sets"], 0)
         seen.append(attrs_of(srv, "flush.begin"))
     # the second interval's live series are not the first's: those it
     # shares were re-stamped, the rest first seen; and the counters that

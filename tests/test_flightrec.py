@@ -272,6 +272,16 @@ def test_a_served_flush_leaves_its_spans_and_the_old_phase_keys():
                      "extract.guard_tick"):
             assert by[name][0][4] == extract, name
         assert _less_cpu(by["extract.readback"][0][6]) == {"wait": True}
+        # what extract.sets is made of: the tick's compaction of what the
+        # drains left pending, then every row's estimate (one set, sparse)
+        (sets,) = by["extract.sets"]
+        (compact,), (estimate,) = (by["extract.sets.compact"],
+                                   by["extract.sets.estimate"])
+        assert compact[4] == estimate[4] == sets[0]
+        assert 0 < compact[6]["pending"] <= 50
+        assert _less_cpu(estimate[6]) == {"sparse_rows": 1, "dense_rows": 0}
+        assert sets[2] <= compact[2] <= compact[3] <= estimate[2] \
+            <= estimate[3] <= sets[3]
         (sinks,) = by["emit.sinks"]
         assert sinks[4] == by["flush.emit"][0][0]
         assert [(s[4], s[6]["sink"]) for s in by["emit.sink"]] == [

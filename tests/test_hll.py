@@ -227,3 +227,157 @@ def test_staged_store_memory_stays_sparse_for_small_sets():
     est = store.estimates(n_series)
     # every series ~30 distinct members
     assert np.all(np.abs(est - per) / per < 0.35)
+
+
+def _estimates_row_at_a_time(store, num_rows):
+    """The estimator one row at a time in float64: the loop
+    ``StagedSetStore.estimates`` was until PR 45, kept as its reference.
+    Reads the store's tiers as a call of ``estimates`` leaves them."""
+    from veneur_tpu.ops import host_engine as he
+
+    store._apply_imports()
+    store._compact_no_promote()
+    m = float(store.m)
+    alpha = 0.7213 / (1.0 + 1.079 / m)
+    out = np.zeros(num_rows, np.float32)
+    rows = store._ckeys // store.m
+    inv = np.power(2.0, -store._crank.astype(np.float64))
+    urows, starts = np.unique(rows, return_index=True)
+    ends = np.r_[starts[1:], rows.size]
+    csum = np.r_[0.0, np.cumsum(inv)]
+    for r, a, b in zip(urows, starts, ends):
+        if r >= num_rows:
+            continue
+        zeros = m - (b - a)
+        inv_sum = zeros + (csum[b] - csum[a])
+        raw = alpha * m * m / inv_sum
+        if raw <= 2.5 * m and zeros > 0:
+            out[r] = m * np.log(m / zeros)
+        else:
+            out[r] = raw
+    if store._slot_of_row:
+        dense = (he.np_hll_estimate_exact(store._dense, store.precision)
+                 if store.host_mode
+                 else np.asarray(hll.estimate(store._dense, store.precision)))
+        for r, s in store._slot_of_row.items():
+            if r < num_rows:
+                out[r] = dense[s]
+    return out
+
+
+def _random_members(store, rng, rows):
+    hashes = rng.integers(0, 2**64, len(rows), dtype=np.uint64)
+    idx, rank = hll.split_hashes(hashes, store.precision)
+    store.insert(np.asarray(rows, np.int32), idx, rank)
+
+
+def _fill_empty(store, rng):
+    return 16
+
+
+def _fill_one_row(store, rng):
+    _random_members(store, rng, np.full(300, 2))
+    return 4
+
+
+def _fill_rows_past_num_rows(store, rng):
+    # rows 0..9 live; the caller asks for 6: row 5 is the last it sees
+    _random_members(store, rng, np.repeat(np.arange(10), 40))
+    return 6
+
+
+def _fill_zipf_with_pending(store, rng):
+    # 2,000 rows under Zipf keys, drained in batches as the ingest side
+    # does; compact_every 4,096 leaves the tail uncompacted at the call
+    for _ in range(27):
+        _random_members(store, rng, (rng.zipf(1.1, 1000) - 1) % 2000)
+    assert store._pend > 0 and store._ckeys.size > 0
+    return 2000
+
+
+def _fill_linear_beside_raw(store, rng):
+    _random_members(store, rng, np.full(500, 0))
+    _random_members(store, rng, np.full(120_000, 1))
+    assert store.dense_rows == 0
+    return 2
+
+
+def _fill_no_zero_register(store, rng):
+    # every register of row 0 hit at rank 1: raw = 1.44 m sits in the
+    # linear-counting range, where there is no zero register to count
+    m = store.m
+    store.insert(np.zeros(m, np.int32), np.arange(m), np.ones(m, np.int8))
+    _random_members(store, rng, np.full(50, 1))
+    return 2
+
+
+def _fill_sparse_beside_dense(store, rng):
+    _random_members(store, rng, np.repeat(np.arange(6), 60))
+    _random_members(store, rng, np.full(5000, 3))  # promoted
+    regs = np.zeros(store.m, np.int8)
+    regs[rng.integers(0, store.m, 900)] = 3
+    store.import_dense(7, regs)  # dense by nature
+    store.import_dense(9, regs)  # past num_rows
+    _random_members(store, rng, np.repeat(np.arange(6), 5))
+    return 8
+
+
+@pytest.mark.parametrize("fill,kw", [
+    (_fill_empty, {}),
+    (_fill_one_row, {}),
+    (_fill_rows_past_num_rows, {}),
+    (_fill_zipf_with_pending, {"compact_every": 4096}),
+    (_fill_linear_beside_raw, {"promote_entries": 1 << 20}),
+    (_fill_no_zero_register, {"promote_entries": 1 << 20}),
+    (_fill_sparse_beside_dense, {"promote_entries": 128,
+                                 "compact_every": 512}),
+    (_fill_sparse_beside_dense, {"promote_entries": 128,
+                                 "compact_every": 512, "host": True}),
+], ids=["empty", "one_row", "rows_past_num_rows", "zipf_with_pending",
+        "linear_beside_raw", "no_zero_register", "sparse_beside_dense",
+        "host"])
+def test_staged_store_estimates_are_the_row_at_a_time_loops(fill, kw):
+    from veneur_tpu.ops.staged_sets import StagedSetStore
+
+    store = StagedSetStore(**kw)
+    num_rows = fill(store, np.random.default_rng(45))
+    with np.errstate(all="raise"):
+        got = store.estimates(num_rows)
+    want = _estimates_row_at_a_time(store, num_rows)
+    assert got.dtype == np.float32 and got.shape == (num_rows,)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, want)
+    if fill is _fill_linear_beside_raw:
+        assert got[0] < 2.5 * store.m < got[1]
+    if fill is _fill_no_zero_register:
+        assert got[0] == np.float32(
+            0.7213 / (1.0 + 1.079 / store.m) * store.m * 2)
+    if fill is _fill_sparse_beside_dense:
+        assert store.dense_rows == 3 and store.host_mode == bool(
+            kw.get("host"))
+        assert got[3] > 4000 and got[7] > 800 and (got[:3] > 50).all()
+
+
+def test_staged_store_registers_dense_beside_sparse():
+    from veneur_tpu.ops.staged_sets import StagedSetStore
+
+    rng = np.random.default_rng(45)
+    store = StagedSetStore(promote_entries=128, compact_every=512)
+    want = np.zeros((10, store.m), np.int8)
+    rows = np.r_[np.repeat(np.arange(6), 60), np.full(5000, 3)]
+    hashes = rng.integers(0, 2**64, rows.size, dtype=np.uint64)
+    idx, rank = hll.split_hashes(hashes)
+    store.insert(rows.astype(np.int32), idx, rank)
+    np.maximum.at(want, (rows, idx), rank)
+    regs = np.zeros(store.m, np.int8)
+    regs[rng.integers(0, store.m, 900)] = 3
+    for row in (7, 9):
+        store.import_dense(row, regs)
+        want[row] = regs
+    assert store.dense_rows == 1 and store.sparse_entries > 0
+    # a dense beside a sparse row, a row that is neither, and the rows
+    # past num_rows (9 is dense, 5 is sparse when 5 are asked for) left out
+    np.testing.assert_array_equal(store.registers(8), want[:8])
+    assert store.dense_rows == 3
+    np.testing.assert_array_equal(store.registers(5), want[:5])
+    np.testing.assert_array_equal(store.registers(10), want)

@@ -130,14 +130,19 @@ def _nbytes(tree) -> int:
     return int(getattr(tree, "nbytes", 0))
 
 
-def wait_span(guard, name: str):
-    """A ``wait: true`` span of the guard's record around a call that
-    blocks on the device; a no-op where there is no guard or no record
-    (the mirror and the set store are also built without one)."""
+def guard_span(guard, name: str, **attrs):
+    """A span of the guard's record; a no-op (yielding None) where there
+    is no guard or no record (the mirror and the set store are also
+    built without one)."""
     rec = getattr(guard, "rec", None)
     if rec is None:
         return contextlib.nullcontext()
-    return rec.span(name, wait=True)
+    return rec.span(name, **attrs)
+
+
+def wait_span(guard, name: str):
+    """A ``wait: true`` span around a call that blocks on the device."""
+    return guard_span(guard, name, wait=True)
 
 
 class DeviceGuard:

@@ -37,7 +37,8 @@ import numpy as np
 
 from veneur_tpu.ops import hll as hll_ops
 from veneur_tpu.ops import host_engine as he
-from veneur_tpu.ops.device_guard import DeviceFaultError, wait_span
+from veneur_tpu.ops.device_guard import (DeviceFaultError, guard_span,
+                                         wait_span)
 
 
 class StagedSetStore:
@@ -332,55 +333,71 @@ class StagedSetStore:
         """Cardinality estimate per directory set row [num_rows] (f32).
 
         Sparse rows evaluate the same estimator as the device kernel
-        (harmonic mean + linear counting) over their distinct registers;
-        dense rows read the device result.
+        (harmonic mean + linear counting) over their distinct registers,
+        every row at once: the sorted keys group a row's registers into
+        one run, and a run's sum is a difference of one cumsum. Dense
+        rows read the device result.
         """
         self._apply_imports()
-        self._compact_no_promote()
+        with guard_span(self._guard, "extract.sets.compact",
+                        pending=self._pend):
+            self._compact_no_promote()
+        with guard_span(self._guard, "extract.sets.estimate",
+                        dense_rows=len(self._slot_of_row)) as span:
+            out = np.zeros(num_rows, np.float32)
+            urows, est = self._sparse_estimates()
+            if span is not None:
+                span.attrs["sparse_rows"] = int(urows.size)
+            keep = urows < num_rows
+            out[urows[keep]] = est[keep]
+            if self._slot_of_row and self._dense is not None:
+                drows, slots = self._dense_rows_below(num_rows)
+                out[drows] = self._dense_estimates()[slots]
+        return out
+
+    def _dense_estimates(self) -> np.ndarray:
+        """The dense tier's estimates by logical slot: the device's, or
+        on a fault (and from then on) the host twin's."""
+        if not self._host:
+            try:
+                sh = self._shard
+                est = self._dev_call(
+                    hll_ops.estimate if sh is None else sh.hll_estimate,
+                    self._dense, self.precision, retryable=True)
+                with wait_span(self._guard, "sets.readback"):
+                    est = np.asarray(est)
+                return (est if sh is None
+                        else est[sh.perm_l2p(self._dense.shape[0])])
+            except DeviceFaultError:
+                self.to_host()
+        # host mode: the bitwise f32 twin of the device estimator
+        # (ops/host_engine parity contract)
+        return he.np_hll_estimate_exact(self._dense, self.precision)
+
+    def _sparse_estimates(self):
+        """(rows, float64 estimates) of the compacted sparse tier."""
+        rows = self._ckeys // self.m
+        if not rows.size:
+            return rows, np.empty(0)
+        # a run a row: where the sorted rows change
+        edges = np.flatnonzero(rows[1:] != rows[:-1]) + 1
+        starts, ends = np.r_[0, edges], np.r_[edges, rows.size]
         m = float(self.m)
         alpha = 0.7213 / (1.0 + 1.079 / m)
-        out = np.zeros(num_rows, np.float32)
-        rows = self._ckeys // self.m
-        inv = np.power(2.0, -self._crank.astype(np.float64))
-        # segmented sums per row over the sorted keys
-        urows, starts = np.unique(rows, return_index=True)
-        ends = np.r_[starts[1:], rows.size]
-        csum = np.r_[0.0, np.cumsum(inv)]
-        for r, a, b in zip(urows, starts, ends):
-            if r >= num_rows:
-                continue
-            d = b - a  # distinct registers
-            zeros = m - d
-            inv_sum = zeros + (csum[b] - csum[a])
-            raw = alpha * m * m / inv_sum
-            if raw <= 2.5 * m and zeros > 0:
-                out[r] = m * np.log(m / zeros)
-            else:
-                out[r] = raw
-        if self._slot_of_row and self._dense is not None:
-            dense_est = None
-            if not self._host:
-                try:
-                    sh = self._shard
-                    est = self._dev_call(
-                        hll_ops.estimate if sh is None else sh.hll_estimate,
-                        self._dense, self.precision, retryable=True)
-                    with wait_span(self._guard, "sets.readback"):
-                        dense_est = np.asarray(est)
-                    if sh is not None:
-                        dense_est = dense_est[
-                            sh.perm_l2p(self._dense.shape[0])]
-                except DeviceFaultError:
-                    self.to_host()
-            if dense_est is None:
-                # host mode: the bitwise f32 twin of the device
-                # estimator (ops/host_engine parity contract)
-                dense_est = he.np_hll_estimate_exact(
-                    self._dense, self.precision)
-            for r, s in self._slot_of_row.items():
-                if r < num_rows:
-                    out[r] = dense_est[s]
-        return out
+        # 2^-rank, exact
+        csum = np.r_[0.0, np.cumsum(
+            np.ldexp(1.0, -self._crank.astype(np.int32)))]
+        zeros = m - (ends - starts)  # registers the row never hit
+        inv_sum = zeros + (csum[ends] - csum[starts])
+        est = alpha * m * m / inv_sum
+        linear = (est <= 2.5 * m) & (zeros > 0)
+        est[linear] = m * np.log(m / zeros[linear])
+        return rows[starts], est
+
+    def _dense_rows_below(self, num_rows: int):
+        """(rows, slots) of the promoted rows under ``num_rows``."""
+        rows = np.flatnonzero(self._slot_lut[:num_rows] >= 0)
+        return rows, self._slot_lut[rows]
 
     def registers(self, num_rows: int) -> np.ndarray:
         """Materialize dense int8 register rows [num_rows, m] (the
@@ -402,9 +419,8 @@ class StagedSetStore:
                 if self._shard is not None:
                     dense_np = dense_np[
                         self._shard.perm_l2p(self._dense.shape[0])]
-            for r, s in self._slot_of_row.items():
-                if r < num_rows:
-                    out[r] = dense_np[s]
+            drows, slots = self._dense_rows_below(num_rows)
+            out[drows] = dense_np[slots]
         return out
 
     @property
