@@ -269,7 +269,8 @@ def test_benchmark_json_agrees_with_the_configurations_file():
     assert own["name"] == CONFIG
     (entry,) = [c for c in bench["configs"] if c["name"] == CONFIG]
     (cell,) = [w for w in bench["workloads"] if w["config"] == CONFIG]
-    assert entry is bench["configs"][-1] and cell is bench["workloads"][-1]
+    # (third of each list: PR 46 appended `local-uniques` after them)
+    assert entry is bench["configs"][2] and cell is bench["workloads"][2]
     assert entry["file"] == f"bench/configs/{CONFIG}.json"
     assert entry["reduced"] == own["reduced"] == []
     assert own["reduced_why"] == {}
@@ -292,7 +293,8 @@ def test_benchmark_json_agrees_with_the_configurations_file():
     # every cell, by a reader that was there
     assert not [m["name"] for m in bench["per_layer"] + bench["end_to_end"]
                 if CELL in m.get("workloads", [])]
-    new = bench["per_layer"][-3:]
+    at = [m["name"] for m in bench["per_layer"]].index("spill_share_pct")
+    new = bench["per_layer"][at:at + 3]
     assert [m["name"] for m in new] == [
         "spill_share_pct", "spill_tick_ms.flush", "sets_ms.flush"]
     args = {

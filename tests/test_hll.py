@@ -255,13 +255,12 @@ def _estimates_row_at_a_time(store, num_rows):
             out[r] = m * np.log(m / zeros)
         else:
             out[r] = raw
-    if store._slot_of_row:
+    if store.dense_rows:
         dense = (he.np_hll_estimate_exact(store._dense, store.precision)
                  if store.host_mode
                  else np.asarray(hll.estimate(store._dense, store.precision)))
-        for r, s in store._slot_of_row.items():
-            if r < num_rows:
-                out[r] = dense[s]
+        for r, s in zip(*store._dense_rows_below(num_rows)):
+            out[r] = dense[s]
     return out
 
 

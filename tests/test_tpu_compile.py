@@ -160,3 +160,34 @@ def test_dense_hll_insert_and_estimate(one_chip):
     _fits("hll.insert_batch", ins)
     est = hll.estimate.lower(regs, precision=14).compile()
     _fits("hll.estimate", est)
+
+
+@pytest.mark.parametrize("length", [1 << 10, 1 << 12, 1 << 14])
+def test_staged_set_insert_at_each_ladder_length(one_chip, length):
+    """The staged store's dense tier as a cell with a few dozen promoted
+    rows runs it (PR 46): every insert is one of three lengths into the
+    least pool, 64 rows; a program each, none of them refused."""
+    from veneur_tpu.ops import hll
+    from veneur_tpu.ops import staged_sets as st
+
+    assert length in st.INSERT_LENGTHS and len(st.INSERT_LENGTHS) == 3
+    regs = _shape(one_chip, (st.POOL_MIN_ROWS, hll.num_registers(14)),
+                  jnp.int8)
+    idx = _shape(one_chip, (length,), jnp.int32)
+    ins = hll.insert_batch.lower(
+        regs, idx, idx, _shape(one_chip, (length,), jnp.int8)).compile()
+    _fits(f"hll.insert_batch[{length}]", ins)
+
+
+def test_staged_set_pool_growth_and_estimate(one_chip):
+    from veneur_tpu.ops import hll
+    from veneur_tpu.ops import staged_sets as st
+
+    rows = st.POOL_MIN_ROWS
+    regs = _shape(one_chip, (rows, hll.num_registers(14)), jnp.int8)
+    grown = st._grow_pool.lower(regs, rows=2 * rows).compile()
+    _fits("staged_sets._grow_pool", grown)
+    assert grown.memory_analysis().output_size_in_bytes >= 2 * rows * (
+        hll.num_registers(14))
+    _fits("hll.estimate[pool]",
+          hll.estimate.lower(regs, precision=14).compile())

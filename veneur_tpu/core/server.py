@@ -2078,6 +2078,10 @@ class Server:
             for w in self.workers:
                 for k, v in (w.commit_counters() or {}).items():
                     cur.attrs[k] = cur.attrs.get(k, 0) + v
+        # where the set entries went, lifetime too (worker.set_counters)
+        for w in self.workers:
+            for k, v in (w.set_counters() or {}).items():
+                self.rec.add(k, v)
         return qs, swapped, span_counts
 
     def _reader_ns(self):

@@ -922,6 +922,14 @@ class DeviceWorker:
         # pool rows -> the largest spill row bucket folded at that pool
         # size (_warm_spill_rows)
         self._spill_rows_warm: dict[int, int] = {}
+        # the staged set store's, across epochs: dense pool rows -> the
+        # longest insert length run there (StagedSetStore._warm_inserts),
+        # and set entries routed to a dense row / into the sparse tier
+        # by the epochs closed so far (set_counters)
+        self._set_inserts_warm: dict[int, int] = {}
+        self._sets_dense_closed = 0
+        self._sets_sparse_closed = 0
+        self._staged_sets = None
         # pool rows -> the row counts flushes have folded and extracted
         # at, at that pool size (_flush_rows)
         self._flush_rows_had: dict[int, set[int]] = {}
@@ -1435,6 +1443,17 @@ class DeviceWorker:
         per_ctx = [ctx.commit_counters() for ctx in self._all_ctxs()]
         return {k: sum(c[k] for c in per_ctx) for k in per_ctx[0]}
 
+    def set_counters(self) -> Optional[dict]:
+        """Set entries the staged store routed to a dense device row
+        (``sets_dense``) and into the sparse host tier (``sets_sparse``),
+        lifetime: the closed epochs' and the live one's. None with the
+        all-dense store."""
+        live = self._staged_sets
+        if live is None:
+            return None
+        return {"sets_dense": self._sets_dense_closed + live.dense_entries,
+                "sets_sparse": self._sets_sparse_closed + live.sparse_routed}
+
     def reader_lock_ns(self) -> Optional[tuple]:
         """(ns the committers waited for a native context's lock, ns
         they held it committing), lifetime, summed over this worker's
@@ -1825,12 +1844,21 @@ class DeviceWorker:
         # staged (sparse-host / dense-device) set store — the scalable
         # default; tpu_set_store: dense keeps the all-dense pool
         if self.set_store == "staged":
-            from veneur_tpu.ops.staged_sets import StagedSetStore
+            from veneur_tpu.ops.staged_sets import (StagedSetStore,
+                                                    pool_rows_for)
 
-            self._staged_sets = StagedSetStore(self.hll_precision,
-                                               shard=self._shard,
-                                               guard=self.guard,
-                                               host=self._host_live)
+            # the epoch just closed says what this one's dense pool
+            # starts at: the power of two that held its dense rows, and
+            # nothing if it had none
+            last = self._staged_sets
+            if last is not None:
+                self._sets_dense_closed += last.dense_entries
+                self._sets_sparse_closed += last.sparse_routed
+            self._staged_sets = StagedSetStore(
+                self.hll_precision, shard=self._shard, guard=self.guard,
+                host=self._host_live, warm=self._set_inserts_warm,
+                pool_rows=(pool_rows_for(last.dense_rows)
+                           if last is not None and last.dense_rows else 0))
         else:
             self._staged_sets = None
         # host raw-sample staging planes (see _device_histo_step); created

@@ -194,8 +194,9 @@ class DeviceGuard:
     # -- the guarded call -------------------------------------------------
 
     def call(self, op: str, fn: Callable, *args, retryable: bool = False,
-             **kwargs):
-        """Run one device operation under the guard.
+             attrs: Optional[dict] = None, **kwargs):
+        """Run one device operation under the guard. ``attrs`` go onto
+        the ``dispatch`` span beside ``op``.
 
         retryable=True only at call sites whose operands are NOT donated
         (extract, set inserts, query evals, allocation pre-flights): a
@@ -208,7 +209,7 @@ class DeviceGuard:
         rec = self.rec
         if rec is None:
             return self._call(op, fn, args, retryable, kwargs)
-        with rec.span("dispatch", op=op):
+        with rec.span("dispatch", op=op, **(attrs or {})):
             out = self._call(op, fn, args, retryable, kwargs)
             if op in _BYTES_OPS:
                 # operands read plus results written: the least HBM

@@ -5,10 +5,12 @@ Semantics spec: the reference's vendored axiomhq/hyperloglog sketch
 samplers/samplers.go:367-463). Re-designed for SIMD execution:
 
 * A pool of S sketches is one dense `int8[S, 2^p]` register array (p=14 ⇒
-  16384 = 128×128 registers per row, one TPU tile-aligned panel). The
-  reference's sparse representation is intentionally dropped — dense rows
-  are what makes insert a single scatter and merge a single elementwise max
-  (documented deviation; memory is 2^p bytes/series, configurable via p).
+  16384 = 128×128 registers per row, one TPU tile-aligned panel): dense
+  rows are what makes insert a single scatter and merge a single
+  elementwise max, at 2^p bytes a series. The reference's sparse
+  representation lives one level up, in ops/staged_sets.py: small sets
+  stay on the host as (row, register, rank) triples and only a set past
+  2^p/8 distinct registers takes a row of this pool.
 
 * Values are hashed host-side (strings never touch the device); the 64-bit
   hash splits into a p-bit register index and the leading-zero rank of the
